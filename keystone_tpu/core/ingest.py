@@ -39,7 +39,8 @@ Resilience invariants preserved from the eager loaders:
   decode pool; producer exceptions surface on the consumer's next
   ``__next__``.  ``join()`` lets tests assert every thread exited.
 
-Two attacks on the decode wall itself (BENCH_r05: threaded decode speedup
+Two attacks on the decode wall itself (bench round r05, 2026-07-30, record
+removed in PR 21: threaded decode speedup
 1.04x — the pool is GIL-bound — while device featurize runs 15-17k
 images/sec):
 
@@ -191,7 +192,8 @@ def _env_int(name: str, default: int, minimum: int) -> int:
 
 #: Decode backends a stream can run: GIL-bound thread pool (PIL/native
 #: decode release the GIL, but entropy decode + colorspace still serialize
-#: badly — BENCH_r05 measured 1.04x threaded "speedup") or true parallel
+#: badly — bench round r05 (2026-07-30; record removed in PR 21) measured
+#: 1.04x threaded "speedup") or true parallel
 #: spawned worker processes returning pixels via shared memory.
 DECODE_BACKENDS = ("thread", "process")
 

@@ -701,8 +701,13 @@ def is_oom_error(e: BaseException) -> bool:
         return False
     if not isinstance(e, (RuntimeError, MemoryError)):
         return False
-    msg = str(e)
-    return isinstance(e, MemoryError) or any(m in msg for m in _OOM_MARKERS)
+    return isinstance(e, MemoryError) or is_oom_text(str(e))
+
+
+def is_oom_text(msg: str) -> bool:
+    """True when ``msg`` (an exception's text, or ``MemoryPlan.error``)
+    carries XLA's memory-exhaustion grammar."""
+    return any(m in msg for m in _OOM_MARKERS)
 
 
 def free_buffers(*arrays) -> None:

@@ -414,20 +414,33 @@ def release_caches(pipeline: Pipeline) -> None:
 
 # -- the placement cost model (shared with core.autoshard) --------------------
 
-#: Per-chip bf16/f32 peak FLOP/s and HBM GB/s by device kind — the roofline
-#: rates the analytic placement prior divides by.  Unknown kinds (the CPU
-#: test platform included) fall back to :data:`_DEFAULT_RATES`; only the
-#: RELATIVE ranking of candidate plans matters to the search, and the
-#: learned calibration (core.autoshard's plan-outcome log) absorbs the
-#: absolute error across runs.
+#: Per-chip peaks by ``device_kind`` — THE peaks table: ``core.profiler``
+#: and ``bench.py`` read this one and keep none of their own.
+#: ``peak_flops`` is the bf16 MXU peak (f32 matmuls run bf16 passes under
+#: default precision, so it is the honest MFU denominator); ``hbm_gbps``
+#: is GB/s (1e9).  Source: Google Cloud TPU documentation, per-chip
+#: specifications — "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM; the other
+#: generations from the same pages.  JAX reports the v5e as ``TPU v5
+#: lite`` (chip run, PR 21); for the chips nobody here could ask, both
+#: spellings the two earlier tables used are kept.  ``ici_gbps`` is the
+#: placement prior's per-link figure, not a published peak.
+_V5E = {"peak_flops": 197e12, "hbm_gbps": 819.0, "ici_gbps": 50.0}
+_V5P = {"peak_flops": 459e12, "hbm_gbps": 2765.0, "ici_gbps": 100.0}
+_V6E = {"peak_flops": 918e12, "hbm_gbps": 1640.0, "ici_gbps": 100.0}
 DEVICE_RATES: dict[str, dict] = {
     "TPU v4": {"peak_flops": 275e12, "hbm_gbps": 1228.0, "ici_gbps": 50.0},
-    "TPU v5e": {"peak_flops": 197e12, "hbm_gbps": 819.0, "ici_gbps": 50.0},
-    "TPU v5 lite": {"peak_flops": 197e12, "hbm_gbps": 819.0, "ici_gbps": 50.0},
-    "TPU v5p": {"peak_flops": 459e12, "hbm_gbps": 2765.0, "ici_gbps": 100.0},
-    "TPU v6e": {"peak_flops": 918e12, "hbm_gbps": 1640.0, "ici_gbps": 100.0},
+    "TPU v5 lite": _V5E,
+    "TPU v5e": _V5E,
+    "TPU v5": _V5P,
+    "TPU v5p": _V5P,
+    "TPU v6 lite": _V6E,
+    "TPU v6e": _V6E,
 }
 
+#: Relative rates for ranking candidate placements on a device that is not
+#: in :data:`DEVICE_RATES` (the CPU test platform): only the ORDER of the
+#: predictions matters there.  Never a denominator for a utilization —
+#: ``core.profiler`` reports ``mfu: None`` for such a device.
 _DEFAULT_RATES = {"peak_flops": 50e9, "hbm_gbps": 20.0, "ici_gbps": 5.0}
 
 
@@ -742,7 +755,8 @@ class IngestAutotuner:
 
     #: Threaded decode scaling below this after a width doubling reads as
     #: "the GIL is the wall, not core count" — the knob that helps is the
-    #: BACKEND, not more width (ISSUE 7: BENCH_r05 measured 1.04x).
+    #: BACKEND, not more width (ISSUE 7: bench round r05, 2026-07-30,
+    #: record removed in PR 21, measured 1.04x).
     SCALING_FLOOR = 1.3
 
     def __init__(

@@ -48,6 +48,7 @@ p99 overhead against a <= 5% bar.
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import math
 import os
@@ -239,25 +240,20 @@ def attributed_call(label: str, shape_key, fn, *args):
 
 # -- device rates --------------------------------------------------------------
 
-_rates_cache: dict | None = None
+@functools.lru_cache(maxsize=1)
+def device_rates() -> dict | None:
+    """``{"peak_flops", "hbm_gbps"}`` of the live device from
+    ``optimize.DEVICE_RATES`` (read once per process), or ``None`` when its
+    ``device_kind`` is not in the table — MFU and roofline position are
+    then reported as ``None``, never against a made-up peak."""
+    import jax
 
+    from . import optimize as kopt
 
-def device_rates() -> dict:
-    """``{"peak_flops", "hbm_gbps"}`` for the live platform — the
-    ``optimize.CostModel`` rate tables, read once per process.  Unknown
-    device kinds get the conservative defaults; only MFU's absolute scale
-    depends on them, and cross-round comparisons (bench_diff) compare
-    like against like."""
-    global _rates_cache
-    if _rates_cache is None:
-        from . import optimize as kopt
-
-        model = kopt.CostModel.for_devices()
-        _rates_cache = {
-            "peak_flops": model.peak_flops,
-            "hbm_gbps": model.hbm_gbps,
-        }
-    return _rates_cache
+    row = kopt.DEVICE_RATES.get(jax.devices()[0].device_kind)
+    return row and {
+        "peak_flops": row["peak_flops"], "hbm_gbps": row["hbm_gbps"],
+    }
 
 
 # -- the program ledger --------------------------------------------------------
@@ -293,7 +289,9 @@ class _ProgramRow:
         intensity = (
             self.flops / self.bytes_accessed if self.bytes_accessed else None
         )
-        ridge = rates["peak_flops"] / (rates["hbm_gbps"] * 1e9)
+        ridge = (
+            rates["peak_flops"] / (rates["hbm_gbps"] * 1e9) if rates else None
+        )
         out = {
             "runs": self.runs,
             "wall_seconds": round(wall, 6),
@@ -301,19 +299,19 @@ class _ProgramRow:
             "bytes_accessed": self.bytes_accessed or None,
             "mfu": (
                 round(flops_rate / rates["peak_flops"], 6)
-                if flops_rate
+                if flops_rate and rates
                 else None
             ),
             "achieved_hbm_gbps": round(gbps, 3) if gbps else None,
             "intensity_flop_per_byte": (
                 round(intensity, 3) if intensity else None
             ),
-            "ridge_flop_per_byte": round(ridge, 3),
+            "ridge_flop_per_byte": round(ridge, 3) if ridge else None,
             # Roofline position: below the ridge intensity the program's
             # ceiling is HBM bandwidth, above it the MXU peak.
             "bound": (
                 ("memory" if intensity < ridge else "compute")
-                if intensity
+                if intensity and ridge
                 else None
             ),
             "last_wall_seconds": round(self.last_wall_seconds, 6),
@@ -358,7 +356,9 @@ def record_program(
     rates = device_rates()
     wall = max(float(wall_seconds), 0.0)
     mfu = (
-        flops / wall / rates["peak_flops"] if flops and wall > 0 else None
+        flops / wall / rates["peak_flops"]
+        if flops and wall > 0 and rates
+        else None
     )
     gbps = (
         bytes_accessed / wall / 1e9 if bytes_accessed and wall > 0 else None
@@ -407,7 +407,7 @@ def ledger_record() -> dict:
     """The bench ``profiler`` section: the ledger plus the device rates
     the MFU figures were computed against and the flops-audit table."""
     return {
-        "rates": dict(device_rates()),
+        "rates": device_rates(),
         "programs": ledger(),
         "flops_audits": flops_audits(),
         "captures": capture_paths(),

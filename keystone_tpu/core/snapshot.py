@@ -5,7 +5,8 @@ tf.data's ``snapshot`` transformation (PAPERS.md) is the model: the first
 pass over an input pipeline materializes its output to disk, and later
 epochs — or later *runs* — read the materialization instead of re-running
 the expensive upstream stages.  Here the expensive upstream stage is JPEG
-decode (BENCH_r05: ~900 images/sec decode vs 15-17k images/sec device
+decode (bench round r05, 2026-07-30, record removed in PR 21: ~900
+images/sec decode vs 15-17k images/sec device
 featurize), so a snapshot turns the decode wall into a sequential-read
 problem.
 

@@ -1,7 +1,8 @@
 """ctypes binding for the native C++ JPEG decoder (native/ingest.cpp).
 
 The shared library is built lazily with the system toolchain on first use
-(g++ + libjpeg, both baked into the image) and cached next to the source.
+(g++ + libjpeg, both baked into the image) under a name derived from its
+source (``utils.platform.build_native_library``).
 ctypes releases the GIL for the duration of each decode call, so the
 thread-pool loader in image_loaders.py parallelizes across host cores with
 no Python image library on the hot path.  ``KEYSTONE_NATIVE_DECODE=0``
@@ -14,43 +15,21 @@ from __future__ import annotations
 import ctypes
 import logging
 import os
-import subprocess
 import threading
 
 import numpy as np
 
+from ..utils.platform import build_native_library
+
 _logger = logging.getLogger(__name__)
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "native")
-_SRC = os.path.join(_NATIVE_DIR, "ingest.cpp")
-_LIB = os.path.join(_NATIVE_DIR, "libkstingest.so")
+_SRC = os.path.join(
+    os.path.dirname(__file__), "..", "native", "ingest.cpp"
+)
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
-
-
-def _build() -> bool:
-    from ..core.resilience import retry
-
-    cmd = [
-        "g++", "-O2", "-shared", "-fPIC", _SRC, "-o", _LIB, "-ljpeg",
-    ]
-
-    # The one-time g++ invocation is plain file IO + a subprocess — fork
-    # failures and filesystem hiccups on busy hosts are transient, so the
-    # build retries with backoff before the loader settles for PIL.  A
-    # compile that blows the 120 s timeout is NOT transient (each retry
-    # would stall startup another two minutes): it fails straight to PIL.
-    @retry(retry_on=(OSError,), name="native_decode_build")
-    def _run():
-        return subprocess.run(cmd, capture_output=True, timeout=120)
-
-    try:
-        res = _run()
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-    return res.returncode == 0 and os.path.exists(_LIB)
 
 
 def _load() -> ctypes.CDLL | None:
@@ -68,16 +47,14 @@ def _load() -> ctypes.CDLL | None:
         _tried = True
         if os.environ.get("KEYSTONE_NATIVE_DECODE", "").strip() == "0":
             return None
+        path = build_native_library(_SRC, "kstingest", link=("-ljpeg",))
+        if path is None:
+            _logger.warning(
+                "native JPEG decoder build failed; falling back to PIL"
+            )
+            return None
         try:
-            if not os.path.exists(_LIB) or os.path.getmtime(
-                _LIB
-            ) < os.path.getmtime(_SRC):
-                if not _build():
-                    _logger.warning(
-                        "native JPEG decoder build failed; falling back to PIL"
-                    )
-                    return None
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(path)
         except OSError:
             _logger.warning(
                 "native JPEG decoder unavailable; falling back to PIL"

@@ -23,7 +23,11 @@ beats the hand-written kernel by 1.7x on the production shape, so the
 XLA path is the DEFAULT and this kernel is opt-in (KEYSTONE_PALLAS=1) —
 kept as the measured proof behind that design choice and as the template
 for shapes where the balance tips (e.g. much larger K, where the [n, k]
-posterior spill that XLA materializes grows linearly).
+posterior spill that XLA materializes grows linearly).  That timing is from
+round 4 (2026-07-30) with default-precision dots; the dots have since
+moved to ``Precision.HIGHEST`` (see the kernel body), which Mosaic compiles
+and which matches the f32 reference to 3.7e-6 at this shape (chip run,
+PR 21) — and can only have made the kernel slower.  Not re-timed.
 
 Parameterization: with inv_var = 1/variances,
 
@@ -69,12 +73,19 @@ def _fv_stats_kernel(
         s1_ref[...] = jnp.zeros_like(s1_ref)
         s2_ref[...] = jnp.zeros_like(s2_ref)
 
+    # HIGHEST: Mosaic's default f32 matmul rounds its operands to bf16,
+    # which puts these statistics ~1e-2 (relative) off the f32 reference
+    # (chip run, PR 21) — two orders outside the kernel's test tolerance.
+    dot = functools.partial(
+        jax.lax.dot_general,
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    mat = (((1,), (0,)), ((), ()))
     x = x_ref[0]  # [d, C] — descriptors as columns
     x2 = x * x
     logit = (
-        jnp.dot(at_ref[...], x, preferred_element_type=jnp.float32)
-        - 0.5 * jnp.dot(bt_ref[...], x2, preferred_element_type=jnp.float32)
-        + c_ref[...]
+        dot(at_ref[...], x, mat) - 0.5 * dot(bt_ref[...], x2, mat) + c_ref[...]
     )  # [k, C]
     m = jnp.max(logit, axis=0, keepdims=True)
     e = jnp.exp(logit - m)
@@ -86,9 +97,9 @@ def _fv_stats_kernel(
 
     s0_ref[0, 0, :] += jnp.sum(q, axis=1)
     # contract over the chunk axis: [d, C] x [k, C] -> [d, k]
-    dims = (((1,), (1,)), ((), ()))
-    s1_ref[0] += jax.lax.dot_general(x, q, dims, preferred_element_type=jnp.float32)
-    s2_ref[0] += jax.lax.dot_general(x2, q, dims, preferred_element_type=jnp.float32)
+    over_chunk = (((1,), (1,)), ((), ()))
+    s1_ref[0] += dot(x, q, over_chunk)
+    s2_ref[0] += dot(x2, q, over_chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

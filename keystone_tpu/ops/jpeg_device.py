@@ -1,6 +1,7 @@
 """Device-resident JPEG decode: split baseline JPEG at the entropy boundary.
 
-The decode wall (BENCH_r05: ~900 images/sec host decode vs 15-17k
+The decode wall (bench round r05, 2026-07-30, record removed in PR 21:
+~900 images/sec host decode vs 15-17k
 images/sec device featurize) has been attacked three times — threaded
 overlap (PR 4), the process pool and the snapshot cache (PR 7) — but the
 host still performed ALL pixel work: Huffman entropy decode, dequant,
@@ -16,9 +17,8 @@ module splits the decoder at that boundary:
   descriptor and the image's quantization tables.  No IDCT, no upsample,
   no colorspace: the heavy O(pixels) math never runs on the host.
 * **device batch pass** (:func:`decode_batch`, one jitted program per
-  geometry): dequantize, 8x8 IDCT (Pallas kernel on TPU,
-  interpret-mode/jnp fallback so tier-1 runs on CPU — bit-equal, see
-  :func:`idct_blocks`), libjpeg-style *fancy* (triangular) chroma
+  geometry): dequantize, 8x8 IDCT (Pallas kernel on TPU, jnp einsum
+  elsewhere, see :func:`idct_blocks`), libjpeg-style *fancy* (triangular) chroma
   upsampling, YCbCr->RGB, clamp/round — pixels are born on device, in
   the same BGR f32 layout :func:`~..loaders.image_loaders.decode_image`
   produces, and can be FUSED straight into a featurize program
@@ -66,10 +66,11 @@ _logger = logging.getLogger(__name__)
 GOLDEN_MAX_ABS = 8.0
 GOLDEN_MEAN_ABS = 1.0
 
-#: ``KEYSTONE_PALLAS_IDCT``: ``1`` forces the Pallas IDCT kernel (interpret
-#: mode off-TPU), ``0`` forces the jnp einsum path; unset = Pallas on TPU
-#: backends, jnp elsewhere (interpret mode is a correctness oracle, not a
-#: fast path — tier-1 asserts the two bit-equal).
+#: ``KEYSTONE_PALLAS_IDCT``: ``1`` forces the Pallas IDCT kernel, ``0``
+#: forces the jnp einsum path; unset = Pallas on TPU backends, jnp
+#: elsewhere.  Off-TPU the kernel only runs under the caller's
+#: ``pltpu.force_tpu_interpret_mode()`` — a correctness oracle, not a fast
+#: path (tier-1 holds the two within :data:`IDCT_ATOL`).
 PALLAS_IDCT_ENV = "KEYSTONE_PALLAS_IDCT"
 
 #: ``KEYSTONE_NATIVE_ENTROPY``: ``0`` forces the pure-Python entropy pass;
@@ -696,15 +697,35 @@ def entropy_decode(data: bytes, *, backend: str | None = None) -> CoeffImage:
 # -- device batch pass ---------------------------------------------------------
 
 
-@functools.lru_cache(maxsize=1)
 def _idct_basis() -> np.ndarray:
-    """Orthonormal 8-point DCT-II basis A (A @ A.T = I): spatial samples
-    x = A.T @ X @ A for coefficient block X."""
+    """Orthonormal 8-point DCT-II basis A (A @ A.T = I), float64: spatial
+    samples x = A.T @ X @ A for coefficient block X."""
     k = np.arange(8)[:, None].astype(np.float64)
     n = np.arange(8)[None, :].astype(np.float64)
     a = np.cos((2 * n + 1) * k * np.pi / 16.0) * 0.5
     a[0] *= 1.0 / np.sqrt(2.0)
-    return a.astype(np.float32)
+    return a
+
+
+#: 8x8 blocks packed side by side into one 128-lane row of the Pallas
+#: kernel's operand.
+_IDCT_PACK = 2
+
+#: Max |pallas - jnp| the two IDCT formulations may differ by on
+#: dequantized coefficients (|X| up to ~2^11): f32 rounding of 64-term
+#: sums in two different association orders.  Far below the 0.5 that
+#: could move a rounded 8-bit sample by more than one level.
+IDCT_ATOL = 2e-2
+
+
+@functools.lru_cache(maxsize=1)
+def _idct_kron() -> np.ndarray:
+    """[128, 128] f32: two copies of kron(A, A) on the diagonal.  With the
+    8x8 block flattened row-major, x = A.T @ X @ A is
+    vec(x) = vec(X) @ kron(A, A); two blocks share a row so the operand is
+    128 lanes wide and the contraction is one native MXU tile."""
+    a = _idct_basis()
+    return np.kron(np.eye(_IDCT_PACK), np.kron(a, a)).astype(np.float32)
 
 
 def _pallas_wanted() -> bool:
@@ -720,64 +741,79 @@ def _pallas_wanted() -> bool:
 
 def idct_blocks_jnp(blocks):
     """[..., 8, 8] dequantized coefficients -> spatial samples (no level
-    shift) — the reference path the Pallas kernel must bit-match."""
+    shift) — the separable reference the Pallas kernel is checked against
+    (:data:`IDCT_ATOL`).  ``HIGHEST`` precision: the TPU's default f32
+    matmul rounds its operands to bf16, which is several 8-bit levels on a
+    DC coefficient."""
+    import jax
     import jax.numpy as jnp
 
-    a = jnp.asarray(_idct_basis())
+    a = jnp.asarray(_idct_basis(), jnp.float32)
     return jnp.einsum(
         "ij,...jk,kl->...il", a.T, blocks, a,
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
-def _idct_kernel(a_ref, x_ref, o_ref):
+def _idct_kernel(k_ref, x_ref, o_ref):
+    import jax
     import jax.numpy as jnp
 
-    a = a_ref[...]
-    o_ref[...] = jnp.einsum(
-        "ij,bjk,kl->bil", a.T, x_ref[...], a,
+    o_ref[...] = jnp.dot(
+        x_ref[...], k_ref[...],
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
-def idct_blocks_pallas(blocks, *, blocks_per_step: int = 256,
-                       interpret: bool | None = None):
-    """Pallas IDCT over [..., 8, 8] blocks: grid over tiles of
-    ``blocks_per_step`` 8x8 blocks, same einsum as :func:`idct_blocks_jnp`
-    inside the kernel (bit-equal in interpret mode by construction).
-    ``interpret=None`` resolves to interpret off-TPU."""
+def idct_blocks_pallas(blocks, *, rows_per_step: int = 1024,
+                       interpret: bool = False):
+    """Pallas IDCT over [..., 8, 8] blocks in lane-dense form: the blocks
+    are flattened two to a 128-lane row and each grid step multiplies a
+    ``[rows_per_step, 128]`` tile by :func:`_idct_kron` on the MXU
+    (compiled by Mosaic on a TPU v5e, 2,048 x 36 blocks within 2.4e-4 of
+    :func:`idct_blocks_jnp` — chip run, PR 21).
+    ``interpret`` is the caller's to say (tests and rehearsals run the
+    interpreter); it is never inferred from the backend."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     lead = blocks.shape[:-2]
-    nb = int(np.prod(lead)) if lead else 1
-    x = blocks.reshape(nb, 8, 8)
-    b = min(blocks_per_step, nb) or 1
-    pad = (-nb) % b
-    if pad:
-        x = jnp.concatenate(
-            [x, jnp.zeros((pad, 8, 8), x.dtype)], axis=0
-        )
+    n = int(np.prod(lead, dtype=np.int64)) * 64
+    lanes = 64 * _IDCT_PACK
+    rows = -(-n // lanes)
+    tile = min(rows_per_step, -(-rows // 8) * 8)
+    rows_pad = -(-rows // tile) * tile
+    x = jnp.pad(
+        blocks.astype(jnp.float32).reshape(n), (0, rows_pad * lanes - n)
+    ).reshape(rows_pad, lanes)
     out = pl.pallas_call(
         _idct_kernel,
-        grid=((nb + pad) // b,),
+        grid=(rows_pad // tile,),
         in_specs=[
-            pl.BlockSpec((8, 8), lambda i: (0, 0)),
-            pl.BlockSpec((b, 8, 8), lambda i: (i, 0, 0)),
+            pl.BlockSpec(
+                (lanes, lanes), lambda i: (0, 0), memory_space=pltpu.VMEM
+            ),
+            pl.BlockSpec(
+                (tile, lanes), lambda i: (i, 0), memory_space=pltpu.VMEM
+            ),
         ],
-        out_specs=pl.BlockSpec((b, 8, 8), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb + pad, 8, 8), jnp.float32),
+        out_specs=pl.BlockSpec(
+            (tile, lanes), lambda i: (i, 0), memory_space=pltpu.VMEM
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows_pad, lanes), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(_idct_basis()), x)
-    return out[:nb].reshape(*lead, 8, 8)
+    )(jnp.asarray(_idct_kron()), x)
+    return out.reshape(rows_pad * lanes)[:n].reshape(*lead, 8, 8)
 
 
 def idct_blocks(blocks):
-    """The production chooser: Pallas on TPU (or ``KEYSTONE_PALLAS_IDCT=1``
-    anywhere, interpret mode off-TPU), jnp einsum otherwise."""
+    """The production chooser: the Pallas kernel on TPU backends (or
+    wherever ``KEYSTONE_PALLAS_IDCT=1`` — off-TPU that needs the caller's
+    ``pltpu.force_tpu_interpret_mode()``), the jnp einsum otherwise."""
     if _pallas_wanted():
         return idct_blocks_pallas(blocks)
     return idct_blocks_jnp(blocks)
@@ -935,14 +971,17 @@ def fused_apply(transform, geom: JpegGeometry, coeffs, qt, *,
             ),
             jax.ShapeDtypeStruct(tuple(qt.shape), np.dtype(np.float32)),
         )
-        try:
-            plan = kmem.plan_program(
-                fused, *sds,
-                label=f"device_decode+featurize:{label}",
+        plan = kmem.plan_program(
+            fused, *sds, label=f"device_decode+featurize:{label}"
+        )
+        if plan.error is not None and not kmem.is_oom_text(plan.error):
+            # Only memory pressure degrades to the two-dispatch path; a
+            # program the compiler refuses would be refused there too.
+            raise RuntimeError(
+                f"{label}: fused decode+featurize failed to compile at "
+                f"{geom.height}x{geom.width} — {plan.error}"
             )
-            admitted = plan.admitted
-        except Exception:  # noqa: BLE001 — planning must never kill decode
-            admitted = True
+        admitted = plan.admitted
         if not admitted:
             counters.record(
                 "device_decode_admission_denied",

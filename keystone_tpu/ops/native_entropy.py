@@ -5,12 +5,13 @@ compilation, and restart-segment splitting in Python
 (ops/jpeg_device.entropy_decode) and hands ONLY the O(compressed-bytes)
 symbol loop to this library — the same split libjpeg draws between its
 marker reader and ``decode_mcu``.  The shared library is built lazily with
-the system g++ on first use (no libjpeg or any other dependency) and
-cached next to the source, mirroring loaders/native_decode.py's contract:
-a transient build failure retries with backoff, a real one degrades to
-the pure-Python loop counted ``native_entropy_unavailable`` and logged
-once per process — the stream stays bit-equal either way, because both
-loops implement the identical algorithm (tier-1 asserts it).
+the system g++ on first use (no libjpeg or any other dependency) under a
+name derived from its source (``utils.platform.build_native_library``,
+the contract loaders/native_decode.py shares): a transient build failure
+retries with backoff, a real one degrades to the pure-Python loop counted
+``native_entropy_unavailable`` and logged once per process — the stream
+stays bit-equal either way, because both loops implement the identical
+algorithm (tier-1 asserts it).
 
 ctypes releases the GIL for the duration of each ``decode_scan`` call, so
 the ingest thread pool finally scales the entropy pass across host cores
@@ -26,10 +27,11 @@ from __future__ import annotations
 import ctypes
 import logging
 import os
-import subprocess
 import threading
 
 import numpy as np
+
+from ..utils.platform import build_native_library
 
 _logger = logging.getLogger(__name__)
 
@@ -37,9 +39,9 @@ _logger = logging.getLogger(__name__)
 #: fallback); anything else builds/loads the native loop on first use.
 NATIVE_ENTROPY_ENV = "KEYSTONE_NATIVE_ENTROPY"
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "native")
-_SRC = os.path.join(_NATIVE_DIR, "entropy.cpp")
-_LIB = os.path.join(_NATIVE_DIR, "libkstentropy.so")
+_SRC = os.path.join(
+    os.path.dirname(__file__), "..", "native", "entropy.cpp"
+)
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -61,23 +63,8 @@ _ERR_MESSAGES = {
 }
 
 
-def _build() -> bool:
-    from ..core.resilience import retry
-
-    cmd = ["g++", "-O2", "-shared", "-fPIC", _SRC, "-o", _LIB]
-
-    # Same build contract as native_decode: fork failures / filesystem
-    # hiccups retry with backoff; a compile blowing the 120 s timeout is
-    # not transient and fails straight to the Python pass.
-    @retry(retry_on=(OSError,), name="native_entropy_build")
-    def _run():
-        return subprocess.run(cmd, capture_output=True, timeout=120)
-
-    try:
-        res = _run()
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-    return res.returncode == 0 and os.path.exists(_LIB)
+def _build() -> str | None:
+    return build_native_library(_SRC, "kstentropy")
 
 
 def _report_unavailable(why: str) -> None:
@@ -116,14 +103,12 @@ def _load() -> ctypes.CDLL | None:
         if _tried:
             return _lib
         _tried = True
+        path = _build()
+        if path is None:
+            _report_unavailable("build failed")
+            return None
         try:
-            if not os.path.exists(_LIB) or os.path.getmtime(
-                _LIB
-            ) < os.path.getmtime(_SRC):
-                if not _build():
-                    _report_unavailable("build failed")
-                    return None
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(path)
         except OSError:
             _report_unavailable("load failed")
             return None

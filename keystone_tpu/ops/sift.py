@@ -166,7 +166,8 @@ class SIFTExtractor(Transformer):
     intermediates — the [N, 8, H, W] orientation planes and the banded-gemm
     sampling tensors, the dominant HBM streams of this op (measured ~197
     MB/image of traffic in f32 at 256x256x4-scales; the op is memory-bound
-    at ~11 FLOP/byte, BENCH_r04 roofline).  Passing ``jnp.bfloat16`` (the
+    at ~11 FLOP/byte, bench round r04 roofline, 2026-07-30, record removed
+    in PR 21).  Passing ``jnp.bfloat16`` (the
     throughput workloads do — imagenet_sift_lcs_fv, voc_sift_fisher,
     bench.py) halves that traffic: gemms accumulate f32 and the
     normalize/clamp/quantize tail runs f32, so the only effect is one

@@ -35,10 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 jax exports it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 from .mesh import DATA_AXIS

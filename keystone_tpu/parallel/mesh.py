@@ -187,6 +187,20 @@ def padded_shard_rows(x, mesh: Mesh | None = None):
     return jax.device_put(x, row_sharding(mesh)), n
 
 
+def rows_by_device(x) -> dict[str, list[int]]:
+    """``{device id: [first row, end row)}`` of every addressable shard of
+    ``x`` — what a record prints to show that rows are spread over the
+    mesh rather than placed on its first device (a device that holds the
+    whole array shows ``[0, N]``)."""
+    return {
+        str(s.device.id): [
+            s.index[0].start or 0,
+            x.shape[0] if s.index[0].stop is None else s.index[0].stop,
+        ]
+        for s in x.addressable_shards
+    }
+
+
 def parse_mesh(spec: str | None) -> Mesh | None:
     """Parse a ``--mesh`` flag: ``"8"`` -> 8-way data mesh, ``"4x2"`` ->
     (data=4, model=2).  None/empty -> no mesh (single device)."""

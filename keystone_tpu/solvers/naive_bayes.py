@@ -18,10 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 jax exports it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from jax.sharding import PartitionSpec as P
 

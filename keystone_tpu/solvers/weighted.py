@@ -36,10 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 jax exports it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -296,8 +293,8 @@ def _fused_bwls_impl(
     passes like the reference's persisted grams), ``num_iter`` passes of a
     lax.scan over blocks (population XᵀR gram + class-solve sweep + model
     and residual updates + residual class means), and the joint-means
-    intercept — round 3 ran ~5 eager dispatches per block per pass over a
-    ~126 ms-round-trip transport.  (reference :134-311.)
+    intercept — one program per fit instead of ~5 eager dispatches per
+    block per pass.  (reference :134-311.)
 
     x: ONE sorted, zero-tail-padded [P, B*bs] design matrix (bs =
     max(widths)); block i occupies columns [i*bs, i*bs + widths[i]) with

@@ -59,9 +59,10 @@ from ..ops.images import (
 )
 from ..ops.stats import Sampler, StandardScaler
 from ..ops.util import ClassLabelIndicatorsFromIntLabels, MaxClassifier
-from ..parallel.mesh import parse_mesh, row_sharding
+from ..parallel.mesh import parse_mesh, row_sharding, rows_by_device
 from ..solvers.block import BlockLeastSquaresEstimator
 from ..solvers.whitening import ZCAWhitenerEstimator
+from ..utils.platform import init_device
 from ..utils.stats import normalize_rows
 from . import serve_common
 from .fv_common import stream_config_from_flags, stream_features_snapshot
@@ -244,6 +245,14 @@ def featurize_chunked(fn, images: np.ndarray, chunk: int, mesh=None) -> jnp.ndar
             dev_block = jax.device_put(dev_block, sharding)
         feats = fn(dev_block)
         outs.append(feats[: chunk - pad] if pad else feats)
+    if sharding is not None and n % mesh.shape["data"] == 0:
+        # Left to itself XLA answers a concatenate along the sharded axis
+        # with a copy of the whole design matrix on every chip; keep it
+        # spread over the data axis, as the chunks were.
+        return jax.jit(
+            lambda *parts: jnp.concatenate(parts, axis=0),
+            out_shardings=sharding,
+        )(*outs)
     return jnp.concatenate(outs, axis=0)
 
 
@@ -582,10 +591,20 @@ def run(
     if cache_plan is not None:
         results["cache_plan"] = cache_plan.record()
     rep = solver.last_fit_report
-    if rep is not None and rep.placement is not None:
-        # The searched placement table — candidates, deny/score rationale,
-        # chosen plan with predicted-vs-actual cost.
-        results["placement"] = rep.placement
+    if rep is not None:
+        # Which tier ran, against what budget, after which step-downs.
+        results["solver"] = {
+            "tier": rep.chosen,
+            "budget_bytes": rep.budget_bytes,
+            "denials": list(rep.denials),
+            "oom_retries": list(rep.oom_retries),
+        }
+        if rep.placement is not None:
+            # The searched placement table — candidates, deny/score
+            # rationale, chosen plan with predicted-vs-actual cost.
+            results["placement"] = rep.placement
+    if mesh is not None:
+        results["feature_rows_by_device"] = rows_by_device(train_features)
     if conf.stream_test_tar is not None and results_autotune is not None:
         results["autotune"] = results_autotune
     # The fitted SERVABLE chain, checkpointed whole for the endpoint:
@@ -808,6 +827,7 @@ def main(argv=None):
     # Before the load stage timer, so its log line has a handler to land on
     # (run() re-applies the same idempotent configuration).
     configure_logging()
+    init_device()
     if a.trainLocation is None and a.streamTrainTar is None:
         p.error("one of --trainLocation / --streamTrainTar is required")
     conf = RandomCifarConfig(

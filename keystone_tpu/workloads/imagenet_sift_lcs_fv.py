@@ -39,6 +39,7 @@ from ..solvers.gmm import GaussianMixtureModel, GaussianMixtureModelEstimator
 from ..solvers.pca import BatchPCATransformer, compute_pca
 from ..solvers.weighted import BlockWeightedLeastSquaresEstimator
 from ..utils.stats import get_err_percent
+from ..utils.platform import init_device
 from .fv_common import (
     collect_autotune,
     fisher_feature_pipeline,
@@ -592,6 +593,8 @@ def main(argv=None):
     a = p.parse_args(argv)
     if a.trace:
         trace.enable(a.trace)
+    configure_logging()
+    init_device()
     conf = ImageNetSiftLcsFVConfig(
         train_location=a.trainLocation,
         test_location=a.testLocation,

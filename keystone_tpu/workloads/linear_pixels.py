@@ -23,6 +23,7 @@ from ..ops.images import GrayScaler, ImageVectorizer
 from ..ops.util import ClassLabelIndicatorsFromIntLabels, MaxClassifier
 from ..parallel.mesh import parse_mesh
 from ..solvers.linear import LinearMapEstimator
+from ..utils.platform import init_device
 
 
 @dataclass
@@ -85,6 +86,8 @@ def main(argv=None):
         help="device mesh, e.g. '8' (data) or '4x2' (data x model)",
     )
     a = p.parse_args(argv)
+    configure_logging()
+    init_device()
     conf = LinearPixelsConfig(
         train_location=a.trainLocation, test_location=a.testLocation
     )

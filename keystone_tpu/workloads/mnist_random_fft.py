@@ -36,6 +36,7 @@ from ..ops.util import (
 )
 from ..parallel.mesh import padded_shard_rows, parse_mesh
 from ..solvers.block import BlockLeastSquaresEstimator
+from ..utils.platform import init_device
 from . import serve_common
 
 
@@ -389,6 +390,7 @@ def main(argv=None):
     # Before the load stage timer, so its log line has a handler to land on
     # (run() re-applies the same idempotent configuration).
     configure_logging()
+    init_device()
     if a.blockSize <= 0 or a.blockSize % 512 != 0:
         p.error("--blockSize must be a positive multiple of 512")
     conf = MnistRandomFFTConfig(

@@ -24,6 +24,7 @@ from ..ops.sparse import CommonSparseFeatures
 from ..ops.util import MaxClassifier
 from ..parallel.mesh import parse_mesh, use_mesh
 from ..solvers.naive_bayes import NaiveBayesEstimator
+from ..utils.platform import init_device
 
 
 @dataclass
@@ -98,6 +99,8 @@ def main(argv=None):
         help="device mesh, e.g. '8' (data) or '4x2' (data x model)",
     )
     a = p.parse_args(argv)
+    configure_logging()
+    init_device()
     conf = NewsgroupsConfig(
         train_location=a.trainLocation,
         test_location=a.testLocation,

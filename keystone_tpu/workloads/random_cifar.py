@@ -32,6 +32,7 @@ from ..ops.stats import StandardScaler
 from ..ops.util import ClassLabelIndicatorsFromIntLabels, MaxClassifier
 from ..parallel.mesh import parse_mesh
 from ..solvers.linear import LinearMapEstimator
+from ..utils.platform import init_device
 from .cifar_random_patch import featurize_chunked
 
 
@@ -149,6 +150,8 @@ def main(argv=None):
         help="device mesh, e.g. '8' (data) or '4x2' (data x model)",
     )
     a = p.parse_args(argv)
+    configure_logging()
+    init_device()
     conf = RandomCifarWorkloadConfig(
         train_location=a.trainLocation,
         test_location=a.testLocation,

@@ -31,6 +31,7 @@ from ..ops.ngram_lm import (
     sharded_scores,
 )
 from ..ops.nlp import NGramsFeaturizer, Tokenizer, fit_word_frequency_encoder
+from ..utils.platform import init_device
 
 
 @dataclass
@@ -112,6 +113,8 @@ def main(argv=None):
     p.add_argument("--numParts", type=int, default=16)
     p.add_argument("--n", type=int, default=3)
     a = p.parse_args(argv)
+    configure_logging()
+    init_device()
     conf = StupidBackoffConfig(train_data=a.trainData, num_parts=a.numParts, n=a.n)
     with open(conf.train_data, encoding="utf-8") as fh:
         lines = [ln.rstrip("\n") for ln in fh if ln.strip()]

@@ -26,6 +26,7 @@ from ..ops.stats import CosineRandomFeatures, StandardScaler
 from ..ops.util import ClassLabelIndicatorsFromIntLabels, MaxClassifier
 from ..parallel.mesh import mask_pad_rows, padded_shard_rows, parse_mesh
 from ..solvers.block import BlockLeastSquaresEstimator
+from ..utils.platform import init_device
 
 
 @dataclass
@@ -138,6 +139,8 @@ def main(argv=None):
         help="device mesh, e.g. '8' (data) or '4x2' (data x model)",
     )
     a = p.parse_args(argv)
+    configure_logging()
+    init_device()
     conf = TimitConfig(
         train_data_location=a.trainDataLocation,
         train_labels_location=a.trainLabelsLocation,

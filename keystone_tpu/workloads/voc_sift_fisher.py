@@ -40,6 +40,7 @@ from ..parallel.mesh import parse_mesh
 from ..solvers.block import BlockLeastSquaresEstimator
 from ..solvers.gmm import GaussianMixtureModel, GaussianMixtureModelEstimator
 from ..solvers.pca import BatchPCATransformer, compute_pca
+from ..utils.platform import init_device
 from . import serve_common
 from .fv_common import (
     bucket_by_shape,
@@ -565,6 +566,8 @@ def main(argv=None):
     a = p.parse_args(argv)
     if a.trace:
         trace.enable(a.trace)
+    configure_logging()
+    init_device()
     if (a.serve or a.serveBench) and not a.pipelineFile:
         p.error("--serve/--serveBench require --pipelineFile")
     if (a.serve or a.serveBench) and a.streamIngest:

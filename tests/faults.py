@@ -158,15 +158,10 @@ def transient_faults(
 
 
 def xla_runtime_error_type() -> type[BaseException]:
-    """The exception type XLA raises at dispatch/execution time (falls back
-    to RuntimeError on jaxlib layouts that do not export it — the OOM
-    detector keys on the RESOURCE_EXHAUSTED text either way)."""
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
+    """The exception type XLA raises at dispatch/execution time."""
+    import jax
 
-        return XlaRuntimeError
-    except ImportError:  # pragma: no cover - jaxlib always has it today
-        return RuntimeError
+    return jax.errors.JaxRuntimeError
 
 
 def resource_exhausted_error(nbytes: int = 1 << 33) -> BaseException:

@@ -1,8 +1,9 @@
 """Unit tests for bench.py's measurement scaffolding (the parts that guard
 the round artifact — no TPU required), and the bench regression
-observatory (tools/bench_diff.py) exercised over the checked-in
-BENCH_r01–r05 round records so the observatory itself runs in tier-1
-without hardware."""
+observatory (tools/bench_diff.py) exercised over a generated five-round
+history shaped like the driver's r01–r05 records (an early metric, the
+headline growing sections, a truncated newest round) so the observatory
+itself runs in tier-1 without hardware."""
 
 import json
 import os
@@ -65,8 +66,8 @@ def test_timed_chain_auto_propagates_real_failures(monkeypatch):
 
 
 def test_solve_at_scale_records_fit_report_per_attempt(monkeypatch):
-    """Regression for the PR 7 probe fix (BENCH_r05 showed raw-OOM rows
-    with no ladder evidence): every probed shape — failures INCLUDED —
+    """Regression for the PR 7 probe fix (bench round r05, 2026-07-30,
+    record removed in PR 21, showed raw-OOM rows with no ladder evidence): every probed shape — failures INCLUDED —
     must carry the estimator's own ``last_fit_report`` record in the
     emitted JSON, and (ISSUE 9) the searched ``placement`` table rides in
     it.  Every probe is made to FAIL (injected post-fit OOM, the report
@@ -103,15 +104,49 @@ def test_solve_at_scale_records_fit_report_per_attempt(monkeypatch):
 # -- the regression observatory (tools/bench_diff.py, ISSUE 11) ---------------
 
 
-def _round(n: int) -> str:
-    return os.path.join(_REPO, f"BENCH_r{n:02d}.json")
+@pytest.fixture
+def rounds_dir(tmp_path):
+    """Five driver-wrapped round records: r01 an early metric, r02 the bare
+    headline, r03/r04 the headline with solve and decode sections (r04
+    improved), r05 truncated by the driver (``parsed: null``)."""
+    early = {"metric": "mnist_random_fft_featurize", "unit": "examples/sec"}
+    head = {"metric": "random_patch_cifar_featurize", "unit": "images/sec/chip"}
+    records = {
+        1: {**early, "value": 1.0e6, "vs_baseline": 1.0},
+        2: {**head, "value": 186858.0, "vs_baseline": 1.0},
+        3: {
+            **head, "value": 497658.16, "mfu": 0.043, "solve_seconds": 10.8,
+            "extra_metrics": {
+                "imagenet_fv_featurize": {"value": 4169.53, "mfu": 0.0462},
+                "jpeg_decode": {"speedup": 1.03},
+            },
+        },
+        4: {
+            **head, "value": 1186580.0, "mfu": 0.103, "solve_seconds": 0.024,
+            "extra_metrics": {
+                "imagenet_fv_featurize": {"value": 5800.0, "mfu": 0.05},
+                "jpeg_decode": {"speedup": 1.04},
+            },
+        },
+        5: None,
+    }
+    for n, parsed in records.items():
+        with open(tmp_path / f"BENCH_r{n:02d}.json", "w") as f:
+            json.dump({"n": n, "rc": 0, "tail": "...", "parsed": parsed}, f)
+    return tmp_path
 
 
-def test_bench_diff_r04_vs_r05_emits_machine_verdict(capsys):
-    """The ISSUE 11 acceptance pair: r05's driver artifact was truncated
-    (``parsed: null``), so the diff must emit an INCOMPARABLE verdict as
+def _round(dirpath, n: int) -> str:
+    return str(dirpath / f"BENCH_r{n:02d}.json")
+
+
+def test_bench_diff_truncated_candidate_emits_machine_verdict(
+    rounds_dir, capsys
+):
+    """The ISSUE 11 acceptance pair: a candidate whose driver artifact was
+    truncated (``parsed: null``) must yield an INCOMPARABLE verdict as
     machine-readable JSON — naming the problem — instead of crashing."""
-    rc = bench_diff.main([_round(4), _round(5)])
+    rc = bench_diff.main([_round(rounds_dir, 4), _round(rounds_dir, 5)])
     assert rc == 2
     first_line = capsys.readouterr().out.splitlines()[0]
     record = json.loads(first_line)
@@ -121,10 +156,10 @@ def test_bench_diff_r04_vs_r05_emits_machine_verdict(capsys):
     assert "null" in record["problems"]["cand"]
 
 
-def test_bench_diff_r03_vs_r04_is_comparable_and_clean(capsys):
-    """r03 -> r04 is the real improvement round (featurize 497k -> 1.19M
-    images/sec/chip): comparable, no regressions, improvements named."""
-    rc = bench_diff.main([_round(3), _round(4)])
+def test_bench_diff_improved_pair_is_comparable_and_clean(rounds_dir, capsys):
+    """An improvement round (featurize 497k -> 1.19M images/sec/chip):
+    comparable, no regressions, improvements named."""
+    rc = bench_diff.main([_round(rounds_dir, 3), _round(rounds_dir, 4)])
     assert rc == 0
     record = json.loads(capsys.readouterr().out.splitlines()[0])
     assert record["verdict"] == "ok"
@@ -134,11 +169,11 @@ def test_bench_diff_r03_vs_r04_is_comparable_and_clean(capsys):
     assert "value" in improved
 
 
-def test_bench_diff_every_checked_in_pair_yields_a_verdict():
-    """The observatory over the whole round history: every consecutive
-    pair produces a structurally-valid verdict (r05's truncated record
+def test_bench_diff_every_pair_yields_a_verdict(rounds_dir):
+    """The observatory over a whole round history: every consecutive
+    pair produces a structurally-valid verdict (the truncated record
     degrades to incomparable, never a crash)."""
-    rounds = bench_diff.list_rounds(_REPO)
+    rounds = bench_diff.list_rounds(str(rounds_dir))
     assert [n for n, _ in rounds] == [1, 2, 3, 4, 5]
     for (n_a, p_a), (n_b, p_b) in zip(rounds, rounds[1:]):
         record = bench_diff.diff_files(p_a, p_b)
@@ -194,8 +229,8 @@ def test_bench_diff_metric_overrides():
         bench_diff.parse_metric_overrides(["a=0.1:sideways"])
 
 
-def test_latest_usable_round_skips_truncated_r05():
-    found = bench_diff.latest_usable_round(_REPO)
+def test_latest_usable_round_skips_truncated_newest(rounds_dir):
+    found = bench_diff.latest_usable_round(str(rounds_dir))
     assert found is not None
     num, path, record = found
     assert num == 4  # r05 is parsed:null — the newest USABLE round is r04

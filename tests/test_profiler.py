@@ -66,11 +66,12 @@ class TestLedger:
         assert ba and ba > 0
         assert kprof.jit_cost(f, x) == (flops, ba)
 
-    def test_record_program_mfu_math(self):
+    def test_record_program_mfu_math(self, monkeypatch):
         _f, _x, compiled = _matmul_compiled(64)
+        rates = {"peak_flops": 2e12, "hbm_gbps": 100.0}
+        monkeypatch.setattr(kprof, "device_rates", lambda: rates)
         with kprof.profiled(True):
             row = kprof.record_program("t", compiled, 0.01)
-            rates = kprof.device_rates()
             assert row["mfu"] == pytest.approx(
                 row["flops"] / 0.01 / rates["peak_flops"], abs=1e-6
             )
@@ -78,6 +79,18 @@ class TestLedger:
             assert led["runs"] == 1
             assert led["mfu"] == pytest.approx(row["mfu"], rel=1e-3)
             assert led["bound"] in ("compute", "memory")
+
+    def test_unknown_device_kind_reports_no_utilization(self):
+        """The CPU test platform is not in optimize.DEVICE_RATES: its
+        programs are timed and counted, but never given an MFU."""
+        _f, _x, compiled = _matmul_compiled(64)
+        assert kprof.device_rates() is None
+        with kprof.profiled(True):
+            row = kprof.record_program("nomfu", compiled, 0.01)
+            led = kprof.ledger()["nomfu"]
+        assert row["mfu"] is None and row["flops"]
+        assert led["mfu"] is None and led["bound"] is None
+        assert led["achieved_hbm_gbps"] is not None
 
     def test_ledger_aggregates_runs(self):
         _f, _x, compiled = _matmul_compiled(32)

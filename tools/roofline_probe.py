@@ -16,7 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import HBM_BW, PEAK_FLOPS, compiled_cost, timed_chain_auto
+from bench import compiled_cost, timed_chain_auto
+from keystone_tpu.core.optimize import DEVICE_RATES
+from keystone_tpu.utils.platform import init_device
 from keystone_tpu.workloads.cifar_random_patch import (
     RandomCifarConfig,
     build_conv_pipeline,
@@ -25,6 +27,16 @@ from keystone_tpu.workloads.cifar_random_patch import (
 
 
 def main():
+    device = init_device()
+    if device["platform"] != "tpu":
+        raise SystemExit(f"roofline_probe: the device is {device}, not a TPU")
+    rates = DEVICE_RATES[device["kind"]]  # a TPU not in the table is an error
+    peak, bw = rates["peak_flops"], rates["hbm_gbps"] * 1e9
+    print(
+        f"# device: {json.dumps(device)}  peak={peak / 1e12:.0f} TFLOP/s  "
+        f"hbm={bw / 1e9:.0f} GB/s"
+    )
+
     # The probe's purpose is reproducing the ROOFLINE.md XLA-variant rows;
     # a stray KEYSTONE_PALLAS=1 would silently swap in the opt-in kernel
     # under the SHIPPED label.
@@ -37,12 +49,6 @@ def main():
     train = rng.uniform(0, 255, (512, 32, 32, 3)).astype(np.float32)
     filters, whitener = learn_filters(conf, train)
     batch = jnp.asarray(rng.uniform(0, 255, (1024, 32, 32, 3)).astype(np.float32))
-
-    kind = jax.devices()[0].device_kind
-    peak, bw = PEAK_FLOPS.get(kind), HBM_BW.get(kind)
-    peak_s = f"{peak / 1e12:.0f} TFLOP/s" if peak else "unknown"
-    bw_s = f"{bw / 1e9:.0f} GB/s" if bw else "unknown"
-    print(f"# device: {kind}  peak={peak_s}  hbm={bw_s}")
 
     def conv_pipe(fused, dtype=jnp.bfloat16):
         pipe = build_conv_pipeline(conf, filters, whitener, fused=fused)
@@ -69,7 +75,7 @@ def main():
             "bytes_per_img": round(by / 1024) if by else None,
             "rel_err_vs_shipped": float(f"{err:.2e}"),
         }
-        if fl and by and peak and bw:
+        if fl and by:
             intensity = fl / by
             rec["fraction_of_ceiling"] = round(
                 (fl / per) / min(intensity * bw, peak), 3
