@@ -1182,9 +1182,10 @@ def run_search(
         def run(mplan):
             rec = placement.candidate(c.name)
             t0 = time.perf_counter()
-            with trace.plan_span(
+            with trace.span(
                 f"plan:{c.name}",
-                predicted_seconds=rec.predicted_seconds if rec else None,
+                cat="plan",
+                predicted_s=rec.predicted_seconds if rec else None,
                 label=label,
                 rank=rec.rank if rec else None,
                 specs=spec_tag(rec.specs if rec else None),
@@ -1237,9 +1238,7 @@ def _block_until_ready(out) -> None:
     honesty + async-OOM surfacing; a result that cannot sync — or no
     live backend — is not an error)."""
     try:
-        import jax
-
-        jax.block_until_ready(out)
+        trace.wait(out, "solve")
     except Exception as e:  # noqa: BLE001 — only OOM matters here
         if kmem.is_oom_error(e):
             raise

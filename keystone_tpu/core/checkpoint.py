@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import trace
 from .pipeline import NODE_REGISTRY, Pipeline
 
 _logger = logging.getLogger("keystone_tpu.checkpoint")
@@ -194,7 +195,8 @@ class _Encoder:
         sharding = _sharding_spec(v)
         if not _is_replicated(v):
             self.all_replicated = False
-        arr = np.asarray(jax.device_get(v))
+        with trace.d2h("checkpoint_array", getattr(v, "nbytes", 0)):
+            arr = np.asarray(jax.device_get(v))
         spec = {"dtype": arr.dtype.name, "shape": list(arr.shape)}
         if sharding != "replicated":
             # Per-array layout provenance (absent == replicated): the

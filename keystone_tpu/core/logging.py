@@ -5,7 +5,7 @@ stage timer (the reference's ``"Pipeline took N s"`` lines,
 MnistRandomFFT.scala:34,86-87) and ``jax.named_scope`` tagging so stages show
 up in the JAX profiler — the Spark-UI ``RDD.setName`` analog.  The stage
 timer is built ON the trace subsystem (core.trace): every timed stage is
-also a structured span in the ``KEYSTONE_TRACE`` timeline.
+a ``trace.Stage``, the layer boundary of a fit's timeline.
 
 As a library we never touch the root logger; workload entry points call
 :func:`configure_logging` to get console output (level from the
@@ -87,12 +87,13 @@ class Logging:
 @contextlib.contextmanager
 def stage_timer(name: str, logger: logging.Logger | None = None):
     """Time a pipeline stage: same ``"<name> took N s"`` log line and
-    signature as ever, now ALSO a ``trace.span`` (cat ``stage``) so stage
-    timings land in the ``KEYSTONE_TRACE`` timeline, plus the
-    ``jax.named_scope`` tag for the JAX profiler."""
+    signature as ever, a ``trace.Stage`` (a span of cat ``stage`` in the
+    ``KEYSTONE_TRACE`` timeline whose exit records ``stage_ms.<name>`` and
+    the wait and copy sums beneath it into ``trace.metrics``, tracing on or
+    off), plus the ``jax.named_scope`` tag for the JAX profiler."""
     logger = logger or _ROOT
     t0 = time.perf_counter()
-    with trace.span(name, cat="stage"):
+    with trace.Stage(name):
         with jax.named_scope(name):
             yield
     logger.info("%s took %.3f s", name, time.perf_counter() - t0)

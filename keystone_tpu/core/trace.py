@@ -12,8 +12,29 @@ schema.  This module is that shared substrate:
   spans: wall time, thread id, nesting depth/parent, arbitrary JSON-able
   attributes (bytes/shape/dtype), optional device-sync time
   (``sp.sync(value)`` runs ``jax.block_until_ready`` and records the
-  synced duration).  When tracing is disabled ``span()`` returns a shared
-  no-op singleton — no allocation, no lock, one attribute check.
+  synced duration).  A span names what caused it: ``args`` carry a
+  process-unique ``id``, the ``parent_id`` and ``root``, the id of its
+  outermost ancestor on the thread (the spans of one fit share it).  When
+  tracing is disabled ``span()`` returns a shared no-op singleton — no
+  allocation, no lock, one attribute check.
+* **The fit timeline.**  A workload's ``run`` is one root span ``fit`` (cat
+  ``fit``).  :class:`Stage` (through ``core.logging.stage_timer``) is the
+  layer boundary: spans of cat ``stage`` that tile the root but for glue,
+  each name once a fit.  Beneath a stage the host code says whether it
+  works or waits, by cat: ``wait`` (:func:`wait`: blocked on the device),
+  ``d2h`` (:func:`d2h`: a read to the host, counted as waiting), ``h2d``
+  (:func:`h2d`: a copy to the device, with bytes), ``dispatch`` (the call
+  of a jitted program), ``concat``, ``eval``, and the solvers' ``solve`` /
+  ``plan`` spans.  At exit a stage records ``stage_ms.<name>`` (self time),
+  ``stage_wait_ms.<name>``, ``stage_h2d_ms.<name>`` and
+  ``stage_h2d_mb.<name>`` into :data:`metrics` — always: the sums are kept
+  on the stage, not read back from spans, so they hold with tracing off
+  and with the flight ring off.
+* **One clock with the device trace.**  While tracing is enabled a span is
+  also a ``jax.profiler.TraceAnnotation`` named ``ks/<cat>/<name>`` with
+  its ``id`` and ``root``: under ``jax.profiler.start_trace`` the program's
+  spans sit in the xplane's host plane on the profiler's clock, beside the
+  device's, with no offset to estimate.  With tracing off none is made.
 * :data:`metrics` — the process-wide registry unifying **counters**,
   **gauges**, and **histograms** behind one API, with an atomic
   :meth:`Metrics.snapshot`.  ``resilience.counters`` (the fault ledger)
@@ -36,7 +57,8 @@ schema.  This module is that shared substrate:
 Overhead discipline: with tracing AND the flight ring off the path is a
 module-state check returning a cached null object; with only the ring on,
 each finished span is one small dict append into a bounded deque (the
-tier-1 suite asserts no retained allocation growth once the ring is warm),
+tier-1 suite asserts no retained allocation growth once the ring is warm,
+and a span's enter + exit under 20 us), a stage adds one registry lock,
 and the bench acceptance bound is < 2% on ``stage_ops`` with tracing off.
 Enabled, each finished span is one dict append under a lock (bounded at
 :data:`MAX_EVENTS`; overflow is counted, never unbounded).
@@ -47,9 +69,11 @@ from __future__ import annotations
 import atexit
 import collections
 import contextlib
+import itertools
 import json
 import logging
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -74,7 +98,10 @@ DEFAULT_FLIGHT_DEPTH = 512
 #: both export formats), never unbounded RAM.
 MAX_EVENTS = 1_000_000
 
-_EPOCH = time.perf_counter()  # ts origin: microseconds since module import
+#: The trace clock's source.  A module attribute so that a test can stand a
+#: clock of its own in for it (``monkeypatch.setattr(trace, "_clock", ...)``).
+_clock = time.perf_counter
+_EPOCH = _clock()  # ts origin: microseconds since module import
 
 # getpid() is a real syscall on every call (Python does not cache it), and
 # on sandboxed kernels it measures ~10us — per EVENT that would dwarf the
@@ -103,7 +130,9 @@ _path: str | None = None
 _tids: dict[int, int] = {}  # threading.get_ident() -> small sequential tid
 _tid_metas: dict[int, dict] = {}  # tid -> its thread_name metadata event
 _tids_in_buffer: set = set()  # tids whose metadata reached _events
-_tls = threading.local()  # per-thread span stack (nesting/parents)
+_tls = threading.local()  # per-thread span stack (nesting/parents), open stages
+#: Process-unique span ids (``next`` on a count is atomic under the GIL).
+_next_id = itertools.count(1).__next__
 _atexit_registered = False
 
 # -- the always-on flight recorder ring.  Deliberately separate from the
@@ -130,7 +159,7 @@ def _parse_flight_depth() -> int:
 
 
 def _now_us() -> float:
-    return (time.perf_counter() - _EPOCH) * 1e6
+    return (_clock() - _EPOCH) * 1e6
 
 
 def now_us() -> float:
@@ -229,12 +258,29 @@ class _NullSpan:
 
 _NULL = _NullSpan()
 
+def _annotate(sp: "Span"):
+    """While tracing is enabled a span is also a
+    ``jax.profiler.TraceAnnotation`` named ``ks/<cat>/<name>`` that carries
+    the span's ``id`` and ``root``: under ``jax.profiler.start_trace`` the
+    program's spans then sit in the xplane's host plane on the profiler's
+    own clock, beside the device's.  A process that has not imported jax
+    (the decode workers) has no profiler to annotate for and makes none."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    note = profiler.TraceAnnotation(
+        f"ks/{sp.cat}/{sp.name}", id=sp.id, root=sp._root
+    )
+    note.__enter__()
+    return note
+
 
 class Span:
     """One live span (use via ``with trace.span(...) as sp``)."""
 
     __slots__ = (
-        "name", "cat", "attrs", "t0", "_tid", "_depth", "_parent", "_epoch"
+        "name", "cat", "attrs", "t0", "id", "_tid", "_depth", "_parent",
+        "_parent_id", "_root", "_note", "_epoch",
     )
 
     def __init__(self, name: str, cat: str, attrs: dict):
@@ -242,23 +288,38 @@ class Span:
         self.cat = cat
         self.attrs = attrs
         self.t0 = 0.0
+        self.id = 0
         self._tid = 0
         self._depth = 0
         self._parent = None
+        self._parent_id = None
+        self._root = 0
+        self._note = None
         self._epoch = 0
 
     def __enter__(self):
         stack = _stack()
+        self.id = _next_id()
         self._depth = len(stack)
-        self._parent = stack[-1].name if stack else None
+        if stack:
+            parent = stack[-1]
+            self._parent = parent.name
+            self._parent_id = parent.id
+            self._root = parent._root
+        else:
+            self._root = self.id
         stack.append(self)
         self._tid = _tid()
         self._epoch = _epoch
+        if _enabled:
+            self._note = _annotate(self)
         self.t0 = _now_us()
         return self
 
     def __exit__(self, etype, exc, tb):
         t1 = _now_us()
+        if self._note is not None:
+            self._note.__exit__(etype, exc, tb)
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -271,8 +332,11 @@ class Span:
             return False
         args = dict(self.attrs)
         args["depth"] = self._depth
+        args["id"] = self.id
+        args["root"] = self._root
         if self._parent is not None:
             args["parent"] = self._parent
+            args["parent_id"] = self._parent_id
         if etype is not None:
             if issubclass(etype, GeneratorExit):
                 # A generator-hosted span (ingest.consume) is closed — not
@@ -350,42 +414,134 @@ def io_span(name: str, nbytes: int, cat: str = "io", **attrs):
     return _IOSpan(name, cat, attrs)
 
 
-class _PlanSpan(Span):
-    """A span over work the placement search PREDICTED a cost for
-    (core.autoshard): records ``predicted_s`` (and optionally
-    ``predicted_bytes``) up front and derives ``measured_s`` plus the
-    predicted/measured ratio ``prediction_error`` at exit — the trace
-    answers "how wrong was the cost model on the plan it chose?" without
-    cross-referencing the plan log by hand."""
+# -- stages: the layer boundary of a fit ---------------------------------------
+#
+# A stage is a span of cat ``stage`` that also keeps sums: its own duration,
+# the part nested stages cover, and what the helpers below (``wait``,
+# ``d2h``, ``h2d``) charge to it.  The sums live on the stage object, found
+# through a per-thread list of open stages, so they hold with tracing off
+# and with the flight ring off (``KEYSTONE_FLIGHT_DEPTH=0``), when the spans
+# themselves are no-ops.
 
-    __slots__ = ()
+
+def _open_stages() -> list:
+    stages = getattr(_tls, "stages", None)
+    if stages is None:
+        stages = _tls.stages = []
+    return stages
+
+
+class Stage:
+    """One stage of a fit (use via ``core.logging.stage_timer``).  At exit
+    it records into :data:`metrics`, always, under one lock:
+
+    * ``stage_ms.<name>`` — its *self* time: duration less the stages
+      nested in it;
+    * ``stage_wait_ms.<name>`` — time beneath it blocked on the device
+      (:func:`wait` and :func:`d2h`);
+    * ``stage_h2d_ms.<name>`` / ``stage_h2d_mb.<name>`` — time and bytes of
+      the host-to-device copies beneath it (:func:`h2d`).
+
+    Waits and copies go to the innermost open stage of their thread only,
+    like self time.  A stage's name occurs once a fit, so the last *n*
+    samples of a name are the last *n* fits."""
+
+    __slots__ = (
+        "name", "_span", "_t0", "nested_us", "wait_us", "h2d_us", "h2d_bytes"
+    )
+
+    def __init__(self, name: str):
+        self.name = name
+        self._span = _NULL
+        self._t0 = 0.0
+        self.nested_us = 0.0
+        self.wait_us = 0.0
+        self.h2d_us = 0.0
+        self.h2d_bytes = 0
+
+    def __enter__(self):
+        self._span = span(self.name, cat="stage")
+        self._span.__enter__()
+        _open_stages().append(self)
+        self._t0 = _now_us()
+        return self
 
     def __exit__(self, etype, exc, tb):
-        measured = (_now_us() - self.t0) / 1e6
-        self.attrs["measured_s"] = round(measured, 6)
-        predicted = self.attrs.get("predicted_s")
-        if predicted and measured > 0:
-            self.attrs["prediction_error"] = round(predicted / measured, 4)
-        return super().__exit__(etype, exc, tb)
+        dur = max(_now_us() - self._t0, 0.0)
+        stages = _open_stages()
+        if stages and stages[-1] is self:
+            stages.pop()
+        elif self in stages:  # exited out of order — heal, as Span does
+            stages.remove(self)
+        if stages:
+            stages[-1].nested_us += dur
+        sums = {
+            "stage_ms": (dur - self.nested_us) / 1e3,
+            "stage_wait_ms": self.wait_us / 1e3,
+            "stage_h2d_ms": self.h2d_us / 1e3,
+            "stage_h2d_mb": self.h2d_bytes / 1e6,
+        }
+        self._span.set(**{k: round(v, 3) for k, v in sums.items()})
+        self._span.__exit__(etype, exc, tb)
+        metrics.observe_all(
+            {f"{kind}.{self.name}": value for kind, value in sums.items()}
+        )
+        return False
 
 
-def plan_span(
-    name: str,
-    predicted_seconds: float | None = None,
-    predicted_bytes: int | None = None,
-    cat: str = "plan",
-    **attrs,
-):
-    """Span for a placement-plan choice: like :func:`span`, plus
-    predicted-vs-measured cost accounting (``predicted_s`` /
-    ``measured_s`` / ``prediction_error`` attrs)."""
-    if not _enabled and _flight is None:
-        return _NULL
-    if predicted_seconds is not None:
-        attrs["predicted_s"] = round(float(predicted_seconds), 6)
-    if predicted_bytes is not None:
-        attrs["predicted_bytes"] = int(predicted_bytes)
-    return _PlanSpan(name, cat, attrs)
+class _Charged:
+    """A span whose duration (and bytes) is also charged to the innermost
+    open stage of the thread: the clock is read here, not taken from the
+    span, which is a no-op when tracing and the flight ring are off."""
+
+    __slots__ = ("_span", "_bytes", "_t0")
+
+    def __init__(self, sp, nbytes: int | None = None):
+        self._span = sp
+        self._bytes = nbytes  # None: a wait; a number: a copy to the device
+        self._t0 = 0.0
+
+    def __enter__(self):
+        self._t0 = _now_us()
+        return self._span.__enter__()
+
+    def __exit__(self, etype, exc, tb):
+        self._span.__exit__(etype, exc, tb)
+        stages = getattr(_tls, "stages", None)
+        if stages:
+            dur = max(_now_us() - self._t0, 0.0)
+            if self._bytes is None:
+                stages[-1].wait_us += dur
+            else:
+                stages[-1].h2d_us += dur
+                stages[-1].h2d_bytes += self._bytes
+        return False
+
+
+def wait(value, name: str = "device"):
+    """``jax.block_until_ready(value)`` inside a span of cat ``wait``, the
+    time charged to the open stage's ``stage_wait_ms``: the host is blocked
+    on the device, not working.  Wraps a sync the fit path already has (put
+    it in front of a ``np.asarray`` or ``float()`` of a device value); unlike
+    :meth:`Span.sync`, which the serving path uses and which only syncs
+    while spans are recorded, it blocks always.  Returns ``value``."""
+    import jax
+
+    with _Charged(span(name, cat="wait")):
+        return jax.block_until_ready(value)
+
+
+def d2h(name: str, nbytes: int, **attrs):
+    """Span (cat ``d2h``, an :func:`io_span`) around a read of ``nbytes``
+    from the device to the host; the time counts as waiting."""
+    return _Charged(io_span(name, nbytes, cat="d2h", **attrs))
+
+
+def h2d(name: str, nbytes: int, **attrs):
+    """Span (cat ``h2d``, an :func:`io_span`) around a copy of ``nbytes``
+    from the host to the device; time and bytes are added to the open
+    stage's ``stage_h2d_ms`` / ``stage_h2d_mb``."""
+    return _Charged(io_span(name, nbytes, cat="h2d", **attrs), int(nbytes))
 
 
 def instant(name: str, **attrs) -> None:
@@ -666,12 +822,22 @@ class Metrics:
             return self._gauges.get(name, default)
 
     # histograms -------------------------------------------------------------
+    def _observe_locked(self, name: str, value: float) -> None:
+        h = self._hists.get(name)
+        if h is None:
+            h = self._hists[name] = _Hist()
+        h.observe(value)
+
     def observe(self, name: str, value: float) -> None:
         with self._lock:
-            h = self._hists.get(name)
-            if h is None:
-                h = self._hists[name] = _Hist()
-            h.observe(value)
+            self._observe_locked(name, value)
+
+    def observe_all(self, values: dict) -> None:
+        """:meth:`observe` each ``name: value`` under one lock (a stage's
+        sums land together or not at all)."""
+        with self._lock:
+            for name, value in values.items():
+                self._observe_locked(name, value)
 
     def hist_windows(self) -> dict:
         """Raw per-histogram sample windows (count/total/min/max plus the

@@ -13,6 +13,8 @@ from dataclasses import dataclass
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import trace
+
 
 @dataclass(frozen=True)
 class BinaryClassificationMetrics:
@@ -164,6 +166,9 @@ def _small_label(i: int) -> str:
 def confusion_matrix(predictions, actuals, num_classes: int):
     """One-pass confusion matrix on device: rows=actual, cols=predicted."""
     predictions = jnp.asarray(predictions).astype(jnp.int32)
+    if isinstance(actuals, np.ndarray):  # the labels come from the host
+        with trace.h2d("labels", actuals.nbytes):
+            actuals = jnp.asarray(actuals)
     actuals = jnp.asarray(actuals).astype(jnp.int32)
     flat = actuals * num_classes + predictions
     counts = jnp.bincount(flat, length=num_classes * num_classes)
@@ -176,7 +181,14 @@ class MulticlassClassifierEvaluator:
 
     @staticmethod
     def apply(predictions, actuals, num_classes: int) -> MulticlassMetrics:
-        return MulticlassMetrics(confusion_matrix(predictions, actuals, num_classes))
+        # The host blocks here until the predictions and their counts exist
+        # on the device, then reads the counts: a round trip an evaluation.
+        counts = trace.wait(
+            confusion_matrix(predictions, actuals, num_classes), "evaluator"
+        )
+        with trace.d2h("confusion_matrix", counts.nbytes):
+            counts = np.asarray(counts)
+        return MulticlassMetrics(counts)
 
     def __new__(cls, predictions, actuals, num_classes: int) -> MulticlassMetrics:  # type: ignore[misc]
         return cls.apply(predictions, actuals, num_classes)

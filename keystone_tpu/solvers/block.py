@@ -126,11 +126,16 @@ class BlockLinearMapper(Transformer):
                 f"{len(blocks)} feature blocks vs {len(self.xs)} model blocks"
             )
         running = None
-        for blk, x, scaler in zip(blocks, self.xs, self.feature_scalers):
-            part = scaler(blk) @ x
-            running = part if running is None else running + part
-            with_intercept = running if self.b is None else running + self.b
-            evaluator(with_intercept)
+        for i, (blk, x, scaler) in enumerate(
+            zip(blocks, self.xs, self.feature_scalers)
+        ):
+            # one span a block: its eager dispatch, then the evaluator's
+            # round trip to the host (its ``wait`` and ``d2h`` nest here)
+            with trace.span("block", cat="eval", block=i):
+                part = scaler(blk) @ x
+                running = part if running is None else running + part
+                with_intercept = running if self.b is None else running + self.b
+                evaluator(with_intercept)
 
 
 jax.tree_util.register_pytree_node(

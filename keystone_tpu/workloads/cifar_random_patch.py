@@ -164,7 +164,9 @@ def learn_filters(conf: RandomCifarConfig, train_images: np.ndarray):
     need_imgs = min(n, max(1, -(-4 * conf.whitener_size // ppi)))
     rng = np.random.default_rng(conf.seed)
     img_idx = rng.permutation(n)[:need_imgs]
-    subset = jnp.asarray(train_images[img_idx])
+    picked = train_images[img_idx]
+    with trace.h2d("filter_images", picked.nbytes):
+        subset = jnp.asarray(picked)
 
     patches = Windower(conf.patch_steps, conf.patch_size)(subset)
     patch_vecs = ImageVectorizer()(patches)
@@ -240,20 +242,23 @@ def featurize_chunked(fn, images: np.ndarray, chunk: int, mesh=None) -> jnp.ndar
         pad = chunk - block.shape[0]
         if pad:
             block = np.pad(block, ((0, pad), (0, 0), (0, 0), (0, 0)))
-        dev_block = jnp.asarray(block)
-        if sharding is not None:
-            dev_block = jax.device_put(dev_block, sharding)
-        feats = fn(dev_block)
-        outs.append(feats[: chunk - pad] if pad else feats)
-    if sharding is not None and n % mesh.shape["data"] == 0:
-        # Left to itself XLA answers a concatenate along the sharded axis
-        # with a copy of the whole design matrix on every chip; keep it
-        # spread over the data axis, as the chunks were.
-        return jax.jit(
-            lambda *parts: jnp.concatenate(parts, axis=0),
-            out_shardings=sharding,
-        )(*outs)
-    return jnp.concatenate(outs, axis=0)
+        with trace.h2d("chunk", block.nbytes):
+            dev_block = jnp.asarray(block)
+            if sharding is not None:
+                dev_block = jax.device_put(dev_block, sharding)
+        with trace.span("chunk", cat="dispatch"):
+            feats = fn(dev_block)
+            outs.append(feats[: chunk - pad] if pad else feats)
+    with trace.span("chunks", cat="concat", chunks=len(outs)):
+        if sharding is not None and n % mesh.shape["data"] == 0:
+            # Left to itself XLA answers a concatenate along the sharded
+            # axis with a copy of the whole design matrix on every chip;
+            # keep it spread over the data axis, as the chunks were.
+            return jax.jit(
+                lambda *parts: jnp.concatenate(parts, axis=0),
+                out_shardings=sharding,
+            )(*outs)
+        return jnp.concatenate(outs, axis=0)
 
 
 def cifar_tar_label(name: str) -> int:
@@ -351,9 +356,9 @@ def _pad_to_chunk(batch, chunk: int):
             return jnp.pad(
                 batch.dev(), ((0, pad), (0, 0), (0, 0), (0, 0))
             )
-        return jnp.asarray(
-            np.pad(batch.host, ((0, pad), (0, 0), (0, 0), (0, 0)))
-        )
+        padded = np.pad(batch.host, ((0, pad), (0, 0), (0, 0), (0, 0)))
+        with trace.h2d("chunk", padded.nbytes):
+            return jnp.asarray(padded)
     return batch.dev()
 
 
@@ -388,8 +393,16 @@ def run(
     axis and the block solver runs fully distributed — the reference runs
     everything over partitioned RDDs (RandomPatchCifar.scala:20-85).
     Filter learning stays replicated: it is the analog of the reference's
-    driver-local ZCA fit (:38-51)."""
+    driver-local ZCA fit (:38-51).
+
+    The run is one root span ``fit``; its stages (``stage_timer``) tile it
+    but for glue, and each occurs once a fit."""
     configure_logging()
+    with trace.span("fit", cat="fit", rows=len(train)):
+        return _fit_and_score(conf, train, test, mesh)
+
+
+def _fit_and_score(conf: RandomCifarConfig, train, test, mesh) -> dict:
     log = _Log()
     t0 = time.perf_counter()
 
@@ -411,16 +424,17 @@ def run(
 
     # Warm the compile cache so the throughput number is steady-state — with
     # the same chunk shape AND sharding the real featurize pass will use.
-    warm_chunk = conf.featurize_chunk
-    warm = jnp.zeros((warm_chunk,) + train.images.shape[1:], jnp.float32)
-    if mesh is not None:
-        d = mesh.shape["data"]
-        warm_chunk = -(-warm_chunk // d) * d
-        warm = jax.device_put(
-            jnp.zeros((warm_chunk,) + train.images.shape[1:], jnp.float32),
-            row_sharding(mesh),
-        )
-    feat_fn(warm).block_until_ready()
+    with stage_timer("warm_featurizer"):
+        warm_chunk = conf.featurize_chunk
+        warm = jnp.zeros((warm_chunk,) + train.images.shape[1:], jnp.float32)
+        if mesh is not None:
+            d = mesh.shape["data"]
+            warm_chunk = -(-warm_chunk // d) * d
+            warm = jax.device_put(
+                jnp.zeros((warm_chunk,) + train.images.shape[1:], jnp.float32),
+                row_sharding(mesh),
+            )
+        trace.wait(feat_fn(warm), "warm_featurizer")
 
     cache_plan = None
     if conf.auto_cache:
@@ -453,7 +467,7 @@ def run(
         with stage_timer("featurize"):
             fitted_feats = chain.fit(train.images)
             train_features = fitted_feats(train.images)
-            train_features.block_until_ready()
+            trace.wait(train_features, "featurize")
         feat_secs = time.perf_counter() - t_feat
         # The scaler model is the chain's tail; the test path applies it to
         # freshly-featurized test data exactly like the manual path.
@@ -467,12 +481,13 @@ def run(
             train_conv = featurize_chunked(
                 feat_fn, train.images, conf.featurize_chunk, mesh=mesh
             )
-            train_conv.block_until_ready()
+            trace.wait(train_conv, "featurize")
         feat_secs = time.perf_counter() - t_feat
 
         # StandardScaler fit on train features (thenEstimator, reference :58)
-        scaler = StandardScaler().fit(train_conv)
-        train_features = scaler(train_conv)
+        with stage_timer("scale"):
+            scaler = StandardScaler().fit(train_conv)
+            train_features = scaler(train_conv)
 
     labels = ClassLabelIndicatorsFromIntLabels(conf.num_classes)(train.labels)
     with stage_timer("solve"):
@@ -519,9 +534,10 @@ def run(
             chunk = conf.featurize_chunk
 
             def conv_per_batch(batch):
-                return np.asarray(
-                    feat_fn(_pad_to_chunk(batch, chunk))
-                )[: len(batch)]
+                feats = trace.wait(
+                    feat_fn(_pad_to_chunk(batch, chunk)), "stream_chunk"
+                )
+                return np.asarray(feats)[: len(batch)]
 
             snap_root = snap_key = None
             if (
@@ -544,16 +560,17 @@ def run(
                     # vice versa.
                     extra=f"decode_mode={stream_cfg.decode_mode}",
                 )
-            test_feats, names, st = stream_features_snapshot(
-                lambda: stream_batches(
-                    conf.stream_test_tar, chunk, config=stream_cfg
-                ),
-                conv_per_batch,
-                root=snap_root,
-                key=snap_key,
-                tar_path=conf.stream_test_tar,
-                meta={"tar": ksnap.tar_identity(conf.stream_test_tar)},
-            )
+            with stage_timer("featurize_test"):
+                test_feats, names, st = stream_features_snapshot(
+                    lambda: stream_batches(
+                        conf.stream_test_tar, chunk, config=stream_cfg
+                    ),
+                    conv_per_batch,
+                    root=snap_root,
+                    key=snap_key,
+                    tar_path=conf.stream_test_tar,
+                    meta={"tar": ksnap.tar_identity(conf.stream_test_tar)},
+                )
             if st is not None and st.tuner is not None:
                 results_autotune = st.tuner.record()
                 log.log_info(
@@ -569,13 +586,16 @@ def run(
             test_pred = predict(scaler(jnp.asarray(test_feats)))
         else:
             test_labels = test.labels
-            test_conv = featurize_chunked(
-                feat_fn, test.images, conf.featurize_chunk, mesh=mesh
-            )
+            with stage_timer("featurize_test"):
+                test_conv = featurize_chunked(
+                    feat_fn, test.images, conf.featurize_chunk, mesh=mesh
+                )
             test_pred = predict(scaler(test_conv))
         test_eval = MulticlassClassifierEvaluator(
             test_pred, test_labels, conf.num_classes
         )
+        with trace.d2h("test_predictions", test_pred.nbytes):
+            test_predictions = np.asarray(test_pred)
 
     secs = time.perf_counter() - t0
     results = {
@@ -583,7 +603,7 @@ def run(
         "test_error": 100.0 * test_eval.total_error,
         # Predicted labels on the test split — the chaos harness diffs
         # these against the fault-free run to rule out silent wrong models.
-        "test_predictions": np.asarray(test_pred),
+        "test_predictions": test_predictions,
         "seconds": secs,
         "featurize_seconds": feat_secs,
         "featurize_images_per_sec": len(train) / feat_secs,
@@ -618,13 +638,14 @@ def run(
         # distribution rides the checkpoint manifest, so the serving
         # tier's drift monitor has a reference to judge live answers
         # against from the moment the engine warm-loads.
-        save_pipeline(
-            conf.pipeline_file,
-            servable,
-            numerics_baseline=knum.OutputSketch.for_outputs(
-                results["test_predictions"]
-            ).record(),
-        )
+        with stage_timer("checkpoint"):
+            save_pipeline(
+                conf.pipeline_file,
+                servable,
+                numerics_baseline=knum.OutputSketch.for_outputs(
+                    results["test_predictions"]
+                ).record(),
+            )
         log.log_info("saved fitted servable pipeline to %s", conf.pipeline_file)
     _maybe_serve(conf, test, results, log)
     log.log_info("Training error is: %s", train_eval.total_error)

@@ -18,7 +18,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ..core.logging import Logging, configure_logging
+from ..core import trace
+from ..core.logging import Logging, configure_logging, stage_timer
 from ..core.memory import log_fit_report
 from ..evaluation.multiclass import MulticlassClassifierEvaluator
 from ..loaders.timit import TIMIT_DIMENSION, TIMIT_NUM_CLASSES, TimitFeaturesData, timit_features_loader
@@ -83,31 +84,8 @@ def run(conf: TimitConfig, data: TimitFeaturesData, mesh=None) -> dict:
     configure_logging()
     log = _Log()
     t0 = time.perf_counter()
-
-    n_test = len(data.test.labels)
-    if mesh is not None:
-        train_data, nvalid = padded_shard_rows(data.train.data, mesh)
-        test_data, _ = padded_shard_rows(data.test.data, mesh)
-    else:
-        train_data, nvalid = jnp.asarray(data.train.data), None
-        test_data = jnp.asarray(data.test.data)
-
-    batch_featurizer = build_batch_featurizers(conf, train_data, nvalid)
-    training_batches = [
-        mask_pad_rows(f(train_data), nvalid) for f in batch_featurizer
-    ]
-
-    labels = ClassLabelIndicatorsFromIntLabels(conf.num_classes)(data.train.labels)
-
-    test_batches = [f(test_data) for f in batch_featurizer]
-
-    solver = BlockLeastSquaresEstimator(
-        conf.num_cosine_features, conf.num_epochs, conf.lam, mesh=mesh
-    )
-    model = solver.fit(training_batches, labels, nvalid=nvalid)
-    log_fit_report(solver, label="timit cosine solve")
-
     results: dict = {}
+    n_test = len(data.test.labels)
 
     def evaluator(pred):
         predicted = MaxClassifier()(pred[:n_test])
@@ -117,7 +95,34 @@ def run(conf: TimitConfig, data: TimitFeaturesData, mesh=None) -> dict:
         results["test_error"] = 100.0 * ev.total_error
         log.log_info("TEST Error is %s%%", results["test_error"])
 
-    model.apply_and_evaluate(test_batches, evaluator)
+    # one root span a fit; the three stages tile it but for glue
+    with trace.span("fit", cat="fit", rows=len(data.train.labels)):
+        if mesh is not None:
+            train_data, nvalid = padded_shard_rows(data.train.data, mesh)
+            test_data, _ = padded_shard_rows(data.test.data, mesh)
+        else:
+            train_data, nvalid = jnp.asarray(data.train.data), None
+            test_data = jnp.asarray(data.test.data)
+
+        with stage_timer("featurize"):
+            batch_featurizer = build_batch_featurizers(conf, train_data, nvalid)
+            training_batches = [
+                mask_pad_rows(f(train_data), nvalid) for f in batch_featurizer
+            ]
+            labels = ClassLabelIndicatorsFromIntLabels(conf.num_classes)(
+                data.train.labels
+            )
+            test_batches = [f(test_data) for f in batch_featurizer]
+
+        with stage_timer("solve"):
+            solver = BlockLeastSquaresEstimator(
+                conf.num_cosine_features, conf.num_epochs, conf.lam, mesh=mesh
+            )
+            model = solver.fit(training_batches, labels, nvalid=nvalid)
+            log_fit_report(solver, label="timit cosine solve")
+
+        with stage_timer("eval"):
+            model.apply_and_evaluate(test_batches, evaluator)
     results["seconds"] = time.perf_counter() - t0
     return results
 

@@ -10,7 +10,11 @@
 * ``resilience.counters`` atomic ``snapshot(reset=)`` (no read/reset race)
   and fault instants in the trace (chaos ``--trace`` invariant);
 * ``stage_timer`` back-compat (same log line, now also a span) and the
-  ``KEYSTONE_LOG_LEVEL`` env knob.
+  ``KEYSTONE_LOG_LEVEL`` env knob;
+* the fit timeline (ISSUE 26): span ``id`` / ``parent_id`` / ``root``, a
+  stage's self time and its wait and copy sums on an injected clock, the
+  stage sets of a tiny CIFAR and a tiny TIMIT fit, profiler annotations
+  only while tracing, and the cost of a span with the ring on.
 """
 
 import gc
@@ -146,6 +150,174 @@ def test_span_error_attribute_recorded(tmp_path):
     trace.flush(path)
     spans = _spans_by_name(trace_view.load_events(path))
     assert spans["doomed"][0]["args"]["error"] == "ValueError"
+
+
+# -- ids: a span names what caused it -----------------------------------------
+
+
+def test_span_id_parent_id_and_root_across_nesting(tmp_path):
+    path = _trace_to(tmp_path)
+    with trace.span("fit", cat="fit"):
+        with trace.span("outer"):
+            with trace.span("inner"):
+                pass
+        with trace.span("sibling"):
+            pass
+    with trace.span("next_fit", cat="fit"):
+        pass
+    trace.flush(path)
+    spans = _spans_by_name(trace_view.load_events(path))
+    arg = lambda name: spans[name][0]["args"]  # noqa: E731
+    fit, outer, inner, sib = arg("fit"), arg("outer"), arg("inner"), arg("sibling")
+    ids = [a["id"] for a in (fit, outer, inner, sib, arg("next_fit"))]
+    assert len(set(ids)) == 5  # process-unique
+    assert "parent_id" not in fit and fit["root"] == fit["id"]
+    assert outer["parent_id"] == fit["id"] and sib["parent_id"] == fit["id"]
+    assert inner["parent_id"] == outer["id"] and inner["parent"] == "outer"
+    # the spans of one fit share its root; the next fit has another
+    assert {a["root"] for a in (fit, outer, inner, sib)} == {fit["id"]}
+    assert arg("next_fit")["root"] == arg("next_fit")["id"] != fit["id"]
+    assert inner["depth"] == 2  # depth and parent stay beside the ids
+
+
+def test_span_ids_and_roots_across_two_threads(tmp_path):
+    path = _trace_to(tmp_path)
+    barrier = threading.Barrier(2)
+
+    def worker(tag):
+        barrier.wait()
+        with trace.span(f"{tag}_root"):
+            for _ in range(20):
+                with trace.span(f"{tag}_leaf"):
+                    pass
+
+    threads = [threading.Thread(target=worker, args=(t,)) for t in ("a", "b")]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(10.0)
+        assert not t.is_alive()
+    trace.flush(path)
+    spans = _spans_by_name(trace_view.load_events(path))
+    every = [ev["args"]["id"] for evs in spans.values() for ev in evs]
+    assert len(every) == 42 and len(set(every)) == 42
+    for tag in ("a", "b"):
+        root = spans[f"{tag}_root"][0]["args"]
+        for leaf in spans[f"{tag}_leaf"]:
+            # a root never crosses threads, whatever was open elsewhere
+            assert leaf["args"]["root"] == root["id"]
+            assert leaf["args"]["parent_id"] == root["id"]
+
+
+# -- stages: self time, waits and copies on an injected clock -------------------
+
+
+class _FakeClock:
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        return self.t
+
+    def tick(self, seconds):
+        self.t += seconds
+
+
+def _last(name):
+    return trace.metrics.hist_windows()[name]["samples"][-1]
+
+
+@pytest.mark.parametrize("flight_depth", [trace.DEFAULT_FLIGHT_DEPTH, 0])
+def test_stage_self_time_and_wait_on_injected_clock(monkeypatch, flight_depth):
+    """A stage's self time leaves out the stage nested in it; a wait or a
+    copy goes to the innermost open stage only.  With the flight ring off
+    the spans are no-ops and the sums are the same."""
+    clock = _FakeClock()
+    monkeypatch.setattr(trace, "_clock", clock)
+    depth_before = trace.flight_depth()
+    trace.set_flight_depth(flight_depth)
+    try:
+        with stage_timer("t_outer"):
+            clock.tick(0.010)
+            with trace._Charged(trace.span("device", cat="wait")):
+                clock.tick(0.004)  # blocked beneath t_outer
+            with stage_timer("t_inner"):
+                clock.tick(0.020)
+                with trace.d2h("read", 64):
+                    clock.tick(0.003)  # counts as waiting, beneath t_inner
+                with trace.h2d("chunk", 2_000_000):
+                    clock.tick(0.002)
+            clock.tick(0.001)
+        assert (trace.span("x") is trace._NULL) == (flight_depth == 0)
+    finally:
+        trace.set_flight_depth(depth_before)
+    assert _last("stage_ms.t_inner") == pytest.approx(25.0)
+    assert _last("stage_ms.t_outer") == pytest.approx(15.0)  # 40 less 25
+    assert _last("stage_wait_ms.t_outer") == pytest.approx(4.0)
+    assert _last("stage_wait_ms.t_inner") == pytest.approx(3.0)
+    assert _last("stage_h2d_ms.t_inner") == pytest.approx(2.0)
+    assert _last("stage_h2d_mb.t_inner") == 2.0
+    assert _last("stage_h2d_mb.t_outer") == 0.0 == _last("stage_h2d_ms.t_outer")
+
+
+def test_wait_blocks_and_returns_its_value():
+    x = jnp.arange(8) * 2
+    with stage_timer("t_wait"):
+        got = trace.wait(x, "probe")
+    assert got is x
+    waits = [e for e in trace.flight_events() if e.get("cat") == "wait"]
+    assert [e["name"] for e in waits] == ["probe"]
+    assert waits[0]["args"]["parent"] == "t_wait"
+    assert _last("stage_wait_ms.t_wait") >= waits[0]["dur"] / 1e3
+
+
+# -- one clock with the device trace --------------------------------------------
+
+
+def test_profiler_annotation_only_while_tracing(tmp_path, monkeypatch):
+    import jax
+
+    made = []
+
+    class Recorder:
+        def __init__(self, name, **kwargs):
+            made.append((name, kwargs))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            made.append("exit")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    assert not trace.enabled() and trace.flight_depth() > 0
+    with stage_timer("quiet"):
+        with trace.span("inner", cat="wait"):
+            pass
+    assert made == []  # the ring records; nothing is handed to the profiler
+    _trace_to(tmp_path)
+    with stage_timer("loud"):
+        with trace.span("inner", cat="wait"):
+            pass
+    names = [m[0] for m in made if m != "exit"]
+    assert names == ["ks/stage/loud", "ks/wait/inner"]
+    assert made.count("exit") == 2
+    (_, outer), (_, inner) = [m for m in made if m != "exit"]
+    assert inner["root"] == outer["id"] == outer["root"] != inner["id"]
+
+
+def test_span_cost_with_the_ring_on_stays_small():
+    """Enter + exit of a span with tracing off and the flight ring on: the
+    cost an untraced fit pays for each of its ~150 spans."""
+    assert not trace.enabled() and trace.flight_depth() > 0
+    costs = []
+    for _ in range(10_000):
+        t0 = time.perf_counter()
+        with trace.span("hot", cat="dispatch"):
+            pass
+        costs.append(time.perf_counter() - t0)
+    costs.sort()
+    assert costs[len(costs) // 2] < 20e-6, costs[len(costs) // 2]
 
 
 # -- disabled-mode overhead ---------------------------------------------------
@@ -729,3 +901,106 @@ def test_trace_view_summarizes(tmp_path, capsys):
     assert "stage_one" in out and "stage_two" in out
     assert "view_probe_fault" in out
     assert "top 10 spans" in out
+
+
+# -- the two fits' timelines ---------------------------------------------------
+
+
+def _stage_ancestor(ev, by_id):
+    while ev is not None:
+        if ev["cat"] == "stage":
+            return ev
+        ev = by_id.get(ev["args"].get("parent_id"))
+    return None
+
+
+def test_tiny_cifar_fit_emits_its_stage_set_under_one_root(tmp_path, rng):
+    from keystone_tpu.loaders.cifar import LabeledImageBatch
+    from keystone_tpu.workloads import cifar_random_patch as cifar
+
+    def batch(n):
+        labels = rng.integers(0, 4, n).astype(np.int32)
+        images = rng.uniform(0, 255, (n, 32, 32, 3)).astype(np.float32)
+        images[:, :, :, 0] += 40.0 * labels[:, None, None]
+        return LabeledImageBatch(images, labels)
+
+    # whole chunks, so that what crosses to the device is the images alone
+    train, test = batch(128), batch(64)
+    conf = cifar.RandomCifarConfig(
+        num_filters=8, patch_steps=2, lam=10.0, whitener_size=500,
+        featurize_chunk=64, num_classes=4,
+        pipeline_file=str(tmp_path / "chain"),
+    )
+    trace.metrics.reset()
+    path = _trace_to(tmp_path)
+    cifar.run(conf, train, test)
+    trace.flush(path)
+    trace.disable()
+    events = [e for e in trace_view.load_events(path) if e.get("ph") == "X"]
+    by_id = {e["args"]["id"]: e for e in events}
+    (fit,) = [e for e in events if e["cat"] == "fit"]
+    assert fit["name"] == "fit" and fit["args"]["rows"] == 128
+    stages = [e for e in events if e["cat"] == "stage"]
+    assert sorted(e["name"] for e in stages) == sorted([
+        "learn_filters", "warm_featurizer", "featurize", "scale", "solve",
+        "eval", "featurize_test", "checkpoint",
+    ])  # each once
+    assert {e["args"]["root"] for e in stages} == {fit["args"]["id"]}
+    nested = {e["name"]: e["args"]["parent"] for e in stages}
+    assert nested.pop("featurize_test") == "eval"
+    assert set(nested.values()) == {"fit"}  # the rest are top-level
+    # the top-level stages tile the root but for glue
+    top = sum(e["dur"] for e in stages if e["args"]["parent"] == "fit")
+    assert top <= fit["dur"] and top >= 0.8 * fit["dur"]
+    waits = [e for e in events if e["cat"] in ("wait", "d2h")]
+    assert len([e for e in waits if e["cat"] == "wait"]) >= 4
+    for ev in waits + [e for e in events if e["cat"] == "h2d"]:
+        assert _stage_ancestor(ev, by_id) is not None, ev
+    hists = trace.metrics.hist_windows()
+    moved = sum(
+        round(h["total"] * 1e6)
+        for name, h in hists.items() if name.startswith("stage_h2d_mb.")
+    )
+    h2d = [e for e in events if e["cat"] == "h2d"]
+    # every copy is in a stage's sum; the chunks are the images, exactly
+    assert sum(e["args"]["bytes"] for e in h2d) == moved
+    chunks = [e["args"]["bytes"] for e in h2d if e["name"] == "chunk"]
+    assert sum(chunks) == train.images.nbytes + test.images.nbytes
+    assert len(chunks) == 3
+    assert {e["name"] for e in h2d} == {"chunk", "filter_images", "labels"}
+    assert len([e for e in events if e["cat"] == "dispatch"]) == 3
+    assert len([e for e in events if e["cat"] == "concat"]) == 2
+    for name in nested.keys() | {"featurize_test"}:
+        assert hists[f"stage_ms.{name}"]["count"] == 1
+        assert hists[f"stage_wait_ms.{name}"]["count"] == 1
+
+
+def test_tiny_timit_run_emits_its_three_stages(tmp_path, rng):
+    from keystone_tpu.loaders.timit import TimitFeaturesData, TimitSplit
+    from keystone_tpu.workloads import timit
+
+    def split(n):
+        return TimitSplit(
+            rng.normal(size=(n, 12)).astype(np.float32),
+            rng.integers(0, 3, n).astype(np.int32),
+        )
+
+    conf = timit.TimitConfig(
+        num_cosines=2, num_cosine_features=32, num_epochs=1, gamma=0.2,
+        lam=1e-2, num_classes=3, dimension=12,
+    )
+    path = _trace_to(tmp_path)
+    timit.run(conf, TimitFeaturesData(split(96), split(32)))
+    trace.flush(path)
+    events = [e for e in trace_view.load_events(path) if e.get("ph") == "X"]
+    (fit,) = [e for e in events if e["cat"] == "fit"]
+    stages = [e for e in events if e["cat"] == "stage"]
+    assert [e["name"] for e in stages] == ["featurize", "solve", "eval"]
+    assert {e["args"]["root"] for e in stages} == {fit["args"]["id"]}
+    # a round trip to the host a block: the evaluator's wait and read
+    blocks = [e for e in events if e["cat"] == "eval"]
+    reads = [e for e in events if e["cat"] == "d2h"]
+    assert len(blocks) == 2 == len(reads)
+    assert {e["args"]["parent_id"] for e in reads} == {
+        e["args"]["id"] for e in blocks
+    }
