@@ -55,12 +55,13 @@ FULL = dict(
     train=50_000, test=10_000, whitener=100_000, requests=256,
     jpeg_train=4_096, jpeg_test=2_048, golden=64,
     idct_images=2_048, fv=(64, 13_165, 64, 16), pool_images=1_024,
-    pool_step=8,
+    pool_step=8, conv=(2_048, 1_250, 128),
 )
 TINY = dict(
     train=600, test=200, whitener=4_000, requests=24,
     jpeg_train=96, jpeg_test=48, golden=8,
     idct_images=8, fv=(3, 700, 24, 8), pool_images=4, pool_step=2,
+    conv=(5, 24, 4),
 )
 
 
@@ -462,10 +463,65 @@ def _kernel_rect_pool(ctx, interpret, rng) -> dict:
     }
 
 
+def _kernel_conv_form(ctx, interpret, rng) -> dict:
+    """FusedConvFeaturizer's kernel form at the benchmark's widths (2,048
+    images x 1,250 filters): against the node's XLA form on every image,
+    and both against the benchmark's plain reference on a slice.  The two
+    forms differ by the XLA form's bf16 activations and its products on
+    un-normalized pixels; the kernel form has to be the closer one."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.lib.manifest import load_module
+    from keystone_tpu.ops.conv_fused import FusedConvFeaturizer
+
+    n, f, ref_n = ctx["size"]["conv"]
+    filters = rng.normal(size=(f, 6, 6, 3)).astype(np.float32)
+    filters /= np.linalg.norm(filters.reshape(f, -1), axis=1)[:, None, None, None]
+    means = (0.1 * rng.normal(size=(108,))).astype(np.float32)
+    palette = rng.uniform(40, 215, (10, 3)).astype(np.float32)
+    imgs = jnp.asarray(
+        _class_images(rng, palette, rng.integers(0, 10, n), 32).transpose(0, 2, 3, 1)
+    )
+    node = FusedConvFeaturizer(
+        filters, whitener_means=means, pool_stride=13, pool_size=14, alpha=0.25
+    )
+    got = np.asarray(
+        jax.jit(lambda im: node._kernel_form(im, interpret=interpret))(imgs)
+    )
+    xla = np.asarray(jax.jit(node._xla_form)(imgs))
+    want = np.asarray(load_module("reference", "cifar_rp")._featurize_chunk(
+        imgs[:ref_n], jnp.asarray(filters.reshape(f, -1)), jnp.asarray(means),
+        0.25, ps=6, pool=14, stride=13, precision="highest",
+    ))
+
+    def rms_gap(a, b):
+        return float(np.sqrt(np.mean((a - b) ** 2)) / np.sqrt(np.mean(b**2)))
+
+    out = {
+        "shape": [n, f],
+        "kernel_vs_xla_rms": rms_gap(got, xla),
+        "kernel_vs_reference_rms": rms_gap(got[:ref_n], want),
+        "xla_vs_reference_rms": rms_gap(xla[:ref_n], want),
+    }
+    check(got.shape == xla.shape == (n, 8 * f), f"shapes {got.shape} {xla.shape}")
+    check(out["kernel_vs_xla_rms"] < 2e-2, f"the two forms differ: {out}")
+    check(out["kernel_vs_reference_rms"] < 4e-3, f"kernel form off the reference: {out}")
+    # Off the chip the XLA form's products are exact f32: no contest.
+    check(
+        interpret
+        or out["kernel_vs_reference_rms"] <= out["xla_vs_reference_rms"] + 1e-4,
+        f"kernel form further from the reference than the XLA form: {out}",
+    )
+    return out
+
+
 KERNELS = {
     "idct_blocks_pallas": _kernel_idct,
     "fv_stats_pallas": _kernel_fv_stats,
     "rect_pool_pallas": _kernel_rect_pool,
+    "conv_rect_pool": _kernel_conv_form,
 }
 
 

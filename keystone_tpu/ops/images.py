@@ -158,6 +158,17 @@ class Pooler(Transformer):
         stride_start = self.pool_size // 2
         return math.ceil((dim - stride_start) / self.stride)
 
+    def windows(self, dim: int) -> tuple:
+        """``(start, length)`` of every pool along an axis of ``dim``
+        pixels — the coverage ``__call__`` gives (truncated at the high
+        edge), for kernels that take the pools themselves."""
+        half = self.pool_size // 2
+        span = 2 * half if self.pool_size % 2 == 1 else self.pool_size
+        return tuple(
+            (p * self.stride, min(p * self.stride + span, dim) - p * self.stride)
+            for p in range(self._num_pools(dim))
+        )
+
     def __call__(self, batch):
         n, h, w, c = batch.shape
         ps, st = self.pool_size, self.stride

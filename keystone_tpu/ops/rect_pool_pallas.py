@@ -24,33 +24,23 @@ to satisfy the constrained tiled layout — the copies cost more than the
 saved second read.  Same boundary economics as the im2col kernels in
 ROOFLINE.md: beating XLA's fusion pipeline requires removing streams it
 is FORCED to keep, and a custom-call boundary adds streams instead.
-Kept opt-in (KEYSTONE_PALLAS=1 in FusedConvFeaturizer) as the measured
-proof and as the template for shapes where a producer emits the layout
-natively.
+Kept as the measured proof (its test and chip_smoke.py's leg C call it
+directly; no node selects it, and ROADMAP design item D5 decides its
+future).  The kernel that did win owns the whole chain and so has no
+boundary on the activation tensor at all: ops/conv_fused.py's kernel form.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _num_pools(dim: int, stride: int, pool_size: int) -> int:
-    return math.ceil((dim - pool_size // 2) / stride)
+from .images import Pooler
 
 
-def _windows(dim: int, stride: int, pool_size: int):
-    """(start, length) per pool — Pooler coverage (truncated high edge)."""
-    half = pool_size // 2
-    span = 2 * half if pool_size % 2 == 1 else pool_size
-    return [
-        (p * stride, min(p * stride + span, dim) - p * stride)
-        for p in range(_num_pools(dim, stride, pool_size))
-    ]
 
 def _kernel(z_ref, o_ref, *, wy, wx, alpha: float, max_val: float):
     z = z_ref[...].astype(jnp.float32)  # [b, oh, ow, F]
@@ -88,8 +78,8 @@ def rect_pool_pallas(
     """[N, oh, ow, F] activations -> [N, npools*2F] pooled features in the
     unfused element order (position-major, pos block then neg block)."""
     n, oh, ow, f = z.shape
-    wy = tuple(_windows(oh, pool_stride, pool_size))
-    wx = tuple(_windows(ow, pool_stride, pool_size))
+    pooler = Pooler(pool_stride, pool_size, None, "sum")
+    wy, wx = pooler.windows(oh), pooler.windows(ow)
     npools = len(wy) * len(wx)
 
     b = images_per_step

@@ -1,0 +1,50 @@
+"""The main path's Pallas kernels, compiled at the benchmark's widths for a
+v5e that is described and not attached: what Mosaic and the TPU compiler
+refuse (a slice off the tiling, too much VMEM, a program over the chip's
+memory) fails here, at no chip time.  Nothing runs, so nothing here says a
+result is right or fast: tests/test_conv_fused.py and chip_smoke.py do.
+
+Every TPU compile of the suite lives in this one file, and the topology is
+described inside a fixture: one process at a time may load libtpu, and only
+the worker that runs this file does.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from keystone_tpu.ops.conv_fused import FusedConvFeaturizer
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to ask
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_conv_kernel_form_compiles_at_benchmark_widths(one_chip):
+    """2,048 images x 1,250 filters (`cifar_rp_10k_share8`): the program
+    holds the kernel, no array of the activations' shape, and a fraction of
+    the XLA form's 3.8 GB of temporaries."""
+    rng = np.random.default_rng(0)
+    node_ = FusedConvFeaturizer(
+        rng.normal(size=(1250, 6, 6, 3)).astype(np.float32),
+        whitener_means=rng.normal(size=(108,)).astype(np.float32),
+        pool_stride=13, pool_size=14, alpha=0.25,
+    )
+    chunk = jax.ShapeDtypeStruct((2048, 32, 32, 3), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(node_._kernel_form).lower(chunk).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "27,27,1250]" not in text and "27,27,1280]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -37,10 +37,8 @@ def main():
         f"hbm={bw / 1e9:.0f} GB/s"
     )
 
-    # The probe's purpose is reproducing the ROOFLINE.md XLA-variant rows;
-    # a stray KEYSTONE_PALLAS=1 would silently swap in the opt-in kernel
-    # under the SHIPPED label.
-    os.environ.pop("KEYSTONE_PALLAS", None)
+    # 100 filters: FusedConvFeaturizer takes its XLA form (conv_form's
+    # rule); tools/conv_form_probe.py times the kernel form.
     conf = RandomCifarConfig(
         num_filters=100, patch_size=6, patch_steps=1, pool_size=14,
         pool_stride=13, alpha=0.25, whitener_size=20000, featurize_chunk=1024,
