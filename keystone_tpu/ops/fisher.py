@@ -40,12 +40,17 @@ def _fv_from_stats(s0, s1, s2, means, variances, weights, n_valid):
     sigma = jnp.sqrt(variances)
     n_safe = jnp.maximum(n_valid, 1.0)[..., None, None]
     s0e = s0[..., None, :]
-    g_mean = (s1 - means * s0e) / (sigma * jnp.sqrt(weights) * n_safe)
+    # A centre whose weight EM drove to exactly 0 takes no posterior mass
+    # (log 0), so its gradients are 0 / 0 by the formula: they are zeros.
+    alive = weights > 0
+    w = jnp.where(alive, weights, 1.0)
+    g_mean = (s1 - means * s0e) / (sigma * jnp.sqrt(w) * n_safe)
     g_var = (
         (s2 - 2.0 * means * s1 + (means * means - variances) * s0e)
-        / (variances * jnp.sqrt(2.0 * weights) * n_safe)
+        / (variances * jnp.sqrt(2.0 * w) * n_safe)
     )
-    return jnp.concatenate([g_mean, g_var], axis=-1)  # [..., d, 2K]
+    both = jnp.concatenate([g_mean, g_var], axis=-1)  # [..., d, 2K]
+    return jnp.where(jnp.concatenate([alive, alive]), both, 0.0)
 
 
 def _use_pallas() -> bool:

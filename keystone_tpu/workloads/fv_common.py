@@ -10,14 +10,19 @@ fixed-dimension feature rows are scattered back to original order.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import memory as kmem
+from ..core import trace
 from ..ops.fisher import FisherVector
 from ..ops.images import GrayScaler, PixelScaler
+from ..ops.sift import DESC_DIM
 from ..ops.stats import NormalizeRows, SignedHellingerMapper
 from ..ops.util import MatrixVectorizer
 from ..parallel.mesh import padded_shard_rows
@@ -159,33 +164,46 @@ def searched_bucket_featurize(label: str, images: list, per_batch, mesh,
     return out, report.placement
 
 
+def draw_columns(totals: dict, num_samples: int, seed: int = 42) -> dict:
+    """The ColumnSampler's draw, before any descriptor exists: ``totals`` is
+    ``{shape: (images, descriptors an image)}`` in bucket order; returns
+    ``{shape: sorted indices into the bucket's images * descriptors
+    columns}``.  Each bucket gets its proportional quota, drawn uniformly
+    without replacement (the reference samples per image,
+    Sampling.scala:12-22); a set no larger than ``num_samples`` is taken
+    whole."""
+    rng = np.random.default_rng(seed)
+    grand_total = sum(n * c for n, c in totals.values())
+    draws = {}
+    for shape, (n, c) in totals.items():
+        total = n * c
+        if grand_total <= num_samples:
+            draws[shape] = np.arange(total)
+        else:
+            quota = min(total, max(1, int(num_samples * total / grand_total)))
+            draws[shape] = np.sort(rng.choice(total, quota, replace=False))
+    return draws
+
+
 def sample_columns(desc_buckets: dict, num_samples: int, seed: int = 42) -> jnp.ndarray:
     """ColumnSampler analog over per-bucket [n, d, cols] descriptor arrays:
     uniform sample of descriptor columns -> [d, <= num_samples].
 
-    Each bucket contributes its proportional quota and only the sampled
-    columns are materialized — never the full descriptor set (the reference
-    ColumnSampler likewise samples per image, Sampling.scala:12-22)."""
-    rng = np.random.default_rng(seed)
+    Only the drawn columns (:func:`draw_columns`) are materialized — never
+    the full descriptor set."""
     # valid image count is len(idx) — descriptor arrays may carry sharding
     # pad rows past it (see shard_batch) which must never be sampled
-    totals = {
-        shape: len(idx) * descs.shape[2]
-        for shape, (idx, descs) in desc_buckets.items()
-    }
-    grand_total = sum(totals.values())
+    draws = draw_columns(
+        {
+            shape: (len(idx), descs.shape[2])
+            for shape, (idx, descs) in desc_buckets.items()
+        },
+        num_samples, seed,
+    )
     picks = []
-    for shape, (idx_arr, descs) in desc_buckets.items():
-        n, d, c = len(idx_arr), descs.shape[1], descs.shape[2]
-        total = totals[shape]
-        if grand_total <= num_samples:
-            quota = total
-            idx = np.arange(total)
-        else:
-            quota = min(total, max(1, int(num_samples * total / grand_total)))
-            idx = np.sort(rng.choice(total, quota, replace=False))
+    for shape, (_idx, descs) in desc_buckets.items():
         # gather the quota columns directly — no transposed full copy
-        im, col = np.divmod(idx, c)
+        im, col = np.divmod(draws[shape], descs.shape[2])
         picks.append(descs[jnp.asarray(im), :, jnp.asarray(col)].T)  # [d, quota]
     return jnp.concatenate(picks, axis=1)
 
@@ -260,6 +278,220 @@ def plan_pca_materialization(
         mesh=mesh,
     )
     return plan, plan.decisions[0].cached
+
+
+# -- the chunked two-pass fit ---------------------------------------------------
+#
+# At the reference's own sizes the descriptors of a training set do not fit
+# a chip (VOC 2007: 73,866 descriptors x 128 x 4 B an image, 189 GB for 5,011
+# images), so a fit never holds them.  A *sampling pass* runs SIFT chunk by
+# chunk and keeps only the columns drawn for the PCA and GMM samples; a
+# *featurizing pass* runs SIFT -> PCA -> Fisher vector -> normalize chunk by
+# chunk and keeps only each chunk's feature rows.  SIFT is one program a
+# shape bucket, run by both passes; each pass adds a small program of its own
+# on the chunk's descriptors.  A bucket's last chunk is padded to the chunk.
+
+#: most images one chunk program takes
+MAX_CHUNK = 64
+#: the share of the admission budget (``core.memory.hbm_budget``) that a
+#: chunk's reckoned descriptors, projections and posteriors may take.  The
+#: reckoning errs high (compiled for the v5e at 64 images of 375x500: the
+#: Fisher-vector half asks 6.4 GB of scratch beside 0.6 GB of descriptor
+#: bytes, SIFT 1.9 GB, against 8.8 GB reckoned), and the features and samples
+#: a fit holds beside it take under 2 GB of a 16 GB chip.
+CHUNK_BUDGET_SHARE = 0.6
+#: chunks the host may dispatch ahead of the device.  A chunk's descriptors
+#: (0.6 GB of bytes at 64 images of 375x500) are a program's output, held from
+#: dispatch until the pass's own half has read them; unbounded, the host ran
+#: ~12 chunks ahead and the allocator's peak read 10.5 GB of a 16 GB chip
+#: (my chip run, PR 28).  Two keep the device's queue full.
+RUN_AHEAD = 2
+#: a chunk's gather of drawn columns is padded to a multiple of this.  The most
+#: any chunk draws moves with the draw (26,100-26,900 of a chunk's 4.7 M
+#: columns at VOC's sizes), and every new value is a new gather program to
+#: compile; a step of many standard deviations (~160 there) keeps one shape.
+SAMPLE_CAP_STEP = 4096
+
+
+@dataclasses.dataclass(frozen=True)
+class ChunkPlan:
+    """How a split's images go through the chunk programs: per shape bucket
+    (in first-occurrence order) the images' ordinals, the descriptors an
+    image, the bytes an image reckoned against the budget, and the chunk."""
+
+    index: dict  # shape -> np.ndarray of image ordinals
+    cols: dict  # shape -> descriptors an image
+    image_bytes: dict  # shape -> reckoned float32 bytes an image
+    chunk: dict  # shape -> images a chunk
+    budget: int | None
+
+    @property
+    def totals(self) -> dict:
+        """``{shape: (images, descriptors an image)}`` for :func:`draw_columns`."""
+        return {s: (len(idx), self.cols[s]) for s, idx in self.index.items()}
+
+    @property
+    def order(self) -> np.ndarray:
+        """Image ordinals in the order the chunk programs emit their rows."""
+        if not self.index:
+            return np.zeros(0, np.int64)
+        return np.concatenate(list(self.index.values()))
+
+
+def plan_chunks(images: list, sift, desc_dim: int, vocab_size: int, mesh=None) -> ChunkPlan:
+    """Bucket ``images`` by shape and choose each bucket's chunk from what
+    can be seen: an image's descriptors, their projection and their
+    posteriors in float32, against ``CHUNK_BUDGET_SHARE`` of the admission
+    budget.  The chunk is the largest power of two that fits (a budget read
+    live moves a little between fits; a power of two does not move with
+    it), at most ``MAX_CHUNK`` and at most the bucket; with no budget to read
+    (the CPU) it is ``MAX_CHUNK``.  Under a mesh it is rounded up to the data
+    axis.  Records one ``fv_plan`` instant."""
+    from ..parallel.mesh import DATA_AXIS
+
+    groups: dict = {}
+    for i, img in enumerate(images):
+        groups.setdefault(tuple(img.shape[:2]), []).append(i)
+    budget = kmem.hbm_budget()
+    index, cols, image_bytes, chunk = {}, {}, {}, {}
+    for shape, idx in groups.items():
+        c = sift.num_descriptors(*shape)
+        per_image = 4 * c * (DESC_DIM + desc_dim + vocab_size)
+        fit = MAX_CHUNK
+        if budget is not None:
+            fit = max(1, int(CHUNK_BUDGET_SHARE * budget) // per_image)
+            fit = min(MAX_CHUNK, 1 << (fit.bit_length() - 1))
+        fit = min(fit, len(idx))
+        if mesh is not None:
+            d = mesh.shape[DATA_AXIS]
+            fit = -(-fit // d) * d
+        index[shape] = np.asarray(idx)
+        cols[shape], image_bytes[shape], chunk[shape] = c, per_image, fit
+    plan = ChunkPlan(index, cols, image_bytes, chunk, budget)
+    trace.instant(
+        "fv_plan",
+        chunk={f"{h}x{w}": c for (h, w), c in chunk.items()},
+        buckets={f"{h}x{w}": len(i) for (h, w), i in index.items()},
+        chunk_bytes={f"{h}x{w}": chunk[(h, w)] * b for (h, w), b in image_bytes.items()},
+        budget=budget,
+    )
+    return plan
+
+
+def _chunks(plan: ChunkPlan, images: list, mesh):
+    """``(shape, images in the chunk that are real, device block, image
+    shape)`` for every chunk of every bucket.  A block crosses as a matrix ``[chunk, H*W*C]`` in
+    the images' own dtype (a matrix copies at several times the rate of an
+    image batch, whose batch axis the device keeps innermost) and is given
+    its shape back inside the program; a short last chunk is padded by
+    repeating its last image, and the pad rows are dropped by the caller."""
+    for shape, idx in plan.index.items():
+        c = plan.chunk[shape]
+        for start in range(0, len(idx), c):
+            sel = idx[start : start + c]
+            block = np.stack([images[i] for i in sel])
+            if len(sel) < c:
+                block = np.pad(
+                    block, ((0, c - len(sel)), (0, 0), (0, 0), (0, 0)), mode="edge"
+                )
+            flat = block.reshape(c, -1)
+            with trace.h2d("chunk", flat.nbytes):
+                dev = shard_batch(flat, mesh)
+            trace.metrics.inc(f"fv.chunks.{shape[0]}x{shape[1]}")
+            yield shape, len(sel), dev, block.shape[1:]
+
+
+@functools.partial(jax.jit, static_argnames=("image_shape",))
+def _describe_chunk(sift, flat, *, image_shape):
+    """SIFT of one chunk: the one program a shape that both passes run (the
+    SIFT programs are the large ones: ~38 MB compiled at VOC's sizes, against
+    a compile cache of 192 MiB on the benchmark's machine).  A quantized
+    descriptor entry is a whole number to 255, so the chunk's descriptors
+    pass to the next program as bytes, exactly, at a quarter of float32."""
+    images = flat.reshape((flat.shape[0],) + image_shape)
+    return sift(grayscale(images)).astype(jnp.uint8)
+
+
+@jax.jit
+def _sample_chunk(descs, im, col):
+    """The sampling pass's half of a chunk: the drawn columns only.  ``im``,
+    ``col``: ``[sets, cap]`` positions inside the chunk (padded with zeros)
+    -> ``[sets, cap, 128]``."""
+    return descs[im, :, col].astype(jnp.float32)
+
+
+@jax.jit
+def _encode_chunk(pca, gmm, descs):
+    """The featurizing pass's half of a chunk: PCA -> Fisher features.  The
+    fitted nodes are arguments, so every fit runs the one program."""
+    return fisher_feature_pipeline(gmm)(pca(descs.astype(jnp.float32)))
+
+
+@jax.jit
+def _gather_samples(parts, positions):
+    """The chunks' padded gathers -> each set's ``[samples, 128]`` rows."""
+    return [
+        jnp.concatenate([p[s] for p in parts], axis=0)[pos]
+        for s, pos in enumerate(positions)
+    ]
+
+
+def sample_descriptor_columns(
+    plan: ChunkPlan, images: list, sift, draws: list, mesh=None
+) -> list:
+    """The sampling pass.  ``draws``: one :func:`draw_columns` result a
+    sample set.  Returns each set's ``[samples, 128]`` descriptor rows, in
+    bucket order and sorted inside a bucket: what ``sample_columns`` gives,
+    transposed, on descriptors held whole."""
+    if not draws:
+        return []
+    cap = {}  # shape -> most columns any chunk of the bucket gathers
+    cuts = {}  # shape -> per set, the draw's boundaries at chunk starts
+    for shape, idx in plan.index.items():
+        c, cols = plan.chunk[shape], plan.cols[shape]
+        edges = np.arange(0, len(idx) + c, c) * cols
+        cuts[shape] = [np.searchsorted(d[shape], edges) for d in draws]
+        most = max(int(np.max(np.diff(cut), initial=0)) for cut in cuts[shape])
+        cap[shape] = max(SAMPLE_CAP_STEP, -(-most // SAMPLE_CAP_STEP) * SAMPLE_CAP_STEP)
+    parts, positions, offset = [], [[] for _ in draws], 0
+    seen: dict = {}
+    for shape, _valid, dev, image_shape in _chunks(plan, images, mesh):
+        k = seen[shape] = seen.get(shape, -1) + 1
+        im = np.zeros((len(draws), cap[shape]), np.int32)
+        col = np.zeros_like(im)
+        first = k * plan.chunk[shape] * plan.cols[shape]
+        for s, d in enumerate(draws):
+            lo, hi = cuts[shape][s][k : k + 2]
+            im[s, : hi - lo], col[s, : hi - lo] = np.divmod(
+                d[shape][lo:hi] - first, plan.cols[shape]
+            )
+            positions[s].append(offset + np.arange(hi - lo))
+        with trace.span("chunk", cat="dispatch", bucket=f"{shape[0]}x{shape[1]}"):
+            descs = _describe_chunk(sift, dev, image_shape=image_shape)
+            parts.append(_sample_chunk(descs, im, col))
+        offset += cap[shape]
+        if len(parts) > RUN_AHEAD:
+            trace.wait(parts[-1 - RUN_AHEAD], "chunk")
+    positions = [np.concatenate(p).astype(np.int32) for p in positions]
+    trace.metrics.inc("fv.descriptors_sampled", int(sum(len(p) for p in positions)))
+    with trace.span("samples", cat="concat", chunks=len(parts)):
+        return _gather_samples(parts, positions)
+
+
+def featurize_chunks(plan: ChunkPlan, images: list, sift, pca, gmm, mesh=None):
+    """The featurizing pass: ``[n, 2 * desc_dim * vocab]`` Fisher features on
+    the device, rows in ``plan.order`` (bucket by bucket), not image order:
+    the caller permutes what is small (labels, scores), never this."""
+    outs = []
+    for shape, valid, dev, image_shape in _chunks(plan, images, mesh):
+        with trace.span("chunk", cat="dispatch", bucket=f"{shape[0]}x{shape[1]}"):
+            descs = _describe_chunk(sift, dev, image_shape=image_shape)
+            feats = _encode_chunk(pca, gmm, descs)
+            outs.append(feats if valid == feats.shape[0] else feats[:valid])
+        if len(outs) > RUN_AHEAD:
+            trace.wait(outs[-1 - RUN_AHEAD], "chunk")
+    with trace.span("chunks", cat="concat", chunks=len(outs)):
+        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
 
 # -- streaming ingest (core.ingest) -------------------------------------------

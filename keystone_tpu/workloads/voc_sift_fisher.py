@@ -5,6 +5,13 @@ Flow: VOC load -> grayscale -> dense SIFT -> [PCA fit or load] -> BatchPCA ->
 [GMM fit or load] -> FisherVector -> vectorize/normalize/hellinger/normalize
 -> BlockLeastSquares(4096, 1, λ) -> per-class scores -> 11-point MAP.
 
+A fit never holds the training descriptors (189 GB at VOC 2007's sizes): a
+sampling pass keeps the columns drawn for the PCA and GMM samples, a
+featurizing pass keeps each chunk's Fisher features, and both run SIFT chunk
+by chunk (``fv_common``, "the chunked two-pass fit").  Two passes need the
+images twice and the draw needs the bucket counts first, so a split is
+loaded whole by the eager loader, not streamed.
+
 The pcaFile/gmm*File flags implement the reference's load-or-fit artifact
 checkpoint pattern (SURVEY §5).
 """
@@ -20,9 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import optimize, trace
+from ..core import trace
 from ..core.checkpoint import checkpoint_exists, load_pipeline, save_pipeline
-from ..core.ingest import stream_batches
 from ..core.logging import Logging, configure_logging, stage_timer
 from ..core.memory import log_fit_report
 from ..core.pipeline import FunctionTransformer, Pipeline
@@ -31,7 +37,6 @@ from ..evaluation.map import MeanAveragePrecisionEvaluator
 from ..loaders.image_loaders import (
     VOC_NUM_CLASSES,
     MultiLabeledImages,
-    voc_labels_map,
     voc_loader,
 )
 from ..ops.sift import SIFTExtractor
@@ -44,76 +49,13 @@ from ..utils.platform import init_device
 from . import serve_common
 from .fv_common import (
     bucket_by_shape,
-    collect_autotune,
+    draw_columns,
+    featurize_chunks,
     fisher_feature_pipeline,
     grayscale,
-    plan_pca_materialization,
-    record_stream_autotune,
-    sample_columns,
-    scatter_features,
-    searched_bucket_featurize,
-    stream_config_from_flags,
-    stream_descriptor_buckets,
+    plan_chunks,
+    sample_descriptor_columns,
 )
-
-
-@dataclass
-class VOCStreamSource:
-    """Streaming stand-in for :class:`MultiLabeledImages` (core.ingest):
-    images are decoded from the tar WHILE the device featurizes — SIFT on
-    batch *i* overlaps decode of batch *i+1* — instead of the eager
-    decode-everything-first path.  ``labels``/``len`` become available
-    after the descriptor pass records the decode-survival order."""
-
-    data_path: str
-    labels_path: str
-    name_prefix: str = "VOCdevkit/VOC2007/JPEGImages/"
-    batch_size: int = 64
-    #: closed-loop ingest autotuner on this source's streams (--autoTune)
-    autotune: bool = False
-    #: decode backend (--decodeBackend): None defers to env
-    decode_backend: str | None = None
-    #: snapshot cache root (--snapshotDir): decoded chunks keyed by tar +
-    #: decode config + this source's member filter (prefix + label file)
-    snapshot_dir: str | None = None
-    #: device-resident decode (--deviceDecode): entropy pass on the host,
-    #: pixels born on-device fused into the SIFT featurize
-    device_decode: bool = False
-
-    def __post_init__(self):
-        self._names: list | None = None
-        self._labels_map: dict | None = None
-
-    @property
-    def images(self) -> "VOCStreamSource":
-        # The workload passes ``data.images`` into the descriptor
-        # extractors; for a stream source the "images" ARE the source.
-        return self
-
-    def labels_map(self) -> dict:
-        if self._labels_map is None:
-            self._labels_map = voc_labels_map(self.labels_path)
-        return self._labels_map
-
-    def record_names(self, names: list) -> None:
-        self._names = names
-
-    @property
-    def labels(self) -> list:
-        if self._names is None:
-            raise RuntimeError(
-                "VOCStreamSource.labels before the descriptor pass — the "
-                "streaming extract must run first (it records image order)"
-            )
-        lm = self.labels_map()
-        return [lm[n] for n in self._names]
-
-    def __len__(self) -> int:
-        if self._names is None:
-            raise RuntimeError(
-                "len(VOCStreamSource) before the descriptor pass"
-            )
-        return len(self._names)
 
 
 @dataclass
@@ -142,11 +84,6 @@ class SIFTFisherConfig:
     # Resumable-solve state path: the BCD fit checkpoints after every block
     # and restarts from the last completed block if the state file exists.
     solve_checkpoint: str | None = None
-    # Cost-based auto-Cacher (core.optimize): decide from a measured probe
-    # whether the PCA-projected descriptors stay resident between GMM
-    # sampling and Fisher featurization, or are re-projected per consumer
-    # under a tight HBM budget.  Decision table in results["cache_plan"].
-    auto_cache: bool = False
     # Placement search (core.autoshard): force the cost-model-ranked
     # candidate search for the block solve (on by default via
     # KEYSTONE_AUTOSHARD); the searched table lands in
@@ -170,68 +107,15 @@ class _Log(Logging):
     pass
 
 
-def extract_sift_buckets(
-    conf: SIFTFisherConfig, images: list, mesh=None, placement_out=None
-) -> dict:
-    """Per shape bucket: grayscale + dense SIFT -> [n, 128, cols].  With a
-    mesh the PLACEMENT (row-sharded over which factorization, or single
-    device) is chosen by the same cost-model-ranked search as the solve
-    (fv_common.searched_bucket_featurize; the hand row-sharded layout is
-    the untrained head, pad rows are dropped downstream).  A caller-passed
-    ``placement_out`` dict receives the searched record under
-    ``"featurize"``."""
+def sift_node(conf: SIFTFisherConfig) -> SIFTExtractor:
     # bf16 intermediates, the measured-throughput configuration; VOC
     # leave-2-out CV (tools/voc_leave2out_cv.py, mean MAP 0.85) validated
     # the accuracy surrogate under this dtype.  Op default stays f32.
-    sift = SIFTExtractor(
+    return SIFTExtractor(
         step_size=conf.sift_step_size,
         scale_step=conf.scale_step,
         compute_dtype=jnp.bfloat16,
     )
-    if isinstance(images, VOCStreamSource):
-        # Streaming ingest: decode of batch i+1 overlaps SIFT of batch i
-        # (core.ingest ring buffer + double-buffered H2D).  Label-less and
-        # non-JPEGImages members are filtered before decode.
-        src = images
-        lm = src.labels_map()
-
-        def keep(name: str) -> bool:
-            return name.startswith(src.name_prefix) and name in lm
-
-        # The keep filter selects the member set, so it must be part of the
-        # snapshot key: prefix + label-file identity (a changed labels CSV
-        # changes the survivor set -> new snapshot).  Computed
-        # unconditionally (one os.stat): inert when snapshots are off,
-        # and an env-only KEYSTONE_SNAPSHOT_DIR is never silently inert
-        # (the stream disables snapshots for unkeyed keep filters).
-        from ..core import snapshot as ksnap
-
-        extra = (
-            f"voc:{src.name_prefix}:"
-            f"{ksnap.file_identity(src.labels_path)}"
-        )
-        cfg = stream_config_from_flags(
-            autotune=src.autotune,
-            decode_backend=src.decode_backend,
-            snapshot_dir=src.snapshot_dir,
-            snapshot_extra=extra,
-            device_decode=src.device_decode,
-        )
-        with stream_batches(
-            src.data_path, src.batch_size, keep=keep, config=cfg
-        ) as st:
-            buckets, names = stream_descriptor_buckets(
-                st, lambda dev: sift(grayscale(dev))
-            )
-        src.record_names(names)
-        record_stream_autotune(src, st)
-        return buckets
-    out, placement = searched_bucket_featurize(
-        "voc_sift_featurize", images, lambda dev: sift(grayscale(dev)), mesh
-    )
-    if placement_out is not None and placement is not None:
-        placement_out["featurize"] = placement
-    return out
 
 
 def run(
@@ -240,39 +124,63 @@ def run(
     test: MultiLabeledImages,
     mesh=None,
 ) -> dict:
-    """With ``mesh``: featurization buckets are row-sharded over the data
-    axis and the block least-squares solve runs distributed ((data, model)
-    shardings via the ambient mesh) — the analog of the reference running
-    this pipeline over partitioned RDDs (VOCSIFTFisher.scala:18-111)."""
+    """With ``mesh``: every chunk is row-sharded over the data axis and the
+    block least-squares solve runs distributed ((data, model) shardings via
+    the ambient mesh) — the analog of the reference running this pipeline
+    over partitioned RDDs (VOCSIFTFisher.scala:18-111).
+
+    The run is one root span ``fit``; its stages (``stage_timer``) tile it
+    but for glue, and each occurs once a fit.  Beside ``map`` and ``aps``
+    the results hold the fitted chain (``pipeline``: pca, gmm, model) and
+    the raw test scores in image order (``test_scores``)."""
     configure_logging()
+    with trace.span("fit", cat="fit", rows=len(train)):
+        return _fit_and_score(conf, train, test, mesh)
+
+
+def _fit_and_score(conf: SIFTFisherConfig, train, test, mesh) -> dict:
     log = _Log()
     t0 = time.perf_counter()
-
-    feat_dim = 2 * conf.desc_dim * conf.vocab_size
-    results_cache_plan = results_placement = None
-    feat_placements: dict = {}
+    sift = sift_node(conf)
+    solver_report = gmm_iterations = None
 
     # Load-or-fit of the WHOLE fitted pipeline (SURVEY §5 generalized): when
     # the checkpoint exists, training featurization and all fits are skipped
     # and the run scores test data with the restored PCA + GMM + model.
-    if conf.pipeline_file is not None and checkpoint_exists(conf.pipeline_file):
+    restored = conf.pipeline_file is not None and checkpoint_exists(conf.pipeline_file)
+    if restored:
         log.log_info("restoring fitted pipeline from %s", conf.pipeline_file)
         ck = load_pipeline(conf.pipeline_file)
         batch_pca, gmm, model = ck["pca"], ck["gmm"], ck["model"]
-        fisher = fisher_feature_pipeline(gmm)
     else:
-        # Part 1+2: SIFT descriptors per shape bucket (reference :36-57).
-        # Runs BEFORE the label node: a streaming source only knows its
-        # image order (and therefore labels) after the descriptor pass.
-        with stage_timer("sift"):
-            train_desc = extract_sift_buckets(
-                conf, train.images, mesh, placement_out=feat_placements
-            )
+        plan = plan_chunks(train.images, sift, conf.desc_dim, conf.vocab_size, mesh)
+        log.log_info(
+            "chunks %s of buckets %s (budget %s)",
+            plan.chunk, {s: len(i) for s, i in plan.index.items()}, plan.budget,
+        )
+        train_labels = ClassLabelIndicatorsFromIntArrayLabels(VOC_NUM_CLASSES)(
+            [train.labels[i] for i in plan.order]
+        )
 
-        label_node = ClassLabelIndicatorsFromIntArrayLabels(VOC_NUM_CLASSES)
-        train_labels = label_node(train.labels)
+        # Part 1+2: the sampling pass (reference :40-50, :59-70 sample the
+        # descriptors of every image; here only the drawn columns outlive
+        # their chunk).  A loaded PCA or GMM needs no sample.
+        with stage_timer("sample_descriptors"):
+            draws = {}
+            if conf.pca_file is None:
+                draws["pca"] = draw_columns(plan.totals, conf.num_pca_samples, conf.seed)
+            if conf.gmm_mean_file is None:
+                draws["gmm"] = draw_columns(
+                    plan.totals, conf.num_gmm_samples, conf.seed + 1
+                )
+            samples = dict(zip(draws, sample_descriptor_columns(
+                plan, train.images, sift, list(draws.values()), mesh
+            )))
+            if draws:
+                trace.metrics.inc("fv.descriptor_passes", len(train))
+                trace.wait(samples, "sample_descriptors")
 
-        # Part 1a: PCA — fit on sampled descriptor columns, or load (:40-50)
+        # Part 1a: PCA — fit on the sampled descriptors, or load (:40-50)
         with stage_timer("pca"):
             if conf.pca_file is not None:
                 pca_mat = jnp.asarray(
@@ -280,59 +188,32 @@ def run(
                     jnp.float32,
                 )
             else:
-                samples = sample_columns(
-                    train_desc, conf.num_pca_samples, conf.seed
+                pca_mat = trace.wait(
+                    compute_pca(samples.pop("pca"), conf.desc_dim), "pca"
                 )
-                pca_mat = compute_pca(samples.T, conf.desc_dim)
             batch_pca = BatchPCATransformer(pca_mat)
 
-            def make_pca_desc() -> dict:
-                return {
-                    shape: (idx, batch_pca(descs))
-                    for shape, (idx, descs) in train_desc.items()
-                }
-
-            materialize = True
-            if conf.auto_cache:
-                # Auto-Cacher decision: the projected set is consumed by
-                # GMM sampling (when fitting one) and Fisher featurization.
-                reuse = (0 if conf.gmm_mean_file is not None else 1) + 1
-                cache_plan, materialize = plan_pca_materialization(
-                    train_desc, batch_pca, reuse, mesh=mesh,
-                    label="voc_pca_descriptors",
-                )
-                log.log_info("%s", cache_plan.summary())
-                results_cache_plan = cache_plan.record()
-            # Cached: one resident projection feeds both consumers (the
-            # status quo).  Denied: each consumer projects on the fly —
-            # deterministic, so samples and features are bit-identical.
-            pca_desc = make_pca_desc() if materialize else None
-
-        # Part 2a: GMM — fit on sampled PCA'd columns, or load (:59-70)
+        # Part 2a: GMM — fit on the projected samples, or load (:59-70)
         with stage_timer("gmm"):
             if conf.gmm_mean_file is not None:
                 gmm = GaussianMixtureModel.load(
                     conf.gmm_mean_file, conf.gmm_var_file, conf.gmm_wts_file
                 )
             else:
-                gmm_samples = sample_columns(
-                    pca_desc if pca_desc is not None else make_pca_desc(),
-                    conf.num_gmm_samples, conf.seed + 1,
-                )
-                gmm = GaussianMixtureModelEstimator(conf.vocab_size).fit(
-                    gmm_samples.T
-                )
+                est = GaussianMixtureModelEstimator(conf.vocab_size)
+                gmm = est.fit(samples.pop("gmm") @ pca_mat)
+                with trace.d2h("gmm_iterations", 4):
+                    gmm_iterations = int(est.last_iterations)
+                trace.metrics.inc("gmm.iterations", gmm_iterations)
             assert_all_finite(gmm, "VOC GMM fit")
 
-        # Part 3: Fisher features (:72-82)
-        with stage_timer("fisher_features"):
-            fisher = fisher_feature_pipeline(gmm)
-            train_features = jnp.asarray(
-                scatter_features(
-                    pca_desc if pca_desc is not None else make_pca_desc(),
-                    fisher, len(train), feat_dim,
-                )
+        # Part 3: the featurizing pass (:72-82)
+        with stage_timer("featurize"):
+            train_features = trace.wait(
+                featurize_chunks(plan, train.images, sift, batch_pca, gmm, mesh),
+                "featurize",
             )
+            trace.metrics.inc("fv.descriptor_passes", len(train))
 
         # Part 4: linear model (:84-86) — mesh-distributed when given one;
         # with a solve checkpoint the BCD fit persists per-block state and
@@ -349,58 +230,61 @@ def run(
         with stage_timer("solve"):
             solver = BlockLeastSquaresEstimator(4096, 1, conf.lam, mesh=mesh)
             model = solver.fit(
-                train_features, train_labels, num_features=feat_dim,
+                train_features, train_labels,
+                num_features=2 * conf.desc_dim * conf.vocab_size,
                 plan=True if conf.auto_shard else None,
                 **solve_kwargs,
             )
             log_fit_report(solver, label="VOC SIFT-Fisher solve")
             assert_all_finite(model, "VOC block least-squares fit")
-            rep = solver.last_fit_report
-            results_placement = rep.placement if rep is not None else None
+            solver_report = solver.last_fit_report
+        del train_features
         if state_path is not None and os.path.exists(state_path):
             # The per-block state is a RESUME artifact, not a model cache:
             # leaving the completed state behind would make a later rerun
             # with different features silently resume into the stale model.
             os.unlink(state_path)
 
-        if conf.pipeline_file is not None:
-            save_pipeline(
-                conf.pipeline_file,
-                {"pca": batch_pca, "gmm": gmm, "model": model},
-            )
-            log.log_info("saved fitted pipeline to %s", conf.pipeline_file)
-
     # Test path (:92-106)
     with stage_timer("eval"):
-        test_desc = extract_sift_buckets(conf, test.images, mesh)
-        test_features = scatter_features(
-            test_desc, lambda d: fisher(batch_pca(d)), len(test), feat_dim
-        )
-
-        predictions = np.asarray(model(jnp.asarray(test_features)))
-    aps = MeanAveragePrecisionEvaluator(test.labels, predictions, VOC_NUM_CLASSES)
+        test_plan = plan_chunks(test.images, sift, conf.desc_dim, conf.vocab_size, mesh)
+        with stage_timer("featurize_test"):
+            test_features = trace.wait(
+                featurize_chunks(test_plan, test.images, sift, batch_pca, gmm, mesh),
+                "featurize_test",
+            )
+        scores = model(test_features)
+        del test_features
+        with trace.d2h("test_scores", scores.nbytes):
+            in_chunk_order = np.asarray(scores)
+        test_scores = np.empty_like(in_chunk_order)
+        test_scores[test_plan.order] = in_chunk_order
+        aps = MeanAveragePrecisionEvaluator(test.labels, test_scores, VOC_NUM_CLASSES)
     results = {
         "aps": aps,
         "map": float(np.mean(aps)),
-        "seconds": time.perf_counter() - t0,
+        "test_scores": test_scores,
+        "pipeline": {"pca": batch_pca, "gmm": gmm, "model": model},
     }
-    if results_cache_plan is not None:
-        results["cache_plan"] = results_cache_plan
-    if results_placement is not None or feat_placements:
-        # The searched placement tables — the block solve's candidates,
-        # deny/score rationale, chosen plan's predicted-vs-actual cost,
-        # and (under a mesh) the searched FEATURIZE placement: one audit
-        # home for every ranked placement decision the run made.
-        if feat_placements:
-            results["placement"] = {
-                "solver": results_placement, **feat_placements
-            }
-        else:
-            results["placement"] = results_placement
-    autotune = collect_autotune(train, test)
-    if autotune:
-        results["autotune"] = autotune
-        log.log_info("ingest autotune: %s", autotune)
+    if gmm_iterations is not None:
+        results["gmm_iterations"] = gmm_iterations
+    if solver_report is not None:
+        # Which tier ran, against what budget, after which step-downs.
+        results["solver"] = {
+            "tier": solver_report.chosen,
+            "budget_bytes": solver_report.budget_bytes,
+            "denials": list(solver_report.denials),
+            "oom_retries": list(solver_report.oom_retries),
+        }
+        if solver_report.placement is not None:
+            # The searched placement table of the block solve: candidates,
+            # deny/score rationale, chosen plan's predicted-vs-actual cost.
+            results["placement"] = solver_report.placement
+    if conf.pipeline_file is not None and not restored:
+        with stage_timer("checkpoint"):
+            save_pipeline(conf.pipeline_file, results["pipeline"])
+        log.log_info("saved fitted pipeline to %s", conf.pipeline_file)
+    results["seconds"] = time.perf_counter() - t0
     _maybe_serve(conf, test, results, log)
     log.log_info("TEST APs are: %s", ",".join(str(a) for a in aps))
     log.log_info("TEST MAP is: %s", results["map"])
@@ -414,16 +298,11 @@ def servable_pipeline(conf: SIFTFisherConfig, bundle: dict) -> Pipeline:
     node is reconstructed from config (it holds no fitted state); the
     fitted arrays ride in the bundle's registered nodes, so the chain
     flows through jit as a pytree."""
-    sift = SIFTExtractor(
-        step_size=conf.sift_step_size,
-        scale_step=conf.scale_step,
-        compute_dtype=jnp.bfloat16,
-    )
     fisher = fisher_feature_pipeline(bundle["gmm"])
     return Pipeline(
         [
             FunctionTransformer(grayscale, name="grayscale"),
-            sift,
+            sift_node(conf),
             bundle["pca"],
             FunctionTransformer(fisher, name="fisher_features"),
             bundle["model"],
@@ -439,12 +318,7 @@ def _maybe_serve(conf: SIFTFisherConfig, test, results: dict, log) -> None:
             "--serve/--serveBench need --pipelineFile — the endpoint "
             "warm-loads the fitted {pca, gmm, model} bundle, it never refits"
         )
-    images = getattr(test, "images", None)
-    if isinstance(images, VOCStreamSource) or not hasattr(images, "__len__"):
-        raise ValueError(
-            "serving draws requests from the EAGER test split — run "
-            "--serve/--serveBench without --streamIngest"
-        )
+    images = test.images
     # One engine serves ONE request shape (the static-shape discipline the
     # shape-bucketed featurize already follows): requests come from the
     # test split's most populous shape bucket.
@@ -492,63 +366,12 @@ def main(argv=None):
         help="resumable BCD state path: per-block checkpoint + auto-resume",
     )
     p.add_argument(
-        "--streamIngest",
-        action="store_true",
-        help="streaming ingest (core.ingest): decode the tar WHILE the "
-        "device runs SIFT, instead of decoding everything first",
-    )
-    p.add_argument(
-        "--streamBatchSize",
-        type=int,
-        default=64,
-        help="images per streamed device batch (--streamIngest only)",
-    )
-    p.add_argument(
-        "--autoCache",
-        action="store_true",
-        help="cost-based auto-Cacher (core.optimize): probe-measured "
-        "decision on PCA-descriptor residency vs re-projection "
-        "(KEYSTONE_AUTOCACHE=1 equivalent)",
-    )
-    p.add_argument(
         "--autoShard",
         action="store_true",
         help="placement search (core.autoshard): force the cost-model "
         "ranked mesh/strategy candidate search for the block solve and "
         "record the searched plan in results['placement'] (on by "
         "default; KEYSTONE_AUTOSHARD=0 disables it except here)",
-    )
-    p.add_argument(
-        "--autoTune",
-        action="store_true",
-        help="closed-loop ingest autotuner on --streamIngest streams: "
-        "retune decode width / ring depth / decode-ahead mid-stream "
-        "(KEYSTONE_AUTOTUNE=1 equivalent)",
-    )
-    p.add_argument(
-        "--decodeBackend",
-        default=None,
-        choices=("thread", "process"),
-        help="decode backend for --streamIngest: 'process' decodes on "
-        "spawned worker processes via shared memory "
-        "(KEYSTONE_DECODE_BACKEND equivalent)",
-    )
-    p.add_argument(
-        "--snapshotDir",
-        default=None,
-        help="snapshot cache root for --streamIngest streams "
-        "(core.snapshot): first pass materializes decoded chunks, repeat "
-        "runs stream the shards at IO speed "
-        "(KEYSTONE_SNAPSHOT_DIR equivalent)",
-    )
-    p.add_argument(
-        "--deviceDecode",
-        action="store_true",
-        help="device-resident JPEG decode for --streamIngest "
-        "(ops.jpeg_device): host entropy pass only, pixels born on-device "
-        "fused into the SIFT featurize; unsupported JPEGs fall back to "
-        "host decode counted per reason (KEYSTONE_DEVICE_DECODE=1 "
-        "equivalent)",
     )
     serve_common.add_serve_args(p)
     p.add_argument(
@@ -570,11 +393,6 @@ def main(argv=None):
     init_device()
     if (a.serve or a.serveBench) and not a.pipelineFile:
         p.error("--serve/--serveBench require --pipelineFile")
-    if (a.serve or a.serveBench) and a.streamIngest:
-        p.error(
-            "--serve/--serveBench draw requests from the eager test split "
-            "— drop --streamIngest for serving runs"
-        )
     conf = SIFTFisherConfig(
         train_location=a.trainLocation,
         test_location=a.testLocation,
@@ -591,7 +409,6 @@ def main(argv=None):
         num_gmm_samples=a.numGmmSamples,
         pipeline_file=a.pipelineFile,
         solve_checkpoint=a.solveCheckpoint,
-        auto_cache=a.autoCache or optimize.auto_cache_env(),
         auto_shard=a.autoShard,
         serve=a.serve,
         serve_bench=a.serveBench,
@@ -603,24 +420,9 @@ def main(argv=None):
         # Restored runs never touch training data — skip decoding the
         # entire training tar (the dominant reload-path cost).
         train = MultiLabeledImages([], [], [])
-    elif a.streamIngest:
-        train = VOCStreamSource(
-            conf.train_location, conf.label_path,
-            batch_size=a.streamBatchSize, autotune=a.autoTune,
-            decode_backend=a.decodeBackend, snapshot_dir=a.snapshotDir,
-            device_decode=a.deviceDecode,
-        )
     else:
         train = voc_loader(conf.train_location, conf.label_path)
-    if a.streamIngest:
-        test = VOCStreamSource(
-            conf.test_location, conf.label_path,
-            batch_size=a.streamBatchSize, autotune=a.autoTune,
-            decode_backend=a.decodeBackend, snapshot_dir=a.snapshotDir,
-            device_decode=a.deviceDecode,
-        )
-    else:
-        test = voc_loader(conf.test_location, conf.label_path)
+    test = voc_loader(conf.test_location, conf.label_path)
     try:
         return run(conf, train, test, mesh=parse_mesh(a.mesh))
     finally:

@@ -21,13 +21,11 @@ from test_fisher_pipelines import (
     _class_image,
     _img_bytes,
     write_imagenet_tar,
-    write_voc_tar,
 )
 
 from keystone_tpu.loaders.image_loaders import (
     _iter_tar_images,
     imagenet_loader,
-    voc_loader,
 )
 from keystone_tpu.workloads.cifar_random_patch import (
     RandomCifarConfig,
@@ -43,11 +41,6 @@ from keystone_tpu.workloads.imagenet_sift_lcs_fv import (
     lcs_descriptor_buckets,
     sift_descriptor_buckets,
 )
-from keystone_tpu.workloads.voc_sift_fisher import (
-    SIFTFisherConfig,
-    VOCStreamSource,
-    extract_sift_buckets,
-)
 from keystone_tpu.core.ingest import stream_batches
 from keystone_tpu.loaders.cifar import LabeledImageBatch
 
@@ -59,24 +52,6 @@ def _buckets_equal(a: dict, b: dict):
         idx_b, desc_b = b[shape]
         np.testing.assert_array_equal(np.asarray(idx_a), np.asarray(idx_b))
         np.testing.assert_array_equal(np.asarray(desc_a), np.asarray(desc_b))
-
-
-def test_voc_streaming_sift_buckets_equal_eager(tmp_path, rng):
-    labels_csv = str(tmp_path / "labels.csv")
-    open(labels_csv, "w").close()
-    tar = str(tmp_path / "voc.tar")
-    write_voc_tar(tar, labels_csv, 8, rng)
-    conf = SIFTFisherConfig(desc_dim=8, vocab_size=4, sift_step_size=8)
-
-    data = voc_loader(tar, labels_csv)
-    eager = extract_sift_buckets(conf, data.images)
-
-    src = VOCStreamSource(tar, labels_csv, batch_size=3)
-    stream = extract_sift_buckets(conf, src.images)
-
-    _buckets_equal(eager, stream)
-    assert len(src) == len(data)
-    assert src.labels == data.labels
 
 
 def test_imagenet_streaming_branches_equal_eager(tmp_path, rng):
