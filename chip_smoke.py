@@ -54,7 +54,7 @@ TEST_ERROR_BAR = 5.0  # percent; chance on 10 balanced classes is 90
 FULL = dict(
     train=50_000, test=10_000, whitener=100_000, requests=256,
     jpeg_train=4_096, jpeg_test=2_048, golden=64,
-    idct_images=2_048, fv=(64, 13_165, 64, 16), pool_images=1_024,
+    idct_images=2_048, fv=(8, 73_866, 80, 256), pool_images=1_024,
     pool_step=8, conv=(2_048, 1_250, 128),
 )
 TINY = dict(
@@ -384,38 +384,37 @@ def _kernel_idct(ctx, interpret, rng) -> dict:
 
 
 def _kernel_fv_stats(ctx, interpret, rng) -> dict:
-    """Fisher-vector statistics with ragged counts
-    (tests/test_gmm_fisher.py TestFvPallasKernel, production shape)."""
-    import jax
+    """Fisher-vector statistics with ragged counts at the published widths
+    (tests/test_gmm_fisher.py TestFvPallasKernel): against the jnp form
+    whose moment products round their operands to bfloat16 as the kernel's
+    do, and (reported) against the one that rounds nothing."""
     import jax.numpy as jnp
     import numpy as np
 
-    from keystone_tpu.ops.fisher import _fv_from_stats, fisher_vector
-    from keystone_tpu.ops.fv_pallas import fv_stats_pallas
+    from keystone_tpu.ops.fv_pallas import fv_stats_jnp, fv_stats_pallas
 
     n, cols, d, k = ctx["size"]["fv"]
-    x = rng.normal(size=(n, cols, d)).astype(np.float32)
+    x = jnp.asarray(rng.normal(size=(n, d, cols)).astype(np.float32))
     means = rng.normal(size=(d, k)).astype(np.float32)
     variances = rng.uniform(0.5, 2.0, (d, k)).astype(np.float32)
     weights = rng.dirichlet(np.ones(k)).astype(np.float32)
-    counts = rng.integers(cols // 2, cols + 1, size=n).astype(np.int32)
-    s0, s1, s2 = fv_stats_pallas(
-        jnp.asarray(np.swapaxes(x, 1, 2)), jnp.asarray(counts),
-        means, variances, weights, interpret=interpret,
-    )
-    got = np.asarray(_fv_from_stats(
-        s0, s1, s2, means, variances, weights,
-        jnp.asarray(counts, jnp.float32),
-    ))
-    mask = (np.arange(cols)[None, :] < counts[:, None]).astype(np.float32)
-    with jax.default_matmul_precision("highest"):
-        want = np.asarray(jax.vmap(
-            lambda xi, mi: fisher_vector(xi, means, variances, weights, mi)
-        )(jnp.asarray(x), jnp.asarray(mask)))
-    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    counts = jnp.asarray(rng.integers(cols // 2, cols + 1, size=n).astype(np.int32))
+    got = fv_stats_pallas(x, counts, means, variances, weights, interpret=interpret)
+
+    def gaps(moment_dtype):
+        want = fv_stats_jnp(
+            x, counts, means, variances, weights, moment_dtype=moment_dtype
+        )
+        return [
+            float(jnp.abs(g - w).max() / jnp.abs(w).max()) for g, w in zip(got, want)
+        ]
+
+    rounded = gaps(jnp.bfloat16)
+    check(max(rounded) < 2e-3, f"fv_stats_pallas off its bf16-operand form by {rounded}")
     return {
         "shape": [n, cols, d, k],
-        "max_abs_err": float(np.abs(got - want).max()),
+        "max_rel_err_s0_s1_s2": rounded,
+        "against_f32_operands": gaps(jnp.float32),
     }
 
 

@@ -22,6 +22,49 @@ TPU-native: posteriors are one [n, k] gemm + softmax; the sufficient
 statistics (s0, s1, s2) are three gemms; everything vmaps over the image
 axis, with an optional validity mask for ragged descriptor counts (XLA needs
 static shapes, SURVEY §7 "hard parts").
+
+**One node, two forms of the same mathematics** (the pattern of
+``ops/conv_fused.py``).  The XLA form (``fisher_vector`` under ``vmap``)
+writes the ``[cols, k]`` float32 posteriors to HBM and crosses them seven
+times: two log-density products, the softmax's max, sum and normalization,
+two moment products.  The kernel form (``ops/fv_pallas.fv_stats_pallas``)
+keeps them in VMEM: its HBM operands are the projected descriptors, read
+once, and the statistics.  Both take each product at the same precision
+(log-density products at full float32, moment products in one bfloat16 pass
+with float32 accumulation; see the kernel's docstring).
+
+**Which form runs** (``fv_form``) follows from where the program runs and
+what it is given, and, against what was expected, from no shape.  An image's
+posteriors cost the XLA form ``7 * 4 B * cols * k`` bytes of HBM traffic; the
+descriptors, which both forms read, ``4 B * cols * d``; so the kernel form was
+expected to win from some multiple of ``7k/d`` up, as the conv featurizer's
+does from ~170 filters (a predecessor of the kernel lost by 1.7x at vocab 16,
+d 64, 13,165 descriptors; round 4).  Measured on v5e (tools/fv_form_probe.py,
+PR 29; ROOFLINE.md, "At the published sizes"), 64 images of 73,866
+descriptors, the device's own time a chunk:
+
+    vocab   d    7k/d   kernel form   XLA form   (ms)
+       2   64    0.22      2.69         6.79
+       4   80    0.35      3.19         8.58
+       8   64    0.88      2.68         8.14
+      16   64    1.75      2.84         9.93     (0.54 : 1.23 at 13,165)
+      16   80    1.40      3.31        11.46
+      64   64    7.0       6.13        20.11
+      64   80    5.6       6.31        21.72
+     256   64   28.0      22.62        61.47
+     256   80   22.4      22.89        62.69     (the benchmark's)
+
+and one image (a served request): 0.046 against 0.086 at vocab 16, 0.36
+against 0.91 at 256.  The XLA form won nowhere: it reads the descriptors once
+a product, four times (1.2-1.5 GB and 1.6-2.5 ms each at the narrowest vocab)
+where the kernel reads them once, and since ``_log_resp`` asks full float32
+(PR 28) its two log-density products alone cost more than the whole kernel at
+every width.  So the rule has no threshold to set, and the shapes ride the
+``fv_form`` instant only.  What it does turn on: the kernel form is a custom call, which the
+compiler does not partition (under a mesh it would run replicated on every
+chip); the kernel knows prefix counts, not arbitrary masks; Mosaic compiles
+for the TPU only.  There the XLA form runs.  No environment variable or flag
+reaches the choice.
 """
 
 from __future__ import annotations
@@ -29,9 +72,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..core import trace
 from ..core.pipeline import Transformer, node
 from ..solvers.gmm import GaussianMixtureModel, _log_resp
-from ..utils.platform import use_pallas_kernels
+from .conv_fused import _on_one_device
+from .fv_pallas import fv_stats_pallas
+
+
+def fv_form(backend: str, one_device: bool, masked: bool) -> str:
+    """``"kernel"`` or ``"xla"``: the rule of the module docstring, in one
+    place."""
+    return "kernel" if backend == "tpu" and one_device and not masked else "xla"
 
 
 def _fv_from_stats(s0, s1, s2, means, variances, weights, n_valid):
@@ -51,15 +102,6 @@ def _fv_from_stats(s0, s1, s2, means, variances, weights, n_valid):
     )
     both = jnp.concatenate([g_mean, g_var], axis=-1)  # [..., d, 2K]
     return jnp.where(jnp.concatenate([alive, alive]), both, 0.0)
-
-
-def _use_pallas() -> bool:
-    """Opt-in (KEYSTONE_PALLAS=1, shared gate utils/platform.py): the
-    hand-written fused kernel MEASURED SLOWER than XLA's own fusion on the
-    production shape (0.95 vs 1.61 ms — see ops/fv_pallas.py docstring), so
-    the XLA path is the default by evidence, and the kernel remains
-    available for shapes where the balance tips (much larger vocab K)."""
-    return use_pallas_kernels()
 
 
 def fisher_vector(descriptors, means, variances, weights, mask=None):
@@ -106,24 +148,19 @@ class FisherVector(Transformer):
         return self.num_dims * self.num_centroids * 2
 
     def __call__(self, batch, mask=None):
-        """``mask``: optional [N, cols] validity for ragged descriptor counts.
+        """``mask``: optional [N, cols] validity for ragged descriptor counts
+        (always the XLA form: the kernel knows prefix counts, not masks)."""
+        n, d, cols = batch.shape
+        form = fv_form(jax.default_backend(), _on_one_device(batch), mask is not None)
+        # Counted where the program is traced: once a jitted shape.
+        trace.metrics.inc(f"fv_form.{form}")
+        trace.instant("fv_form", form=form, images=n, cols=cols, d=d, k=self.gmm.k)
+        if form == "kernel":
+            return self._kernel_form(batch)
+        return self._xla_form(batch, mask)
 
-        Under KEYSTONE_PALLAS=1 on TPU the sufficient statistics run as the
-        fused single-pass Pallas kernel (ops/fv_pallas.py) — measured slower
-        than XLA's fusion at the production shape, kept opt-in; see the
-        kernel docstring.  Masked calls always take the XLA path (the kernel
-        encodes raggedness as prefix counts, not arbitrary masks)."""
+    def _xla_form(self, batch, mask=None):
         gmm = self.gmm
-        if mask is None and _use_pallas():
-            from .fv_pallas import fv_stats_pallas
-
-            s0, s1, s2 = fv_stats_pallas(
-                batch, None, gmm.means, gmm.variances, gmm.weights
-            )
-            n_valid = jnp.full((batch.shape[0],), batch.shape[2], jnp.float32)
-            return _fv_from_stats(
-                s0, s1, s2, gmm.means, gmm.variances, gmm.weights, n_valid
-            )
 
         def one(mat, m):
             return fisher_vector(mat.T, gmm.means, gmm.variances, gmm.weights, m)
@@ -131,3 +168,13 @@ class FisherVector(Transformer):
         if mask is None:
             return jax.vmap(lambda mat: one(mat, None))(batch)
         return jax.vmap(one)(batch, mask)
+
+    def _kernel_form(self, batch, interpret: bool = False):
+        gmm = self.gmm
+        s0, s1, s2 = fv_stats_pallas(
+            batch, None, gmm.means, gmm.variances, gmm.weights, interpret=interpret
+        )
+        n_valid = jnp.full((batch.shape[0],), batch.shape[2], jnp.float32)
+        return _fv_from_stats(
+            s0, s1, s2, gmm.means, gmm.variances, gmm.weights, n_valid
+        )

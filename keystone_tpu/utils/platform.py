@@ -25,20 +25,6 @@ _CHECKOUT = os.path.dirname(
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def use_pallas_kernels() -> bool:
-    """Opt-in gate (KEYSTONE_PALLAS=1, TPU backend only) for the
-    hand-written Pallas kernels that MEASURED SLOWER than XLA's own fusion
-    on their production shapes and are therefore not the defaults — see
-    ops/fv_pallas.py and ops/rect_pool_pallas.py for the measured verdicts.
-    One shared gate so every opt-in kernel engages under the same
-    condition."""
-    import jax
-
-    return os.environ.get("KEYSTONE_PALLAS", "").strip() == "1" and (
-        jax.default_backend() == "tpu"
-    )
-
-
 def compile_cache_dir() -> str:
     """Directory of JAX's persistent compilation cache for this process:
     ``JAX_COMPILATION_CACHE_DIR`` when the machine sets it, otherwise one

@@ -3,8 +3,10 @@
 naive-equivalence replaces the FV golden-file test because the reference's
 feats.csv fixture is absent from its own test resources)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from keystone_tpu.ops.fisher import FisherVector, fisher_vector
 from keystone_tpu.solvers.gmm import GaussianMixtureModel, GaussianMixtureModelEstimator
@@ -159,14 +161,15 @@ class TestFisherVector:
 
 
 class TestFvPallasKernel:
-    """The fused Pallas stats kernel (ops/fv_pallas.py) must match the XLA
-    formulation exactly (same math, reassociated) — run in interpret mode on
-    the CPU test platform; on TPU hardware the same kernel compiles via
-    Mosaic and FisherVector routes to it under KEYSTONE_PALLAS=1."""
+    """The node's kernel form (ops/fv_pallas.py) is the XLA form's
+    mathematics, reassociated, at the XLA form's precisions on a TPU: run in
+    interpret mode on the CPU test platform; on TPU hardware the same kernel
+    compiles via Mosaic (tests/test_tpu_compile.py, chip_smoke.py leg C) and
+    ``fv_form`` routes FisherVector to it."""
 
-    def _case(self, rng, n=3, cols=700, d=24, k=8, ragged=True):
+    def _case(self, rng, n=3, cols=700, d=24, k=8, ragged=True, spread=1.0):
         x = rng.normal(size=(n, cols, d)).astype(np.float32)
-        means = rng.normal(size=(d, k)).astype(np.float32)
+        means = (spread * rng.normal(size=(d, k))).astype(np.float32)
         variances = rng.uniform(0.5, 2.0, (d, k)).astype(np.float32)
         weights = rng.dirichlet(np.ones(k)).astype(np.float32)
         counts = None
@@ -174,48 +177,162 @@ class TestFvPallasKernel:
             counts = rng.integers(cols // 2, cols + 1, size=n).astype(np.int32)
         return x, counts, means, variances, weights
 
-    def test_stats_match_xla(self, rng):
-        from keystone_tpu.ops.fisher import fisher_vector
-        from keystone_tpu.ops.fv_pallas import fv_stats_pallas
-        from keystone_tpu.ops.fisher import _fv_from_stats
+    @staticmethod
+    def _stats(x, counts, means, variances, weights, moment_dtype):
+        from keystone_tpu.ops.fv_pallas import fv_stats_jnp
 
-        x, counts, means, variances, weights = self._case(rng)
-        s0, s1, s2 = fv_stats_pallas(
-            jnp.asarray(np.swapaxes(x, 1, 2)),  # [N, d, D] descriptor columns
-            jnp.asarray(counts), means, variances, weights,
-            chunk=256, interpret=True,
+        return fv_stats_jnp(
+            jnp.asarray(np.swapaxes(x, 1, 2)),
+            None if counts is None else jnp.asarray(counts),
+            means, variances, weights, moment_dtype=moment_dtype,
         )
-        got = np.asarray(
-            _fv_from_stats(
-                s0, s1, s2, means, variances, weights,
-                jnp.asarray(counts, jnp.float32),
-            )
-        )
-        mask = (np.arange(x.shape[1])[None, :] < counts[:, None]).astype(np.float32)
-        want = np.stack([
-            np.asarray(fisher_vector(x[i], means, variances, weights, jnp.asarray(mask[i])))
-            for i in range(x.shape[0])
-        ])
-        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
-    def test_no_counts_and_unaligned_chunk(self, rng):
-        from keystone_tpu.ops.fisher import fisher_vector
+    @staticmethod
+    def _gap(got, want):
+        return float(np.abs(np.asarray(got) - np.asarray(want)).max() / np.abs(want).max())
+
+    @pytest.mark.parametrize(
+        "shape",
+        [
+            # ragged counts (the first kernel test, PR 21 and before)
+            dict(ragged=True, block=256),
+            # no counts, and 333 columns are no multiple of the block: the
+            # last block's lanes past the array must count for nothing
+            dict(cols=333, ragged=False, block=128),
+            # the published widths; 1,100 columns leave a last block of 76
+            dict(n=2, cols=1100, d=80, k=256, ragged=False, block=512, spread=0.1),
+        ],
+        ids=["ragged_counts", "unaligned_block", "d80_k256_unaligned"],
+    )
+    def test_stats_match_xla(self, rng, shape):
+        from keystone_tpu.ops.fisher import _fv_from_stats, fisher_vector
         from keystone_tpu.ops.fv_pallas import fv_stats_pallas
-        from keystone_tpu.ops.fisher import _fv_from_stats
 
-        # cols deliberately not a multiple of chunk: padded rows must fall
-        # outside the implicit all-valid count
-        x, _, means, variances, weights = self._case(rng, cols=333, ragged=False)
-        s0, s1, s2 = fv_stats_pallas(
+        block, case = shape["block"], {k: v for k, v in shape.items() if k != "block"}
+        x, counts, means, variances, weights = self._case(rng, **case)
+        got = fv_stats_pallas(
+            jnp.asarray(np.swapaxes(x, 1, 2)),  # [N, d, cols] descriptor columns
+            None if counts is None else jnp.asarray(counts),
+            means, variances, weights, block=block, interpret=True,
+        )
+        # the statistics, against the form whose products round as the
+        # kernel's do
+        want = self._stats(x, counts, means, variances, weights, jnp.bfloat16)
+        for g, w in zip(got, want):
+            assert self._gap(g, w) < 1e-3
+        # and the Fisher vector against the CPU's float32 XLA form: one
+        # bfloat16 rounding of the moment products' operands apart
+        n_valid = np.full((x.shape[0],), x.shape[1]) if counts is None else counts
+        fv = _fv_from_stats(
+            *got, means, variances, weights, jnp.asarray(n_valid, jnp.float32)
+        )
+        mask = (np.arange(x.shape[1])[None, :] < n_valid[:, None]).astype(np.float32)
+        ref = jax.vmap(
+            lambda xi, mi: fisher_vector(xi, means, variances, weights, mi)
+        )(jnp.asarray(x), jnp.asarray(mask))
+        assert self._gap(fv, ref) < 2e-2
+
+    def test_moment_products_round_as_xla_form(self, rng):
+        """Soft posteriors (every centre takes many descriptors), so that a
+        rounding of the operands shows: the kernel lies an order nearer the
+        bfloat16-operand form than the float32 one, and its log-density
+        products (s0 never meets a bfloat16) are float32's."""
+        from keystone_tpu.ops.fv_pallas import fv_stats_pallas
+
+        x, _, means, _, weights = self._case(
+            rng, n=2, cols=700, d=80, k=256, ragged=False, spread=0.1
+        )
+        variances = rng.uniform(0.9, 1.1, means.shape).astype(np.float32)
+        got = fv_stats_pallas(
             jnp.asarray(np.swapaxes(x, 1, 2)), None, means, variances, weights,
-            chunk=128, interpret=True,
+            block=256, interpret=True,
         )
-        n_valid = jnp.full((x.shape[0],), x.shape[1], jnp.float32)
-        got = np.asarray(
-            _fv_from_stats(s0, s1, s2, means, variances, weights, n_valid)
+        rounded = self._stats(x, None, means, variances, weights, jnp.bfloat16)
+        full = self._stats(x, None, means, variances, weights, jnp.float32)
+        assert self._gap(got[0], full[0]) < 2e-5
+
+        def rms(a, b):
+            a, b = np.asarray(a), np.asarray(b)
+            return float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b**2)))
+
+        # four seeds read 0.9-7.6e-5 against 2.4e-4-1.2e-3: 16-20x apart
+        for g, r, f in zip(got[1:], rounded[1:], full[1:]):
+            assert rms(g, r) < min(1.5e-4, rms(g, f) / 8)
+
+    def test_dead_centre_gives_finite_zeros(self, rng):
+        """A centre of weight 0 (log 0 = -inf) takes no posterior mass and
+        poisons nothing, in the statistics and in the node's kernel form."""
+        from keystone_tpu.ops.fv_pallas import fv_stats_pallas
+
+        x, _, means, variances, weights = self._case(rng, cols=300, ragged=False)
+        weights[2] = 0.0
+        weights /= weights.sum()
+        cols_first = jnp.asarray(np.swapaxes(x, 1, 2))
+        s0, s1, s2 = fv_stats_pallas(
+            cols_first, None, means, variances, weights, block=128, interpret=True
         )
-        want = np.stack([
-            np.asarray(fisher_vector(x[i], means, variances, weights))
-            for i in range(x.shape[0])
-        ])
-        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+        for s in (s0, s1, s2):
+            assert np.isfinite(np.asarray(s)).all()
+        assert np.all(np.asarray(s0)[:, 2] == 0) and np.all(np.asarray(s1)[:, :, 2] == 0)
+        node_ = FisherVector(GaussianMixtureModel(means, variances, weights))
+        fv = np.asarray(node_._kernel_form(cols_first, interpret=True))
+        assert np.isfinite(fv).all()
+        assert np.all(fv[:, :, [2, 8 + 2]] == 0)
+        np.testing.assert_allclose(
+            fv, np.asarray(node_._xla_form(cols_first)), atol=2e-2 * np.abs(fv).max()
+        )
+
+    @pytest.mark.parametrize(
+        "backend,one_device,masked,want",
+        [
+            ("tpu", True, False, "kernel"),  # VOC's cell, a served request
+            ("tpu", False, False, "xla"),  # a mesh: a custom call is not partitioned
+            ("tpu", True, True, "xla"),  # the kernel knows prefix counts only
+            ("cpu", True, False, "xla"),  # every tier-1 test
+            ("gpu", True, False, "xla"),
+        ],
+    )
+    def test_fv_form_rule(self, backend, one_device, masked, want):
+        """Which form for which (backend, placement, mask); no shape enters
+        (ops/fisher.py's table: the XLA form won at none on the chip)."""
+        from keystone_tpu.ops.fisher import fv_form
+
+        assert fv_form(backend, one_device, masked) == want
+
+    def test_fv_form_sees_mesh_and_mask(self, rng, mesh8):
+        """What the node hands the rule: it sees a sharded input and a mask,
+        counts each eager call under ``fv_form.xla`` (a CPU never picks the
+        kernel), and all three give the same Fisher vectors."""
+        from keystone_tpu.core import trace
+        from keystone_tpu.ops.conv_fused import _on_one_device
+        from keystone_tpu.parallel.mesh import row_sharding
+
+        x, _, means, variances, weights = self._case(rng, n=8, cols=40, ragged=False)
+        node_ = FisherVector(GaussianMixtureModel(means, variances, weights))
+        descs = jnp.asarray(np.swapaxes(x, 1, 2))
+        sharded = jax.device_put(descs, row_sharding(mesh8))
+        assert _on_one_device(descs) and not _on_one_device(sharded)
+        before = trace.metrics.get("fv_form.xla")
+        alone = np.asarray(node_(descs))
+        np.testing.assert_allclose(np.asarray(node_(sharded)), alone, atol=1e-5)
+        masked = np.asarray(node_(descs, mask=jnp.ones(descs.shape[::2], jnp.float32)))
+        np.testing.assert_allclose(masked, alone, atol=1e-5)
+        assert trace.metrics.get("fv_form.xla") == before + 3
+        assert trace.metrics.get("fv_form.kernel") == 0
+
+    def test_fv_form_counter_moves(self, rng):
+        """``fv_form.<form>`` counts a traced program, not its calls, and the
+        shapes ride an instant on the timeline."""
+        from keystone_tpu.core import trace
+
+        x, _, means, variances, weights = self._case(rng, cols=40, ragged=False)
+        node_ = FisherVector(GaussianMixtureModel(means, variances, weights))
+        descs = jnp.asarray(np.swapaxes(x, 1, 2))
+        before = trace.metrics.get("fv_form.xla")
+        fn = jax.jit(node_.__call__)
+        fn(descs)
+        fn(descs)
+        assert trace.metrics.get("fv_form.xla") == before + 1
+        assert trace.metrics.get("fv_form.kernel") == 0
+        last = [e for e in trace.flight_events() if e["name"] == "fv_form"][-1]
+        assert last["args"] == {"form": "xla", "images": 3, "cols": 40, "d": 24, "k": 8}

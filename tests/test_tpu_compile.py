@@ -48,3 +48,30 @@ def test_conv_kernel_form_compiles_at_benchmark_widths(one_chip):
     assert "tpu_custom_call" in text
     assert "27,27,1250]" not in text and "27,27,1280]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_fv_kernel_form_compiles_at_published_widths(one_chip):
+    """64 images x 73,866 descriptors x 80 values against 256 centres
+    (`voc_sift_fv_256`, a chunk of 375x500 images): the program holds the
+    kernel, no array of the posteriors' shape (4.8 GB in the XLA form), and
+    next to no temporaries; the ragged last block (73,866 is 36 blocks and
+    138 columns) needs no padded copy."""
+    from keystone_tpu.ops.fisher import FisherVector
+    from keystone_tpu.solvers.gmm import GaussianMixtureModel
+
+    rng = np.random.default_rng(0)
+    d, k = 80, 256
+    node_ = FisherVector(
+        GaussianMixtureModel(
+            rng.normal(size=(d, k)).astype(np.float32),
+            rng.uniform(0.5, 2.0, (d, k)).astype(np.float32),
+            rng.dirichlet(np.ones(k)).astype(np.float32),
+        )
+    )
+    chunk = jax.ShapeDtypeStruct((64, d, 73866), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(node_._kernel_form).lower(chunk).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "73866,256]" not in text and "256,73866]" not in text
+    assert "74112" not in text and "75776" not in text  # no padded descriptors
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
