@@ -54,14 +54,12 @@ TEST_ERROR_BAR = 5.0  # percent; chance on 10 balanced classes is 90
 FULL = dict(
     train=50_000, test=10_000, whitener=100_000, requests=256,
     jpeg_train=4_096, jpeg_test=2_048, golden=64,
-    idct_images=2_048, fv=(8, 73_866, 80, 256), pool_images=1_024,
-    pool_step=8, conv=(2_048, 1_250, 128),
+    idct_images=2_048, fv=(8, 73_866, 80, 256), conv=(2_048, 1_250, 128),
 )
 TINY = dict(
     train=600, test=200, whitener=4_000, requests=24,
     jpeg_train=96, jpeg_test=48, golden=8,
-    idct_images=8, fv=(3, 700, 24, 8), pool_images=4, pool_step=2,
-    conv=(5, 24, 4),
+    idct_images=8, fv=(3, 700, 24, 8), conv=(5, 24, 4),
 )
 
 
@@ -75,19 +73,15 @@ def check(cond, msg: str) -> None:
 
 
 def _class_images(rng, palette, labels, size: int):
-    """[n, 3, size, size] float32: class colour + a class-frequency stripe
-    on one channel + noise — the verify skill's separable generator, ten
-    classes wide."""
-    import numpy as np
+    """[n, size, size, 3] float32 of separable classes: the benchmark's
+    generator (class colour + a class-frequency stripe on one channel +
+    noise, whole levels) at the verify skill's amplitudes."""
+    from benchmark.lib.manifest import load_module
 
-    n = len(labels)
-    img = palette[labels][:, :, None, None] + rng.normal(
-        0, 25, (n, 3, size, size)
-    ).astype(np.float32)
-    xx = np.arange(size, dtype=np.float32)[None, None, :]
-    stripe = 30 * np.sin(xx / (2.0 + labels)[:, None, None])
-    img[np.arange(n), labels % 3] += stripe
-    return np.clip(img, 0, 255)
+    return load_module("datagen", "class_images")._images(
+        rng, palette, labels,
+        {"size": size, "noise_sigma": 25.0, "stripe_amp": 30.0},
+    )
 
 
 def write_cifar_bin(path: str, n: int, rng, palette) -> None:
@@ -99,7 +93,7 @@ def write_cifar_bin(path: str, n: int, rng, palette) -> None:
             img = _class_images(rng, palette, labels, 32).astype(np.uint8)
             rec = np.empty((len(labels), 3073), np.uint8)
             rec[:, 0] = labels
-            rec[:, 1:] = img.reshape(len(labels), -1)
+            rec[:, 1:] = img.transpose(0, 3, 1, 2).reshape(len(labels), -1)
             rec.tofile(f)
 
 
@@ -112,43 +106,13 @@ def write_jpeg_tar(path: str, n: int, rng, palette) -> None:
     with tarfile.open(path, "w") as tf:
         for i in range(n):
             buf = io.BytesIO()
-            Image.fromarray(img[i].transpose(1, 2, 0)).save(
+            Image.fromarray(img[i]).save(
                 buf, format="JPEG", quality=90
             )
             info = tarfile.TarInfo(f"{labels[i]}/img_{i:05d}.jpg")
             info.size = buf.tell()
             buf.seek(0)
             tf.addfile(info, buf)
-
-
-# -- compile accounting --------------------------------------------------------
-
-
-class CompileMeter:
-    """Seconds JAX spent in backend compiles (cache retrievals included)
-    and its persistent-cache hits and misses, from jax.monitoring."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def read(self) -> tuple:
-        return self.seconds, self.hits, self.misses
 
 
 # -- legs ----------------------------------------------------------------------
@@ -418,50 +382,6 @@ def _kernel_fv_stats(ctx, interpret, rng) -> dict:
     }
 
 
-def _kernel_rect_pool(ctx, interpret, rng) -> dict:
-    """Rectify + sum-pool over the production activation layout and dtype,
-    against the op-by-op chain on the SAME activations
-    (tests/test_conv_fused.py test_pallas_rect_pool_matches_xla)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from keystone_tpu.ops.conv_fused import FusedConvFeaturizer
-    from keystone_tpu.ops.images import (
-        ImageVectorizer,
-        Pooler,
-        SymmetricRectifier,
-    )
-    from keystone_tpu.ops.rect_pool_pallas import rect_pool_pallas
-
-    size = ctx["size"]
-    imgs = jnp.asarray(
-        rng.uniform(0, 255, (size["pool_images"], 32, 32, 3)).astype(np.float32)
-    )
-    node = FusedConvFeaturizer(
-        jnp.asarray(rng.normal(size=(100, 6, 6, 3)).astype(np.float32)),
-        whitener_means=jnp.asarray(rng.normal(size=(108,)).astype(np.float32)),
-        pool_stride=13, pool_size=14, alpha=0.25,
-    )
-    z = jax.jit(lambda im: node.conv(im).astype(jnp.bfloat16))(imgs)
-    got = np.asarray(rect_pool_pallas(
-        z, pool_stride=13, pool_size=14, alpha=0.25,
-        images_per_step=size["pool_step"], interpret=interpret,
-    ))
-    want = np.asarray(ImageVectorizer()(
-        Pooler(13, 14, None, "sum")(
-            SymmetricRectifier(alpha=0.25)(z.astype(jnp.float32))
-        )
-    ))
-    np.testing.assert_allclose(
-        got, want, rtol=1e-5, atol=1e-4 * np.abs(want).max()
-    )
-    return {
-        "shape": list(z.shape),
-        "max_abs_err": float(np.abs(got - want).max()),
-    }
-
-
 def _kernel_conv_form(ctx, interpret, rng) -> dict:
     """FusedConvFeaturizer's kernel form at the benchmark's widths (2,048
     images x 1,250 filters): against the node's XLA form on every image,
@@ -481,7 +401,7 @@ def _kernel_conv_form(ctx, interpret, rng) -> dict:
     means = (0.1 * rng.normal(size=(108,))).astype(np.float32)
     palette = rng.uniform(40, 215, (10, 3)).astype(np.float32)
     imgs = jnp.asarray(
-        _class_images(rng, palette, rng.integers(0, 10, n), 32).transpose(0, 2, 3, 1)
+        _class_images(rng, palette, rng.integers(0, 10, n), 32)
     )
     node = FusedConvFeaturizer(
         filters, whitener_means=means, pool_stride=13, pool_size=14, alpha=0.25
@@ -519,7 +439,6 @@ def _kernel_conv_form(ctx, interpret, rng) -> dict:
 KERNELS = {
     "idct_blocks_pallas": _kernel_idct,
     "fv_stats_pallas": _kernel_fv_stats,
-    "rect_pool_pallas": _kernel_rect_pool,
     "conv_rect_pool": _kernel_conv_form,
 }
 
@@ -662,6 +581,8 @@ def main(argv=None) -> int:
     if legs is None:
         legs = ["A", "B", "C"] + (["D"] if device["count"] >= 4 else [])
     full_run = not a.legs and not a.rehearsal
+    from benchmark.lib.compile_meter import CompileMeter
+
     meter = CompileMeter()
     cache_dir = jax.config.jax_compilation_cache_dir  # None: caching off
 
@@ -711,14 +632,14 @@ def main(argv=None) -> int:
                 ok = False
                 failed.append(name)
                 traceback.print_exc()
-            after = meter.read()
+            compiles = CompileMeter.between(before, meter.read())
             rec = {
                 "leg": name, "ok": ok,
                 "wall_seconds": round(time.perf_counter() - t0, 2),
                 # set-up time, not work: zero-ish on a warm cache
-                "compile_seconds": round(after[0] - before[0], 2),
-                "cache_hits": after[1] - before[1],
-                "cache_misses": after[2] - before[2],
+                "compile_seconds": round(compiles["seconds"], 2),
+                "cache_hits": compiles["hits"],
+                "cache_misses": compiles["misses"],
                 **detail,
             }
             records[name] = rec

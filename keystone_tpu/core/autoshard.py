@@ -296,12 +296,11 @@ def plan_log_path() -> str | None:
 
 def hermetic_plan_log() -> str:
     """Point the plan-outcome log at a fresh throwaway file and forget any
-    cached read.  For measurement/chaos drivers (bench sections,
-    tools/chaos_run.py): their fixed-seed synthetic fits must neither
-    TRAIN the operator's real log (three bench rounds would calibrate the
-    bench fingerprints and start reordering the very ranking the driver
-    asserts is hand-identical) nor evict real workload records from its
-    bounded tail."""
+    cached read.  For chaos drivers (tools/chaos_run.py): their fixed-seed
+    synthetic fits must neither TRAIN the operator's real log (a few
+    rounds would calibrate their fingerprints and start reordering the
+    very ranking the driver asserts is hand-identical) nor evict real
+    workload records from its bounded tail."""
     import tempfile
 
     path = os.path.join(

@@ -125,7 +125,7 @@ class _NullTarget:
 
 class ObsAgent:
     """A standalone obs endpoint for processes WITHOUT a serving wire
-    server (fit workers, the bench controller): a
+    server (fit workers, a drill's controller): a
     :class:`~.wire.WireServer` over a null target — the dispatch path
     already answers T_OBS_SNAPSHOT/T_OBS_FLIGHT/T_CLOCK for every wire
     server, so all this adds is the socket."""

@@ -778,8 +778,8 @@ class ShapeRouter:
     def adapt(self) -> dict:
         """One retire sweep: unroute every engine idle past
         ``retire_after_s`` (down to ``min_engines``), drain it, close it,
-        unregister its SLO tracker.  Returns the actions taken (tests and
-        the bench call this directly; the submit path runs it on a
+        unregister its SLO tracker.  Returns the actions taken (tests
+        call this directly; the submit path runs it on a
         background thread every ``adapt_interval_s``)."""
         now = self._clock()
         retired: list[_Entry] = []

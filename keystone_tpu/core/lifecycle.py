@@ -697,7 +697,7 @@ class LifecycleController:
 
     def record(self) -> dict:
         """JSON-able controller state (the ``lifecycle:<label>``
-        ``/statusz`` section; also what the bench drills embed)."""
+        ``/statusz`` section; also what the drills embed)."""
         return {
             "label": self.label,
             "state": self.state,

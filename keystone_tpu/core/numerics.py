@@ -55,8 +55,8 @@ override); with the observatory OFF every hook on the pipeline/ingest/
 serve paths is exactly that check and NO per-site state is retained (the
 tier-1 suite pins zero retained allocation in disabled mode).  ON, a
 sampled probe costs one small reduction + one 8-scalar host transfer;
-``KEYSTONE_NUMERICS_SAMPLE`` thins the cadence and the bench bounds the
-probed-serve p99 overhead at <= 5%.
+``KEYSTONE_NUMERICS_SAMPLE`` thins the cadence (the probed-serve p99
+overhead: not measured on the chip).
 
 This module is deliberately jax-free at import (it sits on the spawned
 decode workers' import path via core.ingest — see

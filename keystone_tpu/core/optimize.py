@@ -414,8 +414,8 @@ def release_caches(pipeline: Pipeline) -> None:
 
 # -- the placement cost model (shared with core.autoshard) --------------------
 
-#: Per-chip peaks by ``device_kind`` — THE peaks table: ``core.profiler``
-#: and ``bench.py`` read this one and keep none of their own.
+#: Per-chip peaks by ``device_kind`` — the package's peaks table:
+#: ``core.profiler`` reads this one and keeps none of its own.
 #: ``peak_flops`` is the bf16 MXU peak (f32 matmuls run bf16 passes under
 #: default precision, so it is the honest MFU denominator); ``hbm_gbps``
 #: is GB/s (1e9).  Source: Google Cloud TPU documentation, per-chip
@@ -613,109 +613,6 @@ class CalibrationModel:
             "features": list(self.feature_names),
             "kinds": list(self.kinds),
         }
-
-
-# -- the snapshot advisor -----------------------------------------------------
-
-#: env var: assumed snapshot-disk sequential bandwidth (GB/s) used by the
-#: advisor when no measured rate is supplied.
-SNAPSHOT_GBPS_ENV = "KEYSTONE_SNAPSHOT_GBPS"
-_DEFAULT_SNAPSHOT_GBPS = 0.5
-
-
-def snapshot_gbps() -> float:
-    raw = os.environ.get(SNAPSHOT_GBPS_ENV, "").strip()
-    if not raw:
-        return _DEFAULT_SNAPSHOT_GBPS
-    try:
-        val = float(raw)
-    except ValueError:
-        raise ValueError(
-            f"{SNAPSHOT_GBPS_ENV}={raw!r} is not a number"
-        ) from None
-    if val <= 0:
-        raise ValueError(f"{SNAPSHOT_GBPS_ENV}={raw!r} must be > 0")
-    return val
-
-
-@dataclasses.dataclass
-class SnapshotAdvice:
-    """The snapshot advisor's decision row (CachePlan's sibling): should a
-    repeat-epoch workload materialize decoded chunks instead of re-decoding
-    every epoch?  Same cost-model shape as the caching inequality — decode
-    seconds saved across repeat epochs vs the IO cost of writing once and
-    reading per epoch."""
-
-    images: int
-    epochs: int
-    bytes_per_image: int
-    decode_images_per_sec: float
-    gbps: float
-    live_seconds: float  #: epochs x one full decode
-    snapshot_seconds: float  #: decode once + write once + read (epochs-1)x
-    advise: bool
-    reason: str
-
-    def record(self) -> dict:
-        out = dataclasses.asdict(self)
-        out["live_seconds"] = round(self.live_seconds, 3)
-        out["snapshot_seconds"] = round(self.snapshot_seconds, 3)
-        return out
-
-
-def advise_snapshot(
-    *,
-    images: int,
-    bytes_per_image: int,
-    decode_images_per_sec: float,
-    epochs: int,
-    gbps: float | None = None,
-) -> SnapshotAdvice:
-    """Cost-based snapshot decision: a snapshot pays when the decode time
-    it removes from epochs 2..N exceeds the one-time shard write plus the
-    per-epoch shard read.  ``decode_images_per_sec`` is the MEASURED live
-    decode rate (bench's decode ceiling, or the stream's own stats);
-    ``gbps`` prices shard IO (``KEYSTONE_SNAPSHOT_GBPS``)."""
-    if images < 0 or epochs < 1 or decode_images_per_sec <= 0:
-        raise ValueError(
-            "advise_snapshot wants images >= 0, epochs >= 1, "
-            "decode_images_per_sec > 0"
-        )
-    rate = gbps if gbps is not None else snapshot_gbps()
-    decode_secs = images / decode_images_per_sec
-    io_secs = images * bytes_per_image / (rate * 2**30)
-    live = epochs * decode_secs
-    snap = decode_secs + io_secs + (epochs - 1) * io_secs
-    advise = epochs > 1 and snap < live
-    if epochs <= 1:
-        reason = "single pass: nothing to amortize"
-    elif advise:
-        reason = (
-            f"snapshot {snap:.2f}s < live {live:.2f}s over {epochs} epochs "
-            f"(decode {decode_secs:.2f}s/epoch, shard IO {io_secs:.2f}s @ "
-            f"{rate}GB/s)"
-        )
-    else:
-        reason = (
-            f"live {live:.2f}s <= snapshot {snap:.2f}s — shard IO would "
-            "cost more than the decode it saves"
-        )
-    out = SnapshotAdvice(
-        images=images,
-        epochs=epochs,
-        bytes_per_image=bytes_per_image,
-        decode_images_per_sec=decode_images_per_sec,
-        gbps=rate,
-        live_seconds=live,
-        snapshot_seconds=snap,
-        advise=advise,
-        reason=reason,
-    )
-    trace.instant(
-        "snapshot_advice", advise=advise, live_seconds=round(live, 3),
-        snapshot_seconds=round(snap, 3), epochs=epochs,
-    )
-    return out
 
 
 # -- the closed-loop ingest autotuner -----------------------------------------

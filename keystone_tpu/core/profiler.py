@@ -17,8 +17,8 @@ Cost Model for Placement on Reconfigurable Dataflow Hardware):
   ``cost_analysis()`` FLOPs/bytes with the wall into live per-program MFU
   and roofline position (``optimize.CostModel`` device rate tables),
   exported as ``profiler_*`` gauges in ``trace.metrics`` (Prometheus rides
-  for free) and ``profiler.program`` trace instants, and aggregated into
-  the bench ``profiler`` section via :func:`ledger_record`.
+  for free) and ``profiler.program`` trace instants, and aggregated by
+  :func:`ledger_record`.
 * **HBM watermark sampler** — a background thread polls
   ``device.memory_stats()`` every ``KEYSTONE_HBM_SAMPLE_MS`` and keeps
   per-:func:`phase` high-water marks; :func:`audit_plan` compares a
@@ -41,8 +41,8 @@ Overhead discipline: :func:`enabled` is one module-flag/env check; with
 the profiler OFF every hook in the execution paths is that single check
 (the tier-1 suite pins an empty ledger and no sampler thread after a
 profiled-shape run).  ON, the per-run cost is one cached cost-analysis
-lookup + a dict update under a lock — the bench measures the serve-path
-p99 overhead against a <= 5% bar.
+lookup + a dict update under a lock (the serve-path p99 overhead: not
+measured on the chip).
 """
 
 from __future__ import annotations
@@ -920,7 +920,7 @@ def profiled(
         if on:
             # Pre-warm the lazies the first attribution would otherwise
             # pay ON the hot path (rate-table import, jax.devices): the
-            # steady-state overhead is the number the bench bounds.
+            # steady-state overhead is what a serving cell would bound.
             device_rates()
             ensure_sampler(interval_ms=interval_ms, stats_fn=stats_fn)
         yield

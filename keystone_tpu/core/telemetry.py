@@ -217,8 +217,6 @@ class SLOTracker:
     BURN_CAPTURE_MIN_COUNT = 20
 
     def observe(self, latency_ms: float, ok: bool = True) -> None:
-        if _suspended:
-            return
         now = self._clock()
         violation = (not ok) or latency_ms > self.slo_ms
         breach = False
@@ -310,7 +308,6 @@ class SLOTracker:
 
 _slo_lock = threading.Lock()
 _slo_trackers: dict[str, SLOTracker] = {}
-_suspended = False  # telemetry_disabled(): the bench's off-mode control
 
 
 def register_slo(label: str, **kwargs) -> SLOTracker:
@@ -347,23 +344,6 @@ class _SLOGroup:
 
 
 trace.metrics.adopt("slo", _SLOGroup())
-
-
-@contextlib.contextmanager
-def telemetry_disabled():
-    """Everything this tier adds, OFF: flight ring depth 0 and SLO
-    observation suspended — the control arm of the bench's telemetry-
-    overhead measurement."""
-    global _suspended
-    prev_depth = trace.flight_depth()
-    prev_susp = _suspended
-    trace.set_flight_depth(0)
-    _suspended = True
-    try:
-        yield
-    finally:
-        trace.set_flight_depth(prev_depth)
-        _suspended = prev_susp
 
 
 # -- the /statusz debug surface ------------------------------------------------

@@ -58,8 +58,7 @@ Overhead discipline: with tracing AND the flight ring off the path is a
 module-state check returning a cached null object; with only the ring on,
 each finished span is one small dict append into a bounded deque (the
 tier-1 suite asserts no retained allocation growth once the ring is warm,
-and a span's enter + exit under 20 us), a stage adds one registry lock,
-and the bench acceptance bound is < 2% on ``stage_ops`` with tracing off.
+and a span's enter + exit under 20 us) and a stage adds one registry lock.
 Enabled, each finished span is one dict append under a lock (bounded at
 :data:`MAX_EVENTS`; overflow is counted, never unbounded).
 """

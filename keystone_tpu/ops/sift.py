@@ -168,8 +168,8 @@ class SIFTExtractor(Transformer):
     MB/image of traffic in f32 at 256x256x4-scales; the op is memory-bound
     at ~11 FLOP/byte, bench round r04 roofline, 2026-07-30, record removed
     in PR 21).  Passing ``jnp.bfloat16`` (the
-    throughput workloads do — imagenet_sift_lcs_fv, voc_sift_fisher,
-    bench.py) halves that traffic: gemms accumulate f32 and the
+    throughput workloads do — imagenet_sift_lcs_fv, voc_sift_fisher)
+    halves that traffic: gemms accumulate f32 and the
     normalize/clamp/quantize tail runs f32, so the only effect is one
     rounding of intermediate values.  MEASURED vs the f32 chain (v5e,
     random-noise 256x256 images — the worst case for near-threshold bins):

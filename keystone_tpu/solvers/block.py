@@ -752,7 +752,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         self.lam = lam
         self.mesh = mesh
         #: core.memory.FitReport of the most recent fit (tier plans, chosen
-        #: tier, denials, OOM retries) — the bench emits it verbatim.
+        #: tier, denials, OOM retries) — workload results embed it.
         self.last_fit_report = None
 
     def fit(
@@ -957,7 +957,10 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             if specs:
                 name = f"fused[mesh {mesh_desc(m)}|{autoshard.spec_tag(specs)}]"
             d_sz, m_sz = m.shape[DATA_AXIS], m.shape[MODEL_AXIS]
-            n_pad = n0 + (-n0) % d_sz
+            # The design matrix may arrive row-padded for the CALLER's data
+            # axis (nvalid < its rows); every candidate pads from there.
+            n_x = int(np.shape(x)[0])
+            n_pad = n_x + (-n_x) % d_sz
             k_pad = k + (-k) % m_sz
             mdict = dict(m.shape)
             lspec = (specs or {}).get("labels", "data@dim0")
@@ -1047,10 +1050,14 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                     (x_p, y_p), nv = pad_shard_inputs(m, nvalid0, x, labels)
                     # Class columns shard over the model axis; zero label
                     # columns stay zero through every BCD update — exact
-                    # pad.
+                    # pad.  Rows: a candidate whose data axis is not the
+                    # caller's pads a caller-padded design matrix and the
+                    # unpadded labels to different counts; labels take the
+                    # design matrix's (zero rows, masked by nv).
+                    row_pad = int(jnp.shape(x_p)[0]) - int(jnp.shape(y_p)[0])
                     col_pad = (-int(jnp.shape(y_p)[1])) % m_sz
-                    if col_pad:
-                        y_p = jnp.pad(y_p, ((0, 0), (0, col_pad)))
+                    if row_pad or col_pad:
+                        y_p = jnp.pad(y_p, ((0, row_pad), (0, col_pad)))
                     nv = nv if nv is not None else int(jnp.shape(y_p)[0])
                 else:
                     # Non-default labels layout: pad rows to the sharded

@@ -580,7 +580,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         self.class_chunk = class_chunk
         self.mesh = mesh
         #: core.memory.FitReport of the most recent fit (tier plans, chosen
-        #: tier, denials, OOM retries) — the bench emits it verbatim.
+        #: tier, denials, OOM retries) — workload results embed it.
         self.last_fit_report = None
 
     def fit(

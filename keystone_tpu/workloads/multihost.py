@@ -13,9 +13,8 @@ faces.
 * **Drivers** (:func:`run_two_process_fit_serve`,
   :func:`run_host_loss_drill`): spawn the workers as REAL subprocesses
   with auto-picked ports and judge the results.  tests/test_multihost.py,
-  the chaos ``host_loss`` family, ``bench.py``'s multihost section and
-  the ``--hosts N`` tools all drive these two functions — one
-  implementation, four consumers.
+  the chaos ``host_loss`` family and the ``--hosts N`` tools all drive
+  these two functions — one implementation, three consumers.
 
 Bit-identity design: XLA's cross-process reductions are NOT bit-identical
 to a single-process run, so nothing numerical crosses hosts through XLA.

@@ -16,8 +16,7 @@ modes through here:
 * ``--serveBench`` — the SLO bench: N concurrent synthetic clients with
   pipelined depth drive the same endpoint; p50/p99 latency, sustained QPS,
   batcher occupancy, and the batched-vs-unbatched QPS ratio land in
-  ``results["serving"]`` (the same record shape bench.py's ``serving``
-  section emits).
+  ``results["serving"]``.
 
 Bucket/deadline knobs come from the ``KEYSTONE_SERVE_*`` env (see
 core.serve / README): the CLI adds client-side shape only

@@ -1139,8 +1139,7 @@ def _snapshot_corrupt_phase(fault: Fault, tmpdir: str, seed: int) -> None:
     def cfg():
         # snapshot_mode pinned: an ambient KEYSTONE_SNAPSHOT_MODE=featurized
         # would stop the ingest tee from committing a decoded snapshot and
-        # fail the family with nothing to corrupt (same hazard bench.py's
-        # no_snap() pins against).
+        # fail the family with nothing to corrupt.
         return ingest.StreamConfig.from_env(
             snapshot_dir=snap_root, snapshot_mode="decoded"
         )

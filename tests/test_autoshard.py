@@ -739,6 +739,36 @@ def test_forced_spec_plan_executes_layout_bit_identical(rng):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_searched_mesh_pads_labels_to_caller_padded_features(rng, mesh42):
+    """A caller under a 4x2 mesh hands the solver features already
+    row-padded for ITS data axis (204 rows for 203 labels, as the mesh
+    workloads do); a searched candidate with another data axis (1x8 pads
+    neither operand) must still give the design matrix and the labels the
+    same rows — and the hand order's model."""
+    from keystone_tpu.parallel.mesh import padded_shard_rows
+
+    x, y = _small_problem(rng, n=203, d=128, k=4)
+    x_pad, nvalid = padded_shard_rows(np.asarray(x), mesh42)
+    assert x_pad.shape[0] == 204 and nvalid == 203
+
+    def fit(plan):
+        est = BlockLeastSquaresEstimator(64, num_iter=1, lam=1.0, mesh=mesh42)
+        model = est.fit(x_pad, y, nvalid=nvalid, plan=plan)
+        return model, est.last_fit_report
+
+    hand, hand_report = fit(False)
+    assert hand_report.chosen == "fused[mesh 4x2]"
+    searched, report = fit(["fused[mesh 1x8]"])
+    assert report.chosen == "fused[mesh 1x8]"
+    np.testing.assert_allclose(
+        np.asarray(searched.b), np.asarray(hand.b), rtol=1e-4, atol=1e-5
+    )
+    for a, b in zip(hand.xs, searched.xs):
+        np.testing.assert_allclose(
+            np.asarray(b), np.asarray(a), rtol=1e-4, atol=1e-5
+        )
+
+
 def test_bwls_mesh_search_spec_candidates_execute(rng):
     import jax
 

@@ -1,0 +1,198 @@
+"""Every name the benchmark reads out of the program still exists in it.
+
+``benchmark/`` finds the program's work by name: device programs by
+``^jit_<function>`` patterns (``pipelines/*.py`` ``PROGRAMS``), stage walls
+by ``stage_timer`` names (``metrics/*.json`` ``stages``), counters and
+histogram families by their registry names.  A rename in the package does
+not fail there: the reader returns ``None``, and on the chip a per-layer
+metric that had a value turns ``null``.  Each case below fails here instead,
+on the CPU, in seconds: it checks that ``benchmark/`` still asks for the
+name (so this table cannot go stale in silence) and that the package still
+has it.  Nothing is trained.
+"""
+
+import ast
+import functools
+import importlib
+import inspect
+import os
+import re
+
+import numpy as np
+import pytest
+
+from benchmark.lib import manifest
+from keystone_tpu.core import trace
+from keystone_tpu.core.logging import stage_timer
+
+#: ``PROGRAMS`` patterns that name a jitted function of the package (the
+#: rest name jax's own eager programs): pattern -> module that holds it.
+PROGRAMS = {
+    r"^jit___call": "keystone_tpu.workloads.cifar_random_patch",
+    r"^jit_sharded_moments_jit$": "keystone_tpu.ops.stats",
+    r"^jit__fused_bcd_impl$": "keystone_tpu.solvers.block",
+    r"^jit__bcd_": "keystone_tpu.solvers.block",
+    r"^jit__hs_block": "keystone_tpu.solvers.block",
+    r"^jit__describe_chunk": "keystone_tpu.workloads.fv_common",
+    r"^jit__sample_chunk": "keystone_tpu.workloads.fv_common",
+    r"^jit__encode_chunk": "keystone_tpu.workloads.fv_common",
+    r"^jit__gather_samples": "keystone_tpu.workloads.fv_common",
+    r"^jit__em_fit": "keystone_tpu.solvers.gmm",
+}
+
+#: pipeline of the cell -> the workload module its window drives, and the
+#: stages of that workload a metric names.  (``timit_rf`` drives a copy of
+#: ``timit.run``'s calls kept in ``benchmark/pipelines``: its stages are
+#: not the package's.)
+STAGES = {
+    "cifar_rp": (
+        "keystone_tpu.workloads.cifar_random_patch",
+        ["learn_filters", "warm_featurizer", "featurize", "scale",
+         "featurize_test", "solve", "eval"],
+    ),
+    "voc_fv": (
+        "keystone_tpu.workloads.voc_sift_fisher",
+        ["sample_descriptors", "pca", "gmm", "featurize",
+         "featurize_test", "solve", "eval"],
+    ),
+}
+
+COUNTERS = {
+    "fv.descriptor_passes": "keystone_tpu.workloads.voc_sift_fisher",
+    "gmm.iterations": "keystone_tpu.workloads.voc_sift_fisher",
+}
+
+HISTOGRAMS = ["stage_ms", "stage_wait_ms", "stage_h2d_mb"]
+
+CASES = (
+    [("program", p, m) for p, m in PROGRAMS.items()]
+    + [
+        ("stage", f"{pipeline}:{s}", module)
+        for pipeline, (module, stages) in STAGES.items() for s in stages
+    ]
+    + [("counter", c, m) for c, m in COUNTERS.items()]
+    + [("histogram", h, "keystone_tpu.core.trace") for h in HISTOGRAMS]
+)
+
+
+@functools.lru_cache(maxsize=None)
+def _metrics() -> tuple:
+    return tuple(
+        manifest.load_json("metrics", name)
+        for name in sorted(os.listdir(os.path.join(manifest.BENCH_DIR, "metrics")))
+        if name.endswith(".json")
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _pipelines_of_cells() -> dict:
+    """``{cell: pipeline}`` from BENCHMARK.json and the configs' files."""
+    return {
+        w["name"]: manifest.cell(w["name"])["config"]["pipeline"]
+        for w in manifest.benchmark_json()["workloads"]
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def _asked_programs() -> frozenset:
+    """Every pattern of every pipeline's ``PROGRAMS``."""
+    return frozenset(
+        p
+        for name in set(_pipelines_of_cells().values())
+        for ps in manifest.load_module("pipelines", name).PROGRAMS.values()
+        for p in ps
+    )
+
+
+def _program_names(module) -> set:
+    """``jit_<name>`` of every jitted function the module holds: the name
+    its compiled program carries in a device trace."""
+    return {
+        "jit_" + getattr(v, "__name__", "")
+        for v in vars(module).values()
+        if callable(v) and hasattr(v, "lower") and hasattr(v, "__wrapped__")
+    }
+
+
+def _literal_first_args(module, callee: str) -> set:
+    """First arguments, where they are string literals, of every call of
+    ``callee`` (a name or a dotted attribute's tail) in the module."""
+    tree = ast.parse(inspect.getsource(module))
+    out = set()
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call) or not node.args:
+            continue
+        name = ast.unparse(node.func)
+        first = node.args[0]
+        if (name == callee or name.endswith("." + callee)) and isinstance(
+            first, ast.Constant
+        ) and isinstance(first.value, str):
+            out.add(first.value)
+    return out
+
+
+def _check_program(pattern, module):
+    assert pattern in _asked_programs(), (
+        f"no PROGRAMS list asks for {pattern} any more"
+    )
+    names = _program_names(importlib.import_module(module))
+    assert any(re.search(pattern, n) for n in names), (
+        f"{module} holds no jitted function whose program {pattern} finds; "
+        f"it holds {sorted(names)}"
+    )
+
+
+def _check_stage(name, module):
+    pipeline, stage = name.split(":")
+    cells = {c for c, p in _pipelines_of_cells().items() if p == pipeline}
+    assert cells, f"no cell drives the pipeline {pipeline}"
+    asked = {
+        s
+        for m in _metrics()
+        if cells & set(m.get("workloads") or cells)
+        for s in (m.get("stages") or [])
+    }
+    assert stage in asked, (
+        f"no metric of {sorted(cells)} lists the stage {stage} any more"
+    )
+    timers = _literal_first_args(importlib.import_module(module), "stage_timer")
+    assert stage in timers, (
+        f"{module} has no stage_timer({stage!r}); it has {sorted(timers)}"
+    )
+
+
+def _check_counter(name, module):
+    assert name in {m.get("counter") for m in _metrics()}, (
+        f"no metric reads the counter {name} any more"
+    )
+    incs = _literal_first_args(importlib.import_module(module), "metrics.inc")
+    assert name in incs, f"{module} counts {sorted(incs)}, not {name}"
+
+
+def _check_histogram(name, _module):
+    kinds = manifest.load_module("readers", "stage_samples").KINDS
+    asked = {m.get("hist") for m in _metrics()} | set(kinds)
+    assert name in asked, f"no reader takes the histograms {name}.* any more"
+    with stage_timer("contract_probe"):
+        trace.wait(np.zeros(1), "contract_probe")
+        with trace.h2d("contract_probe", 8):
+            pass
+    hists = trace.metrics.snapshot()["histograms"]
+    assert f"{name}.contract_probe" in hists, (
+        f"a stage records {sorted(h for h in hists if 'contract_probe' in h)}"
+    )
+
+
+_CHECKS = {
+    "program": _check_program,
+    "stage": _check_stage,
+    "counter": _check_counter,
+    "histogram": _check_histogram,
+}
+
+
+@pytest.mark.parametrize(
+    "kind,name,module", CASES, ids=[f"{k}:{n}" for k, n, _ in CASES]
+)
+def test_benchmark_reads_a_name_the_program_has(kind, name, module):
+    _CHECKS[kind](name, module)

@@ -96,29 +96,6 @@ def test_fused_no_normalization_no_means(rng):
     np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5 * np.abs(ref).max())
 
 
-def test_pallas_rect_pool_matches_xla(rng):
-    """The Pallas rect+pool stage must match the XLA two-reduce_window
-    form — it is kept as a measured-slower template (ops/rect_pool_pallas.py
-    verdict, design item D5), so correctness is its whole value."""
-    from keystone_tpu.ops.rect_pool_pallas import rect_pool_pallas
-
-    imgs = jnp.asarray(rng.uniform(0, 255, (4, 32, 32, 3)).astype(np.float32))
-    filters = jnp.asarray(rng.normal(size=(24, 6, 6, 3)).astype(np.float32))
-    means = jnp.asarray(rng.normal(size=(108,)).astype(np.float32))
-    node_ = FusedConvFeaturizer(
-        filters, whitener_means=means, pool_stride=13, pool_size=14,
-        alpha=0.25, activation_dtype=jnp.float32,
-    )
-    ref = np.asarray(node_(imgs))
-    got = np.asarray(
-        rect_pool_pallas(
-            node_.conv(imgs), pool_stride=13, pool_size=14, alpha=0.25,
-            images_per_step=2, interpret=True,
-        )
-    )
-    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-4 * np.abs(ref).max())
-
-
 # -- the kernel form (Pallas interpreter here; Mosaic in chip_smoke leg C) -----
 
 
@@ -170,7 +147,7 @@ def test_conv_form_rule(mesh8):
     one TPU device where the activation stream dwarfs the patch stream."""
     cifar = dict(positions=27 * 27, d=108)
     assert conv_form("tpu", num_filters=1250, one_device=True, **cifar) == "kernel"
-    # ROOFLINE.md's and bench.py's widths keep the XLA form
+    # ROOFLINE.md's widths keep the XLA form
     assert conv_form("tpu", num_filters=100, one_device=True, **cifar) == "xla"
     assert conv_form("tpu", num_filters=16, one_device=True, **cifar) == "xla"
     # a mesh, or no TPU: the XLA form at any width

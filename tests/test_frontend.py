@@ -87,7 +87,7 @@ class TestRouting:
             rec = router.record()
             json.dumps(rec)
             assert set(rec["engines"]) == {"16", "8"}
-        # route overhead is a registry histogram (the bench regresses on it)
+        # route overhead is a registry histogram
         snap = trace.metrics.snapshot()
         assert snap["histograms"]["router_route_overhead_us"]["count"] >= 16
 

@@ -457,7 +457,7 @@ def test_reanchor_under_live_wire_traffic_full_windows(devices, rng):
     in-flight window FULL: backpressure answers RETRY_AFTER (clients
     absorb and resubmit), the re-anchor swaps engines underneath, and at
     the end every request is answered correctly — zero dropped, the
-    bench's ``reanchor_dropped_requests`` invariant as a tier-1 test."""
+    ``reanchor_dropped_requests`` invariant as a tier-1 test."""
     from keystone_tpu.ops.stats import StandardScalerModel
 
     import jax.numpy as jnp

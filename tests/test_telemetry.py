@@ -364,21 +364,6 @@ def test_postmortems_linked_from_reports(tmp_path, monkeypatch):
     assert path in ServerStats().record()["postmortems"]
 
 
-def test_telemetry_disabled_context():
-    t = telemetry.register_slo("off_probe", slo_ms=5.0)
-    prev_depth = trace.flight_depth()
-    with telemetry.telemetry_disabled():
-        assert trace.flight_depth() == 0
-        t.observe(1.0)
-        with trace.span("invisible"):
-            pass
-    assert trace.flight_depth() == prev_depth
-    assert t.summary()["window"]["count"] == 0
-    assert all(
-        e.get("name") != "invisible" for e in trace.flight_events()
-    )
-
-
 # -- the fresh-process acceptance path (ISSUE 11) -----------------------------
 
 

@@ -30,8 +30,8 @@ traced
 on demand.
 
 Exit status is nonzero if ANY schedule violates the invariant.  The first
-stdout line is the machine-readable JSON record (truncation-proof, same
-convention as bench.py); a short human summary follows.
+stdout line is the machine-readable JSON record (truncation-proof); a
+short human summary follows.
 """
 
 from __future__ import annotations

@@ -8,8 +8,7 @@ Default (in-process) mode builds one deterministic toy engine per
 :class:`~keystone_tpu.core.frontend.ShapeRouter`, and drives a
 mixed-shape request stream from concurrent in-process clients — reporting
 per-shape p50/p99/QPS, the router's stats (engines, routes, warm adds,
-retires), and the ``router_route_overhead_us`` histogram the regression
-observatory (tools/bench_diff.py) watches.
+retires), and the ``router_route_overhead_us`` histogram.
 
 ``--wire`` additionally binds a :class:`~keystone_tpu.core.wire.WireServer`
 and spawns ``--clients`` SEPARATE CLIENT PROCESSES (tools/serve_client.py,
@@ -21,8 +20,8 @@ request-shape-mix shift over the wire: a shape with no engine goes hot
 (RETRY_AFTER backpressure until the router warms an engine for it), then
 the retire sweep runs — the record proves the warm add and the retire.
 
-The first stdout line is the machine-readable JSON record (the bench.py
-convention); human-readable lines follow.  Exit 0 on success, 1 on any
+The first stdout line is the machine-readable JSON record (truncation-
+proof); human-readable lines follow.  Exit 0 on success, 1 on any
 failed client or lost request.
 
 ``--hosts N`` benches the multi-host fleet front (ISSUE 17): N REAL
@@ -37,8 +36,8 @@ request mix trips the armed drift monitor of a served incumbent, the
 :class:`~keystone_tpu.core.lifecycle.LifecycleController` warm-refits on
 fresh data, validates, and hot-swaps the router's engine with requests
 in flight — the record carries ``drift_to_healthy_wall_s``,
-``refit_wall_s``/``swap_wall_s``, and ``dropped_requests`` (pinned 0 by
-tools/bench_diff.py; exit 1 on any drop or a cycle that fails to land).
+``refit_wall_s``/``swap_wall_s``, and ``dropped_requests`` (exit 1 on
+any drop or a cycle that fails to land).
 
 Usage:
     python tools/serve_bench.py                        # in-process
@@ -301,14 +300,13 @@ def run_shift(router, ws, shapes, timeout) -> dict:
 
 
 def drift_refit_drill(tmpdir, *, requests=24, seed=0, timeout=60.0) -> dict:
-    """The closed model-lifecycle drill (ISSUE 18), importable by
-    bench.py's ``extra_metrics.lifecycle`` section: an incumbent fit on
+    """The closed model-lifecycle drill (ISSUE 18): an incumbent fit on
     pre-drift truth serves an armed router; the request mix shifts (new
     truth), the drift monitor trips, and the
     :class:`~keystone_tpu.core.lifecycle.LifecycleController` runs one
     full cycle — warm refit on fresh data, holdout validation, atomic
     hot-swap — while a pump thread keeps requests in flight across the
-    swap.  The record carries the walls bench_diff regresses on
+    swap.  The record carries the drill's walls
     (``drift_to_healthy_wall_s``, ``refit_wall_s``, ``swap_wall_s``),
     ``dropped_requests`` (must stay 0), the post-swap bit-equality
     verdict, and the controller's ``lifecycle:<label>`` statusz section.
@@ -451,7 +449,7 @@ def drift_refit_drill(tmpdir, *, requests=24, seed=0, timeout=60.0) -> dict:
 
 def run_drift_refit(a) -> int:
     """--drift-refit: the lifecycle drill as a CLI record (JSON first
-    line, bench.py convention; exit 1 unless the cycle landed with zero
+    line; exit 1 unless the cycle landed with zero
     dropped requests and bit-equal post-swap answers)."""
     import shutil
     import tempfile
@@ -889,9 +887,8 @@ def main(argv=None) -> int:
             dropped = expected_requests - bench["requests"]
             reshard_info["reanchor_dropped_requests"] = int(dropped)
             record["reshard"] = reshard_info
-            # Top-level copies for the regression observatory's dotted
-            # paths (tools/bench_diff.py): reshard wall must not creep,
-            # dropped requests must stay 0.
+            # Top-level copies for readers of the record: reshard wall
+            # must not creep, dropped requests must stay 0.
             record["reshard_wall_s"] = reshard_info.get("reshard_wall_s")
             record["reanchor_dropped_requests"] = int(dropped)
             ok = (

@@ -8,8 +8,8 @@ backpressure (sleep the hint, resubmit — the retried request keeps its
 ORIGINAL submit timestamp, so reported latency includes the pushback), and
 reports per-request latency percentiles.
 
-The first stdout line is a machine-readable JSON record (the bench.py
-truncation-proof convention); human-readable lines follow.
+The first stdout line is a machine-readable JSON record (truncation-
+proof); human-readable lines follow.
 
 Usage:
     python tools/serve_client.py --port 9123 --shape 16 --requests 64
@@ -215,7 +215,7 @@ def main(argv=None) -> int:
         window=a.window,
         ping_ms=round(rtt * 1e3, 3),
     )
-    # Machine-readable record FIRST, flushed (the bench.py convention).
+    # Machine-readable record FIRST, flushed.
     print(json.dumps(record), flush=True)
     print(
         f"# serve_client pid {os.getpid()}: {record['requests']} requests "

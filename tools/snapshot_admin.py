@@ -25,7 +25,7 @@ This tool makes that state inspectable and reclaimable:
   a key that no longer matches the tar's current identity/config).
 
 The first stdout line is a machine-readable JSON record (same
-truncation-proof convention as bench.py/chaos_run.py); a short human
+truncation-proof convention as chaos_run.py); a short human
 summary follows.  Exit status: 0 ok, 1 bad arguments/validation failure.
 """
 

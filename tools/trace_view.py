@@ -10,9 +10,9 @@ or a JSONL event log (``*.jsonl``) and prints:
 * instant-event summaries (fault counts by kind, HBM admission decisions);
 * streaming-ingest overlap efficiency recomputed FROM span intervals:
   ``max(decode_busy, consume_busy) / wall`` over the ``ingest.decode`` /
-  ``ingest.consume`` spans — the same quantity the bench ``e2e`` section
-  derives from three separate rate passes, here read off one timeline
-  (decode busy time is the union of the parallel decode lanes' intervals).
+  ``ingest.consume`` spans — the quantity three separate rate passes
+  (decode only, consume only, both) would give, here read off one
+  timeline (decode busy time is the union of the parallel decode lanes' intervals).
 
 Usage:
     python tools/trace_view.py /tmp/t.json [--top 10]
@@ -104,7 +104,7 @@ def overlap_from_spans(events: list) -> dict | None:
 
         overlap_efficiency = max(decode_busy, consume_busy) / wall
 
-    — the span-interval form of the bench's ``e2e / min(decode_rate,
+    — the span-interval form of ``e2e / min(decode_rate,
     featurize_rate)``.  Returns None when the trace has no ingest spans.
     """
     decode, consume, all_ingest = [], [], []
