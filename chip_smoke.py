@@ -55,11 +55,12 @@ FULL = dict(
     train=50_000, test=10_000, whitener=100_000, requests=256,
     jpeg_train=4_096, jpeg_test=2_048, golden=64,
     idct_images=2_048, fv=(8, 73_866, 80, 256), conv=(2_048, 1_250, 128),
+    sift=(64, 375, 500),
 )
 TINY = dict(
     train=600, test=200, whitener=4_000, requests=24,
     jpeg_train=96, jpeg_test=48, golden=8,
-    idct_images=8, fv=(3, 700, 24, 8), conv=(5, 24, 4),
+    idct_images=8, fv=(3, 700, 24, 8), conv=(5, 24, 4), sift=(32, 30, 46),
 )
 
 
@@ -436,10 +437,44 @@ def _kernel_conv_form(ctx, interpret, rng) -> dict:
     return out
 
 
+def _kernel_sift_form(ctx, interpret, rng) -> dict:
+    """``SIFTExtractor``'s kernel form (the descriptor assembly in
+    ``ops/sift_pallas.py``) against its XLA form on a chunk of VOC's widest
+    shape, bfloat16 planes: the same bytes but for the order of a float32
+    sum (tests/test_sift_lcs.py TestAssemblyForms), a zero-contrast image
+    all zeros."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from keystone_tpu.ops.sift import SIFTExtractor
+
+    n, h, w = ctx["size"]["sift"]
+    img = rng.uniform(size=(n, h, w)).astype(np.float32)
+    img[1] = 0.5
+    node = SIFTExtractor(scale_step=0, compute_dtype=jnp.bfloat16)
+    got = np.asarray(
+        jax.jit(lambda b: node._kernel_form(b, interpret=interpret).astype(jnp.uint8))(img)
+    ).astype(np.int32)
+    want = np.asarray(
+        jax.jit(lambda b: node._xla_form(b).astype(jnp.uint8))(img)
+    ).astype(np.int32)
+    out = {
+        "shape": [n, h, w, node.num_descriptors(h, w)],
+        "identical_share": float((got == want).mean()),
+        "max_step": int(np.abs(got - want).max()),
+    }
+    check(got.shape == want.shape, f"shapes {got.shape} {want.shape}")
+    check(out["max_step"] <= 1 and out["identical_share"] >= 0.999, f"forms differ: {out}")
+    check(want.max() > 0 and not got[1].any(), "a zero-contrast image gave descriptors")
+    return out
+
+
 KERNELS = {
     "idct_blocks_pallas": _kernel_idct,
     "fv_stats_pallas": _kernel_fv_stats,
     "conv_rect_pool": _kernel_conv_form,
+    "sift_assemble": _kernel_sift_form,
 }
 
 

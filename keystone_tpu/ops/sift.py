@@ -18,16 +18,38 @@ Per scale ``s`` (reference VLFeat.cxx:68-123):
     below contrastthreshold=0.005 are zeroed (:62,167-169);
   * quantize ``min(floor(512·v), 255)`` (:249-263).
 
-Everything is batched ``[N, H, W]`` XLA ops — conv, gather, vmap — so whole
-image batches stay in HBM (the reference pays a JVM→C JNI crossing per
-image).  Descriptor count per image is static given (H, W, params), which
-keeps shapes XLA-friendly; variable-size image sets bucket by shape upstream.
+Everything is batched ``[N, H, W]``: smoothing and gradients are XLA convs
+and elementwise chains, the spatial binning two banded MXU products a scale
+(``_binned_sampling_matrix``), so whole image batches stay in HBM (the
+reference pays a JVM->C JNI crossing per image).  Descriptor count per image
+is static given (H, W, params), which keeps shapes XLA-friendly;
+variable-size image sets bucket by shape upstream.
+
+**The assembly** (binned planes ``[N, 8, 4*Fy, 4*Fx]`` a scale -> normalized
+bytes ``[N, 128, D]``) is a transposition of every value: the planes hold a
+frame's rows and columns innermost, the result its 128 dimensions.  It has
+two forms with one result (``sift_form`` picks; ROOFLINE.md, "At the
+published sizes", has the measurements):
+
+* the *kernel form* (``ops/sift_pallas.py``) reads each scale's planes once
+  and writes each descriptor once, as the bytes the chunk programs hand on:
+  the sampling matrices are built bins-major (rows ``(by, y)``, columns
+  ``(bx, x)``), so a frame row of all 128 dimensions is 32 plane rows, the
+  MXU transposes it by a permutation product, and the tail runs in VMEM;
+* the *XLA form* stages the scales' descriptors as ``[N, D, 128]`` in
+  ``compute_dtype`` and runs the tail on that.  On a TPU the compiler lays
+  the staged array out with the frames along the lanes and pays for it five
+  times over (42.8 of a 61.8 ms chunk of 64 images of 375x500 where the
+  kernel form takes 7.9: PR 31); it is what runs on a CPU, under a mesh, in
+  float32 and on batches that are no multiple of 32 images.
 
 Descriptor layout note: the reference transposes each descriptor
 (vl_dsift_transpose_descriptor, VLFeat.cxx:256) to undo its x/y-swapped
 image layout; we compute directly in (row=y, col=x) convention so no
-transpose is needed — the 128 dims are a fixed permutation of the
-reference's, which is irrelevant to downstream PCA/GMM/FV learning.
+transpose is needed — the 128 dims, ordered ``(by, bx, t)``, are a fixed
+permutation of the reference's, which is irrelevant to downstream
+PCA/GMM/FV learning.  Frames are ordered y-major inside a scale, scales in
+order.
 """
 
 from __future__ import annotations
@@ -38,7 +60,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import trace
 from ..core.pipeline import Transformer, node
+from .conv_fused import _on_one_device
 
 MAGNIF = 6.0
 CONTRAST_THRESHOLD = 0.005
@@ -156,6 +180,22 @@ def _scale_geometry(h: int, w: int, step: int, bin_size: int, num_scales: int, s
     return ys, xs
 
 
+def sift_form(backend: str, one_device: bool, compute_dtype, images: int, fx: int) -> str:
+    """``"kernel"`` or ``"xla"``: which form of the descriptor assembly runs
+    (module docstring).  The kernel form is a Mosaic custom call: it compiles
+    for the TPU only and is not partitioned under a mesh; it reads bfloat16
+    planes two frame rows a word and writes whole byte tiles of 32 images;
+    its blocks hold a frame row of all planes, so a very wide image would
+    not fit VMEM.  Everything else takes the XLA form."""
+    from .sift_pallas import fits
+
+    kernel = (
+        backend == "tpu" and one_device
+        and jnp.dtype(compute_dtype) == jnp.bfloat16 and fits(images, fx)
+    )
+    return "kernel" if kernel else "xla"
+
+
 @node(meta_fields=("step_size", "bin_size", "scales", "scale_step", "compute_dtype"))
 class SIFTExtractor(Transformer):
     """Batched dense SIFT: ``[N, H, W]`` (or [N,H,W,1]) grayscale in [0,1]
@@ -163,21 +203,22 @@ class SIFTExtractor(Transformer):
     (reference SIFTExtractor.scala:27-34 returns DenseMatrix(128, numCols)).
 
     ``compute_dtype`` (default f32): storage dtype of the large per-scale
-    intermediates — the [N, 8, H, W] orientation planes and the banded-gemm
-    sampling tensors, the dominant HBM streams of this op (measured ~197
-    MB/image of traffic in f32 at 256x256x4-scales; the op is memory-bound
-    at ~11 FLOP/byte, bench round r04 roofline, 2026-07-30, record removed
-    in PR 21).  Passing ``jnp.bfloat16`` (the
-    throughput workloads do — imagenet_sift_lcs_fv, voc_sift_fisher)
-    halves that traffic: gemms accumulate f32 and the
-    normalize/clamp/quantize tail runs f32, so the only effect is one
-    rounding of intermediate values.  MEASURED vs the f32 chain (v5e,
-    random-noise 256x256 images — the worst case for near-threshold bins):
-    99.5% of quantized entries within +/-1 — the reference's own MATLAB
-    acceptance envelope (VLFeatSuite.scala:48-51) — with rare tail
-    outliers up to ~13/255; throughput 4.3k -> 5.9k img/s (+35%) on the
-    SIFT->PCA->FV chain, traffic 197 -> 126 MB/image.  One known whole-
-    descriptor failure mode under bf16: a descriptor whose
+    intermediates — the [N, 8, H, W] orientation planes and both banded
+    products' results, the dominant HBM streams of the op ahead of the
+    assembly.  Passing ``jnp.bfloat16`` (the throughput workloads do —
+    imagenet_sift_lcs_fv, voc_sift_fisher) halves that traffic: the products
+    accumulate f32 and the angle mathematics and the whole
+    normalize/clamp/quantize tail run f32 in both forms of the assembly, so
+    the only effect is one rounding of intermediate values.  Against the f32
+    chain on random-noise 256x256 images (the worst case for near-threshold
+    bins; measured on v5e in round 4, before this repo kept its records:
+    ROOFLINE.md, "The SIFT -> PCA -> FV chain") 99.5% of quantized entries
+    lie within +/-1 — the reference's own MATLAB acceptance envelope
+    (VLFeatSuite.scala:48-51) — with rare tail outliers up to ~13/255; the
+    benchmark's `sift_off_share` holds the bf16 program to the plain f32
+    reference on every run (PERF.md section 4).  bfloat16 is also what the
+    kernel form of the assembly reads (two frame rows a 32-bit word).  One
+    known whole-descriptor failure mode under bf16: a descriptor whose
     pre-normalization norm lands within bf16 rounding (~0.4%) of
     CONTRAST_THRESHOLD can flip the zeroing decision vs the f32 chain,
     changing its entire 128-dim column — such near-threshold (i.e.
@@ -201,29 +242,50 @@ class SIFTExtractor(Transformer):
         self.compute_dtype = compute_dtype
 
     def num_descriptors(self, h: int, w: int) -> int:
-        total = 0
-        for s in range(self.scales):
-            b = self.bin_size + 2 * s
-            step = self.step_size + s * self.scale_step
-            ys, xs = _scale_geometry(h, w, step, b, self.scales, s)
-            total += len(ys) * len(xs)
-        return total
+        return sum(len(ys) * len(xs) for _b, ys, xs in self._grids(h, w))
 
     def __call__(self, batch):
         if batch.ndim == 4:
             batch = batch[..., 0]
         n, h, w = batch.shape
-        cdt = self.compute_dtype
-        batch = batch.astype(cdt)
-        per_scale = []
+        grids = list(self._grids(h, w))
+        form = sift_form(
+            jax.default_backend(), _on_one_device(batch), self.compute_dtype,
+            n, max((len(xs) for _b, _ys, xs in grids), default=0),
+        )
+        # Counted where the program is traced: once a jitted shape.
+        trace.metrics.inc(f"sift_form.{form}")
+        trace.instant(
+            "sift_form", form=form, images=n, scales=len(grids),
+            frames=self.num_descriptors(h, w),
+        )
+        if form == "kernel":
+            return self._kernel_form(batch)
+        return self._xla_form(batch)
+
+    def _grids(self, h: int, w: int):
+        """``(bin size, frame rows, frame columns)`` of each scale that has
+        frames."""
         for s in range(self.scales):
             b = self.bin_size + 2 * s
             step = self.step_size + s * self.scale_step
             ys, xs = _scale_geometry(h, w, step, b, self.scales, s)
-            if len(ys) == 0 or len(xs) == 0:
-                continue
-            sigma = b / MAGNIF
-            smoothed = _smooth(batch, sigma)
+            if len(ys) and len(xs):
+                yield b, ys, xs
+
+    def _sampled(self, batch, tile=None):
+        """Per scale with frames: the binned planes ``[N, 8, P, Q]`` in
+        ``compute_dtype`` and the frame grid ``(fy, fx)``.  Rows and columns
+        are ``(frame, bin)`` (``P = 4*fy``, ``Q = 4*fx``); with ``tile =
+        (rows, columns)`` they are ``(bin, frame)``, a bin's frame rows padded
+        to a multiple of ``rows`` and the columns to one of ``columns`` by
+        rows of zeros in the sampling matrices."""
+        _n, h, w = batch.shape
+        cdt = self.compute_dtype
+        batch = batch.astype(cdt)
+        for b, ys, xs in self._grids(h, w):
+            fy, fx = len(ys), len(xs)
+            smoothed = _smooth(batch, b / MAGNIF)
             gy, gx = _gradients(smoothed)
             planes = _orientation_planes(gy, gx).astype(cdt)  # [N, 8, H, W]
             tri = _triangular_kernel(b)
@@ -231,10 +293,20 @@ class SIFTExtractor(Transformer):
             # spatial binning as banded matmuls: triangular conv + bin-center
             # sampling in one MXU gemm per axis (see _binned_sampling_matrix)
             bin_off = np.arange(NUM_BIN_XY) * b
-            yy = (ys[:, None] + bin_off[None, :]).ravel()  # [Fy*4]
-            xx = (xs[:, None] + bin_off[None, :]).ravel()  # [Fx*4]
-            s_y = jnp.asarray(_binned_sampling_matrix(h, yy, tri), cdt)
-            s_x = jnp.asarray(_binned_sampling_matrix(w, xx, tri), cdt)
+            if tile is None:
+                yy = (ys[:, None] + bin_off[None, :]).ravel()  # [Fy*4]
+                m_y = _binned_sampling_matrix(h, yy, tri)
+                xx = (xs[:, None] + bin_off[None, :]).ravel()  # [Fx*4]
+                m_x = _binned_sampling_matrix(w, xx, tri)
+            else:
+                yy = (bin_off[:, None] + ys[None, :]).ravel()  # [4*Fy]
+                m_y = _binned_sampling_matrix(h, yy, tri).reshape(NUM_BIN_XY, fy, h)
+                m_y = np.pad(m_y, ((0, 0), (0, -fy % tile[0]), (0, 0))).reshape(-1, h)
+                xx = (bin_off[:, None] + xs[None, :]).ravel()  # [4*Fx]
+                m_x = _binned_sampling_matrix(w, xx, tri)
+                m_x = np.pad(m_x, ((0, -len(xx) % tile[1]), (0, 0)))
+            s_y = jnp.asarray(m_y, cdt)
+            s_x = jnp.asarray(m_x, cdt)
             # Two explicit gemms (not one opt-einsum) so the [N, 8, P, W]
             # intermediate is stored in compute_dtype — at the production
             # shape it is the single largest tensor of the whole op.
@@ -245,8 +317,30 @@ class SIFTExtractor(Transformer):
             sampled = jnp.einsum(
                 "ntpw,qw->ntpq", part, s_x,
                 preferred_element_type=jnp.float32,
-            ).astype(cdt)  # [N, 8, Fy*4, Fx*4]
-            fy, fx = len(ys), len(xs)
+            ).astype(cdt)
+            yield sampled, fy, fx
+
+    def _kernel_form(self, batch, interpret: bool = False):
+        """The assembly by ``ops/sift_pallas.assemble_scale``: each scale's
+        call writes its frames of the one ``u8[D, N, 128]``, frames outermost,
+        and the transposition to ``[N, 128, D]`` is one the TPU's layout of
+        that shape makes free."""
+        from .sift_pallas import LANES, TY, assemble_scale
+
+        frames = self.num_descriptors(*batch.shape[1:])
+        descs, offset = None, 0
+        for sampled, fy, fx in self._sampled(batch, tile=(TY, LANES)):
+            descs = assemble_scale(
+                sampled, descs, fy=fy, fx=fx, offset=offset, frames=frames,
+                interpret=interpret,
+            )
+            offset += fy * fx
+        return jnp.transpose(descs, (1, 2, 0)).astype(jnp.float32)  # [N, 128, D]
+
+    def _xla_form(self, batch):
+        n = batch.shape[0]
+        per_scale = []
+        for sampled, fy, fx in self._sampled(batch):
             sampled = sampled.reshape(n, NUM_BIN_T, fy, NUM_BIN_XY, fx, NUM_BIN_XY)
             # descriptor dims ordered [by, bx, t]; frames ordered y-major
             desc = jnp.einsum("ntybxc->nyxbct", sampled).reshape(

@@ -7,11 +7,17 @@ LCS against a direct transcription of the reference's per-pixel code, and
 behavioral SIFT properties (rotation shifts orientation mass, flat images
 give zero descriptors, contrast threshold)."""
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from keystone_tpu.core import trace
+from keystone_tpu.ops import sift as sift_ops
 from keystone_tpu.ops.lcs import LCSExtractor, _same_conv2d_zero
-from keystone_tpu.ops.sift import SIFTExtractor
+from keystone_tpu.ops.sift import SIFTExtractor, sift_form
 from keystone_tpu.utils.stats import about_eq
 
 
@@ -65,6 +71,130 @@ class TestSIFT:
             ys, xs = _scale_geometry(64, 64, 2, b, 3, s)
             centers.append(ys[0] + 1.5 * b)  # first frame center
         assert centers[0] == centers[1] == centers[2]
+
+
+def parent_sift(ext: SIFTExtractor, batch):
+    """``SIFTExtractor.__call__`` as it stood before the assembly had two
+    forms (PR 30), kept word for word: what both forms are held to."""
+    n, h, w = batch.shape
+    cdt = ext.compute_dtype
+    batch = batch.astype(cdt)
+    per_scale = []
+    for s in range(ext.scales):
+        b = ext.bin_size + 2 * s
+        step = ext.step_size + s * ext.scale_step
+        ys, xs = sift_ops._scale_geometry(h, w, step, b, ext.scales, s)
+        if len(ys) == 0 or len(xs) == 0:
+            continue
+        smoothed = sift_ops._smooth(batch, b / sift_ops.MAGNIF)
+        gy, gx = sift_ops._gradients(smoothed)
+        planes = sift_ops._orientation_planes(gy, gx).astype(cdt)
+        tri = sift_ops._triangular_kernel(b)
+        bin_off = np.arange(4) * b
+        yy = (ys[:, None] + bin_off[None, :]).ravel()
+        xx = (xs[:, None] + bin_off[None, :]).ravel()
+        s_y = jnp.asarray(sift_ops._binned_sampling_matrix(h, yy, tri), cdt)
+        s_x = jnp.asarray(sift_ops._binned_sampling_matrix(w, xx, tri), cdt)
+        part = jnp.einsum(
+            "ph,nthw->ntpw", s_y, planes, preferred_element_type=jnp.float32
+        ).astype(cdt)
+        sampled = jnp.einsum(
+            "ntpw,qw->ntpq", part, s_x, preferred_element_type=jnp.float32
+        ).astype(cdt)
+        fy, fx = len(ys), len(xs)
+        sampled = sampled.reshape(n, 8, fy, 4, fx, 4)
+        per_scale.append(
+            jnp.einsum("ntybxc->nyxbct", sampled).reshape(n, fy * fx, 128)
+        )
+    descs = jnp.concatenate(per_scale, axis=1)
+    norms = jnp.sqrt(
+        jnp.sum(jnp.square(descs.astype(jnp.float32)), axis=-1, keepdims=True)
+    )
+    normed = descs.astype(jnp.float32) / jnp.maximum(norms, 1e-12)
+    clamped = jnp.minimum(normed, 0.2)
+    norms2 = jnp.linalg.norm(clamped, axis=-1, keepdims=True)
+    final = clamped / jnp.maximum(norms2, 1e-12)
+    final = jnp.where(norms > sift_ops.CONTRAST_THRESHOLD, final, 0.0)
+    return jnp.swapaxes(jnp.minimum(jnp.floor(512.0 * final), 255.0), 1, 2)
+
+
+def _textured(rng, n, h, w):
+    """Images in [0, 1] with gradients everywhere but in image 1, which has
+    no contrast at all."""
+    img = rng.uniform(size=(n, h, w)).astype(np.float32)
+    img[1] = 0.5
+    return jnp.asarray(img)
+
+
+#: VOC's three aspect ratios (375x500, 500x375, 333x500) at an eighth, and a
+#: grid whose coarsest scale (bin 10: 31 rows) has no frame.
+GRIDS = [(47, 63), (63, 47), (42, 63), (30, 46)]
+
+
+class TestAssemblyForms:
+    """The descriptor assembly (binned planes -> normalized bytes) has a
+    kernel form (ops/sift_pallas.py, here in the Pallas interpreter) and the
+    XLA form; both give the descriptors the extractor gave before."""
+
+    @pytest.mark.parametrize("scale_step", [0, 1])
+    @pytest.mark.parametrize("hw", GRIDS, ids=lambda hw: f"{hw[0]}x{hw[1]}")
+    def test_kernel_form_gives_the_parents_descriptors(self, rng, hw, scale_step):
+        ext = SIFTExtractor(scale_step=scale_step, compute_dtype=jnp.bfloat16)
+        batch = _textured(rng, 32, *hw)
+        want = np.asarray(jax.jit(functools.partial(parent_sift, ext))(batch))
+        got = np.asarray(
+            jax.jit(functools.partial(ext._kernel_form, interpret=True))(batch)
+        )
+        assert got.shape == want.shape == (32, 128, ext.num_descriptors(*hw))
+        assert got.dtype == np.float32
+        assert np.abs(got - want).max() <= 1.0
+        assert (got == want).mean() >= 0.999
+        assert want.max() > 0 and not got[1].any()  # zero contrast: zero columns
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    @pytest.mark.parametrize("scale_step", [0, 1])
+    @pytest.mark.parametrize("hw", GRIDS, ids=lambda hw: f"{hw[0]}x{hw[1]}")
+    def test_xla_form_is_the_parents(self, rng, hw, scale_step, dtype):
+        """What every CPU run, mesh run and float32 caller gets."""
+        ext = SIFTExtractor(scale_step=scale_step, compute_dtype=dtype)
+        batch = _textured(rng, 3, *hw)
+        want = np.asarray(jax.jit(functools.partial(parent_sift, ext))(batch))
+        got = np.asarray(jax.jit(ext.__call__)(batch))
+        np.testing.assert_array_equal(got, want)
+        assert not got[1].any()
+
+    @pytest.mark.parametrize(
+        "backend,one_device,dtype,images,fx,want",
+        [
+            ("tpu", True, jnp.bfloat16, 64, 160, "kernel"),
+            ("tpu", True, jnp.bfloat16, 32, 79, "kernel"),
+            ("cpu", True, jnp.bfloat16, 64, 160, "xla"),  # Mosaic: TPU only
+            ("tpu", False, jnp.bfloat16, 64, 160, "xla"),  # a mesh: not partitioned
+            ("tpu", True, jnp.float32, 64, 160, "xla"),  # two bf16 rows a word
+            ("tpu", True, jnp.bfloat16, 1, 160, "xla"),  # byte tiles of 32 images
+            ("tpu", True, jnp.bfloat16, 48, 160, "xla"),
+            ("tpu", True, jnp.bfloat16, 64, 2000, "xla"),  # a frame row over VMEM
+            ("tpu", True, jnp.bfloat16, 64, 0, "xla"),  # no frames at all
+        ],
+    )
+    def test_sift_form_rule(self, backend, one_device, dtype, images, fx, want):
+        assert sift_form(backend, one_device, dtype, images, fx) == want
+
+    def test_sift_form_counter_moves(self, rng):
+        """``sift_form.<form>`` counts a traced program, not its calls, and
+        the instant says what the form was chosen on."""
+        ext = SIFTExtractor(scale_step=0, compute_dtype=jnp.bfloat16)
+        fn = jax.jit(ext.__call__)
+        before = trace.metrics.get("sift_form.xla")
+        for _ in range(3):
+            fn(_textured(rng, 32, 30, 46))
+        assert trace.metrics.get("sift_form.xla") == before + 1
+        assert trace.metrics.get("sift_form.kernel") == 0
+        last = [e for e in trace.flight_events() if e["name"] == "sift_form"][-1]
+        assert last["args"] == {
+            "form": "xla", "images": 32, "scales": 3,
+            "frames": ext.num_descriptors(30, 46),
+        }
 
 
 def naive_lcs(img, stride, stride_start, sub):

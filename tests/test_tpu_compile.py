@@ -75,3 +75,44 @@ def test_fv_kernel_form_compiles_at_published_widths(one_chip):
     assert "73866,256]" not in text and "256,73866]" not in text
     assert "74112" not in text and "75776" not in text  # no padded descriptors
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
+
+
+#: the chunk program before the assembly had a kernel form (commit 44be420,
+#: compiled here for the same described chip): temporaries and the bytes of
+#: XLA's cost analysis a chunk of 64 images, by image shape
+SIFT_PARENT = {(375, 500): (1906e6, 33.5e9), (333, 500): (1647e6, 28.5e9)}
+
+
+@pytest.mark.parametrize("shape", sorted(SIFT_PARENT), ids=lambda s: f"{s[0]}x{s[1]}")
+def test_sift_kernel_form_compiles_at_published_widths(one_chip, monkeypatch, shape):
+    """`fv_common._describe_chunk` at 64 images of VOC's shapes
+    (`voc_sift_fv_256`) in the kernel form: one `sift_assemble` call a scale
+    writing the chunk's bytes in place, no staged `[64, D, 128]` array wider
+    than a byte (1.21 GB in bfloat16 before), under the parent's temporaries
+    and bytes.  The form is steered here, in the test: `sift_form` asks
+    `jax.default_backend()`, which is the CPU's."""
+    import re
+
+    from keystone_tpu.ops import sift
+    from keystone_tpu.workloads import fv_common
+
+    monkeypatch.setattr(sift, "sift_form", lambda *a: "kernel")
+    h, w = shape
+    node_ = sift.SIFTExtractor(scale_step=0, compute_dtype=jnp.bfloat16)
+    frames = node_.num_descriptors(h, w)
+    flat = jax.ShapeDtypeStruct((64, h * w * 3), jnp.uint8, sharding=one_chip)
+    fv_common._describe_chunk.clear_cache()
+    try:
+        compiled = fv_common._describe_chunk.lower(
+            node_, flat, image_shape=(h, w, 3)
+        ).compile()
+    finally:
+        fv_common._describe_chunk.clear_cache()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    staged = re.findall(rf"(\w+)\[(?:64,{frames},128|64,128,{frames}|{frames},64,128)\]", text)
+    assert staged and set(staged) == {"u8"}, set(staged)
+    assert not re.search(rf"dynamic-update-slice\S* = \w+\[64,{frames},128\]", text)
+    temp, moved = SIFT_PARENT[shape]
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * temp
+    assert compiled.cost_analysis()["bytes accessed"] < 0.5 * moved

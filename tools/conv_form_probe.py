@@ -51,8 +51,9 @@ def _time(fn, x, reps):
     return (time.perf_counter() - t0) / reps
 
 
-def _device_ops(fn, x, reps):
-    """Seconds a call by operation, from the device's own trace."""
+def _device_ops(fn, x, reps, top=8):
+    """Seconds a call by operation (the ``top`` longest), from the device's
+    own trace."""
     with tempfile.TemporaryDirectory() as logdir:
         with jax.profiler.trace(logdir):
             for _ in range(reps):
@@ -62,7 +63,7 @@ def _device_ops(fn, x, reps):
     dev = xplane.reduce_trace(plain, window=(0, float("inf")))["devices"][0]
     return {
         "busy_ms": dev["busy_ns"] / reps / 1e6,
-        "ops_ms": [[name, s * 1e3 / reps] for name, s in xplane.top(dev["ops"], 8)],
+        "ops_ms": [[name, s * 1e3 / reps] for name, s in xplane.top(dev["ops"], top)],
     }
 
 
