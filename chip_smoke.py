@@ -16,8 +16,10 @@ means.  Legs:
      not have loaded jax.
   C  every pallas_call in the package, compiled by Mosaic at its
      production shape, against its jnp reference.
-  D  (>= 4 devices) leg A's fit on a 4-way data mesh, and the
-     __graft_entry__ multi-chip dry run in-process on the real devices.
+  D  (>= 4 devices) leg A's fit on a 4-way data mesh at 1,250 filters, a
+     width at which the conv featurizer takes its kernel form under
+     shard_map (each chip its rows of a chunk), and the __graft_entry__
+     multi-chip dry run in-process on the real devices.
 
 ONE process touches the chip: this one.  It starts g++ (native decoders)
 and spawned decode workers, none of which imports jax, and stops them.
@@ -55,12 +57,13 @@ FULL = dict(
     train=50_000, test=10_000, whitener=100_000, requests=256,
     jpeg_train=4_096, jpeg_test=2_048, golden=64,
     idct_images=2_048, fv=(8, 73_866, 80, 256), conv=(2_048, 1_250, 128),
-    sift=(64, 375, 500),
+    sift=(64, 375, 500), mesh_filters=1_250,
 )
 TINY = dict(
     train=600, test=200, whitener=4_000, requests=24,
     jpeg_train=96, jpeg_test=48, golden=8,
     idct_images=8, fv=(3, 700, 24, 8), conv=(5, 24, 4), sift=(32, 30, 46),
+    mesh_filters=24,
 )
 
 
@@ -500,16 +503,27 @@ def leg_c(ctx) -> dict:
 
 def leg_d(ctx) -> dict:
     import __graft_entry__ as graft
+    from keystone_tpu.core import trace
     from keystone_tpu.workloads import cifar_random_patch as cifar
 
+    flags = cifar_flags(ctx["size"])
+    flags[flags.index("--numFilters") + 1] = str(ctx["size"]["mesh_filters"])
+    kernel_before = trace.metrics.get("conv_form.kernel")
     res = cifar.main([
         "--trainLocation", ctx["train_bin"], "--testLocation", ctx["test_bin"],
-        *cifar_flags(ctx["size"]), "--mesh", "4",
+        *flags, "--mesh", "4",
     ])
     check(
         res["test_error"] < TEST_ERROR_BAR,
         f"mesh fit test error {res['test_error']:.2f}%",
     )
+    kernel_forms = trace.metrics.get("conv_form.kernel") - kernel_before
+    if ctx["device"]["platform"] == "tpu":
+        check(
+            kernel_forms >= 1,
+            "the mesh fit's conv featurizer did not take its kernel form: "
+            f"{trace.metrics.counters()}",
+        )
     rows = res["feature_rows_by_device"]
     check(
         len(rows) == 4 and len({tuple(r) for r in rows.values()}) == 4,
@@ -536,6 +550,7 @@ def leg_d(ctx) -> dict:
     return {
         "mesh_fit_test_error_pct": round(res["test_error"], 3),
         "mesh_fit_solver": res["solver"],
+        "mesh_fit_conv_kernel_forms": kernel_forms,
         "mesh_fit_rows_by_device": rows,
         "dryrun_mesh": record["mesh"],
         "dryrun_rows_by_device": dry_rows,

@@ -58,10 +58,13 @@ filters"), 6x6x3 patches on 32x32 images, kernel against XLA form:
 
 so the rule's multiple, 2, lies between the widest shape the XLA form
 won and the narrowest the kernel form did.  The kernel form is a custom
-call, which the compiler does not partition: under a mesh it would run
-replicated on every chip.  A program's input shows whether it spans a
-mesh (``_on_one_device``), and there, as on a CPU (Mosaic compiles for the
-TPU only), the XLA form runs.
+call, which the compiler does not partition: left to GSPMD under a mesh it
+would run replicated on every chip.  A program's input shows which mesh
+axes split it (``parallel.mesh.split_axes``).  Where only the ``data`` axis does, the
+kernel form runs under ``shard_map`` over that axis (``_sharded_kernel_form``:
+each chip its own rows of the chunk against the whole filter bank, filters
+and whitener replicated, nothing exchanged); under a model-axis split, as
+on a CPU (Mosaic compiles for the TPU only), the XLA form runs.
 """
 
 from __future__ import annotations
@@ -74,9 +77,14 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from ..core import trace
 from ..core.pipeline import Transformer, node
+from ..parallel.mesh import DATA_AXIS
+from ..parallel.mesh import input_mesh as _input_mesh
+from ..parallel.mesh import on_one_device as _on_one_device
+from ..parallel.mesh import split_axes as _split_axes
 from .images import Convolver, Pooler
 
 #: The activation stream of the XLA form over the patch stream of the
@@ -105,28 +113,17 @@ def _patch_depth(d: int) -> int:
 
 
 def conv_form(
-    backend: str, positions: int, d: int, num_filters: int, one_device: bool
+    backend: str, positions: int, d: int, num_filters: int, split_axes: tuple
 ) -> str:
     """``"kernel"`` or ``"xla"`` for ``positions`` patches an image of
-    ``d`` values each and ``num_filters`` filters: the rule of the module
-    docstring, in one place."""
-    if backend != "tpu" or not one_device:
+    ``d`` values each and ``num_filters`` filters, on an input split over
+    the mesh axes ``split_axes`` (``()`` on one device): the rule of the
+    module docstring, in one place."""
+    if backend != "tpu" or not set(split_axes) <= {DATA_AXIS}:
         return "xla"
     activation_stream = 3 * 2 * positions * num_filters
     patch_stream = 2 * 2 * positions * _patch_depth(d)
     return "kernel" if activation_stream >= KERNEL_STREAM_RATIO * patch_stream else "xla"
-
-
-def _on_one_device(batch) -> bool:
-    """Whether the input lives on one device, as far as it can be seen: a
-    concrete array says by its sharding; a traced one by the mesh in its
-    type, which ``jit`` takes from an argument committed to a mesh (as
-    ``featurize_chunked`` and the serving engine commit theirs)."""
-    if isinstance(batch, jax.core.Tracer):
-        mesh = getattr(getattr(jax.typeof(batch), "sharding", None), "mesh", None)
-        return mesh is None or mesh.size <= 1
-    sharding = getattr(batch, "sharding", None)
-    return sharding is None or len(sharding.device_set) == 1
 
 
 def _pool_kernel(p_ref, w_ref, o_ref, *, wy, wx, alpha, max_val):
@@ -256,15 +253,21 @@ class FusedConvFeaturizer(Transformer):
         f, ws, _, c = self.conv.filters.shape
         n, h, w, _ = batch.shape
         oh, ow = h - ws + 1, w - ws + 1
-        form = conv_form(
-            jax.default_backend(), oh * ow, ws * ws * c, f, _on_one_device(batch)
-        )
+        axes = _split_axes(batch)
+        form = conv_form(jax.default_backend(), oh * ow, ws * ws * c, f, axes)
+        mesh = _input_mesh(batch) if form == "kernel" and axes else None
+        shards = 1 if mesh is None else mesh.shape[DATA_AXIS]
         # Counted where the program is traced: once a jitted fit.
         trace.metrics.inc(f"conv_form.{form}")
-        trace.instant("conv_form", form=form, images=n, positions=oh * ow, filters=f)
-        if form == "kernel":
+        trace.instant(
+            "conv_form", form=form, images=n, positions=oh * ow, filters=f,
+            shards=shards,
+        )
+        if form == "xla":
+            return self._xla_form(batch)
+        if mesh is None:
             return self._kernel_form(batch)
-        return self._xla_form(batch)
+        return self._sharded_kernel_form(batch, mesh)
 
     def _xla_form(self, batch):
         # Normalized conv activations, stored compact.  The cast fuses into
@@ -369,3 +372,17 @@ class FusedConvFeaturizer(Transformer):
         # node's order, position-major, positive block then negative.
         out = out[:, :n, :f].reshape(2, npools, n, f).transpose(2, 1, 0, 3)
         return out.reshape(n, npools * 2 * f)
+
+    def _sharded_kernel_form(self, batch, mesh, interpret: bool = False):
+        """The kernel form on an input whose rows are split over ``mesh``'s
+        data axis: every chip runs :meth:`_kernel_form` on its own rows
+        against the whole filter bank (the node is replicated), and the
+        features stay split as the images were.  No collective."""
+        fn = jax.shard_map(
+            lambda node_, rows: node_._kernel_form(rows, interpret=interpret),
+            mesh=mesh,
+            in_specs=(P(), P(DATA_AXIS)),
+            out_specs=P(DATA_AXIS),
+            check_vma=False,
+        )
+        return fn(self, batch)

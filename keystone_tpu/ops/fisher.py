@@ -75,7 +75,7 @@ import jax.numpy as jnp
 from ..core import trace
 from ..core.pipeline import Transformer, node
 from ..solvers.gmm import GaussianMixtureModel, _log_resp
-from .conv_fused import _on_one_device
+from ..parallel.mesh import on_one_device as _on_one_device
 from .fv_pallas import fv_stats_pallas
 
 

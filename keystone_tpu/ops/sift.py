@@ -62,7 +62,7 @@ import numpy as np
 
 from ..core import trace
 from ..core.pipeline import Transformer, node
-from .conv_fused import _on_one_device
+from ..parallel.mesh import on_one_device as _on_one_device
 
 MAGNIF = 6.0
 CONTRAST_THRESHOLD = 0.005

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.pipeline import Estimator, Transformer, node
-from ..parallel.collectives import sharded_moments_jit
+from ..parallel.collectives import sharded_moments
 
 
 @node(data_fields=("mean", "std"))
@@ -46,7 +46,7 @@ class StandardScaler(Estimator):
 
     def fit(self, data, nvalid: int | None = None) -> StandardScalerModel:
         n = nvalid if nvalid is not None else data.shape[0]
-        _, s, sq = sharded_moments_jit(data)
+        _, s, sq = sharded_moments(data)
         cnt = jnp.asarray(n, data.dtype)  # true row count (excludes pad rows)
         mean = s / cnt
         if not self.normalize_std_dev:

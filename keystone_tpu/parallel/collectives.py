@@ -38,7 +38,16 @@ from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
 
-from .mesh import DATA_AXIS
+from ..core import trace
+from .mesh import DATA_AXIS, on_one_device
+
+
+def count_psum(nbytes) -> None:
+    """Add the bytes of arrays summed across chips to the counter
+    ``mesh.psum_bytes``.  Called with numbers reckoned from shapes where a
+    sharded program is *called*, not where it is traced, so that every fit
+    counts; the bytes are the arrays', not what the fabric moves."""
+    trace.metrics.inc("mesh.psum_bytes", int(nbytes))
 
 
 def psum_gram(x_block, y_block, axis_name: str = DATA_AXIS):
@@ -82,3 +91,11 @@ def sharded_moments_jit(x):
     s = jnp.sum(x, axis=0)
     sq = jnp.sum(x * x, axis=0)
     return cnt, s, sq
+
+
+def sharded_moments(x):
+    """:func:`sharded_moments_jit`, its two column sums counted as a psum
+    where ``x`` spans chips."""
+    if not on_one_device(x):
+        count_psum(2 * x.shape[1] * x.dtype.itemsize)
+    return sharded_moments_jit(x)

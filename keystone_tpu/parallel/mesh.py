@@ -201,6 +201,37 @@ def rows_by_device(x) -> dict[str, list[int]]:
     }
 
 
+# -- where an array lives, as far as the code that receives it can see ----------
+
+
+def input_mesh(batch):
+    """The named mesh of more than one device that the input lives on, as
+    far as it can be seen, or None: a concrete array says by its sharding;
+    a traced one by the mesh in its type, which ``jit`` takes from an
+    argument committed to a mesh (as ``featurize_chunked`` and the serving
+    engine commit theirs)."""
+    holder = jax.typeof(batch) if isinstance(batch, jax.core.Tracer) else batch
+    mesh = getattr(getattr(holder, "sharding", None), "mesh", None)
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
+def on_one_device(batch) -> bool:
+    """Whether the input lives on one device, as far as it can be seen."""
+    if isinstance(batch, jax.core.Tracer):
+        return input_mesh(batch) is None
+    sharding = getattr(batch, "sharding", None)
+    return sharding is None or len(sharding.device_set) == 1
+
+
+def split_axes(batch) -> tuple:
+    """Names of the axes of more than one device of the input's mesh;
+    ``()`` on one device, ``("?",)`` for a spread with no named mesh."""
+    mesh = input_mesh(batch)
+    if mesh is None:
+        return () if on_one_device(batch) else ("?",)
+    return tuple(name for name, size in mesh.shape.items() if size > 1)
+
+
 def parse_mesh(spec: str | None) -> Mesh | None:
     """Parse a ``--mesh`` flag: ``"8"`` -> 8-way data mesh, ``"4x2"`` ->
     (data=4, model=2).  None/empty -> no mesh (single device)."""

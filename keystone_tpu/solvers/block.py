@@ -30,6 +30,7 @@ from ..core.checkpoint import CheckpointError, _atomic_write_bytes
 from ..core.pipeline import Identity, LabelEstimator, Transformer
 from ..ops.stats import StandardScalerModel
 from ..ops.util import VectorSplitter
+from ..parallel.collectives import count_psum
 from ..parallel.mesh import (
     DATA_AXIS,
     MODEL_AXIS,
@@ -333,6 +334,13 @@ def _execute_fused_bcd_mesh(plan, x, labels, lam, nvalid, num_iter: int,
     the mesh ladder's step-down (the ``spec_mispredict`` family kills the
     top-ranked spec-sharded plan at this very dispatch)."""
     del plan
+    if mesh.size == 1:
+        # A mesh of one device is one device: no constraint of the mesh
+        # program does anything there, so the one-device dispatch serves it
+        # (and whatever stands in for that dispatch sees this fit too).
+        return _execute_fused_bcd(
+            None, (), x, labels, lam, nvalid, num_iter, widths
+        )
     return _fused_bcd_fit(x, labels, lam, nvalid, num_iter, widths, mesh,
                           specs)
 
@@ -1082,6 +1090,10 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                     y_p = jax.device_put(
                         jnp.asarray(y_p), autoshard.spec_sharding(lspec, m, 2)
                     )
+                # what the program sums over the data axis, from the
+                # shapes: a gram and num_iter cross terms a block, the
+                # block means' gemv and the label mean
+                count_psum(hints["coll_bytes"] + (it * (nb * bs + k_pad) if d_sz > 1 else 0))
                 models, label_mean, means = _execute_fused_bcd_mesh(
                     plan, jnp.asarray(x_p), jnp.asarray(y_p), lam_arr,
                     nv, self.num_iter, widths, m, spec_t,

@@ -36,6 +36,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..core import optimize, trace
 from ..core import snapshot as ksnap
@@ -55,7 +56,13 @@ from ..ops.conv_fused import FusedConvFeaturizer
 from ..ops.images import ImageVectorizer, Windower
 from ..ops.stats import Sampler, StandardScaler
 from ..ops.util import ClassLabelIndicatorsFromIntLabels, MaxClassifier
-from ..parallel.mesh import parse_mesh, row_sharding, rows_by_device
+from ..parallel.mesh import (
+    DATA_AXIS,
+    mesh_desc,
+    parse_mesh,
+    row_sharding,
+    rows_by_device,
+)
 from ..solvers.block import BlockLeastSquaresEstimator
 from ..solvers.whitening import ZCAWhitenerEstimator
 from ..utils.platform import init_device
@@ -210,6 +217,63 @@ def build_conv_pipeline(conf: RandomCifarConfig, filters, whitener) -> Pipeline:
 _featurize = jax.jit(Pipeline.__call__)
 
 
+def _pad_rows(block: np.ndarray, rows: int) -> np.ndarray:
+    pad = rows - block.shape[0]
+    return np.pad(block, ((0, pad), (0, 0), (0, 0), (0, 0))) if pad else block
+
+
+@functools.lru_cache(maxsize=None)
+def _local_concat(mesh, tail: int):
+    """The chunks' concatenation where every chip holds its own rows: each
+    chip joins its shards of the chunks (the last cut to ``tail`` rows),
+    nothing crosses chips and no chip holds another's rows.  Left to itself
+    XLA answers a concatenate along the sharded axis with all-to-alls over
+    the whole design matrix and twice its bytes of temporaries."""
+
+    def _join_local_rows(*parts):
+        return jnp.concatenate([*parts[:-1], parts[-1][:tail]], axis=0)
+
+    return jax.jit(
+        jax.shard_map(
+            _join_local_rows, mesh=mesh, in_specs=P(DATA_AXIS), out_specs=P(DATA_AXIS)
+        )
+    )
+
+
+def _featurize_row_sharded(fn, images: np.ndarray, chunk: int, mesh) -> jnp.ndarray:
+    """:func:`featurize_chunked` where the rows split evenly over the data
+    axis: chip ``k`` is fed, chunk by chunk, the rows it will hold of the
+    result (``[k * n / d, (k + 1) * n / d)``), so a chunk crosses from the
+    host to its shards in one ``device_put`` of views and the result is
+    born row-sharded, in the images' order."""
+    n = images.shape[0]
+    d = mesh.shape[DATA_AXIS]
+    per_dev, per_chunk = n // d, chunk // d
+    sharding = row_sharding(mesh)
+    devices = [dev for row in mesh.devices for dev in row]
+    copies = mesh.devices.shape[1]
+    outs = []
+    for lo in range(0, per_dev, per_chunk):
+        hi = min(lo + per_chunk, per_dev)
+        parts = [
+            _pad_rows(images[k * per_dev + lo : k * per_dev + hi], per_chunk)
+            for k in range(d)
+        ]
+        nbytes = sum(p.nbytes for p in parts)
+        with trace.h2d("chunk", nbytes, shards=d):
+            shards = jax.device_put(
+                [p for p in parts for _ in range(copies)], devices
+            )
+            dev_block = jax.make_array_from_single_device_arrays(
+                (d * per_chunk,) + images.shape[1:], sharding, shards
+            )
+        with trace.span("chunk", cat="dispatch"):
+            outs.append(fn(dev_block))
+    with trace.span("chunks", cat="concat", chunks=len(outs)):
+        tail = per_dev - (len(outs) - 1) * per_chunk  # the last chunk's true rows a chip
+        return _local_concat(mesh, tail)(*outs)
+
+
 def featurize_chunked(fn, images: np.ndarray, chunk: int, mesh=None) -> jnp.ndarray:
     """Run the jitted featurizer ``fn`` over fixed-size chunks (pad the tail)
     so the conv activations never exceed one chunk's footprint in HBM.
@@ -219,31 +283,25 @@ def featurize_chunked(fn, images: np.ndarray, chunk: int, mesh=None) -> jnp.ndar
     n = images.shape[0]
     sharding = None
     if mesh is not None:
-        d = mesh.shape["data"]
+        d = mesh.shape[DATA_AXIS]
         chunk = -(-chunk // d) * d  # chunk must split evenly across the axis
+        if n and n % d == 0:
+            return _featurize_row_sharded(fn, images, chunk, mesh)
         sharding = row_sharding(mesh)
     outs = []
     for i in range(0, n, chunk):
-        block = images[i : i + chunk]
-        pad = chunk - block.shape[0]
-        if pad:
-            block = np.pad(block, ((0, pad), (0, 0), (0, 0), (0, 0)))
+        block = _pad_rows(images[i : i + chunk], chunk)
+        pad = chunk - min(chunk, n - i)
         with trace.h2d("chunk", block.nbytes):
-            dev_block = jnp.asarray(block)
-            if sharding is not None:
-                dev_block = jax.device_put(dev_block, sharding)
+            # host to the chunk's shards in one copy, not by way of chip 0
+            dev_block = (
+                jnp.asarray(block) if sharding is None
+                else jax.device_put(block, sharding)
+            )
         with trace.span("chunk", cat="dispatch"):
             feats = fn(dev_block)
             outs.append(feats[: chunk - pad] if pad else feats)
     with trace.span("chunks", cat="concat", chunks=len(outs)):
-        if sharding is not None and n % mesh.shape["data"] == 0:
-            # Left to itself XLA answers a concatenate along the sharded
-            # axis with a copy of the whole design matrix on every chip;
-            # keep it spread over the data axis, as the chunks were.
-            return jax.jit(
-                lambda *parts: jnp.concatenate(parts, axis=0),
-                out_shardings=sharding,
-            )(*outs)
         return jnp.concatenate(outs, axis=0)
 
 
@@ -412,12 +470,13 @@ def _fit_and_score(conf: RandomCifarConfig, train, test, mesh) -> dict:
     # the same chunk shape AND sharding the real featurize pass will use.
     with stage_timer("warm_featurizer"):
         warm_chunk = conf.featurize_chunk
-        warm = jnp.zeros((warm_chunk,) + train.images.shape[1:], jnp.float32)
-        if mesh is not None:
-            d = mesh.shape["data"]
+        if mesh is None:
+            warm = jnp.zeros((warm_chunk,) + train.images.shape[1:], jnp.float32)
+        else:
+            d = mesh.shape[DATA_AXIS]
             warm_chunk = -(-warm_chunk // d) * d
             warm = jax.device_put(
-                jnp.zeros((warm_chunk,) + train.images.shape[1:], jnp.float32),
+                np.zeros((warm_chunk,) + train.images.shape[1:], np.float32),
                 row_sharding(mesh),
             )
         trace.wait(feat_fn(warm), "warm_featurizer")
@@ -603,7 +662,18 @@ def _fit_and_score(conf: RandomCifarConfig, train, test, mesh) -> dict:
             # rationale, chosen plan with predicted-vs-actual cost.
             results["placement"] = rep.placement
     if mesh is not None:
-        results["feature_rows_by_device"] = rows_by_device(train_features)
+        by_device = rows_by_device(train_features)
+        results["feature_rows_by_device"] = by_device
+        trace.metrics.inc("mesh.devices", mesh.size)
+        trace.instant(
+            "mesh_plan",
+            mesh=mesh_desc(mesh),
+            rows_per_device=max(hi - lo for lo, hi in by_device.values()),
+            design_bytes_per_device=max(
+                s.data.nbytes for s in train_features.addressable_shards
+            ),
+            tier=rep.chosen if rep is not None else None,
+        )
     if conf.stream_test_tar is not None and results_autotune is not None:
         results["autotune"] = results_autotune
     # The fitted SERVABLE chain, checkpointed whole for the endpoint:

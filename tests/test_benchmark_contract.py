@@ -29,7 +29,7 @@ from keystone_tpu.core.logging import stage_timer
 #: rest name jax's own eager programs): pattern -> module that holds it.
 PROGRAMS = {
     r"^jit___call": "keystone_tpu.workloads.cifar_random_patch",
-    r"^jit_sharded_moments_jit$": "keystone_tpu.ops.stats",
+    r"^jit_sharded_moments_jit$": "keystone_tpu.parallel.collectives",
     r"^jit__fused_bcd_impl$": "keystone_tpu.solvers.block",
     r"^jit__bcd_": "keystone_tpu.solvers.block",
     r"^jit__hs_block": "keystone_tpu.solvers.block",
@@ -50,6 +50,11 @@ STAGES = {
         ["learn_filters", "warm_featurizer", "featurize", "scale",
          "featurize_test", "solve", "eval"],
     ),
+    "cifar_rp_mesh": (
+        "keystone_tpu.workloads.cifar_random_patch",
+        ["learn_filters", "warm_featurizer", "featurize", "scale",
+         "featurize_test", "solve", "eval"],
+    ),
     "voc_fv": (
         "keystone_tpu.workloads.voc_sift_fisher",
         ["sample_descriptors", "pca", "gmm", "featurize",
@@ -60,6 +65,7 @@ STAGES = {
 COUNTERS = {
     "fv.descriptor_passes": "keystone_tpu.workloads.voc_sift_fisher",
     "gmm.iterations": "keystone_tpu.workloads.voc_sift_fisher",
+    "mesh.psum_bytes": "keystone_tpu.parallel.collectives",
 }
 
 HISTOGRAMS = ["stage_ms", "stage_wait_ms", "stage_h2d_mb"]

@@ -142,37 +142,99 @@ def test_kernel_form_matches_xla_form(
     np.testing.assert_allclose(got, ref, rtol=0, atol=4e-3 * np.abs(ref).max())
 
 
-def test_conv_form_rule(mesh8):
-    """Which form for which (backend, shape, placement): the kernel form on
-    one TPU device where the activation stream dwarfs the patch stream."""
-    cifar = dict(positions=27 * 27, d=108)
-    assert conv_form("tpu", num_filters=1250, one_device=True, **cifar) == "kernel"
-    # ROOFLINE.md's widths keep the XLA form
-    assert conv_form("tpu", num_filters=100, one_device=True, **cifar) == "xla"
-    assert conv_form("tpu", num_filters=16, one_device=True, **cifar) == "xla"
-    # a mesh, or no TPU: the XLA form at any width
-    assert conv_form("tpu", num_filters=1250, one_device=False, **cifar) == "xla"
-    assert conv_form("cpu", num_filters=1250, one_device=True, **cifar) == "xla"
-    assert conv_form("gpu", num_filters=5000, one_device=True, **cifar) == "xla"
-    # monotone in the filter count: one threshold
-    forms = [
-        conv_form("tpu", num_filters=f, one_device=True, **cifar)
-        for f in range(16, 2049, 16)
-    ]
-    assert forms == sorted(forms, reverse=True)  # "xla"... then "kernel"...
+CIFAR_STREAMS = dict(positions=27 * 27, d=108)
 
-    # what the node can see of where its input lives
-    from keystone_tpu.ops.conv_fused import _on_one_device
+
+@pytest.mark.parametrize(
+    "backend,num_filters,split_axes,form",
+    [
+        ("tpu", 1250, (), "kernel"),
+        # ROOFLINE.md's widths keep the XLA form
+        ("tpu", 100, (), "xla"),
+        ("tpu", 16, (), "xla"),
+        # rows split over the data axis alone: the kernel form, a chip its rows
+        ("tpu", 10000, ("data",), "kernel"),
+        ("tpu", 176, ("data",), "kernel"),
+        ("tpu", 100, ("data",), "xla"),
+        # a model-axis split, a spread with no named mesh, or no TPU: the
+        # XLA form at any width
+        ("tpu", 1250, ("data", "model"), "xla"),
+        ("tpu", 10000, ("model",), "xla"),
+        ("tpu", 1250, ("?",), "xla"),
+        ("cpu", 1250, (), "xla"),
+        ("cpu", 10000, ("data",), "xla"),
+        ("gpu", 5000, (), "xla"),
+    ],
+)
+def test_conv_form_rule(backend, num_filters, split_axes, form):
+    """Which form for which (backend, shape, placement): the kernel form on
+    a TPU, on one device or on rows split over the data axis, where the
+    activation stream dwarfs the patch stream."""
+    got = conv_form(
+        backend, num_filters=num_filters, split_axes=split_axes, **CIFAR_STREAMS
+    )
+    assert got == form
+
+
+def test_conv_form_has_one_threshold():
+    """Monotone in the filter count, on one device as on a data mesh."""
+    for axes in ((), ("data",)):
+        forms = [
+            conv_form("tpu", num_filters=f, split_axes=axes, **CIFAR_STREAMS)
+            for f in range(16, 2049, 16)
+        ]
+        assert forms == sorted(forms, reverse=True)  # "xla"... then "kernel"...
+        assert forms.index("kernel") == 176 // 16 - 1  # ~170 filters
+
+
+def test_node_sees_where_its_input_lives(mesh8, mesh42):
+    """What the node can see of where its input lives, concrete and traced."""
+    from keystone_tpu.ops.conv_fused import _on_one_device, _split_axes
     from keystone_tpu.parallel.mesh import row_sharding
 
     seen = []
     imgs = jnp.zeros((8, 12, 12, 3), jnp.float32)
-    probe = jax.jit(lambda x: seen.append(_on_one_device(x)) or x)
+    probe = jax.jit(lambda x: seen.append((_on_one_device(x), _split_axes(x))) or x)
     probe(imgs)
     probe(jax.device_put(imgs, row_sharding(mesh8)))
-    assert seen == [True, False]
+    probe(jax.device_put(imgs, row_sharding(mesh42)))
+    assert seen == [(True, ()), (False, ("data",)), (False, ("data", "model"))]
     assert _on_one_device(imgs) and _on_one_device(np.zeros((2, 12, 12, 3)))
     assert not _on_one_device(jax.device_put(imgs, row_sharding(mesh8)))
+    assert _split_axes(jax.device_put(imgs, row_sharding(mesh8))) == ("data",)
+    assert _split_axes(jax.device_put(imgs, row_sharding(mesh42))) == ("data", "model")
+
+
+@pytest.mark.parametrize("images", [64, 40])
+def test_sharded_kernel_form_equals_one_device(rng, devices, images):
+    """The kernel form under ``shard_map`` over a 4-way data axis (Pallas in
+    the interpreter) against the one-device kernel form, row for row; 40
+    images leave every chip's 10 short of the kernel's block of 16."""
+    from keystone_tpu.parallel.mesh import make_mesh, row_sharding
+
+    mesh = make_mesh(data=4, model=1, devices=devices[:4])
+    node_ = FusedConvFeaturizer(
+        jnp.asarray(rng.normal(size=(200, 6, 6, 3)).astype(np.float32)),
+        whitener_means=jnp.asarray(rng.normal(size=(108,)).astype(np.float32)),
+        pool_stride=13, pool_size=14, alpha=0.25,
+    )
+    imgs = rng.uniform(0, 255, (images, 32, 32, 3)).astype(np.float32)
+    one = np.asarray(node_._kernel_form(jnp.asarray(imgs), interpret=True))
+    sharded = node_._sharded_kernel_form(
+        jax.device_put(imgs, row_sharding(mesh)), mesh, interpret=True
+    )
+    assert sharded.sharding.spec == row_sharding(mesh).spec
+    assert {s.data.shape for s in sharded.addressable_shards} == {(images // 4, 1600)}
+    np.testing.assert_array_equal(np.asarray(sharded), one)
+    # and traced, the mesh taken from the input's type as __call__ takes it
+    from keystone_tpu.ops.conv_fused import _input_mesh
+
+    traced = jax.jit(
+        lambda nd, b: nd._sharded_kernel_form(b, _input_mesh(b), interpret=True)
+    )(node_, jax.device_put(imgs, row_sharding(mesh)))
+    np.testing.assert_allclose(
+        np.asarray(traced), one, rtol=0, atol=2e-3 * np.abs(one).max()
+    )
 
 
 def test_conv_form_counter_moves(rng):
@@ -184,12 +246,51 @@ def test_conv_form_counter_moves(rng):
     )
     imgs = jnp.asarray(rng.uniform(0, 255, (3, 32, 32, 3)).astype(np.float32))
     before = trace.metrics.get("conv_form.xla")
+    kernel_before = trace.metrics.get("conv_form.kernel")
     fn = jax.jit(node_.__call__)
     fn(imgs)
     fn(imgs)
     assert trace.metrics.get("conv_form.xla") == before + 1
-    assert trace.metrics.get("conv_form.kernel") == 0
+    assert trace.metrics.get("conv_form.kernel") == kernel_before
     last = [e for e in trace.flight_events() if e["name"] == "conv_form"][-1]
     assert last["args"] == {
-        "form": "xla", "images": 3, "positions": 729, "filters": 8,
+        "form": "xla", "images": 3, "positions": 729, "filters": 8, "shards": 1,
     }
+
+
+def test_conv_form_kernel_counts_on_a_data_mesh(rng, devices, monkeypatch):
+    """What the mesh cell's program does on the chip, walked through here:
+    with the backend reading ``tpu`` (Pallas in the interpreter), a chunk
+    committed to a 4-way data mesh takes the kernel form under ``shard_map``,
+    counted as ``conv_form.kernel`` with its shards on the instant; the same
+    chunk on a 2 x 2 mesh keeps the XLA form."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from keystone_tpu.parallel.mesh import make_mesh, row_sharding
+
+    node_ = FusedConvFeaturizer(
+        jnp.asarray(rng.normal(size=(192, 6, 6, 3)).astype(np.float32)),
+        whitener_means=jnp.asarray(rng.normal(size=(108,)).astype(np.float32)),
+        pool_stride=13, pool_size=14, alpha=0.25,
+    )
+    imgs = rng.uniform(0, 255, (32, 32, 32, 3)).astype(np.float32)
+    want = np.asarray(node_._xla_form(jnp.asarray(imgs)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    counts = {f: trace.metrics.get(f"conv_form.{f}") for f in ("kernel", "xla")}
+    for (data, model), form, shards in (((4, 1), "kernel", 4), ((2, 2), "xla", 1)):
+        mesh = make_mesh(data=data, model=model, devices=devices[:4])
+        with pltpu.force_tpu_interpret_mode():
+            got = jax.jit(FusedConvFeaturizer.__call__)(
+                node_, jax.device_put(imgs, row_sharding(mesh))
+            )
+        counts[form] += 1
+        assert trace.metrics.get(f"conv_form.{form}") == counts[form]
+        last = [e for e in trace.flight_events() if e["name"] == "conv_form"][-1]
+        assert last["args"] == {
+            "form": form, "images": 32, "positions": 729, "filters": 192,
+            "shards": shards,
+        }
+        assert len(got.sharding.device_set) == 4
+        np.testing.assert_allclose(
+            np.asarray(got), want, rtol=0, atol=1e-2 * np.abs(want).max()
+        )

@@ -21,15 +21,27 @@ from keystone_tpu.ops.conv_fused import FusedConvFeaturizer
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to ask
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def data_mesh(topo):
+    """The four described chips as ``--mesh 4`` builds them: data 4 x model 1."""
+    from keystone_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(data=4, model=1, devices=topo.devices)
 
 
 def test_conv_kernel_form_compiles_at_benchmark_widths(one_chip):
@@ -116,3 +128,96 @@ def test_sift_kernel_form_compiles_at_published_widths(one_chip, monkeypatch, sh
     temp, moved = SIFT_PARENT[shape]
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * temp
     assert compiled.cost_analysis()["bytes accessed"] < 0.5 * moved
+
+
+
+def _collectives(text: str) -> list:
+    """``(operation, result type)`` of every collective of a compiled program."""
+    import re
+
+    return [
+        (m.group(2), m.group(1))
+        for m in re.finditer(
+            r"= (.+?) (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
+            r"(?:-start)?\(", text,
+        )
+    ]
+
+
+def test_mesh_featurizer_compiles_at_published_widths(data_mesh):
+    """2,048 images x 10,000 filters over four chips (`cifar_rp_10k_mesh4`):
+    the kernel form under ``shard_map`` holds the kernel, no array of the
+    activations' shape, exchanges nothing, and leaves a chip its 512 rows of
+    the chunk's features."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from keystone_tpu.ops.conv_fused import _input_mesh
+    from keystone_tpu.parallel.mesh import row_sharding
+
+    rng = np.random.default_rng(0)
+    node_ = FusedConvFeaturizer(
+        rng.normal(size=(10000, 6, 6, 3)).astype(np.float32),
+        whitener_means=rng.normal(size=(108,)).astype(np.float32),
+        pool_stride=13, pool_size=14, alpha=0.25,
+    )
+    everywhere = NamedSharding(data_mesh, P())
+    node_s = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=everywhere), node_
+    )
+    chunk = jax.ShapeDtypeStruct(
+        (2048, 32, 32, 3), jnp.float32, sharding=row_sharding(data_mesh)
+    )
+    compiled = jax.jit(
+        lambda nd, b: nd._sharded_kernel_form(b, _input_mesh(b))
+    ).lower(node_s, chunk).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "27,27,10000]" not in text and "27,27,10240]" not in text
+    assert not _collectives(text)
+    assert "f32[512,80000]" in text and "f32[2048,80000]" not in text
+    assert compiled.output_shardings.spec == row_sharding(data_mesh).spec
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_mesh_solve_compiles_at_published_widths(data_mesh):
+    """25,000 x 81,920 over four chips, twenty blocks of 4,096: a chip holds
+    its 6,250 rows and no operand of all 25,000, the grams cross chips as
+    all-reduces of ``f32[4096,4096]``, and arguments, results and temporaries
+    together stay under 14 GB a chip."""
+    from keystone_tpu.parallel.mesh import row_sharding
+    from keystone_tpu.solvers import block
+
+    sds = jax.ShapeDtypeStruct
+    row = row_sharding(data_mesh)
+    widths = tuple([4096] * 19 + [80000 - 19 * 4096])
+    compiled = block._fused_bcd_fit.lower(
+        sds((25000, 81920), jnp.float32, sharding=row),
+        sds((25000, 10), jnp.float32, sharding=row),
+        sds((), jnp.float32), sds((), jnp.int32), 1, widths, data_mesh, None,
+    ).compile()
+    text = compiled.as_text()
+    reduced = [kind for op, kind in _collectives(text) if op == "all-reduce"]
+    assert any("f32[4096,4096]" in kind for kind in reduced), reduced
+    assert "[25000," not in text and "f32[6250,81920]" in text
+    mem = compiled.memory_analysis()
+    per_chip = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    )
+    assert 2.0e9 < per_chip < 14e9, per_chip
+
+
+def test_local_concatenation_moves_nothing_between_chips(data_mesh):
+    """The chunks of a 25,000-row fit joined where they lie: no collective,
+    next to no temporaries (XLA's own concatenate along the sharded axis:
+    all-to-alls over the whole matrix and 4 GB of temporaries a chip)."""
+    from keystone_tpu.parallel.mesh import row_sharding
+    from keystone_tpu.workloads.cifar_random_patch import _local_concat
+
+    row = row_sharding(data_mesh)
+    parts = [jax.ShapeDtypeStruct((2048, 80000), jnp.float32, sharding=row)] * 13
+    compiled = _local_concat(data_mesh, 6250 - 12 * 512).lower(*parts).compile()
+    assert not _collectives(compiled.as_text())
+    assert compiled.output_shardings.spec == row.spec
+    mem = compiled.memory_analysis()
+    assert 6250 * 80000 * 4 <= mem.output_size_in_bytes < 2.01e9  # rows padded to a tile
+    assert mem.temp_size_in_bytes < 1 << 28
