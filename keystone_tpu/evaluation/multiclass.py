@@ -8,8 +8,10 @@ segment-sum) — the reference's single ``aggregate`` pass over the zipped RDD.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -163,16 +165,22 @@ def _small_label(i: int) -> str:
     return out
 
 
+@functools.partial(jax.jit, static_argnames="num_classes")
+def _confusion_counts(predictions, actuals, num_classes: int):
+    flat = actuals.astype(jnp.int32) * num_classes + predictions.astype(jnp.int32)
+    counts = jnp.bincount(flat, length=num_classes * num_classes)
+    return counts.reshape(num_classes, num_classes)
+
+
 def confusion_matrix(predictions, actuals, num_classes: int):
-    """One-pass confusion matrix on device: rows=actual, cols=predicted."""
-    predictions = jnp.asarray(predictions).astype(jnp.int32)
+    """One-pass confusion matrix on device: rows=actual, cols=predicted.
+    One compiled program a shape and ``num_classes``."""
     if isinstance(actuals, np.ndarray):  # the labels come from the host
         with trace.h2d("labels", actuals.nbytes):
             actuals = jnp.asarray(actuals)
-    actuals = jnp.asarray(actuals).astype(jnp.int32)
-    flat = actuals * num_classes + predictions
-    counts = jnp.bincount(flat, length=num_classes * num_classes)
-    return counts.reshape(num_classes, num_classes)
+    return _confusion_counts(
+        jnp.asarray(predictions), jnp.asarray(actuals), num_classes
+    )
 
 
 class MulticlassClassifierEvaluator:

@@ -4,8 +4,11 @@
 The reference splits the feature axis into blocks (VectorSplitter), solves
 block coordinate descent over them, and applies the model block-by-block with
 a partial-sum reduce over zipped RDDs.  Here blocks are slices of an HBM
-array; block application is a sum of MXU gemms; the streaming
-``applyAndEvaluate`` form is preserved for models wider than memory.
+array, and applying the fitted model is one compiled program a shape
+(``_block_apply``: every block's slice and centring an operand of its MXU
+gemm, the fitted model the program's argument, so a refit compiles
+nothing); the streaming ``applyAndEvaluate`` form is preserved for models
+wider than memory, one step program a block (``_block_step``).
 """
 
 from __future__ import annotations
@@ -40,6 +43,7 @@ from ..parallel.mesh import (
     pad_shard_inputs,
     reduced_mesh,
     row_sharding,
+    split_axes,
 )
 
 _logger = logging.getLogger("keystone_tpu.solvers.block")
@@ -70,20 +74,6 @@ class BlockLinearMapper(Transformer):
         )
         self.vector_splitter = VectorSplitter(block_size)
 
-    def apply_blocks(self, blocks: Sequence):
-        """Apply to pre-split feature blocks (reference :47-74)."""
-        if len(blocks) != len(self.xs):
-            raise ValueError(
-                f"{len(blocks)} feature blocks vs {len(self.xs)} model blocks"
-            )
-        out = None
-        for blk, x, scaler in zip(blocks, self.xs, self.feature_scalers):
-            part = scaler(blk) @ x
-            out = part if out is None else out + part
-        if self.b is not None:
-            out = out + self.b
-        return out
-
     def _split_features(self, batch):
         """Cut a concatenated [n, D] feature matrix into this model's OWN
         fitted block widths.  The nominal ``vector_splitter`` (block_size
@@ -106,10 +96,27 @@ class BlockLinearMapper(Transformer):
             i += w
         return out
 
+    def _blocks_of(self, batch_or_blocks) -> list:
+        """The input as this model's feature blocks: a list of blocks as it
+        is, a concatenated matrix cut at the fitted widths."""
+        if isinstance(batch_or_blocks, (list, tuple)):
+            blocks = list(batch_or_blocks)
+        else:
+            blocks = self._split_features(batch_or_blocks)
+        if len(blocks) != len(self.xs):
+            raise ValueError(
+                f"{len(blocks)} feature blocks vs {len(self.xs)} model blocks"
+            )
+        return blocks
+
+    def apply_blocks(self, blocks: Sequence):
+        """Apply to pre-split feature blocks (reference :47-74)."""
+        return _block_apply(self, list(blocks))
+
     def __call__(self, batch):
-        if isinstance(batch, (list, tuple)):
-            return self.apply_blocks(batch)
-        return self.apply_blocks(self._split_features(batch))
+        """Scores of a concatenated [n, D] matrix or of a list of blocks:
+        one compiled program a shape, this model its argument."""
+        return _block_apply(self, batch)
 
     def apply_and_evaluate(
         self, batch_or_blocks, evaluator: Callable[[jnp.ndarray], None]
@@ -117,25 +124,16 @@ class BlockLinearMapper(Transformer):
         """Invoke ``evaluator`` on the running prediction after each block —
         streaming evaluation without materializing all block products
         (reference BlockLinearMapper.scala:104-137)."""
-        blocks = (
-            batch_or_blocks
-            if isinstance(batch_or_blocks, (list, tuple))
-            else self._split_features(batch_or_blocks)
-        )
-        if len(blocks) != len(self.xs):
-            raise ValueError(
-                f"{len(blocks)} feature blocks vs {len(self.xs)} model blocks"
-            )
         running = None
         for i, (blk, x, scaler) in enumerate(
-            zip(blocks, self.xs, self.feature_scalers)
+            zip(self._blocks_of(batch_or_blocks), self.xs, self.feature_scalers)
         ):
-            # one span a block: its eager dispatch, then the evaluator's
+            # one span a block: its one step program, then the evaluator's
             # round trip to the host (its ``wait`` and ``d2h`` nest here)
             with trace.span("block", cat="eval", block=i):
-                part = scaler(blk) @ x
-                running = part if running is None else running + part
-                with_intercept = running if self.b is None else running + self.b
+                running, with_intercept = _block_step(
+                    running, blk, x, scaler, self.b
+                )
                 evaluator(with_intercept)
 
 
@@ -146,6 +144,52 @@ jax.tree_util.register_pytree_node(
         kids[0], block_size, kids[1], kids[2]
     ),
 )
+
+
+def _add_block(running, blk, x, scaler):
+    """The running scores plus one block's: the block centred by its own
+    scaler, times its own model.  The order is the fit's (the model was
+    fitted on centred blocks, and one bf16 pass rounds a product's
+    *operands*), so nothing here is re-associated."""
+    part = scaler(blk) @ x
+    return part if running is None else running + part
+
+
+def _count_block_apply(blocks: Sequence, xs: Sequence) -> None:
+    """Counted where a block-apply program is traced: once a process and
+    shape, so a second fit at the same shapes leaves the counter alone."""
+    trace.metrics.inc("block_apply.traced")
+    trace.instant(
+        "block_apply",
+        rows=int(np.prod(blocks[0].shape[:-1])),
+        columns=sum(int(blk.shape[-1]) for blk in blocks),
+        blocks=len(blocks),
+        classes=int(xs[0].shape[-1]),
+        axes=list(split_axes(blocks[0])),
+    )
+
+
+@jax.jit
+def _block_apply(model: BlockLinearMapper, batch):
+    """The dense apply, ``Σ scaler_i(blk_i) @ x_i + b`` in block order, as
+    one program: the slices and the centring fuse into the products'
+    operands, so no ``[N, block]`` copy is written.  The fitted model is an
+    argument, so every fit at the same shapes runs the same executable."""
+    blocks = model._blocks_of(batch)
+    _count_block_apply(blocks, model.xs)
+    out = None
+    for blk, x, scaler in zip(blocks, model.xs, model.feature_scalers):
+        out = _add_block(out, blk, x, scaler)
+    return out if model.b is None else out + model.b
+
+
+@jax.jit
+def _block_step(running, blk, x, scaler, b):
+    """One step of the streamed apply: (the running sum with this block's
+    scores added, the same with the intercept)."""
+    _count_block_apply([blk], [x])
+    running = _add_block(running, blk, x, scaler)
+    return running, running if b is None else running + b
 
 
 def _fused_bcd_impl(x, labels, lam, nvalid, num_iter: int, widths, mesh,

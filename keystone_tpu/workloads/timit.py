@@ -6,7 +6,7 @@ Per batch b of ``numCosines``: CosineRandomFeatures(440 -> 4096,
 Gaussian or Cauchy W) then StandardScaler — the batches are the solver's
 feature blocks; BlockLeastSquares runs ``numEpochs`` BCD sweeps over them;
 evaluation streams through ``apply_and_evaluate`` exactly as the reference
-does (:105-113).
+does (:105-113): one compiled step a block, then the evaluator's round trip.
 """
 
 from __future__ import annotations

@@ -1,9 +1,14 @@
 """Evaluator tests (reference src/test/scala/evaluation/*Suite.scala)."""
 
+import jax.numpy as jnp
+import numpy as np
+import pytest
 
+from keystone_tpu.evaluation import multiclass
 from keystone_tpu.evaluation.multiclass import (
     BinaryClassifierEvaluator,
     MulticlassClassifierEvaluator,
+    confusion_matrix,
 )
 
 
@@ -52,3 +57,29 @@ def test_multiclass_matches_sklearn_style_micro(rng):
     acc = (pred == actual).mean()
     assert abs(m.total_accuracy - acc) < 1e-9
     assert abs(m.total_error - (1 - acc)) < 1e-9
+
+
+@pytest.mark.parametrize("labels_on", ["host", "device", "list"])
+@pytest.mark.parametrize("preds_on", ["host", "device"])
+def test_confusion_matrix_program_equals_the_numpy_count(rng, preds_on, labels_on):
+    """The counts as one compiled program, wherever predictions and labels
+    come from: rows the actual class, columns the predicted."""
+    n, k = 333, 5
+    actual = rng.integers(0, k, n)
+    pred = rng.integers(0, k, n)
+    want = np.zeros((k, k), np.int64)
+    np.add.at(want, (actual, pred), 1)
+    place = {"host": np.asarray, "device": jnp.asarray, "list": list}
+    got = confusion_matrix(place[preds_on](pred), place[labels_on](actual), k)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    m = MulticlassClassifierEvaluator(place[preds_on](pred), place[labels_on](actual), k)
+    np.testing.assert_array_equal(m.confusion_matrix, want)
+
+
+def test_confusion_matrix_compiles_once_a_shape(rng):
+    multiclass._confusion_counts.clear_cache()
+    for _ in range(3):
+        confusion_matrix(rng.integers(0, 4, 50), rng.integers(0, 4, 50), 4)
+    assert multiclass._confusion_counts._cache_size() == 1
+    confusion_matrix(rng.integers(0, 4, 50), rng.integers(0, 4, 50), 6)
+    assert multiclass._confusion_counts._cache_size() == 2

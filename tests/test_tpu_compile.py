@@ -221,3 +221,61 @@ def test_local_concatenation_moves_nothing_between_chips(data_mesh):
     mem = compiled.memory_analysis()
     assert 6250 * 80000 * 4 <= mem.output_size_in_bytes < 2.01e9  # rows padded to a tile
     assert mem.temp_size_in_bytes < 1 << 28
+
+
+def _described_mapper(widths, classes, sharding):
+    """A fitted block model of shapes only, placed by ``sharding``."""
+    from keystone_tpu.ops.stats import StandardScalerModel
+    from keystone_tpu.solvers.block import BlockLinearMapper
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    return BlockLinearMapper(
+        [sds(w, classes) for w in widths], 4096, sds(classes),
+        [StandardScalerModel(sds(w)) for w in widths],
+    )
+
+
+def test_mesh_apply_compiles_at_published_widths(data_mesh):
+    """The fitted model applied to ``f32[25000, 80000]`` over four chips
+    (`cifar_rp_10k_mesh4`: twenty blocks, ten classes, the model on every
+    chip): nothing crosses chips, a chip reads its 6,250 rows once and
+    writes their scores where they lie, and no centred ``[6250, 4096]``
+    block (102 MB) is ever written: under 32 MiB of temporaries a chip."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from keystone_tpu.parallel.mesh import row_sharding
+    from keystone_tpu.solvers import block
+
+    row = row_sharding(data_mesh)
+    model = _described_mapper(
+        [4096] * 19 + [80000 - 19 * 4096], 10, NamedSharding(data_mesh, P())
+    )
+    batch = jax.ShapeDtypeStruct((25000, 80000), jnp.float32, sharding=row)
+    compiled = block._block_apply.lower(model, batch).compile()
+    text = compiled.as_text()
+    assert not _collectives(text)
+    assert "[25000," not in text and "f32[6250,80000]" in text
+    assert compiled.output_shardings.spec == row.spec
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 32 << 20, mem.temp_size_in_bytes
+    assert mem.output_size_in_bytes < 1 << 20
+    moved = compiled.cost_analysis()["bytes accessed"]
+    assert moved < 1.1 * 6250 * 80000 * 4, moved
+
+
+def test_apply_compiles_at_benchmark_widths(one_chip):
+    """``f32[50000, 10000]`` against three blocks on one chip
+    (`cifar_rp_10k_share8`): the slices and the centring are the products'
+    operands, so the 2 GB matrix is read once (the eager chain wrote and
+    read a sliced and a centred copy of it) and nothing temporary is kept."""
+    from keystone_tpu.solvers import block
+
+    model = _described_mapper([4096, 4096, 10000 - 8192], 10, one_chip)
+    batch = jax.ShapeDtypeStruct((50000, 10000), jnp.float32, sharding=one_chip)
+    compiled = block._block_apply.lower(model, batch).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 32 << 20, mem.temp_size_in_bytes
+    moved = compiled.cost_analysis()["bytes accessed"]
+    assert moved < 1.1 * 50000 * 10000 * 4, moved
