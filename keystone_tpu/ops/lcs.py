@@ -7,11 +7,15 @@ of a regular grid — 96-dim descriptors for RGB (4·4·3·2).
 
 The reference runs per-image Scala while-loops over a conv2D helper
 (utils/images/ImageUtils.scala:162-274: zero-padded 'same' separable
-convolution); here both convolutions are batched XLA depthwise convs and the
-neighborhood sampling is one static gather — whole batches stay in HBM.
+convolution); here the box windows are sums of shifted copies of a whole
+batch, channels leading, taken only on the grid of places that some keypoint
+samples, and the neighborhood sampling is static strided slices — whole
+batches stay in HBM.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +58,23 @@ def _same_conv2d_zero(batch, xfilt, yfilt):
     return jnp.moveaxis(out.reshape(n, c, h, w), 1, -1)
 
 
+def _box_mean_at(x, size: int, axis: int, first: int, pitch: int, count: int):
+    """The reference conv2D with a box of ``size`` along ``axis`` (zero
+    padding of size-1 split floor/ceil, low/high; output same size), read at
+    the ``count`` places ``first, first + pitch, ...`` only: the sum of the
+    window's shifted, strided copies."""
+    low = (size - 1) // 2
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (low, size - 1 - low)
+    padded = jnp.pad(x, pad)
+    # place q's window is padded[q : q + size]
+    last = first + pitch * (count - 1)
+    out = jax.lax.slice_in_dim(padded, first, last + 1, pitch, axis=axis)
+    for t in range(1, size):
+        out = out + jax.lax.slice_in_dim(padded, first + t, last + t + 1, pitch, axis=axis)
+    return out * (1.0 / size)
+
+
 @node(meta_fields=("stride", "stride_start", "sub_patch_size"))
 class LCSExtractor(Transformer):
     """Batched LCS: ``[N, H, W, C]`` -> ``[N, descDim, numKeypoints]``
@@ -93,29 +114,51 @@ class LCSExtractor(Transformer):
     def __call__(self, batch):
         n, h, w, c = batch.shape
         s = self.sub_patch_size
-        box = np.full(s, 1.0 / s, np.float32)
-        means = _same_conv2d_zero(batch, box, box)
-        sq = _same_conv2d_zero(batch * batch, box, box)
-        stds = jnp.sqrt(jnp.maximum(sq - means * means, 0.0))
-
         xs = self._keypoints(w)
         ys = self._keypoints(h)
         nbr = self._neighborhood()
-        # all sampled positions: keypoint + neighbor offset
-        sx = (xs[:, None] + nbr[None, :]).ravel()  # [Kx*4]
-        sy = (ys[:, None] + nbr[None, :]).ravel()  # [Ky*4]
+        dim = c * nbr.size * nbr.size * 2
+        if len(xs) == 0 or len(ys) == 0:
+            return jnp.zeros((n, dim, 0), batch.dtype)
+        # Every sampled place is keypoint + offset = first + stride i + s j,
+        # so the statistics are needed on a grid of pitch gcd(stride, s) only
+        # (every second row and column at the reference's stride 4 and patch
+        # 6): the windows are summed there and nowhere else.
+        pitch = math.gcd(self.stride, s)
+        ky, kx = self.stride // pitch, s // pitch  # a keypoint's, an offset's step on the grid
 
-        def sample(img):  # [N, H, W, C] -> [N, Kx, 4, Ky, 4, C]
-            g = img[:, jnp.asarray(sy), :, :][:, :, jnp.asarray(sx), :]
-            g = g.reshape(n, len(ys), nbr.size, len(xs), nbr.size, c)
-            # a = y-neighbor (ny), b = x-neighbor (nx); reference order is
-            # nx outer, ny inner (:108-113)
-            return jnp.einsum("nyaxbc->nxycba", g)  # [N,Kx,Ky,C,nx,ny]
+        def grid(keys):
+            first = int(keys[0] + nbr[0])
+            return first, (int(keys[-1] + nbr[-1]) - first) // pitch + 1
 
-        m = sample(means)
-        sd = sample(stds)
-        # interleave mean/std on a trailing axis -> [N,Kx,Ky,C,nx,ny,2]
-        pairs = jnp.stack([m, sd], axis=-1)
-        k_total = len(xs) * len(ys)
-        desc = pairs.reshape(n, k_total, c * nbr.size * nbr.size * 2)
-        return jnp.swapaxes(desc, 1, 2)  # [N, descDim, K]
+        (y0, rows), (x0, cols) = grid(ys), grid(xs)
+        # channels lead, so the image plane is the tiled pair of axes (with
+        # three channels innermost an accelerator pads them to a full tile)
+        x = jnp.moveaxis(batch, -1, 1)
+
+        def window_means(img):
+            return _box_mean_at(_box_mean_at(img, s, 2, y0, pitch, rows), s, 3, x0, pitch, cols)
+
+        means = window_means(x)
+        stds = jnp.sqrt(jnp.maximum(window_means(x * x) - means * means, 0.0))
+
+        def sample(img):  # [N, C, rows, cols] -> [N, C, nx * ny, Kx, Ky]
+            # a neighbour's places are a strided run of the grid: static
+            # slices, no gather.  nx outer, ny inner (reference :108-113)
+            by_row = [
+                jax.lax.slice_in_dim(img, j * kx, j * kx + ky * (len(ys) - 1) + 1, ky, axis=2)
+                for j in range(nbr.size)
+            ]
+            planes = [
+                jnp.swapaxes(
+                    jax.lax.slice_in_dim(by_row[jy], jx * kx, jx * kx + ky * (len(xs) - 1) + 1, ky, axis=3),
+                    2, 3,
+                )
+                for jx in range(nbr.size)
+                for jy in range(nbr.size)
+            ]
+            return jnp.stack(planes, axis=2)
+
+        # interleave mean/std behind the neighbour -> [N, C, nx*ny, 2, Kx, Ky]
+        pairs = jnp.stack([sample(means), sample(stds)], axis=3)
+        return pairs.reshape(n, dim, len(xs) * len(ys))  # [N, descDim, K], K x-major

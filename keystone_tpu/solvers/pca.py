@@ -45,6 +45,25 @@ class BatchPCATransformer(Transformer):
         return jnp.einsum("dk,ndc->nkc", self.pca_mat, batch)
 
 
+@node(data_fields=("centre",))
+class DescriptorCentre(Transformer):
+    """Descriptors less a fixed vector: ``[N, d, cols]`` batches (descriptors
+    as columns) or ``[n, d]`` sampled rows.  PCA is fitted on centred samples
+    and projects without centring (PCA.scala:35-40, 63-106), and a Fisher
+    vector does not see a translation of its descriptors and its mixture
+    together; float32 products on an accelerator do.  Descriptors that lie
+    far from zero against their spread (LCS's window means: levels near 128,
+    a spread of tens) lose that spread in one bfloat16 pass of the projection
+    and in the moments' ``s1 - mu s0``; centred on their PCA sample's mean
+    they keep it (PERF.md, PR 34)."""
+
+    def __init__(self, centre):
+        self.centre = centre
+
+    def __call__(self, batch):
+        return batch - (self.centre if batch.ndim == 2 else self.centre[:, None])
+
+
 def compute_pca(data_mat, dims: int):
     """The reference's computePCA (PCA.scala:63-106): mean-center, f32 SVD,
     MATLAB sign convention (largest-|element| of each column positive), first

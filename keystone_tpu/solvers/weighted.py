@@ -16,7 +16,7 @@ TPU-native re-design:
 * the per-class solves run as a ``lax.scan`` over *chunks* of classes with a
   ``vmap`` inside each chunk — ``class_chunk`` classes are gathered, built
   into mixture-weighted normal equations, and solved concurrently as one
-  batched ``linalg.solve`` (the reference solves all classes concurrently
+  batched Cholesky factorization (the reference solves all classes concurrently
   across partitions, :228-263); only a [chunk, n_max, d] slab is ever
   materialized, never the full [C, n_max, d] tensor;
 * with a mesh, features are row-sharded over the data axis (population
@@ -35,6 +35,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import jax.scipy.linalg as jsl
 import numpy as np
 from jax import shard_map
 
@@ -58,6 +59,12 @@ from ..parallel.mesh import (
     reduced_mesh,
 )
 from .block import BlockLinearMapper, _blocked_design_matrix, _design_matrix_owned
+
+#: The statistics of the normal equations (the population gram, a class's
+#: covariance, both XᵀR) ask full float32 products: on a TPU a float32 matmul
+#: is otherwise one bfloat16 pass, whose rounding of the gram is of the size
+#: of ImageNetSiftLcsFV's λ = 6e-5 on unit-norm Fisher rows (PERF.md, PR 34).
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _bwls_spec_variants(m, n_classes: int) -> list[dict]:
@@ -227,8 +234,8 @@ def _class_solves(
 
         class_mean = jnp.sum(xc, axis=0) / n_c
         zm = (xc - class_mean) * mask[:, None]
-        class_cov = zm.T @ zm / n_c
-        class_xtr = xc.T @ r_c / n_c
+        class_cov = jnp.matmul(zm.T, zm, precision=_HIGHEST) / n_c
+        class_xtr = jnp.matmul(xc.T, r_c, precision=_HIGHEST) / n_c
 
         mean_diff = class_mean - pop_mean
         joint_xtx = (
@@ -238,8 +245,13 @@ def _class_solves(
         )
         mean_mixture_wt = rm_c * (1.0 - w) + w * (jnp.sum(r_c) / n_c)
         joint_xtr = xtr_c * (1.0 - w) + class_xtr * w - jm_c * mean_mixture_wt
-        # λ-shifted solve (reference :259-260)
-        return jnp.linalg.solve(joint_xtx + lam * eye, joint_xtr - m_c * lam)
+        # λ-shifted solve (reference :259-260).  The system is symmetric
+        # positive definite by construction (a mixture of covariances, a
+        # rank-one term and λI; pad columns carry a unit diagonal), so it is
+        # factored for what it is: Cholesky, a third of a pivoted LU's
+        # operations and no row exchanges
+        factor = jsl.cho_factor(joint_xtx + lam * eye, lower=True)
+        return jsl.cho_solve(factor, joint_xtr - m_c * lam)
 
     solve_chunk = jax.vmap(one_class)
 
@@ -344,7 +356,10 @@ def _fused_bwls_impl(
         i, pd = inp
         xb = slice_block(i)
         pop_mean = jnp.sum(xb, axis=0) / n
-        pop_cov = xb.T @ xb / n - jnp.outer(pop_mean, pop_mean) + jnp.diag(pd)
+        pop_cov = (
+            jnp.matmul(xb.T, xb, precision=_HIGHEST) / n
+            - jnp.outer(pop_mean, pop_mean) + jnp.diag(pd)
+        )
         class_means = _class_sums(xb, seg_ids, num_classes) / counts_f[:, None]
         joint_means = w * class_means + (1.0 - w) * pop_mean
         return carry, (pop_cov, pop_mean, joint_means)
@@ -359,7 +374,7 @@ def _fused_bwls_impl(
         res, rmean = carry
         i, pop_cov, pop_mean, jm, model = inp
         xb = slice_block(i)
-        pop_xtr = xb.T @ res / n
+        pop_xtr = jnp.matmul(xb.T, res, precision=_HIGHEST) / n
         dw = _class_solves(
             xb, res, starts, counts, pop_cov, pop_mean, pop_xtr,
             jm, rmean, model, lam, w, n_max, chunk, mesh,
@@ -448,14 +463,17 @@ def _bwls_block_stats(xb, seg_ids, counts_f, n, w, pad_diag_i, num_classes: int)
     stepwise/host-staged ladder tiers (identical math, one dispatch per
     block)."""
     pop_mean = jnp.sum(xb, axis=0) / n
-    pop_cov = xb.T @ xb / n - jnp.outer(pop_mean, pop_mean) + jnp.diag(pad_diag_i)
+    pop_cov = (
+        jnp.matmul(xb.T, xb, precision=_HIGHEST) / n
+        - jnp.outer(pop_mean, pop_mean) + jnp.diag(pad_diag_i)
+    )
     class_means = _class_sums(xb, seg_ids, num_classes) / counts_f[:, None]
     return pop_cov, pop_mean, w * class_means + (1.0 - w) * pop_mean
 
 
 @jax.jit
 def _bwls_block_xtr(xb, res, n):
-    return xb.T @ res / n
+    return jnp.matmul(xb.T, res, precision=_HIGHEST) / n
 
 
 @jax.jit
@@ -834,6 +852,18 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             )
         if cond_rows and self.last_fit_report is not None:
             self.last_fit_report.conditioning = cond_rows
+        # One system a class, block and pass, each at the block's full width.
+        bs = max(widths)
+        chunk = max(1, min(self.class_chunk, n_classes))
+        trace.metrics.inc("bwls.class_solves", n_classes * len(widths) * self.num_iter)
+        trace.metrics.inc("bwls.classes", n_classes)
+        trace.instant(
+            "bwls_plan", rows=n, n_max=n_max, classes=n_classes, blocks=len(widths),
+            class_chunk=chunk,
+            tier=self.last_fit_report.chosen if self.last_fit_report else None,
+            slab_bytes=np.dtype(dtype).itemsize * chunk * n_max * bs,
+            systems_bytes=np.dtype(dtype).itemsize * chunk * bs * bs,
+        )
         model_list = [models_st[i, :wd] for i, wd in enumerate(widths)]
         return BlockLinearMapper(model_list, self.block_size, b)
 

@@ -45,9 +45,10 @@ def shard_batch(batch, mesh):
     (zero-padding n up to an axis multiple), or plain device_put without a
     mesh.  Pad rows ride through the per-image featurizers as garbage rows
     and are dropped at scatter time (``scatter_features`` slices to the
-    bucket's true image count) and at sampling time (``sample_columns``
-    samples only valid images) — the bucket featurize program itself is
-    purely data-parallel, so no masking is needed in between."""
+    bucket's true image count; ``featurize_chunks`` to a chunk's real
+    images) and at sampling time (only valid images are drawn from) — the
+    bucket featurize program itself is purely data-parallel, so no masking
+    is needed in between."""
     dev, _n = padded_shard_rows(np.asarray(batch), mesh)
     return dev
 
@@ -55,113 +56,6 @@ def shard_batch(batch, mesh):
 def grayscale(batch) -> jnp.ndarray:
     """PixelScaler then GrayScaler -> [n, H, W] in [0, 1]."""
     return GrayScaler()(PixelScaler()(jnp.asarray(batch)))[..., 0]
-
-
-def searched_bucket_featurize(label: str, images: list, per_batch, mesh,
-                              *, plan=None):
-    """Eager bucket featurize with the PLACEMENT chosen by the same
-    cost-model-ranked search the solvers use (core.autoshard, ISSUE 10) —
-    the hand-written ``shard_batch(batch, mesh)`` layout stops being the
-    only option and becomes the prior head of a ranked candidate list:
-
-    * ``row_sharded[mesh DxM]`` for the given mesh (the hand placement,
-      rank 0 on an untrained model — bit-identical default), and for
-      every other (data, model) factorization of the same devices;
-    * the ``single_device`` floor (plain ``device_put``), pinned last.
-
-    The chosen candidate runs the WHOLE bucket featurize through the
-    unchanged ``run_ladder`` contract, so a sharded featurize that dies
-    RESOURCE_EXHAUSTED at runtime steps down the ranking counted
-    (``autoshard_stepdown``) instead of killing the workload, and the
-    measured outcome trains the cross-program calibration like any solve
-    plan.  Returns ``(buckets, placement_record_or_None)`` — the record
-    lands next to the solver's in ``results["placement"]``, so featurize
-    and solve placements are chosen by one ranking machinery and audited
-    in one table.  ``mesh=None`` (or a disabled search) is the plain
-    hand path."""
-    from ..core import autoshard
-    from ..core import memory as kmem
-    from ..parallel.mesh import DATA_AXIS, enumerate_meshes, mesh_desc
-
-    raw = bucket_by_shape(images)
-
-    def featurize_with(m):
-        return {
-            shape: (idx, per_batch(shard_batch(batch, m)))
-            for shape, (idx, batch) in raw.items()
-        }
-
-    if mesh is None or not autoshard.will_search(plan):
-        return featurize_with(mesh), None
-
-    total_bytes = sum(int(b.nbytes) for _i, b in raw.values())
-    # The featurize consumes uint8 pixels but computes in float32 — the
-    # roofline prior charges the device-resident working set.
-    f32_bytes = total_bytes * 4
-
-    def tier(m, prior_rank, hand):
-        d_sz = m.shape[DATA_AXIS]
-
-        def run(_mplan, m=m):
-            return featurize_with(m)
-
-        return autoshard.Candidate(
-            f"row_sharded[mesh {mesh_desc(m)}]",
-            "featurize_mesh",
-            plan=lambda m=m, d_sz=d_sz: kmem.plan_bytes(
-                f"{label}:row_sharded[{mesh_desc(m)}]",
-                argument_bytes=total_bytes // d_sz,
-                temp_bytes=f32_bytes // d_sz,
-                mesh=m,
-            ),
-            run=run,
-            hints={
-                "arg_bytes": total_bytes // d_sz,
-                "temp_bytes": f32_bytes // d_sz,
-                "h2d_bytes": total_bytes // d_sz,
-                "dispatches": len(raw),
-            },
-            mesh_axes=dict(m.shape),
-            prior_rank=prior_rank,
-            hand=hand,
-            specs={"batch": "data@dim0"},
-        )
-
-    cands = [tier(mesh, 0, True)]
-    for extra in enumerate_meshes(list(mesh.devices.flat)):
-        if mesh_desc(extra) != mesh_desc(mesh):
-            cands.append(tier(extra, len(cands), False))
-    cands.append(autoshard.Candidate(
-        "single_device",
-        "featurize",
-        plan=lambda: kmem.plan_bytes(
-            f"{label}:single_device",
-            argument_bytes=total_bytes,
-            temp_bytes=f32_bytes,
-        ),
-        run=lambda _mplan: featurize_with(None),
-        hints={
-            "arg_bytes": total_bytes,
-            "temp_bytes": f32_bytes,
-            "h2d_bytes": total_bytes,
-            "dispatches": len(raw),
-        },
-        prior_rank=len(cands),
-        floor=True,
-        specs={"batch": "replicated"},
-    ))
-    report = kmem.FitReport(label=label)
-    out = autoshard.run_search(
-        label, cands, report,
-        fingerprint=autoshard.fingerprint(
-            label,
-            sorted((shape, len(idx)) for shape, (idx, _b) in raw.items()),
-            dict(mesh.shape),
-            autoshard.device_fingerprint(),
-        ),
-        plan=plan,
-    )
-    return out, report.placement
 
 
 def draw_columns(totals: dict, num_samples: int, seed: int = 42) -> dict:
@@ -284,12 +178,15 @@ def plan_pca_materialization(
 #
 # At the reference's own sizes the descriptors of a training set do not fit
 # a chip (VOC 2007: 73,866 descriptors x 128 x 4 B an image, 189 GB for 5,011
-# images), so a fit never holds them.  A *sampling pass* runs SIFT chunk by
-# chunk and keeps only the columns drawn for the PCA and GMM samples; a
-# *featurizing pass* runs SIFT -> PCA -> Fisher vector -> normalize chunk by
-# chunk and keeps only each chunk's feature rows.  SIFT is one program a
-# shape bucket, run by both passes; each pass adds a small program of its own
-# on the chunk's descriptors.  A bucket's last chunk is padded to the chunk.
+# images), so a fit never holds them.  A *sampling pass* runs the descriptor
+# nodes chunk by chunk and keeps only the columns drawn for the PCA and GMM
+# samples; a *featurizing pass* runs descriptors -> PCA -> Fisher vector ->
+# normalize chunk by chunk and keeps only each chunk's feature rows.  A
+# descriptor node is one program a shape bucket, run by both passes; each pass
+# adds a small program of its own on the chunk's descriptors.  A bucket's
+# last chunk is padded to the chunk.  A pipeline with several descriptor
+# branches (ImageNetSiftLcsFV: SIFT and LCS) feeds them all from one trip of
+# the chunk's bytes to the device.
 
 #: most images one chunk program takes
 MAX_CHUNK = 64
@@ -313,22 +210,88 @@ RUN_AHEAD = 2
 SAMPLE_CAP_STEP = 4096
 
 
+@functools.partial(jax.jit, static_argnames=("image_shape",))
+def _describe_chunk(sift, flat, *, image_shape):
+    """SIFT of one chunk: the one program a shape that both passes run (the
+    SIFT programs are the large ones: ~38 MB compiled at VOC's sizes, against
+    a compile cache of 192 MiB on the benchmark's machine).  A quantized
+    descriptor entry is a whole number to 255, so the chunk's descriptors
+    pass to the next program as bytes, exactly, at a quarter of float32."""
+    images = flat.reshape((flat.shape[0],) + image_shape)
+    return sift(grayscale(images)).astype(jnp.uint8)
+
+
+@functools.partial(jax.jit, static_argnames=("image_shape",))
+def _describe_lcs_chunk(lcs, flat, *, image_shape):
+    """Local colour statistics of one chunk, on the images' own channels and
+    0..255 levels as the reference takes them (ImageNetSiftLcsFV.scala:96-99:
+    no pixel scaler before LCS).  Means and deviations are no whole numbers,
+    so they pass on as float32."""
+    images = flat.reshape((flat.shape[0],) + image_shape)
+    return lcs(images.astype(jnp.float32))
+
+
+@dataclasses.dataclass(frozen=True)
+class DescriptorBranch:
+    """A descriptor node as the chunk programs take it: its name (the suffix
+    of its counters), the node (an argument of ``describe``), the entries of
+    one descriptor, the compiled chunk program ``(node, [chunk, H*W*C] bytes,
+    image_shape=) -> [chunk, dim, descriptors]`` in the type its descriptors
+    cross to the next program as, and ``(H, W) -> descriptors an image``."""
+
+    name: str
+    node: object
+    dim: int
+    describe: object
+    cols: object
+
+
+def sift_branch(sift) -> DescriptorBranch:
+    """Dense SIFT of the grayscale image: 128 entries, handed on as bytes."""
+    return DescriptorBranch("sift", sift, DESC_DIM, _describe_chunk, sift.num_descriptors)
+
+
+def lcs_branch(lcs, channels: int = 3) -> DescriptorBranch:
+    """Local colour statistics of the image's ``channels``: a mean and a
+    deviation at 4 x 4 places a channel (96 entries for three), float32."""
+    return DescriptorBranch(
+        "lcs", lcs, 2 * 16 * channels, _describe_lcs_chunk, lcs.num_keypoints
+    )
+
+
+def _branch_list(nodes) -> tuple:
+    """``nodes`` as a list of branches, and whether a list was given: a node
+    given alone (a branch, or a bare SIFT extractor as VOCSIFTFisher's callers
+    pass it) takes and gives its draws, samples and fitted nodes unwrapped."""
+    if isinstance(nodes, (list, tuple)):
+        return list(nodes), True
+    one = nodes if isinstance(nodes, DescriptorBranch) else sift_branch(nodes)
+    return [one], False
+
+
 @dataclasses.dataclass(frozen=True)
 class ChunkPlan:
     """How a split's images go through the chunk programs: per shape bucket
     (in first-occurrence order) the images' ordinals, the descriptors an
-    image, the bytes an image reckoned against the budget, and the chunk."""
+    image of each branch, the bytes an image reckoned against the budget, and
+    the chunk."""
 
     index: dict  # shape -> np.ndarray of image ordinals
-    cols: dict  # shape -> descriptors an image
-    image_bytes: dict  # shape -> reckoned float32 bytes an image
+    cols: dict  # shape -> descriptors an image, one entry a branch
+    image_bytes: dict  # shape -> reckoned float32 bytes an image, all branches
     chunk: dict  # shape -> images a chunk
     budget: int | None
 
+    def totals_of(self, branch: int) -> dict:
+        """``{shape: (images, descriptors an image)}`` of one branch, for
+        :func:`draw_columns`."""
+        return {s: (len(idx), self.cols[s][branch]) for s, idx in self.index.items()}
+
     @property
     def totals(self) -> dict:
-        """``{shape: (images, descriptors an image)}`` for :func:`draw_columns`."""
-        return {s: (len(idx), self.cols[s]) for s, idx in self.index.items()}
+        """:meth:`totals_of` the first branch (the only one of a plan made
+        for one node)."""
+        return self.totals_of(0)
 
     @property
     def order(self) -> np.ndarray:
@@ -338,25 +301,28 @@ class ChunkPlan:
         return np.concatenate(list(self.index.values()))
 
 
-def plan_chunks(images: list, sift, desc_dim: int, vocab_size: int, mesh=None) -> ChunkPlan:
+def plan_chunks(images: list, nodes, desc_dim: int, vocab_size: int, mesh=None) -> ChunkPlan:
     """Bucket ``images`` by shape and choose each bucket's chunk from what
-    can be seen: an image's descriptors, their projection and their
-    posteriors in float32, against ``CHUNK_BUDGET_SHARE`` of the admission
-    budget.  The chunk is the largest power of two that fits (a budget read
+    can be seen: an image's descriptors (each branch's own dimension), their
+    projection and their posteriors in float32, summed over the branches,
+    against ``CHUNK_BUDGET_SHARE`` of the admission budget.  The chunk is the
+    largest power of two that fits (a budget read
     live moves a little between fits; a power of two does not move with
     it), at most ``MAX_CHUNK`` and at most the bucket; with no budget to read
     (the CPU) it is ``MAX_CHUNK``.  Under a mesh it is rounded up to the data
     axis.  Records one ``fv_plan`` instant."""
     from ..parallel.mesh import DATA_AXIS
 
+    branches, _ = _branch_list(nodes)
     groups: dict = {}
     for i, img in enumerate(images):
         groups.setdefault(tuple(img.shape[:2]), []).append(i)
     budget = kmem.hbm_budget()
-    index, cols, image_bytes, chunk = {}, {}, {}, {}
+    index, cols, image_bytes, chunk, branch_bytes = {}, {}, {}, {}, {}
     for shape, idx in groups.items():
-        c = sift.num_descriptors(*shape)
-        per_image = 4 * c * (DESC_DIM + desc_dim + vocab_size)
+        c = tuple(b.cols(*shape) for b in branches)
+        each = [4 * n * (b.dim + desc_dim + vocab_size) for n, b in zip(c, branches)]
+        per_image = sum(each)
         fit = MAX_CHUNK
         if budget is not None:
             fit = max(1, int(CHUNK_BUDGET_SHARE * budget) // per_image)
@@ -367,12 +333,17 @@ def plan_chunks(images: list, sift, desc_dim: int, vocab_size: int, mesh=None) -
             fit = -(-fit // d) * d
         index[shape] = np.asarray(idx)
         cols[shape], image_bytes[shape], chunk[shape] = c, per_image, fit
+        branch_bytes[shape] = each
     plan = ChunkPlan(index, cols, image_bytes, chunk, budget)
     trace.instant(
         "fv_plan",
         chunk={f"{h}x{w}": c for (h, w), c in chunk.items()},
         buckets={f"{h}x{w}": len(i) for (h, w), i in index.items()},
         chunk_bytes={f"{h}x{w}": chunk[(h, w)] * b for (h, w), b in image_bytes.items()},
+        branch_chunk_bytes={
+            b.name: {f"{h}x{w}": chunk[(h, w)] * each[k] for (h, w), each in branch_bytes.items()}
+            for k, b in enumerate(branches)
+        },
         budget=budget,
     )
     return plan
@@ -384,7 +355,8 @@ def _chunks(plan: ChunkPlan, images: list, mesh):
     the images' own dtype (a matrix copies at several times the rate of an
     image batch, whose batch axis the device keeps innermost) and is given
     its shape back inside the program; a short last chunk is padded by
-    repeating its last image, and the pad rows are dropped by the caller."""
+    repeating its last image, and the pad rows are dropped by the caller.
+    Every branch of a pass reads the one block."""
     for shape, idx in plan.index.items():
         c = plan.chunk[shape]
         for start in range(0, len(idx), c):
@@ -401,35 +373,30 @@ def _chunks(plan: ChunkPlan, images: list, mesh):
             yield shape, len(sel), dev, block.shape[1:]
 
 
-@functools.partial(jax.jit, static_argnames=("image_shape",))
-def _describe_chunk(sift, flat, *, image_shape):
-    """SIFT of one chunk: the one program a shape that both passes run (the
-    SIFT programs are the large ones: ~38 MB compiled at VOC's sizes, against
-    a compile cache of 192 MiB on the benchmark's machine).  A quantized
-    descriptor entry is a whole number to 255, so the chunk's descriptors
-    pass to the next program as bytes, exactly, at a quarter of float32."""
-    images = flat.reshape((flat.shape[0],) + image_shape)
-    return sift(grayscale(images)).astype(jnp.uint8)
-
-
 @jax.jit
 def _sample_chunk(descs, im, col):
     """The sampling pass's half of a chunk: the drawn columns only.  ``im``,
-    ``col``: ``[sets, cap]`` positions inside the chunk (padded with zeros)
-    -> ``[sets, cap, 128]``."""
-    return descs[im, :, col].astype(jnp.float32)
+    ``col``: a sample set's positions inside the chunk (each set padded with
+    zeros to its own cap) -> a set's ``[cap, dim]`` rows."""
+    return [descs[i, :, c].astype(jnp.float32) for i, c in zip(im, col)]
 
 
 @jax.jit
-def _encode_chunk(pca, gmm, descs):
-    """The featurizing pass's half of a chunk: PCA -> Fisher features.  The
-    fitted nodes are arguments, so every fit runs the one program."""
-    return fisher_feature_pipeline(gmm)(pca(descs.astype(jnp.float32)))
+def _encode_chunk(chains, descs):
+    """The featurizing pass's half of a chunk: each branch's ``(pca, gmm)``
+    on its descriptors, PCA -> Fisher features, the branches' rows side by
+    side.  The fitted nodes are arguments, so every fit runs the one
+    program."""
+    rows = [
+        fisher_feature_pipeline(gmm)(pca(d.astype(jnp.float32)))
+        for (pca, gmm), d in zip(chains, descs)
+    ]
+    return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=1)
 
 
 @jax.jit
 def _gather_samples(parts, positions):
-    """The chunks' padded gathers -> each set's ``[samples, 128]`` rows."""
+    """The chunks' padded gathers -> each set's ``[samples, dim]`` rows."""
     return [
         jnp.concatenate([p[s] for p in parts], axis=0)[pos]
         for s, pos in enumerate(positions)
@@ -437,56 +404,81 @@ def _gather_samples(parts, positions):
 
 
 def sample_descriptor_columns(
-    plan: ChunkPlan, images: list, sift, draws: list, mesh=None
+    plan: ChunkPlan, images: list, nodes, draws: list, mesh=None
 ) -> list:
     """The sampling pass.  ``draws``: one :func:`draw_columns` result a
-    sample set.  Returns each set's ``[samples, 128]`` descriptor rows, in
-    bucket order and sorted inside a bucket: what ``sample_columns`` gives,
-    transposed, on descriptors held whole."""
-    if not draws:
-        return []
-    cap = {}  # shape -> most columns any chunk of the bucket gathers
-    cuts = {}  # shape -> per set, the draw's boundaries at chunk starts
-    for shape, idx in plan.index.items():
-        c, cols = plan.chunk[shape], plan.cols[shape]
-        edges = np.arange(0, len(idx) + c, c) * cols
-        cuts[shape] = [np.searchsorted(d[shape], edges) for d in draws]
-        most = max(int(np.max(np.diff(cut), initial=0)) for cut in cuts[shape])
-        cap[shape] = max(SAMPLE_CAP_STEP, -(-most // SAMPLE_CAP_STEP) * SAMPLE_CAP_STEP)
-    parts, positions, offset = [], [[] for _ in draws], 0
+    sample set (with a list of branches: such a list a branch).  Returns each
+    set's ``[samples, dim]`` descriptor rows, in bucket order and sorted
+    inside a bucket."""
+    branches, listed = _branch_list(nodes)
+    draws = list(draws) if listed else [draws]
+    if not any(draws):
+        return [[] for _ in branches] if listed else []
+    cap = {}  # (branch, shape) -> per set, the most columns a chunk gathers
+    cuts = {}  # (branch, shape) -> per set, the draw's boundaries at chunk starts
+    for b, sets in enumerate(draws):
+        for shape, idx in plan.index.items():
+            c, cols = plan.chunk[shape], plan.cols[shape][b]
+            edges = np.arange(0, len(idx) + c, c) * cols
+            cuts[b, shape] = [np.searchsorted(d[shape], edges) for d in sets]
+            cap[b, shape] = [
+                max(SAMPLE_CAP_STEP, -(-int(np.max(np.diff(cut), initial=0)) // SAMPLE_CAP_STEP) * SAMPLE_CAP_STEP)
+                for cut in cuts[b, shape]
+            ]
+    parts = [[] for _ in branches]
+    positions = [[[] for _ in sets] for sets in draws]
+    offset = [[0] * len(sets) for sets in draws]
     seen: dict = {}
     for shape, _valid, dev, image_shape in _chunks(plan, images, mesh):
         k = seen[shape] = seen.get(shape, -1) + 1
-        im = np.zeros((len(draws), cap[shape]), np.int32)
-        col = np.zeros_like(im)
-        first = k * plan.chunk[shape] * plan.cols[shape]
-        for s, d in enumerate(draws):
-            lo, hi = cuts[shape][s][k : k + 2]
-            im[s, : hi - lo], col[s, : hi - lo] = np.divmod(
-                d[shape][lo:hi] - first, plan.cols[shape]
-            )
-            positions[s].append(offset + np.arange(hi - lo))
         with trace.span("chunk", cat="dispatch", bucket=f"{shape[0]}x{shape[1]}"):
-            descs = _describe_chunk(sift, dev, image_shape=image_shape)
-            parts.append(_sample_chunk(descs, im, col))
-        offset += cap[shape]
-        if len(parts) > RUN_AHEAD:
-            trace.wait(parts[-1 - RUN_AHEAD], "chunk")
-    positions = [np.concatenate(p).astype(np.int32) for p in positions]
-    trace.metrics.inc("fv.descriptors_sampled", int(sum(len(p) for p in positions)))
-    with trace.span("samples", cat="concat", chunks=len(parts)):
-        return _gather_samples(parts, positions)
+            for b, (branch, sets) in enumerate(zip(branches, draws)):
+                if not sets:
+                    continue
+                cols = plan.cols[shape][b]
+                first = k * plan.chunk[shape] * cols
+                im, col = [], []
+                for s, d in enumerate(sets):
+                    lo, hi = cuts[b, shape][s][k : k + 2]
+                    at = np.zeros((2, cap[b, shape][s]), np.int32)
+                    at[0, : hi - lo], at[1, : hi - lo] = np.divmod(d[shape][lo:hi] - first, cols)
+                    im.append(at[0])
+                    col.append(at[1])
+                    positions[b][s].append(offset[b][s] + np.arange(hi - lo))
+                    offset[b][s] += cap[b, shape][s]
+                descs = branch.describe(branch.node, dev, image_shape=image_shape)
+                parts[b].append(_sample_chunk(descs, im, col))
+        last = [p[-1 - RUN_AHEAD] for p in parts if len(p) > RUN_AHEAD]
+        if last:
+            trace.wait(last, "chunk")
+    out = []
+    for b, branch in enumerate(branches):
+        if not draws[b]:
+            out.append([])
+            continue
+        at = [np.concatenate(p).astype(np.int32) for p in positions[b]]
+        drawn = int(sum(len(p) for p in at))
+        trace.metrics.inc("fv.descriptors_sampled", drawn)
+        trace.metrics.inc(f"fv.descriptors_sampled.{branch.name}", drawn)
+        with trace.span("samples", cat="concat", chunks=len(parts[b]), branch=branch.name):
+            out.append(_gather_samples(parts[b], at))
+        parts[b] = None  # a branch's padded gathers go as its samples are made
+    return out if listed else out[0]
 
 
-def featurize_chunks(plan: ChunkPlan, images: list, sift, pca, gmm, mesh=None):
-    """The featurizing pass: ``[n, 2 * desc_dim * vocab]`` Fisher features on
-    the device, rows in ``plan.order`` (bucket by bucket), not image order:
-    the caller permutes what is small (labels, scores), never this."""
+def featurize_chunks(plan: ChunkPlan, images: list, nodes, pca, gmm, mesh=None):
+    """The featurizing pass: ``[n, 2 * desc_dim * vocab]`` Fisher features a
+    branch, side by side, on the device, rows in ``plan.order`` (bucket by
+    bucket), not image order: the caller permutes what is small (labels,
+    scores), never this.  With a list of branches ``pca`` and ``gmm`` are
+    lists too, one fitted node a branch."""
+    branches, listed = _branch_list(nodes)
+    chains = tuple(zip(pca, gmm)) if listed else ((pca, gmm),)
     outs = []
     for shape, valid, dev, image_shape in _chunks(plan, images, mesh):
         with trace.span("chunk", cat="dispatch", bucket=f"{shape[0]}x{shape[1]}"):
-            descs = _describe_chunk(sift, dev, image_shape=image_shape)
-            feats = _encode_chunk(pca, gmm, descs)
+            descs = tuple(b.describe(b.node, dev, image_shape=image_shape) for b in branches)
+            feats = _encode_chunk(chains, descs)
             outs.append(feats if valid == feats.shape[0] else feats[:valid])
         if len(outs) > RUN_AHEAD:
             trace.wait(outs[-1 - RUN_AHEAD], "chunk")

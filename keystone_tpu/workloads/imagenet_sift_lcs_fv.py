@@ -8,6 +8,13 @@ Per branch (SIFT / LCS):
   -> L2.
 Branches are concatenated (ZipVectors) and solved with
 BlockWeightedLeastSquares(4096, 1, λ, w); evaluation is top-5 error.
+
+Images held in memory fit through ``fv_common``'s chunked two-pass fit, as
+VOCSIFTFisher does: one trip of a chunk's bytes to the device feeds both
+branches' programs, a sampling pass keeps the drawn columns, a featurizing
+pass a chunk's ``[chunk, 2·2·descDim·vocabSize]`` rows, and the features stay
+on the device to the solver.  A streamed source (``ImageNetStreamSource``)
+keeps the older resident form, which holds a branch's descriptors whole.
 """
 
 from __future__ import annotations
@@ -16,6 +23,7 @@ import argparse
 import time
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -24,6 +32,7 @@ from ..core.checkpoint import checkpoint_exists, load_pipeline, save_pipeline
 from ..core.ingest import stream_batches
 from ..core.logging import Logging, configure_logging, stage_timer
 from ..core.memory import log_fit_report
+from ..core.pipeline import Pipeline
 from ..core.resilience import assert_all_finite
 from ..loaders.image_loaders import (
     LabeledImages,
@@ -36,19 +45,26 @@ from ..ops.stats import SignedHellingerMapper
 from ..ops.util import ClassLabelIndicatorsFromIntLabels, TopKClassifier
 from ..parallel.mesh import parse_mesh
 from ..solvers.gmm import GaussianMixtureModel, GaussianMixtureModelEstimator
-from ..solvers.pca import BatchPCATransformer, compute_pca
+from ..solvers.pca import BatchPCATransformer, DescriptorCentre, compute_pca
 from ..solvers.weighted import BlockWeightedLeastSquaresEstimator
 from ..utils.stats import get_err_percent
 from ..utils.platform import init_device
 from .fv_common import (
+    bucket_by_shape,
     collect_autotune,
+    draw_columns,
+    featurize_chunks,
     fisher_feature_pipeline,
     grayscale,
+    lcs_branch,
+    plan_chunks,
     plan_pca_materialization,
     record_stream_autotune,
     sample_columns,
+    sample_descriptor_columns,
     scatter_features,
-    searched_bucket_featurize,
+    shard_batch,
+    sift_branch,
     stream_config_from_flags,
     stream_descriptor_buckets,
 )
@@ -199,6 +215,66 @@ class _Log(Logging):
     pass
 
 
+def gmm_sample_count(conf: ImageNetSiftLcsFVConfig) -> int:
+    """Columns drawn for a branch's EM.  The reference samples numGmmSamples
+    and fits on a shuffled 1e6 of them whatever the flag says
+    (shuffleArray(...).take(1e6), ImageNetSiftLcsFV.scala:85-86): a uniform
+    draw of a uniform draw is a uniform draw, so the capped count is drawn
+    outright and no larger sample ever exists."""
+    return min(conf.num_gmm_samples, GMM_FIT_CAP)
+
+
+def sift_node(conf: ImageNetSiftLcsFVConfig) -> SIFTExtractor:
+    # bf16 intermediates: measured +35% chain throughput at 99.5%-within-1
+    # quantized-descriptor agreement (see SIFTExtractor docstring) — the
+    # throughput workload opts in; the op default stays f32.
+    return SIFTExtractor(scale_step=conf.sift_scale_step, compute_dtype=jnp.bfloat16)
+
+
+def lcs_node(conf: ImageNetSiftLcsFVConfig) -> LCSExtractor:
+    return LCSExtractor(conf.lcs_stride, conf.lcs_border, conf.lcs_patch)
+
+
+def descriptor_branches(conf: ImageNetSiftLcsFVConfig) -> list:
+    """The chunk programs' two branches, SIFT's first (its features are the
+    first half of a row)."""
+    return [sift_branch(sift_node(conf)), lcs_branch(lcs_node(conf))]
+
+
+def branch_draws(conf: ImageNetSiftLcsFVConfig, plan, branch: int, name: str) -> dict:
+    """The branch's column draws, ``{"pca": ..., "gmm": ...}``: SIFT's from
+    ``seed`` and ``seed + 1``, LCS's a hundred on (the reference gives each
+    sampler its own generator, :52-57, :81-86, :108-113, :136-141)."""
+    seed = conf.seed + (100 if name == "lcs" else 0)
+    totals = plan.totals_of(branch)
+    return {
+        "pca": draw_columns(totals, conf.num_pca_samples, seed),
+        "gmm": draw_columns(totals, gmm_sample_count(conf), seed + 1),
+    }
+
+
+def branch_preparation(name: str, centre=None):
+    """What a branch's descriptors pass through on their way to its PCA:
+    SIFT's the signed square root (reference :48); LCS's go as they are
+    (:104), less the mean of the branch's PCA sample where there is one (a
+    frame the Fisher vector does not see and the chip's products do:
+    ``DescriptorCentre``)."""
+    if name == "sift":
+        return SignedHellingerMapper()
+    return DescriptorCentre(jnp.zeros((1,), jnp.float32) if centre is None else centre)
+
+
+def branch_projection(name: str, batch_pca, centre=None):
+    """A branch's descriptors -> what its Fisher vector takes."""
+    return Pipeline([branch_preparation(name, centre), batch_pca])
+
+
+@jax.jit
+def _prepare_rows(preparation, rows):
+    """A branch's sampled descriptors as its PCA and EM see them."""
+    return preparation(rows)
+
+
 def _fit_branch(
     conf: ImageNetSiftLcsFVConfig, desc_buckets: dict, pca_file, gmm_files,
     seed: int, label: str = "branch", mesh=None,
@@ -245,13 +321,8 @@ def _fit_branch(
     else:
         gmm_samples = sample_columns(
             pca_desc if pca_desc is not None else make_pca_desc(),
-            conf.num_gmm_samples, seed + 1,
+            gmm_sample_count(conf), seed + 1,
         )
-        # The reference caps the EM training set at 1e6 samples regardless of
-        # numGmmSamples (shuffleArray(...).take(1e6),
-        # ImageNetSiftLcsFV.scala:85-86) — match it to bound EM compute/HBM.
-        if gmm_samples.shape[1] > GMM_FIT_CAP:
-            gmm_samples = gmm_samples[:, :GMM_FIT_CAP]
         gmm = GaussianMixtureModelEstimator(conf.vocab_size).fit(gmm_samples.T)
     assert_all_finite(gmm, "branch GMM fit")
 
@@ -262,51 +333,33 @@ def _fit_branch(
     return batch_pca, gmm, pca_desc, cache_plan
 
 
+def _resident_buckets(images, per_batch, mesh) -> dict:
+    """``{shape: (ordinals, descriptors of the whole bucket)}``: the stream's
+    batches as they decode, or a list's shape buckets."""
+    if isinstance(images, ImageNetStreamSource):
+        return _streaming_buckets(images, per_batch)
+    return {
+        shape: (idx, per_batch(shard_batch(batch, mesh)))
+        for shape, (idx, batch) in bucket_by_shape(images).items()
+    }
+
+
 def sift_descriptor_buckets(
     conf: ImageNetSiftLcsFVConfig, images: list, mesh=None,
-    placement_out=None,
 ) -> dict:
-    """SIFT branch descriptors (:40-94): SIFT -> BatchSignedHellinger.
-    With a mesh the bucket placement is chosen by the cost-model-ranked
-    search (fv_common.searched_bucket_featurize; the hand row-sharded
-    layout is the untrained head); ``placement_out`` receives the searched
-    record under ``"featurize_sift"``."""
-    # bf16 intermediates: measured +35% chain throughput at 99.5%-within-1
-    # quantized-descriptor agreement (see SIFTExtractor docstring) — the
-    # throughput workload opts in; the op default stays f32.
-    sift = SIFTExtractor(
-        scale_step=conf.sift_scale_step, compute_dtype=jnp.bfloat16
-    )
+    """SIFT branch descriptors held whole (:40-94): SIFT ->
+    BatchSignedHellinger, a bucket at a time, row-sharded under a mesh."""
+    sift = sift_node(conf)
     hell = SignedHellingerMapper()
-    if isinstance(images, ImageNetStreamSource):
-        return _streaming_buckets(
-            images, lambda dev: hell(sift(grayscale(dev)))
-        )
-    buckets, placement = searched_bucket_featurize(
-        "imagenet_sift_featurize", images,
-        lambda dev: hell(sift(grayscale(dev))), mesh,
-    )
-    if placement_out is not None and placement is not None:
-        placement_out["featurize_sift"] = placement
-    return buckets
+    return _resident_buckets(images, lambda dev: hell(sift(grayscale(dev))), mesh)
 
 
 def lcs_descriptor_buckets(
     conf: ImageNetSiftLcsFVConfig, images: list, mesh=None,
-    placement_out=None,
 ) -> dict:
-    """LCS branch descriptors (:96-148): raw LCS straight into PCA, with
-    the searched bucket placement under a mesh (record lands in
-    ``placement_out["featurize_lcs"]``)."""
-    lcs = LCSExtractor(conf.lcs_stride, conf.lcs_border, conf.lcs_patch)
-    if isinstance(images, ImageNetStreamSource):
-        return _streaming_buckets(images, lcs)
-    buckets, placement = searched_bucket_featurize(
-        "imagenet_lcs_featurize", images, lcs, mesh,
-    )
-    if placement_out is not None and placement is not None:
-        placement_out["featurize_lcs"] = placement
-    return buckets
+    """LCS branch descriptors held whole (:96-148): raw LCS straight into
+    PCA."""
+    return _resident_buckets(images, lcs_node(conf), mesh)
 
 
 def branch_features(
@@ -318,16 +371,12 @@ def branch_features(
     gmm_files,
     seed: int,
     mesh=None,
-    placement_out=None,
 ):
-    """Fit transformers on train, apply to train AND test.  Returns the
+    """The resident form of a branch: fit transformers on train, apply to
+    train AND test, every descriptor of a split held at once.  Returns the
     fitted (batch_pca, gmm) too so callers can checkpoint the branch, and
-    the auto-Cacher decision table (None when the pass is off).
-    ``placement_out``: dict receiving the train pass's searched featurize
-    placement record (see the descriptor functions)."""
-    train_desc = descriptor_fn(
-        conf, train_images, mesh, placement_out=placement_out
-    )
+    the auto-Cacher decision table (None when the pass is off)."""
+    train_desc = descriptor_fn(conf, train_images, mesh)
     batch_pca, gmm, train_pca_desc, cache_plan = _fit_branch(
         conf, train_desc, pca_file, gmm_files, seed,
         label=descriptor_fn.__name__.replace("_descriptor_buckets", ""),
@@ -369,20 +418,211 @@ def run(
     test: LabeledImages,
     mesh=None,
 ) -> dict:
-    """With ``mesh``: featurization buckets are row-sharded over the data
-    axis and the 2·2·descDim·vocabSize-feature class-weighted solve runs
-    distributed — row-sharded population grams with ICI all-reduce and
-    model-axis-sharded batched class solves (the reference runs this over
-    partitioned RDDs + treeReduce, ImageNetSiftLcsFV.scala:150-195)."""
+    """With ``mesh``: every chunk is row-sharded over the data axis and the
+    2·2·descDim·vocabSize-feature class-weighted solve runs distributed —
+    row-sharded population grams with ICI all-reduce and model-axis-sharded
+    batched class solves (the reference runs this over partitioned RDDs +
+    treeReduce, ImageNetSiftLcsFV.scala:150-195).
+
+    The run is one root span ``fit``; its stages (``stage_timer``) tile it
+    but for glue, and each occurs once a fit.  Beside the errors the results
+    hold the fitted chain (``pipeline``: both branches' PCA and GMM, the
+    model) and the raw test scores in image order (``test_scores``)."""
     configure_logging()
+    if isinstance(train, ImageNetStreamSource) or isinstance(test, ImageNetStreamSource):
+        return _run_resident(conf, train, test, mesh)
+    with trace.span("fit", cat="fit", rows=len(train)):
+        return _fit_and_score(conf, train, test, mesh)
+
+
+def _fit_and_score(conf: ImageNetSiftLcsFVConfig, train, test, mesh) -> dict:
+    log = _Log()
+    t0 = time.perf_counter()
+    branches = descriptor_branches(conf)
+    names = [b.name for b in branches]
+    solver_report = None
+    gmm_iterations: dict = {}
+
+    restored = conf.pipeline_file is not None and checkpoint_exists(conf.pipeline_file)
+    if restored:
+        # Load-or-fit of the whole fitted pipeline: skip training
+        # featurization and every fit; score test with restored state.
+        log.log_info("restoring fitted pipeline from %s", conf.pipeline_file)
+        ck = load_pipeline(conf.pipeline_file)
+        pcas = [ck[f"{name}_pca"] for name in names]
+        gmms = [ck[f"{name}_gmm"] for name in names]
+        centres = {"lcs": ck["lcs_centre"].centre} if "lcs_centre" in ck else {}
+        model = ck["model"]
+    else:
+        plan = plan_chunks(train.images, branches, conf.desc_dim, conf.vocab_size, mesh)
+        log.log_info(
+            "chunks %s of buckets %s (budget %s)",
+            plan.chunk, {s: len(i) for s, i in plan.index.items()}, plan.budget,
+        )
+        train_labels = ClassLabelIndicatorsFromIntLabels(conf.num_classes)(
+            np.asarray(train.labels)[plan.order]
+        )
+        pca_files = {"sift": conf.sift_pca_file, "lcs": conf.lcs_pca_file}
+        gmm_files = {
+            "sift": (conf.sift_gmm_mean_file, conf.sift_gmm_var_file, conf.sift_gmm_wts_file),
+            "lcs": (conf.lcs_gmm_mean_file, conf.lcs_gmm_var_file, conf.lcs_gmm_wts_file),
+        }
+
+        # The sampling pass (:52-57, :81-86, :108-113, :136-141): both
+        # branches' PCA and GMM columns from one trip of each chunk.  A
+        # loaded PCA or GMM needs no sample.
+        with stage_timer("sample_descriptors"):
+            draws = []
+            for b, name in enumerate(names):
+                loaded = {"pca": pca_files[name], "gmm": gmm_files[name][0]}
+                sets = branch_draws(conf, plan, b, name)
+                draws.append({k: d for k, d in sets.items() if loaded[k] is None})
+            drawn = sample_descriptor_columns(
+                plan, train.images, branches, [list(d.values()) for d in draws], mesh
+            )
+            samples = {
+                name: dict(zip(sets, rows)) for name, sets, rows in zip(names, draws, drawn)
+            }
+            if any(draws):
+                trace.metrics.inc("fv.image_passes", len(train))
+                for name, sets in zip(names, draws):
+                    if sets:
+                        trace.metrics.inc(f"fv.descriptor_passes.{name}", len(train))
+                trace.wait(samples, "sample_descriptors")
+
+        # PCA a branch: fit on its sampled descriptors, or load (:52-60, :108-116)
+        pcas, centres = [], {}
+        with stage_timer("pca"):
+            for name in names:
+                with trace.span("pca", cat="dictionary", branch=name):
+                    if pca_files[name] is not None:
+                        pca_mat = jnp.asarray(
+                            np.loadtxt(pca_files[name], delimiter=",", ndmin=2).T,
+                            jnp.float32,
+                        )
+                    else:
+                        rows = samples[name].pop("pca")
+                        if name == "lcs":
+                            centres[name] = jnp.mean(rows, axis=0)
+                        rows = _prepare_rows(branch_preparation(name, centres.get(name)), rows)
+                        pca_mat = trace.wait(compute_pca(rows, conf.desc_dim), "pca")
+                    pcas.append(BatchPCATransformer(pca_mat))
+
+        # GMM a branch: EM on its projected samples, or load (:81-91, :136-145)
+        gmms = []
+        with stage_timer("gmm"):
+            for name, pca in zip(names, pcas):
+                with trace.span("gmm", cat="dictionary", branch=name):
+                    mean_f, var_f, wts_f = gmm_files[name]
+                    if mean_f is not None:
+                        gmm = GaussianMixtureModel.load(mean_f, var_f, wts_f)
+                    else:
+                        rows = _prepare_rows(
+                            branch_preparation(name, centres.get(name)), samples[name].pop("gmm")
+                        )
+                        est = GaussianMixtureModelEstimator(conf.vocab_size)
+                        gmm = est.fit(rows @ pca.pca_mat)
+                        with trace.d2h("gmm_iterations", 4):
+                            gmm_iterations[name] = int(est.last_iterations)
+                        trace.metrics.inc("gmm.iterations", gmm_iterations[name])
+                        trace.metrics.inc(f"gmm.iterations.{name}", gmm_iterations[name])
+                    assert_all_finite(gmm, f"{name} branch GMM fit")
+                    gmms.append(gmm)
+        del samples
+
+    projections = [
+        branch_projection(name, pca, centres.get(name)) for name, pca in zip(names, pcas)
+    ]
+
+    if not restored:
+        # The featurizing pass: SIFT's Fisher features then LCS's, a row
+        # (ZipVectors, :179-183), on the device from here to the solver
+        with stage_timer("featurize"):
+            train_features = trace.wait(
+                featurize_chunks(plan, train.images, branches, projections, gmms, mesh),
+                "featurize",
+            )
+            trace.metrics.inc("fv.image_passes", len(train))
+            for name in names:
+                trace.metrics.inc(f"fv.descriptor_passes.{name}", len(train))
+
+        # 2·2·descDim·vocabSize features (:186-188)
+        with stage_timer("solve"):
+            solver = BlockWeightedLeastSquaresEstimator(
+                4096, 1, conf.lam, conf.mixture_weight, mesh=mesh
+            )
+            model = solver.fit(
+                train_features, train_labels,
+                num_features=2 * 2 * conf.desc_dim * conf.vocab_size,
+                plan=True if conf.auto_shard else None,
+            )
+            log_fit_report(solver, label="ImageNet weighted block solve")
+            assert_all_finite(model, "ImageNet weighted block solve")
+            solver_report = solver.last_fit_report
+        del train_features
+
+    with stage_timer("eval"):
+        test_plan = plan_chunks(test.images, branches, conf.desc_dim, conf.vocab_size, mesh)
+        with stage_timer("featurize_test"):
+            test_features = trace.wait(
+                featurize_chunks(test_plan, test.images, branches, projections, gmms, mesh),
+                "featurize_test",
+            )
+        scores = model(test_features)
+        del test_features
+        k = min(5, conf.num_classes)
+        topk = TopKClassifier(k)(scores)
+        with trace.d2h("test_scores", scores.nbytes + topk.nbytes):
+            in_chunk_order = np.asarray(scores)
+            topk_in_chunk_order = np.asarray(topk)
+        test_scores = np.empty_like(in_chunk_order)
+        test_scores[test_plan.order] = in_chunk_order
+        top = np.empty_like(topk_in_chunk_order)
+        top[test_plan.order] = topk_in_chunk_order
+        err = get_err_percent(top, test.labels, k)
+    results = {
+        "top5_err_percent": err,
+        "top1_err_percent": get_err_percent(top, test.labels, 1),
+        "test_scores": test_scores,
+        "pipeline": {
+            **{f"{name}_pca": pca for name, pca in zip(names, pcas)},
+            **{f"{name}_gmm": gmm for name, gmm in zip(names, gmms)},
+            **{f"{name}_centre": DescriptorCentre(c) for name, c in centres.items()},
+            "model": model,
+        },
+    }
+    if gmm_iterations:
+        results["gmm_iterations"] = gmm_iterations
+    if solver_report is not None:
+        # Which tier ran, against what budget, after which step-downs.
+        results["solver"] = {
+            "tier": solver_report.chosen,
+            "budget_bytes": solver_report.budget_bytes,
+            "denials": list(solver_report.denials),
+            "oom_retries": list(solver_report.oom_retries),
+        }
+        if solver_report.placement is not None:
+            # The searched placement table for the weighted block solve —
+            # candidates, deny/score rationale, predicted-vs-actual cost.
+            results["placement"] = solver_report.placement
+    if conf.pipeline_file is not None and not restored:
+        with stage_timer("checkpoint"):
+            save_pipeline(conf.pipeline_file, results["pipeline"])
+        log.log_info("saved fitted pipeline to %s", conf.pipeline_file)
+    results["seconds"] = time.perf_counter() - t0
+    log.log_info("TEST Top-%d error is: %s %%", k, err)
+    return results
+
+
+def _run_resident(conf: ImageNetSiftLcsFVConfig, train, test, mesh) -> dict:
+    """The fit of a streamed source: a branch's descriptors are held whole
+    between its passes (``branch_features``), so it runs at sizes whose
+    descriptors fit the device."""
     log = _Log()
     t0 = time.perf_counter()
 
     sift_plan = lcs_plan = placement_rec = None
-    feat_placements: dict = {}
     if conf.pipeline_file is not None and checkpoint_exists(conf.pipeline_file):
-        # Load-or-fit of the whole fitted pipeline: skip training
-        # featurization and every fit; score test with restored state.
         log.log_info("restoring fitted pipeline from %s", conf.pipeline_file)
         ck = load_pipeline(conf.pipeline_file)
         test_sift = branch_test_features(
@@ -408,7 +648,6 @@ def run(
                 (conf.sift_gmm_mean_file, conf.sift_gmm_var_file, conf.sift_gmm_wts_file),
                 conf.seed,
                 mesh,
-                placement_out=feat_placements,
             )
         with stage_timer("lcs_branch"):
             train_lcs, test_lcs, lcs_pca, lcs_gmm, lcs_plan = branch_features(
@@ -420,7 +659,6 @@ def run(
                 (conf.lcs_gmm_mean_file, conf.lcs_gmm_var_file, conf.lcs_gmm_wts_file),
                 conf.seed + 100,
                 mesh,
-                placement_out=feat_placements,
             )
 
         # ZipVectors (:179-183) — kept host-side; the solver shards its blocks
@@ -477,11 +715,7 @@ def run(
         for name, plan in (("sift", sift_plan), ("lcs", lcs_plan)):
             if plan is not None:
                 log.log_info("%s branch %s", name, plan.summary())
-    if feat_placements:
-        # The searched FEATURIZE placements (per descriptor branch) next
-        # to the solve's — one audit home for every ranked placement.
-        results["placement"] = {"solver": placement_rec, **feat_placements}
-    elif placement_rec is not None:
+    if placement_rec is not None:
         # The searched placement table for the weighted block solve —
         # candidates, deny/score rationale, predicted-vs-actual cost.
         results["placement"] = placement_rec

@@ -816,48 +816,6 @@ def test_specs_env_disables_spec_dimension(rng, monkeypatch):
     assert not any(c.get("specs") for c in p["candidates"])
 
 
-def test_searched_featurize_placement(rng, mesh42):
-    """fv_common's featurize placement rides the same search machinery:
-    hand row-sharded layout at the untrained head (bit-identical default),
-    a placement record with the spec column, single-device floor last."""
-    from keystone_tpu.workloads.fv_common import (
-        bucket_by_shape,
-        searched_bucket_featurize,
-        shard_batch,
-    )
-
-    images = [
-        rng.integers(0, 255, (24, 16, 3)).astype(np.uint8) for _ in range(6)
-    ] + [
-        rng.integers(0, 255, (16, 16, 3)).astype(np.uint8) for _ in range(4)
-    ]
-    per_batch = lambda dev: jnp.asarray(dev, jnp.float32).sum(  # noqa: E731
-        axis=(1, 2, 3), keepdims=True
-    )[:, :, None]
-    out, placement = searched_bucket_featurize(
-        "test_featurize", images, per_batch, mesh42
-    )
-    assert placement is not None
-    assert placement["ranking"][0].startswith("row_sharded[mesh 4x2]")
-    assert placement["ranking"][-1] == "single_device"
-    assert placement["chosen"] == placement["ranking"][0]
-    # bit-identical to the hand path
-    hand = {
-        shape: (idx, per_batch(shard_batch(batch, mesh42)))
-        for shape, (idx, batch) in bucket_by_shape(images).items()
-    }
-    assert set(out) == set(hand)
-    for shape in out:
-        np.testing.assert_array_equal(
-            np.asarray(out[shape][1]), np.asarray(hand[shape][1])
-        )
-    # no mesh -> plain hand path, no record
-    out2, rec2 = searched_bucket_featurize(
-        "test_featurize", images, per_batch, None
-    )
-    assert rec2 is None and set(out2) == set(hand)
-
-
 # -- the cross-program calibration model (ISSUE 10) ----------------------------
 
 

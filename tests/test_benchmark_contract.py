@@ -38,6 +38,11 @@ PROGRAMS = {
     r"^jit__encode_chunk": "keystone_tpu.workloads.fv_common",
     r"^jit__gather_samples": "keystone_tpu.workloads.fv_common",
     r"^jit__em_fit": "keystone_tpu.solvers.gmm",
+    r"^jit__describe_lcs_chunk": "keystone_tpu.workloads.fv_common",
+    r"^jit__prepare_rows": "keystone_tpu.workloads.imagenet_sift_lcs_fv",
+    r"^jit__fused_bwls": "keystone_tpu.solvers.weighted",
+    r"^jit__class_solves": "keystone_tpu.solvers.weighted",
+    r"^jit__block_apply": "keystone_tpu.solvers.block",
 }
 
 #: pipeline of the cell -> the workload module its window drives, and the
@@ -59,6 +64,10 @@ STAGES = {
         "keystone_tpu.workloads.voc_sift_fisher",
         ["sample_descriptors", "pca", "gmm", "featurize",
          "featurize_test", "solve", "eval"],
+    ),
+    "imagenet_fv": (
+        "keystone_tpu.workloads.imagenet_sift_lcs_fv",
+        ["featurize", "featurize_test", "solve", "eval"],
     ),
 }
 

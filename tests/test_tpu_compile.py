@@ -279,3 +279,124 @@ def test_apply_compiles_at_benchmark_widths(one_chip):
     assert mem.temp_size_in_bytes < 32 << 20, mem.temp_size_in_bytes
     moved = compiled.cost_analysis()["bytes accessed"]
     assert moved < 1.1 * 50000 * 10000 * 4, moved
+
+
+# -- ImageNetSiftLcsFV at its own widths (`imagenet_sift_lcs_fv_16`) ------------------
+
+
+def test_weighted_solve_compiles_at_published_classes(one_chip):
+    """The fused weighted solve at ``f32[8000 + 8, 4096]`` x 1,000 classes of
+    8 rows (``imagenet_fv_fit``): sixteen classes' systems at a time, so the
+    program's temporaries hold ``[16, 4096, 4096]`` arrays and never a
+    ``[1000, ...]`` stack of systems or of class rows."""
+    import re
+
+    from keystone_tpu.solvers import weighted
+
+    n, d, classes, n_max, chunk = 8000, 4096, 1000, 8, 16
+    p = n + n_max
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    compiled = weighted._fused_bwls_fit_variant((0, 1)).lower(
+        sds((p, d)), sds((p, classes)), sds((p, 1)), sds((p,), jnp.int32),
+        sds((classes,), jnp.int32), sds((classes,), jnp.int32), sds((classes,)), sds((classes,)),
+        sds((), jnp.int32), sds(()), sds(()),
+        1, n_max, chunk, classes, (d,), None, None,
+    ).compile()
+    text = compiled.as_text()
+    assert re.search(rf"f32\[{chunk},{d},{d}\]", text)
+    assert not re.search(rf"\[{classes},{d},{d}\]", text)
+    assert not re.search(rf"\[{classes},{n_max},{d}\]", text)
+    mem = compiled.memory_analysis()
+    # sixteen 67 MB systems, their factors and the triangular solves' scratch
+    assert mem.temp_size_in_bytes < 6 << 30, mem.temp_size_in_bytes
+    print("weighted solve: temp", mem.temp_size_in_bytes, "args", mem.argument_size_in_bytes)
+
+
+def _imagenet_branches():
+    from keystone_tpu.workloads import imagenet_sift_lcs_fv as inet
+
+    return inet.descriptor_branches(inet.ImageNetSiftLcsFVConfig())
+
+
+def test_lcs_chunk_compiles_at_published_widths(one_chip):
+    """The LCS branch's chunk program at 64 images of 375x500: its own module
+    name, float32 descriptors ``[64, 96, 10062]``, and no array with the
+    three channels innermost after the first transpose (an accelerator pads
+    such an axis to a whole tile)."""
+    from keystone_tpu.workloads import fv_common
+
+    lcs = _imagenet_branches()[1]
+    assert (lcs.dim, lcs.cols(375, 500)) == (96, 10062)
+    flat = jax.ShapeDtypeStruct((64, 375 * 500 * 3), jnp.uint8, sharding=one_chip)
+    compiled = lcs.describe.lower(lcs.node, flat, image_shape=(375, 500, 3)).compile()
+    assert lcs.describe is fv_common._describe_lcs_chunk
+    text = compiled.as_text()
+    assert "jit__describe_lcs_chunk" in text
+    assert "f32[64,96,10062]" in text
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes < 1.02 * 64 * 96 * 10062 * 4  # the tile's pad
+    assert mem.temp_size_in_bytes < 2 << 30, mem.temp_size_in_bytes
+    print("lcs chunk: temp", mem.temp_size_in_bytes)
+
+
+def test_two_branch_encode_compiles_at_published_widths(one_chip, monkeypatch):
+    """Both branches' PCA and Fisher vectors of a 64-image chunk of 375x500
+    as one program: SIFT's bytes ``[64, 128, 40584]`` and LCS's float32
+    ``[64, 96, 10062]`` in, ``[64, 4096]`` rows out; no operand holds every
+    image's descriptors (``[8000, 40584, 128]``)."""
+    from keystone_tpu.ops import fisher
+    from keystone_tpu.solvers.gmm import GaussianMixtureModel
+    from keystone_tpu.solvers.pca import BatchPCATransformer
+    from keystone_tpu.workloads import fv_common
+    from keystone_tpu.workloads.imagenet_sift_lcs_fv import branch_projection
+
+    monkeypatch.setattr(fisher, "fv_form", lambda *a: "kernel")
+    rng = np.random.default_rng(0)
+    d, k = 64, 16
+    chains, descs = [], []
+    for branch, dtype in zip(_imagenet_branches(), (jnp.uint8, jnp.float32)):
+        pca = BatchPCATransformer(rng.normal(size=(branch.dim, d)).astype(np.float32))
+        gmm = GaussianMixtureModel(
+            rng.normal(size=(d, k)).astype(np.float32),
+            rng.uniform(0.5, 2.0, (d, k)).astype(np.float32),
+            rng.dirichlet(np.ones(k)).astype(np.float32),
+        )
+        chains.append((branch_projection(branch.name, pca, jnp.zeros((branch.dim,), jnp.float32)), gmm))
+        descs.append(
+            jax.ShapeDtypeStruct((64, branch.dim, branch.cols(375, 500)), dtype, sharding=one_chip)
+        )
+    fv_common._encode_chunk.clear_cache()
+    try:
+        compiled = fv_common._encode_chunk.lower(tuple(chains), tuple(descs)).compile()
+    finally:
+        fv_common._encode_chunk.clear_cache()
+    text = compiled.as_text()
+    assert "f32[64,4096]" in text
+    assert "8000," not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3 << 30, mem.temp_size_in_bytes
+    print("two-branch encode: temp", mem.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("samples", [10_000_000, 1_000_000])
+def test_pca_svd_at_the_published_sample(one_chip, samples):
+    """``compute_pca`` of SIFT's ``[samples, 128]`` PCA sample.  At the
+    published 1e7 the sample is 5.1 GB and the program's temporaries are
+    several copies of it: beside the LCS branch's 3.8 GB sample and a chunk it
+    does not fit a 16 GB chip, which is what cuts ``num_pca_samples`` to 1e6
+    in ``imagenet_sift_lcs_fv_16`` (PERF.md section 4)."""
+    from keystone_tpu.solvers.pca import compute_pca
+
+    x = jax.ShapeDtypeStruct((samples, 128), jnp.float32, sharding=one_chip)
+    try:
+        compiled = jax.jit(compute_pca, static_argnums=1).lower(x, 64).compile()
+    except Exception as e:  # noqa: BLE001 - the compiler refusing the size is the finding
+        assert samples == 10_000_000 and "RESOURCE_EXHAUSTED" in str(e), e
+        return
+    mem = compiled.memory_analysis()
+    held = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    print(f"svd at {samples}: temp {mem.temp_size_in_bytes} args {mem.argument_size_in_bytes}")
+    if samples == 10_000_000:
+        assert held > 10e9, held
+    else:
+        assert held < 2.5e9, held

@@ -344,7 +344,7 @@ def test_sampling_pass_programs_do_not_move_with_the_draw(data, chunk_of_four):
         (rows,) = fv_common.sample_descriptor_columns(plan, train, sift, draws)
         assert rows.shape == (sum(len(d) for d in draws[0].values()), 128)
     # one a shape of a chunk's descriptors, whatever the seed
-    assert fv_common._sample_chunk._cache_size() == len({(plan.chunk[s], plan.cols[s]) for s in plan.index})
+    assert fv_common._sample_chunk._cache_size() == len({(plan.chunk[s], plan.cols[s][0]) for s in plan.index})
     assert fv_common._gather_samples._cache_size() == 1
 
 
@@ -378,7 +378,7 @@ def test_budget_rule_picks_the_chunk(data, monkeypatch):
     assert whole.chunk == {s: len(i) for s, i in whole.index.items()}
     # descriptors, projections and posteriors of a bucket, in float32
     bucket_bytes = {s: len(i) * whole.image_bytes[s] for s, i in whole.index.items()}
-    for shape, c in whole.cols.items():
+    for shape, (c,) in whole.cols.items():
         assert whole.image_bytes[shape] == 4 * c * (128 + CONF.desc_dim + CONF.vocab_size)
     # a budget whose share holds 5 images: chunks of 4, the power of two below
     tight = int(5 * max(whole.image_bytes.values()) / fv_common.CHUNK_BUDGET_SHARE)
@@ -395,7 +395,7 @@ def test_budget_rule_picks_the_chunk(data, monkeypatch):
     voc07 = fv_common.plan_chunks(
         [np.zeros((375, 500, 3), np.uint8)] * 200, SIFTExtractor(3, scale_step=0), 80, 256
     )
-    assert voc07.cols[(375, 500)] == 73866
+    assert voc07.cols[(375, 500)] == (73866,)
     assert voc07.chunk[(375, 500)] == 64
     assert 200 * voc07.image_bytes[(375, 500)] > voc07.budget
 

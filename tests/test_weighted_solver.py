@@ -215,3 +215,39 @@ def test_regroup_skew_guard_falls_back_exactly(rng, mesh42):
         )
     for a, b in zip(host_fit.xs, dev_fit.xs):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        # the deployment's λ and mixture weight on unit-norm rows, fewer rows
+        # a class than features: a class's covariance is rank-deficient and
+        # the system is positive definite by the population's share and λ
+        dict(n_per=4, classes=6, d=16, block=16, iters=1, lam=6e-5, w=0.25, unit=True),
+        # pad columns (widths 4, 4, 2): their unit diagonal keeps the factor
+        dict(n_per=12, classes=3, d=10, block=4, iters=2, lam=0.1, w=0.3, unit=False),
+        # no ridge at all on full-rank data
+        dict(n_per=30, classes=3, d=6, block=6, iters=1, lam=0.0, w=0.5, unit=False),
+        # a mixture weight near one: the class's own statistics carry the system
+        dict(n_per=20, classes=4, d=8, block=8, iters=2, lam=1e-3, w=0.9, unit=False),
+    ],
+    ids=["published_lambda_rank_deficient", "pad_columns", "no_ridge", "class_heavy"],
+)
+def test_cholesky_class_solves_match_pivoted_solve(rng, case):
+    """The class systems are factored by Cholesky; the transcription solves
+    the same systems by numpy's pivoted LU in float64."""
+    classes, d = case["classes"], case["d"]
+    idx = np.repeat(np.arange(classes), case["n_per"])
+    feats = rng.normal(scale=2.0, size=(classes, d))[idx] + rng.normal(size=(len(idx), d))
+    if case["unit"]:
+        feats /= np.linalg.norm(feats, axis=1, keepdims=True)
+    feats = feats.astype(np.float32)
+    labels = (2.0 * np.eye(classes)[idx] - 1.0).astype(np.float32)
+    perm = rng.permutation(len(idx))
+    feats, labels = feats[perm], labels[perm]
+    args = (case["block"], case["iters"], case["lam"], case["w"])
+    x, b = fit_full(feats, labels, *args)
+    xn, bn = naive_bwls(feats.astype(np.float64), labels.astype(np.float64), *args)
+    scale = max(1.0, np.abs(xn).max())
+    np.testing.assert_allclose(x, xn, atol=2e-3 * scale)
+    np.testing.assert_allclose(b, bn, atol=2e-3 * scale)
