@@ -18,12 +18,14 @@ Per scale ``s`` (reference VLFeat.cxx:68-123):
     below contrastthreshold=0.005 are zeroed (:62,167-169);
   * quantize ``min(floor(512·v), 255)`` (:249-263).
 
-Everything is batched ``[N, H, W]``: smoothing and gradients are XLA convs
-and elementwise chains, the spatial binning two banded MXU products a scale
-(``_binned_sampling_matrix``), so whole image batches stay in HBM (the
-reference pays a JVM->C JNI crossing per image).  Descriptor count per image
-is static given (H, W, params), which keeps shapes XLA-friendly;
-variable-size image sets bucket by shape upstream.
+Everything is batched ``[N, H, W]``: the Gaussian smoothing and the spatial
+binning are each two banded MXU products a scale with the edge padding in
+the matrices (``_binned_sampling_matrix``; ``_smooth``), gradients and
+orientation planes elementwise chains, so whole image batches stay in HBM
+(the reference pays a JVM->C JNI crossing per image) and the compiled
+program holds no one-channel convolution and no padded copy of a plane.
+Descriptor count per image is static given (H, W, params), which keeps
+shapes XLA-friendly; variable-size image sets bucket by shape upstream.
 
 **The assembly** (binned planes ``[N, 8, 4*Fy, 4*Fx]`` a scale -> normalized
 bytes ``[N, 128, D]``) is a transposition of every value: the planes hold a
@@ -107,29 +109,24 @@ def _binned_sampling_matrix(
     return s
 
 
-def _conv1d_axis(batch, kernel, axis):
-    """Convolve [N, H, W] along ``axis`` (1=rows/y, 2=cols/x) with edge pad."""
-    k = jnp.asarray(kernel, batch.dtype)
-    klen = k.shape[0]
-    r = (klen - 1) // 2
-    pad = [(0, 0), (0, 0), (0, 0)]
-    pad[axis] = (r, klen - 1 - r)
-    x = jnp.pad(batch, pad, mode="edge")
-    # depthwise conv via conv_general_dilated on a singleton channel
-    x4 = x[:, None, :, :]  # [N, 1, H, W]
-    if axis == 1:
-        kern = k[::-1].reshape(1, 1, klen, 1)
-    else:
-        kern = k[::-1].reshape(1, 1, 1, klen)
-    out = jax.lax.conv_general_dilated(
-        x4, kern, (1, 1), "VALID", dimension_numbers=("NCHW", "OIHW", "NCHW")
-    )
-    return out[:, 0]
-
-
 def _smooth(batch, sigma: float):
+    """Gaussian smoothing of ``[N, H, W]`` with edge padding as two banded
+    MXU products, rows then columns: ``_binned_sampling_matrix`` at every
+    pixel *is* the edge-padded convolution matrix (the clamp folds the taps
+    that leave the image onto its edge row), so no padded copy of the planes
+    exists.  Weights and both results are rounded to the batch's dtype, the
+    sums run in float32: what a one-channel convolution of the padded planes
+    gives, up to the order of a 7-15 term float32 sum."""
+    _n, h, w = batch.shape
     k = _gaussian_kernel(sigma)
-    return _conv1d_axis(_conv1d_axis(batch, k, 1), k, 2)
+    g_y = jnp.asarray(_binned_sampling_matrix(h, np.arange(h), k), batch.dtype)
+    g_x = jnp.asarray(_binned_sampling_matrix(w, np.arange(w), k), batch.dtype)
+    rows = jnp.einsum(
+        "ph,nhw->npw", g_y, batch, preferred_element_type=jnp.float32
+    ).astype(batch.dtype)
+    return jnp.einsum(
+        "npw,qw->npq", rows, g_x, preferred_element_type=jnp.float32
+    ).astype(batch.dtype)
 
 
 def _gradients(batch):
@@ -258,6 +255,7 @@ class SIFTExtractor(Transformer):
         trace.instant(
             "sift_form", form=form, images=n, scales=len(grids),
             frames=self.num_descriptors(h, w),
+            smooth="banded", smooth_rows=f"{h}x{h}", smooth_cols=f"{w}x{w}",
         )
         if form == "kernel":
             return self._kernel_form(batch)

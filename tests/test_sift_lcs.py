@@ -73,9 +73,40 @@ class TestSIFT:
         assert centers[0] == centers[1] == centers[2]
 
 
-def parent_sift(ext: SIFTExtractor, batch):
+def _conv1d_axis(batch, kernel, axis):
+    """Convolve [N, H, W] along ``axis`` (1=rows/y, 2=cols/x) with edge pad."""
+    k = jnp.asarray(kernel, batch.dtype)
+    klen = k.shape[0]
+    r = (klen - 1) // 2
+    pad = [(0, 0), (0, 0), (0, 0)]
+    pad[axis] = (r, klen - 1 - r)
+    x = jnp.pad(batch, pad, mode="edge")
+    # depthwise conv via conv_general_dilated on a singleton channel
+    x4 = x[:, None, :, :]  # [N, 1, H, W]
+    if axis == 1:
+        kern = k[::-1].reshape(1, 1, klen, 1)
+    else:
+        kern = k[::-1].reshape(1, 1, 1, klen)
+    out = jax.lax.conv_general_dilated(
+        x4, kern, (1, 1), "VALID", dimension_numbers=("NCHW", "OIHW", "NCHW")
+    )
+    return out[:, 0]
+
+
+def conv_smooth(batch, sigma: float):
+    """``ops/sift._smooth`` as it stood while the smoothing was two edge pads
+    and two one-channel convolutions a scale (to PR 34), kept word for word
+    with ``_conv1d_axis`` above: the plain reference the banded products are
+    held to."""
+    k = sift_ops._gaussian_kernel(sigma)
+    return _conv1d_axis(_conv1d_axis(batch, k, 1), k, 2)
+
+
+def parent_sift(ext: SIFTExtractor, batch, smooth=conv_smooth):
     """``SIFTExtractor.__call__`` as it stood before the assembly had two
-    forms (PR 30), kept word for word: what both forms are held to."""
+    forms (PR 30), kept word for word, on the smoothing as it stood before it
+    was banded products (PR 35): what both forms are held to.  With ``smooth``
+    the program's own, only the assembly differs from the program's."""
     n, h, w = batch.shape
     cdt = ext.compute_dtype
     batch = batch.astype(cdt)
@@ -86,7 +117,7 @@ def parent_sift(ext: SIFTExtractor, batch):
         ys, xs = sift_ops._scale_geometry(h, w, step, b, ext.scales, s)
         if len(ys) == 0 or len(xs) == 0:
             continue
-        smoothed = sift_ops._smooth(batch, b / sift_ops.MAGNIF)
+        smoothed = smooth(batch, b / sift_ops.MAGNIF)
         gy, gx = sift_ops._gradients(smoothed)
         planes = sift_ops._orientation_planes(gy, gx).astype(cdt)
         tri = sift_ops._triangular_kernel(b)
@@ -131,17 +162,103 @@ def _textured(rng, n, h, w):
 GRIDS = [(47, 63), (63, 47), (42, 63), (30, 46)]
 
 
+def _parent(ext, smooth=conv_smooth):
+    return jax.jit(functools.partial(parent_sift, ext, smooth=smooth))
+
+
+def _bf16_steps(got, want):
+    """``|got - want|`` in steps of bfloat16 at ``want`` (8 bits: a step is
+    ``2**(exponent - 7)``)."""
+    got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+    step = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30))) - 7)
+    return np.abs(got - want) / step
+
+
+#: sigma of the four scales (bin 4, 6, 8, 10): radii 3, 4, 6, 7
+SIGMAS = [b / sift_ops.MAGNIF for b in (4, 6, 8, 10)]
+
+
+class TestBandedSmoothing:
+    """``ops/sift._smooth`` (two banded products, the edge padding folded into
+    the matrices) against the edge pads and one-channel convolutions it
+    replaced (``conv_smooth``)."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    @pytest.mark.parametrize("sigma", SIGMAS, ids=lambda s: f"sigma{s:.2f}")
+    @pytest.mark.parametrize(
+        "hw", [(32, 32), (37, 53), (48, 5), (3, 41)], ids=lambda hw: f"{hw[0]}x{hw[1]}"
+    )
+    def test_banded_smooth_is_the_convolution(self, rng, hw, sigma, dtype):
+        """Float32: to the order of the sum.  bfloat16: the same weights and
+        roundings wherever the window lies inside the image (one step at
+        most); within the kernel's radius of a border the taps that fold
+        onto the edge pixel are one weight of the matrix, rounded once more
+        (48x5 and 3x41 are narrower than every radius: all border)."""
+        h, w = hw
+        batch = jnp.asarray(rng.uniform(size=(3, h, w)).astype(np.float32), dtype)
+        want = conv_smooth(batch, sigma)
+        got = jax.jit(sift_ops._smooth, static_argnums=1)(batch, sigma)
+        assert got.shape == want.shape and got.dtype == want.dtype == dtype
+        if dtype == jnp.float32:
+            assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 1e-6
+            return
+        steps = _bf16_steps(got, want)
+        assert steps.max() <= 2.0
+        r = (len(sift_ops._gaussian_kernel(sigma)) - 1) // 2
+        inside = steps[:, r : h - r, r : w - r]
+        assert inside.size == 0 or inside.max() <= 1.0
+
+    def test_constant_image_stays_constant(self):
+        """No contrast in, none out: the rows of both matrices sum to one
+        closely enough that a flat image stays within one bfloat16 step of
+        itself, edge rows (the folded weights) included."""
+        levels = jnp.linspace(0.05, 1.0, 20, dtype=jnp.float32)
+        for dtype in (jnp.float32, jnp.bfloat16):
+            batch = jnp.broadcast_to(levels[:, None, None], (20, 24, 31)).astype(dtype)
+            for sigma in SIGMAS:
+                out = np.asarray(sift_ops._smooth(batch, sigma).astype(jnp.float32))
+                spread = out.max(axis=(1, 2)) - out.min(axis=(1, 2))
+                assert (spread <= np.asarray(levels) * 2.0**-7).all()
+
+
 class TestAssemblyForms:
     """The descriptor assembly (binned planes -> normalized bytes) has a
     kernel form (ops/sift_pallas.py, here in the Pallas interpreter) and the
     XLA form; both give the descriptors the extractor gave before."""
+
+    @pytest.mark.parametrize(
+        "form,dtype",
+        [("xla", jnp.float32), ("xla", jnp.bfloat16), ("kernel", jnp.bfloat16)],
+        ids=["xla-f32", "xla-bf16", "kernel-bf16"],
+    )
+    @pytest.mark.parametrize("scale_step", [0, 1])
+    @pytest.mark.parametrize("hw", GRIDS, ids=lambda hw: f"{hw[0]}x{hw[1]}")
+    def test_forms_follow_the_convolved_parent(self, rng, hw, scale_step, form, dtype):
+        """Both forms against the parent on the smoothing it had (edge pads
+        and one-channel convolutions).  Float32: the order of a sum, so a
+        floor now and then.  bfloat16: the border band's weights are rounded
+        once more (``TestBandedSmoothing``), and these grids are an eighth of
+        VOC's, so the band is a third of the image: the envelope is the one
+        the bfloat16 chain has against the float32 chain (class docstring of
+        ``SIFTExtractor``)."""
+        ext = SIFTExtractor(scale_step=scale_step, compute_dtype=dtype)
+        batch = _textured(rng, 32 if form == "kernel" else 3, *hw)
+        want = np.asarray(_parent(ext)(batch))
+        run = ext.__call__ if form == "xla" else functools.partial(ext._kernel_form, interpret=True)
+        off = np.abs(np.asarray(jax.jit(run)(batch)) - want)
+        assert want.max() > 0 and off[1].max() == 0  # zero contrast: zeros in both
+        if dtype == jnp.float32:
+            assert off.max() <= 1.0 and (off == 0).mean() >= 0.9995
+        else:
+            assert (off <= 1.0).mean() >= 0.99 and (off == 0).mean() >= 0.95
+            assert off.max() <= 32.0
 
     @pytest.mark.parametrize("scale_step", [0, 1])
     @pytest.mark.parametrize("hw", GRIDS, ids=lambda hw: f"{hw[0]}x{hw[1]}")
     def test_kernel_form_gives_the_parents_descriptors(self, rng, hw, scale_step):
         ext = SIFTExtractor(scale_step=scale_step, compute_dtype=jnp.bfloat16)
         batch = _textured(rng, 32, *hw)
-        want = np.asarray(jax.jit(functools.partial(parent_sift, ext))(batch))
+        want = np.asarray(_parent(ext, sift_ops._smooth)(batch))
         got = np.asarray(
             jax.jit(functools.partial(ext._kernel_form, interpret=True))(batch)
         )
@@ -158,7 +275,7 @@ class TestAssemblyForms:
         """What every CPU run, mesh run and float32 caller gets."""
         ext = SIFTExtractor(scale_step=scale_step, compute_dtype=dtype)
         batch = _textured(rng, 3, *hw)
-        want = np.asarray(jax.jit(functools.partial(parent_sift, ext))(batch))
+        want = np.asarray(_parent(ext, sift_ops._smooth)(batch))
         got = np.asarray(jax.jit(ext.__call__)(batch))
         np.testing.assert_array_equal(got, want)
         assert not got[1].any()
@@ -182,7 +299,8 @@ class TestAssemblyForms:
 
     def test_sift_form_counter_moves(self, rng):
         """``sift_form.<form>`` counts a traced program, not its calls, and
-        the instant says what the form was chosen on."""
+        the instant says what the form was chosen on and how the planes were
+        smoothed (one way: two banded products a scale, of these sizes)."""
         ext = SIFTExtractor(scale_step=0, compute_dtype=jnp.bfloat16)
         fn = jax.jit(ext.__call__)
         before = trace.metrics.get("sift_form.xla")
@@ -194,6 +312,7 @@ class TestAssemblyForms:
         assert last["args"] == {
             "form": "xla", "images": 32, "scales": 3,
             "frames": ext.num_descriptors(30, 46),
+            "smooth": "banded", "smooth_rows": "30x30", "smooth_cols": "46x46",
         }
 
 

@@ -95,23 +95,17 @@ def test_fv_kernel_form_compiles_at_published_widths(one_chip):
 SIFT_PARENT = {(375, 500): (1906e6, 33.5e9), (333, 500): (1647e6, 28.5e9)}
 
 
-@pytest.mark.parametrize("shape", sorted(SIFT_PARENT), ids=lambda s: f"{s[0]}x{s[1]}")
-def test_sift_kernel_form_compiles_at_published_widths(one_chip, monkeypatch, shape):
-    """`fv_common._describe_chunk` at 64 images of VOC's shapes
-    (`voc_sift_fv_256`) in the kernel form: one `sift_assemble` call a scale
-    writing the chunk's bytes in place, no staged `[64, D, 128]` array wider
-    than a byte (1.21 GB in bfloat16 before), under the parent's temporaries
-    and bytes.  The form is steered here, in the test: `sift_form` asks
-    `jax.default_backend()`, which is the CPU's."""
-    import re
-
+def _compiled_sift_chunk(one_chip, monkeypatch, shape):
+    """`fv_common._describe_chunk` at 64 images of ``shape`` in the kernel
+    form, compiled for the described chip, and the frames an image.  The form
+    is steered here, in the test: `sift_form` asks `jax.default_backend()`,
+    which is the CPU's."""
     from keystone_tpu.ops import sift
     from keystone_tpu.workloads import fv_common
 
     monkeypatch.setattr(sift, "sift_form", lambda *a: "kernel")
     h, w = shape
     node_ = sift.SIFTExtractor(scale_step=0, compute_dtype=jnp.bfloat16)
-    frames = node_.num_descriptors(h, w)
     flat = jax.ShapeDtypeStruct((64, h * w * 3), jnp.uint8, sharding=one_chip)
     fv_common._describe_chunk.clear_cache()
     try:
@@ -120,6 +114,19 @@ def test_sift_kernel_form_compiles_at_published_widths(one_chip, monkeypatch, sh
         ).compile()
     finally:
         fv_common._describe_chunk.clear_cache()
+    return compiled, node_.num_descriptors(h, w)
+
+
+@pytest.mark.parametrize("shape", sorted(SIFT_PARENT), ids=lambda s: f"{s[0]}x{s[1]}")
+def test_sift_kernel_form_compiles_at_published_widths(one_chip, monkeypatch, shape):
+    """`fv_common._describe_chunk` at 64 images of VOC's shapes
+    (`voc_sift_fv_256`) in the kernel form: one `sift_assemble` call a scale
+    writing the chunk's bytes in place, no staged `[64, D, 128]` array wider
+    than a byte (1.21 GB in bfloat16 before), under the parent's temporaries
+    and bytes."""
+    import re
+
+    compiled, frames = _compiled_sift_chunk(one_chip, monkeypatch, shape)
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     staged = re.findall(rf"(\w+)\[(?:64,{frames},128|64,128,{frames}|{frames},64,128)\]", text)
@@ -128,6 +135,44 @@ def test_sift_kernel_form_compiles_at_published_widths(one_chip, monkeypatch, sh
     temp, moved = SIFT_PARENT[shape]
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * temp
     assert compiled.cost_analysis()["bytes accessed"] < 0.5 * moved
+
+
+def test_sift_chunk_smooths_by_banded_products(one_chip, monkeypatch):
+    """`_describe_chunk` at 64 x 375x500 since the Gaussian smoothing is two
+    banded products a scale (PR 35): every `convolution` of the compiled
+    program is a product (a window of one; eight of them the smoothing's,
+    contracting 375 rows or 500 columns), none has one feature in and out,
+    and no plane is padded beyond the image (the eight edge pads made
+    `[64, 375 + 2r, 500]` and `[64, 375, 500 + 2r]`).  While the smoothing was
+    eight one-channel convolutions (commit abbf73a, compiled here) the
+    program moved 14.78 GB a chunk by XLA's reckoning and held 651 MB of
+    temporaries and 28.4 MB of code."""
+    import re
+
+    h, w = 375, 500
+    compiled, _frames = _compiled_sift_chunk(one_chip, monkeypatch, (h, w))
+    text = compiled.as_text()
+    convs = re.findall(
+        r"= (\w+)\[([\d,]+)\]\S* convolution\(.*?window=\{size=([\dx]+)[ }].*?dim_labels=\w+_\w+->(\w+)",
+        text,
+    )
+    assert len(convs) == 16, convs  # two products a scale each: smoothing, binning
+    for _dtype, dims, window, out_labels in convs:
+        assert set(window.split("x")) == {"1"}, (dims, window)
+        assert int(dims.split(",")[out_labels.index("f")]) > 1, (dims, out_labels)
+    smoothing = [dims for _d, dims, _w, _o in convs if dims in (f"64,{h},{w}", f"64,{w},{h}")]
+    assert len(smoothing) == 8, convs
+    padded = [
+        (int(a), int(b))
+        for a, b in re.findall(r"= \w+\[64,(\d+),(\d+)\]\S* pad\(", text)
+        if (int(a), int(b)) != (h, w)
+    ]
+    assert not padded, padded
+    mem = compiled.memory_analysis()
+    moved = compiled.cost_analysis()["bytes accessed"]
+    assert moved <= 14.78e9
+    assert mem.temp_size_in_bytes <= 651.4e6
+    print("sift chunk: code", mem.generated_code_size_in_bytes, "temp", mem.temp_size_in_bytes, "bytes", moved)
 
 
 
