@@ -935,10 +935,10 @@ def search(
     """Enumerate -> prune -> score -> rank.  Pure decision pass: nothing is
     compiled and nothing runs — see :func:`run_search` for execution."""
     t0 = time.perf_counter()
-    model = model if model is not None else kopt.CostModel.for_devices()
-    records: list[CandidateRecord] = []
-    survivors: list[tuple[Candidate, CandidateRecord]] = []
-    with trace.span("autoshard.search", cat="plan", label=label):
+    with trace.host("search", "autoshard.search", label=label):
+        model = model if model is not None else kopt.CostModel.for_devices()
+        records: list[CandidateRecord] = []
+        survivors: list[tuple[Candidate, CandidateRecord]] = []
         # 1. zero-cost batch preflight: analytic per-chip bytes vs budget.
         analytic = kmem.plan_batch([
             (
@@ -1050,35 +1050,31 @@ def search(
             ranking.insert(at, (by_name[rec.name], rec))
         for i, (_c, rec) in enumerate(ranking):
             rec.rank = i
-    plan = PlacementPlan(
-        label=label,
-        fingerprint=fingerprint,
-        devices=device_fingerprint(),
-        trained=trained,
-        margin=margin if survivors else UNTRAINED_MARGIN,
-        candidates=records,
-        ranking=[rec.name for _, rec in ranking],
-        search_seconds=time.perf_counter() - t0,
-        analytic_plans={
-            rec.name: analytic[rec.name] for rec in records if rec.pruned
-        },
-    )
-    trace.instant(
-        "autoshard_plan",
-        label=label,
-        fingerprint=fingerprint,
-        ranking=plan.ranking,
-        pruned=[r.name for r in records if r.pruned],
-        trained=trained,
-    )
-    # The search itself is part of the metrics surface (ISSUE 11): how
-    # many searches this process ran, how long they take, and whether the
-    # cost model was trained — readable from one registry snapshot next
-    # to the serving/ingest/fault groups.
-    trace.metrics.inc("autoshard_searches")
-    trace.metrics.observe("autoshard_search_seconds", plan.search_seconds)
-    trace.metrics.gauge("autoshard_last_search_trained", 1.0 if trained else 0.0)
-    _logger.info("%s", plan.summary())
+        plan = PlacementPlan(
+            label=label,
+            fingerprint=fingerprint,
+            devices=device_fingerprint(),
+            trained=trained,
+            margin=margin if survivors else UNTRAINED_MARGIN,
+            candidates=records,
+            ranking=[rec.name for _, rec in ranking],
+            search_seconds=time.perf_counter() - t0,
+            analytic_plans={
+                rec.name: analytic[rec.name] for rec in records if rec.pruned
+            },
+        )
+        trace.instant(
+            "autoshard_plan",
+            label=label,
+            fingerprint=fingerprint,
+            ranking=plan.ranking,
+            pruned=[r.name for r in records if r.pruned],
+            trained=trained,
+        )
+        # How many searches a stage ran and how long they took are the open
+        # stage's ``stage_host_n.<stage>.search`` / ``stage_host_ms.<stage>.search``
+        # (the ``search`` section above); the instant carries ``trained``.
+        _logger.info("%s", plan.summary())
     return plan
 
 
@@ -1181,9 +1177,9 @@ def run_search(
         def run(mplan):
             rec = placement.candidate(c.name)
             t0 = time.perf_counter()
-            with trace.span(
+            with trace.host(
+                "dispatch",
                 f"plan:{c.name}",
-                cat="plan",
                 predicted_s=rec.predicted_seconds if rec else None,
                 label=label,
                 rank=rec.rank if rec else None,
@@ -1228,7 +1224,8 @@ def run_search(
     try:
         out = kmem.run_ladder(label, tiers, report)
     finally:
-        _finish(placement, report, measured, fingerprint, label)
+        with trace.host("finish", "autoshard.finish", label=label):
+            _finish(placement, report, measured, fingerprint, label)
     return out
 
 

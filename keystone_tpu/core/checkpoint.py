@@ -510,44 +510,45 @@ def save_pipeline(path: str, pipe, numerics_baseline: dict | None = None) -> str
     answers against (``serve.load_engine`` arms it on warm load).  Pure
     metadata: it never affects what the pipeline computes.
     """
-    npz_path, manifest_path = checkpoint_paths(path)
-    enc = _Encoder()
-    root = enc.encode(pipe, "root")
-    import hashlib
-    import io
+    with trace.host("write", "save_pipeline"):  # its d2h reads are charged as waits
+        npz_path, manifest_path = checkpoint_paths(path)
+        enc = _Encoder()
+        root = enc.encode(pipe, "root")
+        import hashlib
+        import io
 
-    buf = io.BytesIO()
-    np.savez(buf, **enc.arrays)
-    npz_bytes = buf.getvalue()
-    manifest = {
-        "format": FORMAT_NAME,
-        "version": FORMAT_VERSION,
-        # Ties the pair together: the two files are replaced in separate
-        # atomic renames, so a preemption between them could leave a new
-        # .npz next to an old .json (or vice versa) — the hash check on
-        # load rejects any mixed pair.
-        "npz_sha256": hashlib.sha256(npz_bytes).hexdigest(),
-        # Where this checkpoint was solved: the load path refuses to
-        # restore NON-replicated arrays onto a different topology (see
-        # CheckpointMismatch) instead of silently resharding them.
-        "topology": _current_topology(),
-        "all_replicated": enc.all_replicated,
-        "root": root,
-        "arrays": enc.specs,
-    }
-    if numerics_baseline is not None:
-        manifest["numerics_baseline"] = numerics_baseline
-    _atomic_write_bytes(npz_path, npz_bytes)
-    _atomic_write_bytes(
-        manifest_path, json.dumps(manifest, indent=1).encode("utf-8")
-    )
-    _logger.info(
-        "saved checkpoint %s (%d arrays, %.1f KiB)",
-        npz_path,
-        len(enc.arrays),
-        buf.getbuffer().nbytes / 1024,
-    )
-    return os.path.splitext(npz_path)[0]
+        buf = io.BytesIO()
+        np.savez(buf, **enc.arrays)
+        npz_bytes = buf.getvalue()
+        manifest = {
+            "format": FORMAT_NAME,
+            "version": FORMAT_VERSION,
+            # Ties the pair together: the two files are replaced in separate
+            # atomic renames, so a preemption between them could leave a new
+            # .npz next to an old .json (or vice versa) — the hash check on
+            # load rejects any mixed pair.
+            "npz_sha256": hashlib.sha256(npz_bytes).hexdigest(),
+            # Where this checkpoint was solved: the load path refuses to
+            # restore NON-replicated arrays onto a different topology (see
+            # CheckpointMismatch) instead of silently resharding them.
+            "topology": _current_topology(),
+            "all_replicated": enc.all_replicated,
+            "root": root,
+            "arrays": enc.specs,
+        }
+        if numerics_baseline is not None:
+            manifest["numerics_baseline"] = numerics_baseline
+        _atomic_write_bytes(npz_path, npz_bytes)
+        _atomic_write_bytes(
+            manifest_path, json.dumps(manifest, indent=1).encode("utf-8")
+        )
+        _logger.info(
+            "saved checkpoint %s (%d arrays, %.1f KiB)",
+            npz_path,
+            len(enc.arrays),
+            buf.getbuffer().nbytes / 1024,
+        )
+        return os.path.splitext(npz_path)[0]
 
 
 def _ensure_standard_registry() -> None:

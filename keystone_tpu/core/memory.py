@@ -839,7 +839,7 @@ def run_ladder(label: str, tiers: Sequence[Tier], report: FitReport):
     with trace.span(f"solve:{label}", cat="solve") as solve_sp:
         for i, tier in enumerate(tiers):
             floor = i == len(tiers) - 1
-            with trace.span(f"plan:{tier.name}", cat="solve", solve=label):
+            with trace.host("plan", f"plan:{tier.name}", solve=label):
                 plan = tier.plan()
             report.plans[tier.name] = plan
             if plan.budget_bytes is not None:
@@ -872,10 +872,12 @@ def run_ladder(label: str, tiers: Sequence[Tier], report: FitReport):
                 )
                 last_oom = e
                 continue
-            report.chosen = tier.name
-            if report.degraded() or tier.name != tiers[0].name:
-                counters.record("solver_tier_degraded", report.summary())
-            _logger.info("%s: running tier=%s (%s)", label, tier.name, plan.reason)
+            with trace.host("finish", "fit_report", solve=label):
+                report.chosen = tier.name
+                if report.degraded() or tier.name != tiers[0].name:
+                    counters.record("solver_tier_degraded", report.summary())
+                _logger.info("%s: running tier=%s (%s)", label, tier.name, plan.reason)
+                solve_sp.set(report=report.record())
             if profiler.enabled():
                 # Device cost attribution (ISSUE 14): the chosen tier's
                 # compiled program lands in the per-program MFU ledger
@@ -893,7 +895,6 @@ def run_ladder(label: str, tiers: Sequence[Tier], report: FitReport):
                     phase_name=f"solve:{label}",
                     fingerprint=report.fingerprint,
                 )
-            solve_sp.set(report=report.record())
             return out
         # Unreachable in practice (the floor either returns or raises), but
         # be explicit if a caller builds a ladder whose floor denied AND

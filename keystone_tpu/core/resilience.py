@@ -278,7 +278,10 @@ def assert_all_finite(tree, name: str = "fitted model"):
         dtype = np.dtype(getattr(leaf, "dtype", np.float32))
         if dtype.kind not in "fc":
             continue
-        finite = np.isfinite(np.asarray(jax.device_get(leaf), np.float64)).all()
+        if isinstance(leaf, jax.Array):  # a read from the device: a wait, not work
+            with trace.d2h("finite_check", leaf.nbytes):
+                leaf = jax.device_get(leaf)
+        finite = np.isfinite(np.asarray(leaf, np.float64)).all()
         if not finite:
             bad.append(i)
     if bad:

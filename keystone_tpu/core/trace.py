@@ -23,13 +23,22 @@ schema.  This module is that shared substrate:
   each name once a fit.  Beneath a stage the host code says whether it
   works or waits, by cat: ``wait`` (:func:`wait`: blocked on the device),
   ``d2h`` (:func:`d2h`: a read to the host, counted as waiting), ``h2d``
-  (:func:`h2d`: a copy to the device, with bytes), ``dispatch`` (the call
-  of a jitted program), ``concat``, ``eval``, and the solvers' ``solve`` /
-  ``plan`` spans.  At exit a stage records ``stage_ms.<name>`` (self time),
-  ``stage_wait_ms.<name>``, ``stage_h2d_ms.<name>`` and
-  ``stage_h2d_mb.<name>`` into :data:`metrics` — always: the sums are kept
-  on the stage, not read back from spans, so they hold with tracing off
-  and with the flight ring off.
+  (:func:`h2d`: a copy to the device, with bytes) and ``host``
+  (:func:`host`: a named section of host work, one of :data:`SECTIONS`:
+  ``dispatch`` the call of a jitted program, ``stack``, ``draw``,
+  ``concat``, the solvers' ``search`` / ``plan`` / ``place`` / ``sort`` /
+  ``finish``, the checkpoint's ``write``).  At exit a stage records
+  ``stage_ms.<name>`` (self time), ``stage_wait_ms.<name>``,
+  ``stage_h2d_ms.<name>`` and ``stage_h2d_mb.<name>`` into :data:`metrics`
+  and, for every section charged beneath it,
+  ``stage_host_ms.<name>.<section>`` (summed self time),
+  ``stage_host_n.<name>.<section>`` (occurrences) and
+  ``stage_max_ms.<name>.<part>`` (the longest single occurrence; a part is
+  a section, ``wait`` or ``h2d``) — always: the sums are kept on the
+  stage, not read back from spans, so they hold with tracing off and with
+  the flight ring off.  Every microsecond of a stage's self time lands in
+  exactly one of ``wait``, ``h2d``, a section or ``other``
+  (``stage_host_ms.<name>.other``: what no helper covers).
 * **One clock with the device trace.**  While tracing is enabled a span is
   also a ``jax.profiler.TraceAnnotation`` named ``ks/<cat>/<name>`` with
   its ``id`` and ``root``: under ``jax.profiler.start_trace`` the program's
@@ -58,7 +67,9 @@ Overhead discipline: with tracing AND the flight ring off the path is a
 module-state check returning a cached null object; with only the ring on,
 each finished span is one small dict append into a bounded deque (the
 tier-1 suite asserts no retained allocation growth once the ring is warm,
-and a span's enter + exit under 20 us) and a stage adds one registry lock.
+and a span's enter + exit under 20 us), a charge (:func:`wait`,
+:func:`h2d`, :func:`host`) adds two clock reads and a few float adds to its
+span, and a stage adds one registry lock.
 Enabled, each finished span is one dict append under a lock (bounded at
 :data:`MAX_EVENTS`; overflow is counted, never unbounded).
 """
@@ -417,17 +428,61 @@ def io_span(name: str, nbytes: int, cat: str = "io", **attrs):
 #
 # A stage is a span of cat ``stage`` that also keeps sums: its own duration,
 # the part nested stages cover, and what the helpers below (``wait``,
-# ``d2h``, ``h2d``) charge to it.  The sums live on the stage object, found
-# through a per-thread list of open stages, so they hold with tracing off
-# and with the flight ring off (``KEYSTONE_FLIGHT_DEPTH=0``), when the spans
-# themselves are no-ops.
+# ``d2h``, ``h2d``, ``host``) charge to it.  The sums live on the stage
+# object, found through a per-thread list of open stages, so they hold with
+# tracing off and with the flight ring off (``KEYSTONE_FLIGHT_DEPTH=0``),
+# when the spans themselves are no-ops.  Stages and charges of a thread also
+# share one list of open frames: a frame that ends adds its duration to the
+# frame it lies in, so a charge knows what part of it other frames cover and
+# charges its self time only.
+
+#: The named sections of host work (:func:`host`): a closed vocabulary, so
+#: that a histogram's name says what it holds (``PERF.md`` section 3 lists
+#: where each is charged).
+SECTIONS = frozenset({
+    "dispatch",  # the call of compiled or eager programs (no sync)
+    "stack",  # a chunk's images gathered, padded and flattened on the host
+    "draw",  # the sampling pass's positions inside a chunk
+    "concat",  # the chunks' results joined
+    "search",  # the solvers' candidate enumeration and placement search
+    "plan",  # a tier's admission preflight
+    "place",  # operands padded, sorted and placed ahead of a tier's program
+    "sort",  # the weighted solver's class sort
+    "finish",  # after a solve's program: the plan's bookkeeping and log, the model cut into blocks
+    "write",  # a checkpoint serialized and written
+})
+
+#: A stage name's parts seen so far in the process: a part a stage instance
+#: does not charge is recorded as 0, so after its first sample a part has
+#: one sample a stage instance and lines up with ``stage_ms.<name>``.
+_parts_seen: dict[str, set] = {}
 
 
-def _open_stages() -> list:
-    stages = getattr(_tls, "stages", None)
-    if stages is None:
-        stages = _tls.stages = []
-    return stages
+def _open(kind: str) -> list:
+    """The calling thread's open ``stages`` or ``frames``, innermost last."""
+    found = getattr(_tls, kind, None)
+    if found is None:
+        found = []
+        setattr(_tls, kind, found)
+    return found
+
+
+def _close(kind: str, item) -> list:
+    """Take ``item`` off its thread's open ``kind``; returns what stays open."""
+    found = _open(kind)
+    if found and found[-1] is item:
+        found.pop()
+    elif item in found:  # exited out of order — heal, as Span does
+        found.remove(item)
+    return found
+
+
+def _close_frame(frame, dur: float) -> None:
+    """Take ``frame`` off its thread's open frames and add its duration to
+    the frame it lay in."""
+    frames = _close("frames", frame)
+    if frames:
+        frames[-1].covered_us += dur
 
 
 class Stage:
@@ -439,81 +494,119 @@ class Stage:
     * ``stage_wait_ms.<name>`` — time beneath it blocked on the device
       (:func:`wait` and :func:`d2h`);
     * ``stage_h2d_ms.<name>`` / ``stage_h2d_mb.<name>`` — time and bytes of
-      the host-to-device copies beneath it (:func:`h2d`).
+      the host-to-device copies beneath it (:func:`h2d`);
+    * ``stage_host_ms.<name>.<section>`` / ``stage_host_n.<name>.<section>``
+      — self time and occurrences of each named section of host work
+      beneath it (:func:`host`), and ``stage_host_ms.<name>.other``: the
+      self time nothing above covers;
+    * ``stage_max_ms.<name>.<part>`` — the longest single occurrence of a
+      part (a section, ``wait`` or ``h2d``): one long wait and many slow
+      ones have the same sum and not the same maximum.
 
-    Waits and copies go to the innermost open stage of their thread only,
-    like self time.  A stage's name occurs once a fit, so the last *n*
-    samples of a name are the last *n* fits."""
+    A charge is its self time (what a charge or a stage nested in it covers
+    is taken out), so self = wait + h2d + the sections + ``other``.  Charges
+    go to the innermost open stage of their thread only, like self time.  A
+    stage's name occurs once a fit, so the last *n* samples of a name are
+    the last *n* fits."""
 
-    __slots__ = (
-        "name", "_span", "_t0", "nested_us", "wait_us", "h2d_us", "h2d_bytes"
-    )
+    __slots__ = ("name", "_span", "_t0", "nested_us", "covered_us", "h2d_bytes", "parts")
 
     def __init__(self, name: str):
         self.name = name
         self._span = _NULL
         self._t0 = 0.0
         self.nested_us = 0.0
-        self.wait_us = 0.0
-        self.h2d_us = 0.0
+        self.covered_us = 0.0  # a frame's slot; a stage's ``other`` needs none
         self.h2d_bytes = 0
+        self.parts: dict = {}  # part -> [summed self us, occurrences, longest us]
 
     def __enter__(self):
         self._span = span(self.name, cat="stage")
         self._span.__enter__()
-        _open_stages().append(self)
+        _open("stages").append(self)
+        _open("frames").append(self)
         self._t0 = _now_us()
         return self
 
     def __exit__(self, etype, exc, tb):
         dur = max(_now_us() - self._t0, 0.0)
-        stages = _open_stages()
-        if stages and stages[-1] is self:
-            stages.pop()
-        elif self in stages:  # exited out of order — heal, as Span does
-            stages.remove(self)
+        stages = _close("stages", self)
         if stages:
             stages[-1].nested_us += dur
-        sums = {
-            "stage_ms": (dur - self.nested_us) / 1e3,
-            "stage_wait_ms": self.wait_us / 1e3,
-            "stage_h2d_ms": self.h2d_us / 1e3,
-            "stage_h2d_mb": self.h2d_bytes / 1e6,
+        _close_frame(self, dur)
+        self_us = dur - self.nested_us
+        parts = self.parts
+        seen = _parts_seen.get(self.name)
+        if seen is None:
+            seen = _parts_seen.setdefault(self.name, {"wait", "h2d"})
+        seen.update(parts)
+        values = {
+            f"stage_ms.{self.name}": self_us / 1e3,
+            f"stage_h2d_mb.{self.name}": self.h2d_bytes / 1e6,
         }
-        self._span.set(**{k: round(v, 3) for k, v in sums.items()})
-        self._span.__exit__(etype, exc, tb)
-        metrics.observe_all(
-            {f"{kind}.{self.name}": value for kind, value in sums.items()}
+        host_ms = {}
+        other_us = self_us
+        for part in list(seen):
+            total_us, n, longest_us = parts.get(part, (0.0, 0, 0.0))
+            other_us -= total_us
+            values[f"stage_max_ms.{self.name}.{part}"] = longest_us / 1e3
+            if part in ("wait", "h2d"):
+                values[f"stage_{part}_ms.{self.name}"] = total_us / 1e3
+            else:
+                values[f"stage_host_ms.{self.name}.{part}"] = total_us / 1e3
+                values[f"stage_host_n.{self.name}.{part}"] = n
+                if n:
+                    host_ms[part] = round(total_us / 1e3, 3)
+        values[f"stage_host_ms.{self.name}.other"] = other_us / 1e3
+        self._span.set(
+            stage_ms=round(self_us / 1e3, 3),
+            stage_wait_ms=round(values[f"stage_wait_ms.{self.name}"], 3),
+            stage_h2d_ms=round(values[f"stage_h2d_ms.{self.name}"], 3),
+            stage_h2d_mb=round(self.h2d_bytes / 1e6, 3),
+            host_ms=host_ms,
         )
+        self._span.__exit__(etype, exc, tb)
+        metrics.observe_all(values)
         return False
 
 
 class _Charged:
-    """A span whose duration (and bytes) is also charged to the innermost
-    open stage of the thread: the clock is read here, not taken from the
-    span, which is a no-op when tracing and the flight ring are off."""
+    """A span whose self time (and bytes) is also charged to the innermost
+    open stage of the thread, as ``part``: ``wait``, ``h2d`` or one of
+    :data:`SECTIONS`.  The clock is read here, not taken from the span,
+    which is a no-op when tracing and the flight ring are off."""
 
-    __slots__ = ("_span", "_bytes", "_t0")
+    __slots__ = ("_span", "_part", "_bytes", "_t0", "covered_us")
 
-    def __init__(self, sp, nbytes: int | None = None):
+    def __init__(self, sp, part: str = "wait", nbytes: int = 0):
         self._span = sp
-        self._bytes = nbytes  # None: a wait; a number: a copy to the device
+        self._part = part
+        self._bytes = nbytes
         self._t0 = 0.0
+        self.covered_us = 0.0  # what the frames nested in this one took
 
     def __enter__(self):
+        _open("frames").append(self)
         self._t0 = _now_us()
         return self._span.__enter__()
 
     def __exit__(self, etype, exc, tb):
         self._span.__exit__(etype, exc, tb)
+        dur = max(_now_us() - self._t0, 0.0)
+        _close_frame(self, dur)
         stages = getattr(_tls, "stages", None)
         if stages:
-            dur = max(_now_us() - self._t0, 0.0)
-            if self._bytes is None:
-                stages[-1].wait_us += dur
+            stage = stages[-1]
+            own = max(dur - self.covered_us, 0.0)
+            got = stage.parts.get(self._part)
+            if got is None:
+                stage.parts[self._part] = [own, 1, own]
             else:
-                stages[-1].h2d_us += dur
-                stages[-1].h2d_bytes += self._bytes
+                got[0] += own
+                got[1] += 1
+                if own > got[2]:
+                    got[2] = own
+            stage.h2d_bytes += self._bytes
         return False
 
 
@@ -540,7 +633,19 @@ def h2d(name: str, nbytes: int, **attrs):
     """Span (cat ``h2d``, an :func:`io_span`) around a copy of ``nbytes``
     from the host to the device; time and bytes are added to the open
     stage's ``stage_h2d_ms`` / ``stage_h2d_mb``."""
-    return _Charged(io_span(name, nbytes, cat="h2d", **attrs), int(nbytes))
+    return _Charged(io_span(name, nbytes, cat="h2d", **attrs), "h2d", int(nbytes))
+
+
+def host(section: str, name: str | None = None, **attrs):
+    """Span (cat ``host``, named by its ``section``, one of :data:`SECTIONS`)
+    around host work that is neither a wait nor a copy; its self time goes
+    to the open stage's ``stage_host_ms.<stage>.<section>``.  ``name`` says
+    which site of the section this is (the span's ``site``)."""
+    if section not in SECTIONS:
+        raise ValueError(f"{section!r} is no host section: {sorted(SECTIONS)}")
+    if name is not None:
+        attrs["site"] = name
+    return _Charged(span(section, cat="host", **attrs), section)
 
 
 def instant(name: str, **attrs) -> None:

@@ -178,9 +178,10 @@ def confusion_matrix(predictions, actuals, num_classes: int):
     if isinstance(actuals, np.ndarray):  # the labels come from the host
         with trace.h2d("labels", actuals.nbytes):
             actuals = jnp.asarray(actuals)
-    return _confusion_counts(
-        jnp.asarray(predictions), jnp.asarray(actuals), num_classes
-    )
+    with trace.host("dispatch", "confusion_counts"):
+        return _confusion_counts(
+            jnp.asarray(predictions), jnp.asarray(actuals), num_classes
+        )
 
 
 class MulticlassClassifierEvaluator:

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import trace
 from ..core.pipeline import FunctionNode, Transformer, node
 
 
@@ -23,9 +24,10 @@ class ClassLabelIndicatorsFromIntLabels(Transformer):
         self.num_classes = num_classes
 
     def __call__(self, labels):
-        labels = jnp.asarray(labels)
-        eye = jnp.eye(self.num_classes, dtype=jnp.float32)
-        return 2.0 * eye[labels] - 1.0
+        with trace.host("dispatch", "label_indicators"):  # four eager programs
+            labels = jnp.asarray(labels)
+            eye = jnp.eye(self.num_classes, dtype=jnp.float32)
+            return 2.0 * eye[labels] - 1.0
 
 
 @node(data_fields=(), meta_fields=("num_classes",))

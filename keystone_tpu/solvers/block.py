@@ -128,9 +128,10 @@ class BlockLinearMapper(Transformer):
         for i, (blk, x, scaler) in enumerate(
             zip(self._blocks_of(batch_or_blocks), self.xs, self.feature_scalers)
         ):
-            # one span a block: its one step program, then the evaluator's
-            # round trip to the host (its ``wait`` and ``d2h`` nest here)
-            with trace.span("block", cat="eval", block=i):
+            # one section a block: its one step program, then the evaluator's
+            # round trip to the host (its ``wait`` and ``d2h`` nest here and
+            # are charged as themselves)
+            with trace.host("dispatch", "block", block=i):
                 running, with_intercept = _block_step(
                     running, blk, x, scaler, self.b
                 )
@@ -876,9 +877,10 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 "does not run under a mesh — fit without a mesh or without "
                 "checkpointing"
             )
-        x, widths = _blocked_design_matrix(
-            features, self.block_size, num_features
-        )
+        with trace.host("place", "design_matrix"):  # the eager column pad
+            x, widths = _blocked_design_matrix(
+                features, self.block_size, num_features
+            )
         # Conditioning monitor (ISSUE 15): per-block κ estimates riding
         # the blocked design matrix this fit already formed (row-capped,
         # so the probe never re-uploads a host-staged matrix).  One flag
@@ -958,10 +960,11 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         all_cond = (cond_rows or []) + list(solve_cond)
         if all_cond and self.last_fit_report is not None:
             self.last_fit_report.conditioning = all_cond
-        model_list = [models[i, :w] for i, w in enumerate(widths)]
-        feature_scalers = [
-            StandardScalerModel(means[i, :w]) for i, w in enumerate(widths)
-        ]
+        with trace.host("finish", "model_blocks"):  # two eager slices a block
+            model_list = [models[i, :w] for i, w in enumerate(widths)]
+            feature_scalers = [
+                StandardScalerModel(means[i, :w]) for i, w in enumerate(widths)
+            ]
         return BlockLinearMapper(
             model_list, self.block_size, label_mean, feature_scalers
         )
@@ -991,7 +994,8 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         )
         xdt = jax.dtypes.canonicalize_dtype(x.dtype)
         it = np.dtype(dtype).itemsize
-        lam_arr = jnp.asarray(self.lam, dtype)
+        with trace.host("place", "lam"):
+            lam_arr = jnp.asarray(self.lam, dtype)
 
         report = kmem.FitReport(label="bcd_fit")
         self.last_fit_report = report
@@ -1098,42 +1102,43 @@ class BlockLeastSquaresEstimator(LabelEstimator):
 
             def run(plan):
                 report.mesh_shape = dict(m.shape)
-                if spec_t is None or lspec == "data@dim0":
-                    (x_p, y_p), nv = pad_shard_inputs(m, nvalid0, x, labels)
-                    # Class columns shard over the model axis; zero label
-                    # columns stay zero through every BCD update — exact
-                    # pad.  Rows: a candidate whose data axis is not the
-                    # caller's pads a caller-padded design matrix and the
-                    # unpadded labels to different counts; labels take the
-                    # design matrix's (zero rows, masked by nv).
-                    row_pad = int(jnp.shape(x_p)[0]) - int(jnp.shape(y_p)[0])
-                    col_pad = (-int(jnp.shape(y_p)[1])) % m_sz
-                    if row_pad or col_pad:
-                        y_p = jnp.pad(y_p, ((0, row_pad), (0, col_pad)))
-                    nv = nv if nv is not None else int(jnp.shape(y_p)[0])
-                else:
-                    # Non-default labels layout: pad rows to the sharded
-                    # design matrix's count and columns to a model-axis
-                    # multiple, then PLACE per the chosen spec — the
-                    # program's constraint and this placement read the
-                    # same spec string, so they cannot drift.
-                    (x_p,), nv = pad_shard_inputs(m, nvalid0, x)
-                    nv = nv if nv is not None else n0
-                    row_pad = int(jnp.shape(x_p)[0]) - n0
-                    col_pad = (-k) % m_sz
-                    if isinstance(labels, jax.Array):
-                        y_p = (
-                            jnp.pad(labels, ((0, row_pad), (0, col_pad)))
-                            if row_pad or col_pad else labels
-                        )
+                with trace.host("place", "operands"):
+                    if spec_t is None or lspec == "data@dim0":
+                        (x_p, y_p), nv = pad_shard_inputs(m, nvalid0, x, labels)
+                        # Class columns shard over the model axis; zero label
+                        # columns stay zero through every BCD update — exact
+                        # pad.  Rows: a candidate whose data axis is not the
+                        # caller's pads a caller-padded design matrix and the
+                        # unpadded labels to different counts; labels take the
+                        # design matrix's (zero rows, masked by nv).
+                        row_pad = int(jnp.shape(x_p)[0]) - int(jnp.shape(y_p)[0])
+                        col_pad = (-int(jnp.shape(y_p)[1])) % m_sz
+                        if row_pad or col_pad:
+                            y_p = jnp.pad(y_p, ((0, row_pad), (0, col_pad)))
+                        nv = nv if nv is not None else int(jnp.shape(y_p)[0])
                     else:
-                        y_p = np.pad(
-                            np.asarray(labels),
-                            ((0, row_pad), (0, col_pad)),
+                        # Non-default labels layout: pad rows to the sharded
+                        # design matrix's count and columns to a model-axis
+                        # multiple, then PLACE per the chosen spec — the
+                        # program's constraint and this placement read the
+                        # same spec string, so they cannot drift.
+                        (x_p,), nv = pad_shard_inputs(m, nvalid0, x)
+                        nv = nv if nv is not None else n0
+                        row_pad = int(jnp.shape(x_p)[0]) - n0
+                        col_pad = (-k) % m_sz
+                        if isinstance(labels, jax.Array):
+                            y_p = (
+                                jnp.pad(labels, ((0, row_pad), (0, col_pad)))
+                                if row_pad or col_pad else labels
+                            )
+                        else:
+                            y_p = np.pad(
+                                np.asarray(labels),
+                                ((0, row_pad), (0, col_pad)),
+                            )
+                        y_p = jax.device_put(
+                            jnp.asarray(y_p), autoshard.spec_sharding(lspec, m, 2)
                         )
-                    y_p = jax.device_put(
-                        jnp.asarray(y_p), autoshard.spec_sharding(lspec, m, 2)
-                    )
                 # what the program sums over the data axis, from the
                 # shapes: a gram and num_iter cross terms a block, the
                 # block means' gemv and the label mean
@@ -1186,47 +1191,48 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             inner_chosen.append(report.chosen)
             return out
 
-        cands = [mesh_tier(mesh, 0, True)]
-        rm = reduced_mesh(mesh)
-        if rm is not None:
-            cands.append(mesh_tier(rm, 1, True))
-        # The searched candidate set: every remaining (data, model)
-        # factorization of the SAME devices, then (KEYSTONE_AUTOSHARD_SPECS)
-        # the per-operand SPEC assignments of every mesh shape — e.g.
-        # model-axis-sharded label columns, or fully-replicated model
-        # blocks — each an executable layout, ranked by the cost model but
-        # never promoted past the hand rungs on an untrained prior.  Only
-        # enumerated when the search will run — a hand-ladder walk would
-        # discard them, and each costs a jax Mesh construction.
-        if autoshard.will_search(plan_arg):
-            hand_shapes = {
-                mesh_desc(c_mesh) for c_mesh in (mesh, rm) if c_mesh
-            }
-            searched_meshes = [mesh] + ([rm] if rm is not None else [])
-            for extra in enumerate_meshes(list(mesh.devices.flat)):
-                if mesh_desc(extra) not in hand_shapes:
-                    searched_meshes.append(extra)
-                    cands.append(mesh_tier(extra, len(cands), False))
-            if autoshard.specs_enabled():
-                for sm in searched_meshes:
-                    for sp in _bcd_spec_variants(sm):
-                        cands.append(
-                            mesh_tier(sm, len(cands), False, specs=sp)
-                        )
-        cands.append(autoshard.Candidate(
-            "single_device", "single_device", plan_single, run_single,
-            hints={
-                # Host pull + refit on one chip: the whole design matrix
-                # crosses back over PCIe and nothing divides — the floor's
-                # predicted cost is honest about why it is the floor.
-                "arg_bytes": itx * n0 * nb * bs + it * n0 * k,
-                "h2d_bytes": itx * n0 * nb * bs + it * n0 * k,
-                "flops": 2.0 * n0 * bs * bs * nb
-                + self.num_iter * 4.0 * n0 * bs * k * nb,
-                "dispatches": 3,
-            },
-            prior_rank=len(cands), floor=True,
-        ))
+        with trace.host("search", "enumerate"):
+            cands = [mesh_tier(mesh, 0, True)]
+            rm = reduced_mesh(mesh)
+            if rm is not None:
+                cands.append(mesh_tier(rm, 1, True))
+            # The searched candidate set: every remaining (data, model)
+            # factorization of the SAME devices, then (KEYSTONE_AUTOSHARD_SPECS)
+            # the per-operand SPEC assignments of every mesh shape — e.g.
+            # model-axis-sharded label columns, or fully-replicated model
+            # blocks — each an executable layout, ranked by the cost model but
+            # never promoted past the hand rungs on an untrained prior.  Only
+            # enumerated when the search will run — a hand-ladder walk would
+            # discard them, and each costs a jax Mesh construction.
+            if autoshard.will_search(plan_arg):
+                hand_shapes = {
+                    mesh_desc(c_mesh) for c_mesh in (mesh, rm) if c_mesh
+                }
+                searched_meshes = [mesh] + ([rm] if rm is not None else [])
+                for extra in enumerate_meshes(list(mesh.devices.flat)):
+                    if mesh_desc(extra) not in hand_shapes:
+                        searched_meshes.append(extra)
+                        cands.append(mesh_tier(extra, len(cands), False))
+                if autoshard.specs_enabled():
+                    for sm in searched_meshes:
+                        for sp in _bcd_spec_variants(sm):
+                            cands.append(
+                                mesh_tier(sm, len(cands), False, specs=sp)
+                            )
+            cands.append(autoshard.Candidate(
+                "single_device", "single_device", plan_single, run_single,
+                hints={
+                    # Host pull + refit on one chip: the whole design matrix
+                    # crosses back over PCIe and nothing divides — the floor's
+                    # predicted cost is honest about why it is the floor.
+                    "arg_bytes": itx * n0 * nb * bs + it * n0 * k,
+                    "h2d_bytes": itx * n0 * nb * bs + it * n0 * k,
+                    "flops": 2.0 * n0 * bs * bs * nb
+                    + self.num_iter * 4.0 * n0 * bs * k * nb,
+                    "dispatches": 3,
+                },
+                prior_rank=len(cands), floor=True,
+            ))
         # The solver declares its fit as a profiler PHASE (core.profiler):
         # the HBM watermark sampler attributes this solve's high-water
         # mark to "bcd_fit", separable from serving/ingest residency in
@@ -1264,14 +1270,16 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         dtype = jax.dtypes.canonicalize_dtype(labels.dtype)
         xdt = jax.dtypes.canonicalize_dtype(x.dtype)
         it = np.dtype(dtype).itemsize
-        budget = kmem.hbm_budget()
+        with trace.host("plan", "budget"):  # asks the device for its free bytes
+            budget = kmem.hbm_budget()
 
         donate_x = donate if donate is not None else _design_matrix_owned(x, features)
         donate_y = donate if donate is not None else not isinstance(labels, jax.Array)
         dn = tuple(i for i, d in ((0, donate_x), (1, donate_y)) if d)
 
-        lam_arr = jnp.asarray(self.lam, dtype)
-        nv_arr = jnp.asarray(nvalid, jnp.int32)
+        with trace.host("place", "lam"):
+            lam_arr = jnp.asarray(self.lam, dtype)
+            nv_arr = jnp.asarray(nvalid, jnp.int32)
         sds = jax.ShapeDtypeStruct
         x_s, y_s = sds((n, nb * bs), xdt), sds((n, k), dtype)
         lam_s, i32_s = sds((), dtype), sds((), jnp.int32)
@@ -1340,9 +1348,10 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             return jnp.asarray(labels)
 
         def run_fused(plan):
+            with trace.host("place", "operands"):
+                x_dev, y_dev = jnp.asarray(get_x()), get_y_dev()
             return _execute_fused_bcd(
-                plan, dn, jnp.asarray(get_x()), get_y_dev(), lam_arr, nv_arr,
-                self.num_iter, widths,
+                plan, dn, x_dev, y_dev, lam_arr, nv_arr, self.num_iter, widths,
             )
 
         def run_stepwise(plan):

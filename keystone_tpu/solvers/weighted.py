@@ -647,7 +647,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         # Class of each valid row: device argmax for device labels, so only
         # the [n] int vector crosses to host (round 2 pulled the whole
         # design matrix); plain numpy argmax for host labels.
-        with trace.span("bwls.class_sort", cat="solve", n=n, classes=n_classes):
+        with trace.host("sort", "bwls.class_sort", n=n, classes=n_classes):
             if isinstance(labels, jax.Array):
                 class_idx = np.asarray(jnp.argmax(labels[:n], axis=1))
             else:
@@ -667,9 +667,10 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         starts_np = np.concatenate([[0], np.cumsum(counts_np)[:-1]])
         n_max = int(counts_np.max())
 
-        x, widths = _blocked_design_matrix(
-            features, self.block_size, num_features
-        )
+        with trace.host("place", "design_matrix"):
+            x, widths = _blocked_design_matrix(
+                features, self.block_size, num_features
+            )
         # Conditioning monitor (ISSUE 15): per-block κ estimates on the
         # blocked design matrix this fit already formed (row-capped probe;
         # one flag check when the observatory is off).
@@ -846,8 +847,10 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                 n_classes, widths, dtype, donate, plan_arg=plan,
             )
         else:
+            with trace.host("place", "solve_context"):
+                solve_ctx = prep(None, labels)
             models_st, b = self._fit_ladder(
-                features, x, labels, prep(None, labels), order, n, n_max,
+                features, x, labels, solve_ctx, order, n, n_max,
                 n_classes, widths, dtype, donate, plan_arg=plan,
             )
         if cond_rows and self.last_fit_report is not None:
@@ -864,7 +867,8 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             slab_bytes=np.dtype(dtype).itemsize * chunk * n_max * bs,
             systems_bytes=np.dtype(dtype).itemsize * chunk * bs * bs,
         )
-        model_list = [models_st[i, :wd] for i, wd in enumerate(widths)]
+        with trace.host("finish", "model_blocks"):
+            model_list = [models_st[i, :wd] for i, wd in enumerate(widths)]
         return BlockLinearMapper(model_list, self.block_size, b)
 
     def _fit_mesh_ladder(
@@ -1145,12 +1149,14 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         d_tot = nb * bs
         it = np.dtype(dtype).itemsize
         xdt = jax.dtypes.canonicalize_dtype(x.dtype)
-        budget = kmem.hbm_budget()
+        with trace.host("plan", "budget"):
+            budget = kmem.hbm_budget()
         donate_input = bool(donate)
 
-        lam_arr = jnp.asarray(self.lam, dtype)
-        w_arr = jnp.asarray(self.mixture_weight, dtype)
-        nv_arr = jnp.asarray(n, jnp.int32)
+        with trace.host("place", "lam"):
+            lam_arr = jnp.asarray(self.lam, dtype)
+            w_arr = jnp.asarray(self.mixture_weight, dtype)
+            nv_arr = jnp.asarray(n, jnp.int32)
         statics = (self.num_iter, n_max, chunk, n_classes, widths, None, None)
 
         sds = jax.ShapeDtypeStruct
@@ -1249,7 +1255,8 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             return xs, ls
 
         def run_fused(plan):
-            xs, ls = sorted_device_inputs()
+            with trace.host("place", "operands"):
+                xs, ls = sorted_device_inputs()
             args = (xs, ls, valid_d, seg_ids, starts, counts, counts_f,
                     joint_label_mean, nv_arr, lam_arr, w_arr)
             del xs, ls  # the args tuple holds the only refs; donation eats them

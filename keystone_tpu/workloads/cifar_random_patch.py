@@ -255,11 +255,12 @@ def _featurize_row_sharded(fn, images: np.ndarray, chunk: int, mesh) -> jnp.ndar
     outs = []
     for lo in range(0, per_dev, per_chunk):
         hi = min(lo + per_chunk, per_dev)
-        parts = [
-            _pad_rows(images[k * per_dev + lo : k * per_dev + hi], per_chunk)
-            for k in range(d)
-        ]
-        nbytes = sum(p.nbytes for p in parts)
+        with trace.host("stack", "chunk"):
+            parts = [
+                _pad_rows(images[k * per_dev + lo : k * per_dev + hi], per_chunk)
+                for k in range(d)
+            ]
+            nbytes = sum(p.nbytes for p in parts)
         with trace.h2d("chunk", nbytes, shards=d):
             shards = jax.device_put(
                 [p for p in parts for _ in range(copies)], devices
@@ -267,9 +268,9 @@ def _featurize_row_sharded(fn, images: np.ndarray, chunk: int, mesh) -> jnp.ndar
             dev_block = jax.make_array_from_single_device_arrays(
                 (d * per_chunk,) + images.shape[1:], sharding, shards
             )
-        with trace.span("chunk", cat="dispatch"):
+        with trace.host("dispatch", "chunk"):
             outs.append(fn(dev_block))
-    with trace.span("chunks", cat="concat", chunks=len(outs)):
+    with trace.host("concat", "chunks", chunks=len(outs)):
         tail = per_dev - (len(outs) - 1) * per_chunk  # the last chunk's true rows a chip
         return _local_concat(mesh, tail)(*outs)
 
@@ -290,18 +291,19 @@ def featurize_chunked(fn, images: np.ndarray, chunk: int, mesh=None) -> jnp.ndar
         sharding = row_sharding(mesh)
     outs = []
     for i in range(0, n, chunk):
-        block = _pad_rows(images[i : i + chunk], chunk)
-        pad = chunk - min(chunk, n - i)
+        with trace.host("stack", "chunk"):
+            block = _pad_rows(images[i : i + chunk], chunk)
+            pad = chunk - min(chunk, n - i)
         with trace.h2d("chunk", block.nbytes):
             # host to the chunk's shards in one copy, not by way of chip 0
             dev_block = (
                 jnp.asarray(block) if sharding is None
                 else jax.device_put(block, sharding)
             )
-        with trace.span("chunk", cat="dispatch"):
+        with trace.host("dispatch", "chunk"):
             feats = fn(dev_block)
             outs.append(feats[: chunk - pad] if pad else feats)
-    with trace.span("chunks", cat="concat", chunks=len(outs)):
+    with trace.host("concat", "chunks", chunks=len(outs)):
         return jnp.concatenate(outs, axis=0)
 
 
@@ -400,7 +402,8 @@ def _pad_to_chunk(batch, chunk: int):
             return jnp.pad(
                 batch.dev(), ((0, pad), (0, 0), (0, 0), (0, 0))
             )
-        padded = np.pad(batch.host, ((0, pad), (0, 0), (0, 0), (0, 0)))
+        with trace.host("stack", "chunk"):
+            padded = np.pad(batch.host, ((0, pad), (0, 0), (0, 0), (0, 0)))
         with trace.h2d("chunk", padded.nbytes):
             return jnp.asarray(padded)
     return batch.dev()
@@ -470,16 +473,19 @@ def _fit_and_score(conf: RandomCifarConfig, train, test, mesh) -> dict:
     # the same chunk shape AND sharding the real featurize pass will use.
     with stage_timer("warm_featurizer"):
         warm_chunk = conf.featurize_chunk
-        if mesh is None:
-            warm = jnp.zeros((warm_chunk,) + train.images.shape[1:], jnp.float32)
-        else:
-            d = mesh.shape[DATA_AXIS]
-            warm_chunk = -(-warm_chunk // d) * d
-            warm = jax.device_put(
-                np.zeros((warm_chunk,) + train.images.shape[1:], np.float32),
-                row_sharding(mesh),
-            )
-        trace.wait(feat_fn(warm), "warm_featurizer")
+        with trace.host("dispatch", "warm_chunk"):
+            if mesh is None:
+                warm = jnp.zeros((warm_chunk,) + train.images.shape[1:], jnp.float32)
+            else:
+                d = mesh.shape[DATA_AXIS]
+                warm_chunk = -(-warm_chunk // d) * d
+                warm = jax.device_put(
+                    np.zeros((warm_chunk,) + train.images.shape[1:], np.float32),
+                    row_sharding(mesh),
+                )
+            warmed = feat_fn(warm)
+        trace.wait(warmed, "warm_featurizer")
+        del warmed  # a chunk's features: not to be held through the fit
 
     cache_plan = None
     if conf.auto_cache:
@@ -525,7 +531,7 @@ def _fit_and_score(conf: RandomCifarConfig, train, test, mesh) -> dict:
             trace.wait(train_conv, "featurize")
 
         # StandardScaler fit on train features (thenEstimator, reference :58)
-        with stage_timer("scale"):
+        with stage_timer("scale"), trace.host("dispatch", "scaler"):
             scaler = StandardScaler().fit(train_conv)
             train_features = scaler(train_conv)
 
@@ -548,7 +554,8 @@ def _fit_and_score(conf: RandomCifarConfig, train, test, mesh) -> dict:
             assert_all_finite(model, "cifar random-patch model")
 
     def predict(features):
-        return MaxClassifier()(model(features))
+        with trace.host("dispatch", "predict"):
+            return MaxClassifier()(model(features))
 
     with stage_timer("eval"):
         train_pred = predict(train_features)

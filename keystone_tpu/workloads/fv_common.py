@@ -66,16 +66,17 @@ def draw_columns(totals: dict, num_samples: int, seed: int = 42) -> dict:
     without replacement (the reference samples per image,
     Sampling.scala:12-22); a set no larger than ``num_samples`` is taken
     whole."""
-    rng = np.random.default_rng(seed)
-    grand_total = sum(n * c for n, c in totals.values())
-    draws = {}
-    for shape, (n, c) in totals.items():
-        total = n * c
-        if grand_total <= num_samples:
-            draws[shape] = np.arange(total)
-        else:
-            quota = min(total, max(1, int(num_samples * total / grand_total)))
-            draws[shape] = np.sort(rng.choice(total, quota, replace=False))
+    with trace.host("draw", "columns", samples=num_samples):
+        rng = np.random.default_rng(seed)
+        grand_total = sum(n * c for n, c in totals.values())
+        draws = {}
+        for shape, (n, c) in totals.items():
+            total = n * c
+            if grand_total <= num_samples:
+                draws[shape] = np.arange(total)
+            else:
+                quota = min(total, max(1, int(num_samples * total / grand_total)))
+                draws[shape] = np.sort(rng.choice(total, quota, replace=False))
     return draws
 
 
@@ -361,12 +362,13 @@ def _chunks(plan: ChunkPlan, images: list, mesh):
         c = plan.chunk[shape]
         for start in range(0, len(idx), c):
             sel = idx[start : start + c]
-            block = np.stack([images[i] for i in sel])
-            if len(sel) < c:
-                block = np.pad(
-                    block, ((0, c - len(sel)), (0, 0), (0, 0), (0, 0)), mode="edge"
-                )
-            flat = block.reshape(c, -1)
+            with trace.host("stack", "chunk"):
+                block = np.stack([images[i] for i in sel])
+                if len(sel) < c:
+                    block = np.pad(
+                        block, ((0, c - len(sel)), (0, 0), (0, 0), (0, 0)), mode="edge"
+                    )
+                flat = block.reshape(c, -1)
             with trace.h2d("chunk", flat.nbytes):
                 dev = shard_batch(flat, mesh)
             trace.metrics.inc(f"fv.chunks.{shape[0]}x{shape[1]}")
@@ -416,25 +418,27 @@ def sample_descriptor_columns(
         return [[] for _ in branches] if listed else []
     cap = {}  # (branch, shape) -> per set, the most columns a chunk gathers
     cuts = {}  # (branch, shape) -> per set, the draw's boundaries at chunk starts
-    for b, sets in enumerate(draws):
-        for shape, idx in plan.index.items():
-            c, cols = plan.chunk[shape], plan.cols[shape][b]
-            edges = np.arange(0, len(idx) + c, c) * cols
-            cuts[b, shape] = [np.searchsorted(d[shape], edges) for d in sets]
-            cap[b, shape] = [
-                max(SAMPLE_CAP_STEP, -(-int(np.max(np.diff(cut), initial=0)) // SAMPLE_CAP_STEP) * SAMPLE_CAP_STEP)
-                for cut in cuts[b, shape]
-            ]
+    with trace.host("draw", "cuts"):
+        for b, sets in enumerate(draws):
+            for shape, idx in plan.index.items():
+                c, cols = plan.chunk[shape], plan.cols[shape][b]
+                edges = np.arange(0, len(idx) + c, c) * cols
+                cuts[b, shape] = [np.searchsorted(d[shape], edges) for d in sets]
+                cap[b, shape] = [
+                    max(SAMPLE_CAP_STEP, -(-int(np.max(np.diff(cut), initial=0)) // SAMPLE_CAP_STEP) * SAMPLE_CAP_STEP)
+                    for cut in cuts[b, shape]
+                ]
     parts = [[] for _ in branches]
     positions = [[[] for _ in sets] for sets in draws]
     offset = [[0] * len(sets) for sets in draws]
     seen: dict = {}
     for shape, _valid, dev, image_shape in _chunks(plan, images, mesh):
         k = seen[shape] = seen.get(shape, -1) + 1
-        with trace.span("chunk", cat="dispatch", bucket=f"{shape[0]}x{shape[1]}"):
-            for b, (branch, sets) in enumerate(zip(branches, draws)):
-                if not sets:
-                    continue
+        bucket = f"{shape[0]}x{shape[1]}"
+        for b, (branch, sets) in enumerate(zip(branches, draws)):
+            if not sets:
+                continue
+            with trace.host("draw", "chunk", bucket=bucket, branch=branch.name):
                 cols = plan.cols[shape][b]
                 first = k * plan.chunk[shape] * cols
                 im, col = [], []
@@ -446,6 +450,7 @@ def sample_descriptor_columns(
                     col.append(at[1])
                     positions[b][s].append(offset[b][s] + np.arange(hi - lo))
                     offset[b][s] += cap[b, shape][s]
+            with trace.host("dispatch", "chunk", bucket=bucket, branch=branch.name):
                 descs = branch.describe(branch.node, dev, image_shape=image_shape)
                 parts[b].append(_sample_chunk(descs, im, col))
         last = [p[-1 - RUN_AHEAD] for p in parts if len(p) > RUN_AHEAD]
@@ -456,11 +461,11 @@ def sample_descriptor_columns(
         if not draws[b]:
             out.append([])
             continue
-        at = [np.concatenate(p).astype(np.int32) for p in positions[b]]
-        drawn = int(sum(len(p) for p in at))
-        trace.metrics.inc("fv.descriptors_sampled", drawn)
-        trace.metrics.inc(f"fv.descriptors_sampled.{branch.name}", drawn)
-        with trace.span("samples", cat="concat", chunks=len(parts[b]), branch=branch.name):
+        with trace.host("concat", "samples", chunks=len(parts[b]), branch=branch.name):
+            at = [np.concatenate(p).astype(np.int32) for p in positions[b]]
+            drawn = int(sum(len(p) for p in at))
+            trace.metrics.inc("fv.descriptors_sampled", drawn)
+            trace.metrics.inc(f"fv.descriptors_sampled.{branch.name}", drawn)
             out.append(_gather_samples(parts[b], at))
         parts[b] = None  # a branch's padded gathers go as its samples are made
     return out if listed else out[0]
@@ -476,13 +481,13 @@ def featurize_chunks(plan: ChunkPlan, images: list, nodes, pca, gmm, mesh=None):
     chains = tuple(zip(pca, gmm)) if listed else ((pca, gmm),)
     outs = []
     for shape, valid, dev, image_shape in _chunks(plan, images, mesh):
-        with trace.span("chunk", cat="dispatch", bucket=f"{shape[0]}x{shape[1]}"):
+        with trace.host("dispatch", "chunk", bucket=f"{shape[0]}x{shape[1]}"):
             descs = tuple(b.describe(b.node, dev, image_shape=image_shape) for b in branches)
             feats = _encode_chunk(chains, descs)
             outs.append(feats if valid == feats.shape[0] else feats[:valid])
         if len(outs) > RUN_AHEAD:
             trace.wait(outs[-1 - RUN_AHEAD], "chunk")
-    with trace.span("chunks", cat="concat", chunks=len(outs)):
+    with trace.host("concat", "chunks", chunks=len(outs)):
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
 

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from ..core import trace
 from ..core.logging import Logging, configure_logging, stage_timer
 from ..core.memory import log_fit_report
+from ..core.pipeline import Pipeline
 from ..evaluation.multiclass import MulticlassClassifierEvaluator
 from ..loaders.timit import TIMIT_DIMENSION, TIMIT_NUM_CLASSES, TimitFeaturesData, timit_features_loader
 from ..ops.stats import CosineRandomFeatures, StandardScaler
@@ -53,6 +54,23 @@ class _Log(Logging):
     pass
 
 
+class FeaturizerBlock(Pipeline):
+    """One block's chain, cosine features then their scaler.  A call runs
+    its handful of small programs eagerly; its time is the open stage's
+    host section ``dispatch``."""
+
+    def __call__(self, batch):
+        with trace.host("dispatch", "featurizer_block"):
+            return super().__call__(batch)
+
+
+jax.tree_util.register_pytree_node(
+    FeaturizerBlock,
+    lambda p: (p.nodes, None),
+    lambda _, nodes: FeaturizerBlock(list(nodes)),
+)
+
+
 def build_batch_featurizers(conf: TimitConfig, train_data, nvalid=None) -> list:
     """numCosines [CosineRandomFeatures -> StandardScaler] chains (:65-84).
 
@@ -63,17 +81,18 @@ def build_batch_featurizers(conf: TimitConfig, train_data, nvalid=None) -> list:
     key = jax.random.PRNGKey(conf.seed)
     featurizers = []
     for _ in range(conf.num_cosines):
-        key, sub = jax.random.split(key)
-        rf = CosineRandomFeatures.create(
-            conf.dimension,
-            conf.num_cosine_features,
-            conf.gamma,
-            sub,
-            w_dist=conf.rf_type,
-        )
-        feats = mask_pad_rows(rf(train_data), nvalid)
-        scaler = StandardScaler().fit(feats, nvalid=nvalid)
-        featurizers.append(rf.then(scaler))
+        with trace.host("dispatch", "fit_featurizer_block"):
+            key, sub = jax.random.split(key)
+            rf = CosineRandomFeatures.create(
+                conf.dimension,
+                conf.num_cosine_features,
+                conf.gamma,
+                sub,
+                w_dist=conf.rf_type,
+            )
+            feats = mask_pad_rows(rf(train_data), nvalid)
+            scaler = StandardScaler().fit(feats, nvalid=nvalid)
+            featurizers.append(FeaturizerBlock([rf, scaler]))
     return featurizers
 
 

@@ -67,7 +67,7 @@ STAGES = {
     ),
     "imagenet_fv": (
         "keystone_tpu.workloads.imagenet_sift_lcs_fv",
-        ["featurize", "featurize_test", "solve", "eval"],
+        ["sample_descriptors", "featurize", "featurize_test", "solve", "eval"],
     ),
 }
 
@@ -79,6 +79,14 @@ COUNTERS = {
 
 HISTOGRAMS = ["stage_ms", "stage_wait_ms", "stage_h2d_mb"]
 
+#: the family beneath a stage that ``readers/host_sections.py`` cuts by
+#: prefix: its constant there -> a name a probed stage must record
+SECTION_FAMILIES = {
+    "HOST": "stage_host_ms.contract_probe.dispatch",
+    "COUNT": "stage_host_n.contract_probe.dispatch",
+    "LONGEST": "stage_max_ms.contract_probe.wait",
+}
+
 CASES = (
     [("program", p, m) for p, m in PROGRAMS.items()]
     + [
@@ -87,6 +95,7 @@ CASES = (
     ]
     + [("counter", c, m) for c, m in COUNTERS.items()]
     + [("histogram", h, "keystone_tpu.core.trace") for h in HISTOGRAMS]
+    + [("section_family", f, "keystone_tpu.core.trace") for f in SECTION_FAMILIES]
 )
 
 
@@ -198,11 +207,30 @@ def _check_histogram(name, _module):
     )
 
 
+def _check_section_family(constant, _module):
+    prefix = getattr(manifest.load_module("readers", "host_sections"), constant)
+    recorded = SECTION_FAMILIES[constant]
+    assert recorded.startswith(prefix), f"the reader cuts {prefix}*, not {recorded}"
+    kinds = manifest.load_module("readers", "stage_samples").KINDS
+    assert not prefix.startswith(tuple(k + "." for k in kinds)), (
+        f"{prefix} would read as a stage of one of {kinds}"
+    )
+    with stage_timer("contract_probe"):
+        trace.wait(np.zeros(1), "contract_probe")
+        with trace.host("dispatch", "contract_probe"):
+            pass
+    hists = trace.metrics.snapshot()["histograms"]
+    assert recorded in hists, (
+        f"a stage records {sorted(h for h in hists if 'contract_probe' in h)}"
+    )
+
+
 _CHECKS = {
     "program": _check_program,
     "stage": _check_stage,
     "counter": _check_counter,
     "histogram": _check_histogram,
+    "section_family": _check_section_family,
 }
 
 

@@ -43,6 +43,7 @@ def mesh_fit(data, tmp_path_factory):
     out = pipeline.fit(conf, data, pipeline.program_seed(SEED),
                        str(tmp_path_factory.mktemp("mesh") / "fit"))
     after = dict(trace.metrics.counters())
+    hists = trace.metrics.hist_windows()
     plans = [e for e in trace.flight_events() if e["name"] == "mesh_plan"]
     chunks = [
         e for e in trace.flight_events()
@@ -52,7 +53,7 @@ def mesh_fit(data, tmp_path_factory):
         "conf": conf, "pipeline": pipeline, "out": out,
         "produced": pipeline.produced(out, conf, data, SEED),
         "counted": {k: after.get(k, 0) - before.get(k, 0) for k in after},
-        "plan": plans[-1]["args"], "chunks": chunks,
+        "plan": plans[-1]["args"], "chunks": chunks, "hists": hists,
     }
 
 
@@ -105,6 +106,20 @@ def test_mesh_fit_counts_its_psums_and_says_its_plan(mesh_fit):
     assert mesh_fit["chunks"] and all(
         e["args"]["shards"] == 4 for e in mesh_fit["chunks"][-8:]
     )
+
+
+@pytest.mark.parametrize("stage, sections", [
+    ("solve", {"search", "plan", "place", "dispatch", "finish"}),
+    ("featurize", {"stack", "dispatch", "concat"}),
+])
+def test_mesh_fit_charges_its_host_sections(mesh_fit, stage, sections):
+    """The mesh tier's search, plans, operand placement and dispatch, and the
+    row-sharded featurizer's chunks, are named sections of their stages; the
+    stage's parts sum to its self time."""
+    from test_host_sections import newest_parts
+
+    parts = newest_parts([stage], mesh_fit["hists"])[stage]
+    assert sections <= {part for part, (_, n) in parts.items() if n}, parts
 
 
 @pytest.mark.parametrize("rows,chunk", [(384, 64), (200, 64), (96, 128)])

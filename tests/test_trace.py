@@ -14,7 +14,10 @@
 * the fit timeline (ISSUE 26): span ``id`` / ``parent_id`` / ``root``, a
   stage's self time and its wait and copy sums on an injected clock, the
   stage sets of a tiny CIFAR and a tiny TIMIT fit, profiler annotations
-  only while tracing, and the cost of a span with the ring on.
+  only while tracing, and the cost of a span with the ring on;
+* named host sections (ISSUE 36): a stage's self time is its wait, its
+  copies, its sections and ``other`` to the microsecond, tracing on or off
+  and with the flight ring off; the sections a tiny fit of each kind records.
 """
 
 import gc
@@ -269,6 +272,290 @@ def test_wait_blocks_and_returns_its_value():
     assert [e["name"] for e in waits] == ["probe"]
     assert waits[0]["args"]["parent"] == "t_wait"
     assert _last("stage_wait_ms.t_wait") >= waits[0]["dur"] / 1e3
+
+
+# -- named host sections: every microsecond of a stage in exactly one part --------
+
+
+def _ms(us):
+    return us / 1e3
+
+
+def _nested_sections(clock):
+    """A section inside a section: each is charged its own time only."""
+    with stage_timer("h_nest"):
+        clock.tick(0.000_007)
+        with trace.host("dispatch", "outer"):
+            clock.tick(0.000_100)
+            with trace.host("place"):
+                clock.tick(0.000_030)
+            with trace.host("dispatch", "inner"):
+                clock.tick(0.000_011)
+            clock.tick(0.000_002)
+    return {"h_nest": {
+        "self": 150, "other": 7,
+        "dispatch": (113, 2, 102), "place": (30, 1, 30),
+    }}
+
+
+def _wait_inside_a_section(clock):
+    with stage_timer("h_wait"):
+        with trace.host("dispatch"):
+            clock.tick(0.000_040)
+            with trace._Charged(trace.span("device", cat="wait")):
+                clock.tick(0.004_600)  # one long wait
+            with trace.d2h("read", 8):
+                clock.tick(0.000_300)
+            clock.tick(0.000_005)
+        clock.tick(0.000_001)
+    return {"h_wait": {
+        "self": 4946, "other": 1, "dispatch": (45, 1, 45), "wait": (4900, 2, 4600),
+    }}
+
+
+def _section_inside_an_h2d(clock):
+    with stage_timer("h_copy"):
+        with trace.h2d("chunk", 3_000_000):
+            clock.tick(0.000_200)
+            with trace.host("stack"):
+                clock.tick(0.000_050)
+            clock.tick(0.000_020)
+        with trace.h2d("chunk", 1_000_000):
+            clock.tick(0.000_090)
+    return {"h_copy": {
+        "self": 360, "other": 0, "stack": (50, 1, 50), "h2d": (310, 2, 220),
+        "h2d_mb": 4.0,
+    }}
+
+
+def _two_threads(clock):
+    """Each thread's charges go to its own open stage; the clock is shared,
+    so the threads take turns."""
+    import threading
+
+    turn = threading.Semaphore(0)
+    done = threading.Semaphore(0)
+
+    def worker():
+        turn.acquire()
+        with stage_timer("h_thread_b"):
+            with trace.host("draw"):
+                clock.tick(0.000_060)
+            clock.tick(0.000_004)
+        done.release()
+
+    t = threading.Thread(target=worker)
+    t.start()
+    with stage_timer("h_thread_a"):
+        with trace.host("dispatch"):
+            clock.tick(0.000_010)
+            turn.release()
+            done.acquire()  # the other thread's 64 us pass on this one's clock
+            t.join()
+        clock.tick(0.000_003)
+    return {
+        "h_thread_a": {"self": 77, "other": 3, "dispatch": (74, 1, 74)},
+        "h_thread_b": {"self": 64, "other": 4, "draw": (60, 1, 60)},
+    }
+
+
+def _stage_nested_in_a_stage(clock):
+    """A stage opened inside a section: its whole duration leaves the outer
+    stage's self time and the section's charge alike."""
+    with stage_timer("h_outer"):
+        clock.tick(0.000_005)
+        with trace.host("dispatch"):
+            clock.tick(0.000_020)
+            with stage_timer("h_inner"):
+                clock.tick(0.000_300)
+                with trace.host("concat"):
+                    clock.tick(0.000_040)
+            clock.tick(0.000_002)
+        with trace.host("concat"):
+            clock.tick(0.000_009)
+    return {
+        "h_outer": {"self": 36, "other": 5, "dispatch": (22, 1, 22), "concat": (9, 1, 9)},
+        "h_inner": {"self": 340, "other": 300, "concat": (40, 1, 40)},
+    }
+
+
+def _exception_leaves_a_section(clock):
+    with pytest.raises(RuntimeError):
+        with stage_timer("h_raise"):
+            clock.tick(0.000_002)
+            with trace.host("plan"):
+                clock.tick(0.000_015)
+                with trace.host("search"):
+                    clock.tick(0.000_008)
+                    raise RuntimeError("denied")
+    with stage_timer("h_raise_next"):  # nothing of the failed stage is left open
+        with trace.host("plan"):
+            clock.tick(0.000_001)
+    return {
+        "h_raise": {"self": 25, "other": 2, "plan": (15, 1, 15), "search": (8, 1, 8)},
+        "h_raise_next": {"self": 1, "other": 0, "plan": (1, 1, 1)},
+    }
+
+
+@pytest.mark.parametrize("mode", ["ring", "no_ring", "traced"])
+@pytest.mark.parametrize("scenario", [
+    _nested_sections, _wait_inside_a_section, _section_inside_an_h2d, _two_threads,
+    _stage_nested_in_a_stage, _exception_leaves_a_section,
+])
+def test_stage_parts_sum_to_its_self_time(monkeypatch, tmp_path, scenario, mode):
+    """self = wait + h2d + the named sections + other, to the microsecond,
+    with the flight ring on, with ``KEYSTONE_FLIGHT_DEPTH=0`` (the spans are
+    no-ops) and while tracing."""
+    clock = _FakeClock()
+    monkeypatch.setattr(trace, "_clock", clock)
+    depth_before = trace.flight_depth()
+    trace.set_flight_depth(0 if mode == "no_ring" else depth_before)
+    if mode == "traced":
+        _trace_to(tmp_path)
+    try:
+        expected = scenario(clock)
+        assert (trace.span("x") is trace._NULL) == (mode == "no_ring")
+    finally:
+        trace.set_flight_depth(depth_before)
+    for stage, want in expected.items():
+        self_ms = _last(f"stage_ms.{stage}")
+        assert self_ms == pytest.approx(_ms(want["self"]), abs=1e-6)
+        parts = {"other": _last(f"stage_host_ms.{stage}.other")}
+        assert parts["other"] == pytest.approx(_ms(want["other"]), abs=1e-6)
+        for part in ("wait", "h2d"):
+            total, n, longest = want.get(part, (0, 0, 0))
+            parts[part] = _last(f"stage_{part}_ms.{stage}")
+            assert parts[part] == pytest.approx(_ms(total), abs=1e-6), part
+            assert _last(f"stage_max_ms.{stage}.{part}") == pytest.approx(_ms(longest), abs=1e-6)
+        for section in set(want) & trace.SECTIONS:
+            total, n, longest = want[section]
+            parts[section] = _last(f"stage_host_ms.{stage}.{section}")
+            assert parts[section] == pytest.approx(_ms(total), abs=1e-6), section
+            assert _last(f"stage_host_n.{stage}.{section}") == n
+            assert _last(f"stage_max_ms.{stage}.{section}") == pytest.approx(_ms(longest), abs=1e-6)
+        assert sum(parts.values()) == pytest.approx(self_ms, abs=1e-6)  # 1e-6 ms: a nanosecond
+        assert _last(f"stage_h2d_mb.{stage}") == want.get("h2d_mb", 0.0)
+    if mode == "traced":
+        cats = {e["cat"] for e in trace.events() if e.get("ph") == "X"}
+        assert "host" in cats and "stage" in cats
+
+
+def test_a_part_a_stage_instance_does_not_charge_reads_zero():
+    """From its first sample on a part has one sample a stage instance, so
+    its samples line up with ``stage_ms.<stage>`` from the end."""
+    for charge in (True, False, True):
+        with stage_timer("h_sparse"):
+            if charge:
+                with trace.host("finish"):
+                    pass
+    hists = trace.metrics.hist_windows()
+    assert hists["stage_host_n.h_sparse.finish"]["samples"][-3:] == [1, 0, 1]
+    assert hists["stage_host_ms.h_sparse.finish"]["samples"][-2] == 0.0
+    assert hists["stage_max_ms.h_sparse.finish"]["count"] == hists["stage_ms.h_sparse"]["count"]
+
+
+def test_host_section_names_are_a_closed_vocabulary():
+    with pytest.raises(ValueError, match="no host section"):
+        trace.host("glue")
+    # no name of the family begins with a prefix the stage readers cut stages by
+    with stage_timer("h_names"):
+        with trace.host("write", "probe"):
+            pass
+    family = [
+        n for n in trace.metrics.hist_windows()
+        if n.endswith((".h_names.write", ".h_names.other", ".h_names.wait", ".h_names.h2d"))
+    ]
+    assert sorted(n.split(".")[0] for n in family) == [
+        "stage_host_ms", "stage_host_ms", "stage_host_n",
+        "stage_max_ms", "stage_max_ms", "stage_max_ms",
+    ]
+
+
+def test_section_is_an_annotation_on_the_profilers_clock(tmp_path, monkeypatch):
+    import jax
+
+    made = []
+
+    class Recorder:
+        def __init__(self, name, **kwargs):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            pass
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    _trace_to(tmp_path)
+    with stage_timer("solve"):
+        with trace.host("search", "autoshard.search", label="bcd_fit"):
+            pass
+    assert made == ["ks/stage/solve", "ks/host/search"]
+    (ev,) = [e for e in trace.events() if e.get("cat") == "host"]
+    assert ev["name"] == "search" and ev["args"]["site"] == "autoshard.search"
+    assert ev["args"]["parent"] == "solve"
+
+
+def test_section_cost_with_the_ring_on_stays_small():
+    """Enter + exit of a section beneath an open stage, tracing off and the
+    flight ring on: what an untraced fit pays for each of its charges."""
+    assert not trace.enabled() and trace.flight_depth() > 0
+    costs = []
+    with stage_timer("h_cost"):
+        for _ in range(10_000):
+            t0 = time.perf_counter()
+            with trace.host("dispatch"):
+                pass
+            costs.append(time.perf_counter() - t0)
+    costs.sort()
+    assert costs[len(costs) // 2] < 20e-6, costs[len(costs) // 2]
+    assert _last("stage_host_n.h_cost.dispatch") == 10_000
+
+
+@pytest.mark.parametrize("flight_depth", [trace.DEFAULT_FLIGHT_DEPTH, 0])
+def test_sections_retain_nothing_once_the_ring_is_warm(flight_depth):
+    assert not trace.enabled()
+    depth_before = trace.flight_depth()
+    trace.set_flight_depth(flight_depth)
+    filters = [tracemalloc.Filter(True, trace.__file__)]
+    tracemalloc.start()
+    try:
+        with stage_timer("h_retain"):
+            for _ in range(trace.DEFAULT_FLIGHT_DEPTH + 200):
+                with trace.host("stack", "chunk"):
+                    pass
+            gc.collect()
+            before = tracemalloc.take_snapshot().filter_traces(filters)
+            for _ in range(5000):
+                with trace.host("stack", "chunk"):
+                    with trace.h2d("chunk", 64):
+                        pass
+            gc.collect()
+            after = tracemalloc.take_snapshot().filter_traces(filters)
+    finally:
+        tracemalloc.stop()
+        trace.set_flight_depth(depth_before)
+    grew = sum(s.size for s in after.statistics("filename")) - sum(
+        s.size for s in before.statistics("filename")
+    )
+    assert grew < 8192, f"5000 sections retained {grew} bytes"
+
+
+@pytest.mark.parametrize("entry", ["searches", "search_seconds", "last_search_trained"])
+def test_autoshard_registry_entries_are_gone(rng, entry):
+    """The search's count and time are the open stage's ``search`` section
+    (the names are put together here so that a grep for them finds nothing)."""
+    name = "autoshard_" + entry
+    x = jnp.asarray(rng.normal(size=(64, 24)).astype(np.float32))
+    y = jnp.asarray(np.eye(3, dtype=np.float32)[rng.integers(0, 3, 64)])
+    with stage_timer("h_search"):
+        BlockLeastSquaresEstimator(8, 1, 1.0).fit(x, y, plan=True)
+    snap = trace.metrics.snapshot()
+    for group in ("counters", "gauges", "histograms"):
+        assert name not in snap[group]
+    assert _last("stage_host_n.h_search.search") == 1
+    assert _last("stage_host_ms.h_search.search") > 0
 
 
 # -- one clock with the device trace --------------------------------------------
@@ -956,8 +1243,8 @@ def test_tiny_cifar_fit_emits_its_stage_set_under_one_root(tmp_path, rng):
     assert sum(chunks) == train.images.nbytes + test.images.nbytes
     assert len(chunks) == 3
     assert {e["name"] for e in h2d} == {"chunk", "filter_images", "labels"}
-    assert len([e for e in events if e["cat"] == "dispatch"]) == 3
-    assert len([e for e in events if e["cat"] == "concat"]) == 2
+    chunked = [e for e in events if e["cat"] == "host" and e["args"].get("site") in ("chunk", "chunks")]
+    assert sorted(e["name"] for e in chunked) == 2 * ["concat"] + 3 * ["dispatch"] + 3 * ["stack"]
     for name in nested.keys() | {"featurize_test"}:
         assert hists[f"stage_ms.{name}"]["count"] == 1
         assert hists[f"stage_wait_ms.{name}"]["count"] == 1
@@ -986,7 +1273,8 @@ def test_tiny_timit_run_emits_its_three_stages(tmp_path, rng):
     assert [e["name"] for e in stages] == ["featurize", "solve", "eval"]
     assert {e["args"]["root"] for e in stages} == {fit["args"]["id"]}
     # a round trip to the host a block: the evaluator's wait and read
-    blocks = [e for e in events if e["cat"] == "eval"]
+    blocks = [e for e in events if e["cat"] == "host" and e["args"].get("site") == "block"]
+    assert {e["name"] for e in blocks} == {"dispatch"}
     reads = [e for e in events if e["cat"] == "d2h"]
     assert len(blocks) == 2 == len(reads)
     assert {e["args"]["parent_id"] for e in reads} == {

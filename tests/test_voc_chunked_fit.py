@@ -438,7 +438,10 @@ def test_stages_spans_and_counters_once_a_fit(data, tmp_path):
     assert len(root) == 1 and root[0]["args"]["rows"] == n
     assert sorted(e["name"] for e in spans if e["cat"] == "stage") == sorted(stages)
     assert sum(e["cat"] == "h2d" and e["name"] == "chunk" for e in spans) == 6
-    dispatched = [e for e in spans if e["cat"] == "dispatch" and e["name"] == "chunk"]
+    dispatched = [
+        e for e in spans
+        if e["cat"] == "host" and e["name"] == "dispatch" and e["args"].get("site") == "chunk"
+    ]
     assert {e["args"]["bucket"] for e in dispatched} == {"40x52", "52x40"}
     assert {e["cat"] for e in spans} >= {"wait", "d2h"}
     plans = [e for e in events if e.get("ph") == "i" and e["name"] == "fv_plan"]
