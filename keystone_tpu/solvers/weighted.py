@@ -13,12 +13,18 @@ TPU-native re-design:
 * population statistics are plain gemms over the sorted [N, d] block — under
   ``jit`` with row-sharded inputs XLA lowers them to local gram + ICI
   all-reduce (the treeReduce replacement);
-* the per-class solves run as a ``lax.scan`` over *chunks* of classes with a
-  ``vmap`` inside each chunk — ``class_chunk`` classes are gathered, built
-  into mixture-weighted normal equations, and solved concurrently as one
-  batched Cholesky factorization (the reference solves all classes concurrently
-  across partitions, :228-263); only a [chunk, n_max, d] slab is ever
-  materialized, never the full [C, n_max, d] tensor;
+* the per-class solves run as a ``lax.scan`` over *chunks* of classes —
+  ``class_chunk`` classes are gathered and their mixture-weighted normal
+  equations factored and solved together by the solver's own blocked
+  routine, ``_factor_solve`` (the reference solves all classes concurrently
+  across partitions, :228-263): a left-looking Cholesky over panels 128
+  wide that assembles each panel of the systems as it reaches it, inverts
+  each diagonal block once and keeps the inverse for both substitutions,
+  carries the right-hand side through the factorization as one more row,
+  and writes only the panels it later reads, into one ``[chunk, d + 1, d]``
+  scratch that is the scan's carry.  Only that scratch and a
+  ``[chunk, n_max, d]`` slab are ever materialized: no ``[chunk, d, d]``
+  array of systems, never the full [C, n_max, d] tensor;
 * with a mesh, features are row-sharded over the data axis (population
   grams lower to local gram + ICI all-reduce) and each class chunk is
   sharded over the model axis — the class-partitioned parallelism of the
@@ -35,7 +41,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import jax.scipy.linalg as jsl
 import numpy as np
 from jax import shard_map
 
@@ -198,6 +203,88 @@ class _RegroupPlan:
         return self._jitted[mesh](x, self.send_idx, self.recv_idx)
 
 
+#: Panel width of ``_factor_solve``, whatever the systems' width: at 4,096
+#: columns and sixteen classes a chunk the sweep over 1,000 classes took
+#: 1,839 ms at 128, 2,139 at 256 and 2,346 at 512 on a TPU v5e (ROOFLINE.md,
+#: "The class systems").  The panel products run at four fifths of the MXU's
+#: peak at 128 already, and a wider diagonal block is factored and inverted
+#: by the library in steps of 128, which inverts its blocks a second time.
+_FACTOR_PANEL = 128
+
+
+def _factor_panels(d: int) -> list[tuple[int, int]]:
+    """Column ranges of the panels of a system ``d`` wide; the last may be
+    narrower."""
+    return [(r0, min(r0 + _FACTOR_PANEL, d)) for r0 in range(0, d, _FACTOR_PANEL)]
+
+
+def _dot(a, b, a_axis: int, b_axis: int):
+    """Batched ``HIGHEST`` product over axis ``a_axis`` of ``a`` and ``b_axis``
+    of ``b``, the batch their first axes.  (``lax.dot_general`` and not
+    ``jnp.einsum``: the blocked routine below makes some 160 products of as
+    many shapes, and tracing them as einsums took three times as long.)"""
+    return jax.lax.dot_general(
+        a, b, (((a_axis,), (b_axis,)), ((0,), (0,))), precision=_HIGHEST
+    )
+
+
+def _factor_solve(panel, d: int, work):
+    """Factor a chunk of symmetric positive definite systems and solve each
+    for its right-hand side: a left-looking blocked Cholesky that keeps what
+    it computes.
+
+    ``panel(r0, r1)`` gives ``[B, d + 1 - r0, r1 - r0]``: columns ``r0:r1`` of
+    every system from row ``r0`` down, and beneath them, as row ``d``, the
+    same columns of its right-hand side.  A panel is updated by one product
+    with the panels to its left (``S = A - L Lᵀ``) and its diagonal block
+    factored by ``lax.linalg.cholesky``.  The rows below are then solved
+    against that block's transpose with the identity in the dead block's
+    place above them, so the one triangular solve (the block inverted
+    **once**, one product) yields ``L_kk⁻ᵀ`` above the panel of ``L``, and
+    ``work`` takes both as they come.  The right-hand side's row takes part
+    in both products, so it leaves the last panel as ``y = L⁻¹ b``: the
+    forward substitution costs no pass of its own.  The back substitution
+    ``Lᵀ x = y`` reads each panel once, block row by block row, with the
+    inverses kept from the factorization.
+
+    ``work`` is ``[B, d + 1, d]`` scratch: the panels of ``L`` below the
+    diagonal, ``L_kk⁻ᵀ`` in each diagonal block's place, ``y`` as row ``d``.
+    Only what this call wrote is read, and nothing above the diagonal, so
+    its content on entry does not matter and nothing has to clear it.  A
+    system that is not positive definite makes its diagonal block's factor
+    NaN, and the products carry that into every later panel and all of its
+    ``x``.  Every product is ``HIGHEST`` (the library's triangular solve
+    asks that of its own).
+
+    Returns ``(x [B, d], work)``.
+    """
+    panels = _factor_panels(d)
+    eyes = {
+        q: jnp.broadcast_to(jnp.eye(q, dtype=work.dtype), (work.shape[0], q, q))
+        for q in {r1 - r0 for r0, r1 in panels}
+    }
+    for r0, r1 in panels:
+        q = r1 - r0
+        s = panel(r0, r1)
+        if r0:
+            s = s - _dot(work[:, r0:, :r0], work[:, r0:r1, :r0], 2, 2)
+        l_kk = jax.lax.linalg.cholesky(s[:, :q], symmetrize_input=False)
+        solved = jax.lax.linalg.triangular_solve(
+            l_kk, jnp.concatenate([eyes[q], s[:, q:]], axis=1),
+            left_side=False, lower=True, transpose_a=True,
+        )  # [I; S_below] L_kk⁻ᵀ
+        work = jax.lax.dynamic_update_slice(work, solved, (0, r0, r0))
+    y = work[:, d]
+    x = y[:, d:]  # grows upwards from the last block
+    for r0, r1 in reversed(panels):
+        t = y[:, r0:r1]
+        if r1 < d:
+            t = t - _dot(work[:, r1:d, r0:r1], x, 1, 1)  # L_belowᵀ x
+        x_k = _dot(work[:, r0:r1, r0:r1], t, 2, 1)  # L_kk⁻ᵀ t
+        x = jnp.concatenate([x_k, x], axis=1)
+    return x, work
+
+
 @functools.partial(jax.jit, static_argnames=("n_max", "chunk", "mesh"))
 def _class_solves(
     xb_pad,  # [N + pad, d] sorted block features, zero tail
@@ -217,43 +304,58 @@ def _class_solves(
     mesh=None,
 ):
     """Per-class solve sweep (reference :228-263): scan over class chunks,
-    ``chunk`` concurrent batched solves per step — returns ΔW [d, C]."""
+    ``chunk`` concurrent solves per step — returns ΔW [d, C]."""
     d = xb_pad.shape[1]
     c_total = starts.shape[0]
     w = mixture_weight
-    eye = jnp.eye(d, dtype=xb_pad.dtype)
+    dtype = xb_pad.dtype
     row_ids = jnp.arange(n_max)
+    # What every class's system shares (reference :252-258): the population's
+    # share of the mixture and λI.  Pad columns hold a unit diagonal here.
+    shared = pop_cov * (1.0 - w) + lam * jnp.eye(d, dtype=dtype)
 
     def one_class(start, cnt, c, xtr_c, jm_c, rm_c, m_c):
+        """A class's share of its system: its centred rows, their count, the
+        mean's offset from the population's, and the right-hand side."""
         xc = jax.lax.dynamic_slice(xb_pad, (start, 0), (n_max, d))
-        mask = (row_ids < cnt).astype(xb_pad.dtype)
+        mask = (row_ids < cnt).astype(dtype)
         xc = xc * mask[:, None]
         # this class's own residual column (:231)
         r_c = jax.lax.dynamic_slice(res_pad, (start, c), (n_max, 1))[:, 0] * mask
-        n_c = cnt.astype(xb_pad.dtype)
+        n_c = cnt.astype(dtype)
 
         class_mean = jnp.sum(xc, axis=0) / n_c
         zm = (xc - class_mean) * mask[:, None]
-        class_cov = jnp.matmul(zm.T, zm, precision=_HIGHEST) / n_c
         class_xtr = jnp.matmul(xc.T, r_c, precision=_HIGHEST) / n_c
 
-        mean_diff = class_mean - pop_mean
-        joint_xtx = (
-            pop_cov * (1.0 - w)
-            + class_cov * w
-            + jnp.outer(mean_diff, mean_diff) * ((1.0 - w) * w)
-        )
         mean_mixture_wt = rm_c * (1.0 - w) + w * (jnp.sum(r_c) / n_c)
         joint_xtr = xtr_c * (1.0 - w) + class_xtr * w - jm_c * mean_mixture_wt
-        # λ-shifted solve (reference :259-260).  The system is symmetric
-        # positive definite by construction (a mixture of covariances, a
-        # rank-one term and λI; pad columns carry a unit diagonal), so it is
-        # factored for what it is: Cholesky, a third of a pivoted LU's
-        # operations and no row exchanges
-        factor = jsl.cho_factor(joint_xtx + lam * eye, lower=True)
-        return jsl.cho_solve(factor, joint_xtr - m_c * lam)
+        # λ-shifted right-hand side (reference :259-260)
+        return zm, n_c, class_mean - pop_mean, joint_xtr - m_c * lam
 
-    solve_chunk = jax.vmap(one_class)
+    def solve_chunk(work, inp):
+        zm, n_c, mean_diff, rhs = jax.vmap(one_class)(*inp)
+        cov_weight = (w / n_c)[:, None, None]
+        mean_rows = mean_diff[:, :, None]
+        mean_cols = (mean_diff * ((1.0 - w) * w))[:, None, :]
+        rhs_row = rhs[:, None, :]
+
+        def panel(r0, r1):
+            """Columns ``r0:r1`` of the chunk's systems from row ``r0`` down
+            (reference :252-258), the right-hand sides beneath.  The system
+            is symmetric positive definite by construction (a mixture of
+            covariances, a rank-one term and λI; pad columns carry a unit
+            diagonal), and is assembled a panel at a time, each at the
+            block's full width: no ``[chunk, d, d]`` array of systems exists
+            beside the factor."""
+            system = (
+                shared[r0:, r0:r1]
+                + _dot(zm[:, :, r0:], zm[:, :, r0:r1], 1, 1) * cov_weight
+                + mean_rows[:, r0:] * mean_cols[:, :, r0:r1]
+            )
+            return jnp.concatenate([system, rhs_row[:, :, r0:r1]], axis=1)
+
+        return _factor_solve(panel, d, work)
 
     # Pad the class axis to a chunk multiple by repeating class 0 (results
     # for the repeats are discarded; repeating a real class keeps every
@@ -277,19 +379,24 @@ def _class_solves(
         chunked(model_block.T[cls_pad]),
     )
 
-    model_spec = None
+    model_spec = work_spec = None
     if mesh is not None and chunk % mesh.shape[MODEL_AXIS] == 0:
         model_spec = NamedSharding(mesh, P(MODEL_AXIS, None))
+        work_spec = NamedSharding(mesh, P(MODEL_AXIS, None, None))
 
-    def step(carry, inp):
-        dws = solve_chunk(*inp)  # [chunk, d]
+    def step(work, inp):
+        dws, work = solve_chunk(work, inp)  # [chunk, d]
         if model_spec is not None:
             # Class-partitioned parallelism: each device in the model axis
             # owns chunk/model_size of the concurrent class solves.
             dws = jax.lax.with_sharding_constraint(dws, model_spec)
-        return carry, dws
+            work = jax.lax.with_sharding_constraint(work, work_spec)
+        return work, dws
 
-    _, dws = jax.lax.scan(step, None, xs)  # [n_chunks, chunk, d]
+    # The factor's scratch is the scan's carry: written once here, and each
+    # chunk overwrites the panels it reads.
+    work = jnp.zeros((chunk, d + 1, d), dtype)
+    _, dws = jax.lax.scan(step, work, xs)  # [n_chunks, chunk, d]
     return dws.reshape(n_chunks * chunk, d)[:c_total].T  # [d, C]
 
 
@@ -858,14 +965,18 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         # One system a class, block and pass, each at the block's full width.
         bs = max(widths)
         chunk = max(1, min(self.class_chunk, n_classes))
-        trace.metrics.inc("bwls.class_solves", n_classes * len(widths) * self.num_iter)
+        class_solves = n_classes * len(widths) * self.num_iter
+        factor_panel = min(_FACTOR_PANEL, bs)
+        factor_panels = len(_factor_panels(bs))
+        trace.metrics.inc("bwls.class_solves", class_solves)
+        trace.metrics.inc("bwls.factor_panels", class_solves * factor_panels)
         trace.metrics.inc("bwls.classes", n_classes)
         trace.instant(
             "bwls_plan", rows=n, n_max=n_max, classes=n_classes, blocks=len(widths),
-            class_chunk=chunk,
+            class_chunk=chunk, factor_panel=factor_panel, factor_panels=factor_panels,
             tier=self.last_fit_report.chosen if self.last_fit_report else None,
             slab_bytes=np.dtype(dtype).itemsize * chunk * n_max * bs,
-            systems_bytes=np.dtype(dtype).itemsize * chunk * bs * bs,
+            factor_bytes=np.dtype(dtype).itemsize * chunk * (bs + 1) * bs,
         )
         with trace.host("finish", "model_blocks"):
             model_list = [models_st[i, :wd] for i, wd in enumerate(widths)]

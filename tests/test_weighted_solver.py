@@ -251,3 +251,142 @@ def test_cholesky_class_solves_match_pivoted_solve(rng, case):
     scale = max(1.0, np.abs(xn).max())
     np.testing.assert_allclose(x, xn, atol=2e-3 * scale)
     np.testing.assert_allclose(b, bn, atol=2e-3 * scale)
+
+
+def _chunk_of_systems(rng, case):
+    """Inputs of ``_class_solves`` for one block ``d`` wide (the last ``pad``
+    columns zero with a unit diagonal on the population covariance, as
+    ``_fused_bwls_impl`` pads a short block), and the float64 systems and
+    right-hand sides they stand for."""
+    classes, n_per, d, pad = case["classes"], case["n_per"], case["d"], case.get("pad", 0)
+    lam, w = case["lam"], case["w"]
+    n = classes * n_per
+    x = rng.normal(scale=1.5, size=(classes, d)).repeat(n_per, 0) + rng.normal(size=(n, d))
+    if case.get("unit"):
+        x /= np.linalg.norm(x, axis=1, keepdims=True)
+    x[:, d - pad:] = 0.0
+    x = x.astype(np.float32).astype(np.float64)
+    res = rng.normal(size=(n, classes)).astype(np.float32).astype(np.float64)
+    pop_mean = x.mean(0)
+    pop_cov = x.T @ x / n - np.outer(pop_mean, pop_mean) + np.diag(np.arange(d) >= d - pad)
+    pop_xtr = x.T @ res / n
+    xc = x.reshape(classes, n_per, d)
+    class_means = xc.mean(1)
+    joint_means = w * class_means + (1 - w) * pop_mean
+    rmean = res.reshape(classes, n_per, classes).mean(1).mean(0)
+    model = rng.normal(size=(d, classes))
+    systems, rhs = [], []
+    for c in range(classes):
+        zm = xc[c] - class_means[c]
+        md = class_means[c] - pop_mean
+        r_c = res[c * n_per:(c + 1) * n_per, c]
+        systems.append(
+            pop_cov * (1 - w) + zm.T @ zm / n_per * w + np.outer(md, md) * (1 - w) * w
+            + lam * np.eye(d)
+        )
+        mix = rmean[c] * (1 - w) + w * r_c.mean()
+        rhs.append(
+            pop_xtr[:, c] * (1 - w) + xc[c].T @ r_c / n_per * w - joint_means[c] * mix
+            - model[:, c] * lam
+        )
+    f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
+    args = (
+        f32(np.concatenate([x, np.zeros((n_per, d))])), f32(np.concatenate([res, np.zeros((n_per, classes))])),
+        jnp.arange(classes, dtype=jnp.int32) * n_per, jnp.full(classes, n_per, jnp.int32),
+        f32(pop_cov), f32(pop_mean), f32(pop_xtr), f32(joint_means), f32(rmean), f32(model),
+        f32(lam), f32(w),
+    )
+    return args, np.stack(systems), np.stack(rhs)
+
+
+def _dense_panel(systems, rhs):
+    """``_factor_solve``'s ``panel`` for systems held whole."""
+    return lambda r0, r1: jnp.concatenate([systems[:, r0:, r0:r1], rhs[:, None, r0:r1]], axis=1)
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        dict(d=8, classes=5, n_per=6, chunk=5, lam=0.1, w=0.25),
+        # the deployment's λ and mixture weight on unit-norm rows, twelve rows
+        # under 128 columns: rank-deficient classes and population, one panel
+        dict(d=128, classes=3, n_per=4, chunk=1, lam=6e-5, w=0.25, unit=True),
+        # a last panel narrower than the others (128 + 72), no ridge, and
+        # pad columns that only their unit diagonal keeps positive
+        dict(d=200, classes=5, n_per=60, chunk=5, lam=0.0, w=0.3, pad=10),
+        # three whole panels; seven classes in chunks of five (the last
+        # chunk repeats class 0); the class's own statistics carry the system
+        dict(d=384, classes=7, n_per=70, chunk=5, lam=1e-3, w=0.9),
+    ],
+    ids=["one_small_panel", "published_lambda_chunk_of_one", "ragged_panel_pad_columns", "three_panels_class_heavy"],
+)
+def test_blocked_factor_and_solve_match_float64(rng, case):
+    """The solver's own blocked routine against numpy's pivoted solve in
+    float64, through ``_class_solves`` (its systems assembled a panel at a
+    time) and on the same systems held whole; the right-hand side that rides
+    in the factorization against scipy's forward substitution."""
+    import scipy.linalg
+
+    from keystone_tpu.solvers import weighted
+
+    d = case["d"]
+    args, systems, rhs = _chunk_of_systems(rng, case)
+    want = np.stack([np.linalg.solve(a, b) for a, b in zip(systems, rhs)])
+    scale = np.linalg.norm(want, axis=1, keepdims=True)
+
+    dw = np.asarray(weighted._class_solves(*args, case["n_per"], case["chunk"], None))
+    assert dw.shape == (d, case["classes"])
+    assert (np.linalg.norm(dw.T - want, axis=1, keepdims=True) / scale).max() < 2e-3
+
+    # scratch that holds NaN on entry: only what the call wrote is read
+    work = jnp.full((len(systems), d + 1, d), jnp.nan, jnp.float32)
+    x, work = weighted._factor_solve(
+        _dense_panel(jnp.asarray(systems, jnp.float32), jnp.asarray(rhs, jnp.float32)), d, work
+    )
+    assert (np.linalg.norm(np.asarray(x) - want, axis=1, keepdims=True) / scale).max() < 2e-3
+    y_want = np.stack([
+        scipy.linalg.solve_triangular(np.linalg.cholesky(a), b, lower=True)
+        for a, b in zip(systems, rhs)
+    ])
+    y_gap = np.linalg.norm(np.asarray(work[:, d]) - y_want, axis=1) / np.linalg.norm(y_want, axis=1)
+    assert y_gap.max() < 1e-3
+
+
+@pytest.mark.parametrize("d,bad_column", [(8, 3), (300, 5), (300, 290)], ids=["one_panel", "first_panel", "last_panel"])
+def test_indefinite_system_is_nonfinite_and_alone(rng, d, bad_column):
+    """A system that is not positive definite surfaces as that class's
+    solution non-finite (what ``cho_factor`` gave); the chunk's other
+    classes are as sound as without it."""
+    from keystone_tpu.solvers import weighted
+
+    classes, bad_class = 4, 2
+    roots = rng.normal(size=(classes, d, 2 * d))
+    systems = roots @ roots.transpose(0, 2, 1) / (2 * d) + 0.1 * np.eye(d)
+    systems[bad_class, bad_column, bad_column] = -1.0
+    rhs = rng.normal(size=(classes, d))
+    x, _work = weighted._factor_solve(
+        _dense_panel(jnp.asarray(systems, jnp.float32), jnp.asarray(rhs, jnp.float32)),
+        d, jnp.zeros((classes, d + 1, d), jnp.float32),
+    )
+    x = np.asarray(x)
+    assert not np.isfinite(x[bad_class]).any()
+    sound = [c for c in range(classes) if c != bad_class]
+    want = np.stack([np.linalg.solve(systems[c], rhs[c]) for c in sound])
+    assert np.isfinite(x[sound]).all()
+    assert (np.linalg.norm(x[sound] - want, axis=1) / np.linalg.norm(want, axis=1)).max() < 1e-3
+
+
+def test_fit_counts_its_factor_panels(rng):
+    """``bwls_plan`` says the panel width the routine chose and the panels a
+    system, and ``bwls.factor_panels`` counts them: classes x blocks x passes
+    x panels."""
+    from keystone_tpu.core import trace
+
+    feats, labels = make_problem(rng, n=60, d=10, num_classes=3)
+    before = trace.metrics.get("bwls.factor_panels")
+    BlockWeightedLeastSquaresEstimator(4, 2, 0.1, 0.5).fit(
+        jnp.asarray(feats, jnp.float32), jnp.asarray(labels, jnp.float32)
+    )
+    plan = [e for e in trace.flight_events() if e["name"] == "bwls_plan"][-1]["args"]
+    assert (plan["factor_panel"], plan["factor_panels"]) == (4, 1)  # a block of 4 is one panel
+    assert trace.metrics.get("bwls.factor_panels") - before == 3 * 3 * 2 * 1
