@@ -20,6 +20,11 @@ means.  Legs:
      width at which the conv featurizer takes its kernel form under
      shard_map (each chip its rows of a chunk), and the __graft_entry__
      multi-chip dry run in-process on the real devices.
+  E  TimitPipeline at its documented 50 blocks of 4,096 cosine features
+     through ``timit.run``, 8,192 rows, under a budget the 6.7 GB design
+     matrix does not fit: the solver must make every block inside its one
+     fused program (tier ``fused[made]``, no denial), count what it made,
+     and never let the device's peak grow by the matrix.
 
 ONE process touches the chip: this one.  It starts g++ (native decoders)
 and spawned decode workers, none of which imports jax, and stops them.
@@ -58,12 +63,14 @@ FULL = dict(
     jpeg_train=4_096, jpeg_test=2_048, golden=64,
     idct_images=2_048, fv=(8, 73_866, 80, 256), conv=(2_048, 1_250, 128),
     sift=(64, 375, 500), mesh_filters=1_250,
+    timit=dict(train=8_192, test=2_048, width=4_096, budget="8G"),
 )
 TINY = dict(
     train=600, test=200, whitener=4_000, requests=24,
     jpeg_train=96, jpeg_test=48, golden=8,
     idct_images=8, fv=(3, 700, 24, 8), conv=(5, 24, 4), sift=(32, 30, 46),
     mesh_filters=24,
+    timit=dict(train=1_024, test=256, width=32, budget="12M"),
 )
 
 
@@ -563,7 +570,66 @@ def leg_d(ctx) -> dict:
     }
 
 
-LEGS = {"A": leg_a, "B": leg_b, "C": leg_c, "D": leg_d}
+def leg_e(ctx) -> dict:
+    import jax
+    import numpy as np
+
+    from keystone_tpu.core import trace
+    from keystone_tpu.core.memory import HBM_BUDGET_ENV
+    from keystone_tpu.loaders.timit import TimitFeaturesData, TimitSplit
+    from keystone_tpu.workloads import timit
+
+    size = ctx["size"]["timit"]
+    conf = timit.TimitConfig(num_cosine_features=size["width"])  # 50 blocks, 5 epochs
+    rng = np.random.default_rng(38)
+    centres = rng.normal(0, 1.0, (conf.num_classes, conf.dimension))
+
+    def split(n):
+        labels = rng.integers(0, conf.num_classes, n).astype(np.int32)
+        rows = centres[labels] + 0.3 * rng.normal(size=(n, conf.dimension))
+        return TimitSplit(rows.astype(np.float32), labels)
+
+    data = TimitFeaturesData(split(size["train"]), split(size["test"]))
+    device = jax.devices()[0]
+    stats = device.memory_stats() or {}
+    in_use, peak_before = stats.get("bytes_in_use", 0), stats.get("peak_bytes_in_use", 0)
+    made_before = trace.metrics.get("bcd.block_rows_made")
+    old = os.environ.get(HBM_BUDGET_ENV)
+    os.environ[HBM_BUDGET_ENV] = size["budget"]
+    try:
+        res = timit.run(conf, data)
+    finally:
+        if old is None:
+            del os.environ[HBM_BUDGET_ENV]
+        else:
+            os.environ[HBM_BUDGET_ENV] = old
+    report = res["fit_report"]
+    plan = report.bcd_plan
+    check(
+        (report.chosen, report.denials, report.oom_retries) == ("fused[made]", [], []),
+        f"the 50-block solve did not run made and fused: {report.summary()}",
+    )
+    check(res["test_error"] < TEST_ERROR_BAR, f"test error {res['test_error']:.2f}%")
+    made = trace.metrics.get("bcd.block_rows_made") - made_before
+    due = size["train"] * conf.num_cosines * (conf.num_epochs + 2)  # moments, gram, epochs
+    check(made == due, f"bcd.block_rows_made grew by {made}, not {due}")
+    peak = (device.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    grew = max(0, peak - max(peak_before, in_use))
+    check(
+        grew < plan["matrix_bytes"],
+        f"the device's peak grew by {grew} bytes, the matrix is {plan['matrix_bytes']}",
+    )
+    return {
+        "timit_test_error_pct": round(res["test_error"], 3),
+        "timit_tier": report.chosen,
+        "timit_bcd_plan": plan,
+        "timit_block_rows_made": made,
+        "timit_peak_grew_bytes": grew,
+        "timit_fit_seconds": round(res["seconds"], 2),
+    }
+
+
+LEGS = {"A": leg_a, "B": leg_b, "C": leg_c, "D": leg_d, "E": leg_e}
 
 
 # -- driver --------------------------------------------------------------------
@@ -590,7 +656,7 @@ def main(argv=None) -> int:
     )
     p.add_argument(
         "--legs", default=None,
-        help="comma-separated subset of A,B,C,D (a subset is never a pass)",
+        help="comma-separated subset of A,B,C,D,E (a subset is never a pass)",
     )
     p.add_argument(
         "--expect-warm-cache", action="store_true",
@@ -629,7 +695,7 @@ def main(argv=None) -> int:
     from jax.experimental.pallas import tpu as pltpu
 
     if legs is None:
-        legs = ["A", "B", "C"] + (["D"] if device["count"] >= 4 else [])
+        legs = ["A", "B", "C"] + (["D"] if device["count"] >= 4 else []) + ["E"]
     full_run = not a.legs and not a.rehearsal
     from benchmark.lib.compile_meter import CompileMeter
 

@@ -294,15 +294,24 @@ def clear_plan_cache() -> None:
     _plan_cache.clear()
 
 
+def _is_array_like(a) -> bool:
+    return hasattr(a, "shape") and hasattr(a, "dtype")
+
+
 def _cache_key(fn, args, kwargs):
     sig = []
     for a in (*args, *sorted(kwargs.items())):
-        if hasattr(a, "shape") and hasattr(a, "dtype"):
+        leaves, treedef = jax.tree_util.tree_flatten(a)
+        if leaves and all(_is_array_like(leaf) for leaf in leaves):
             # The sharding is part of the compiled program's identity: the
             # same shapes planned for a (4, 2) mesh and an (8, 1) mesh are
             # different SPMD modules with different per-chip footprints.
-            sharding = getattr(a, "sharding", None)
-            sig.append(("arr", tuple(a.shape), str(a.dtype), str(sharding)))
+            # An argument that is a pytree of arrays (the block solver's
+            # ``BlockSource``) is its structure and its leaves' signatures.
+            sig.append(("arr", str(treedef)) + tuple(
+                (tuple(leaf.shape), str(leaf.dtype), str(getattr(leaf, "sharding", None)))
+                for leaf in leaves
+            ))
         else:
             sig.append(("static", a))
     return (id(fn), tuple(sig))
@@ -465,7 +474,7 @@ def plan_program(
         analytic_args = sum(
             shard_bytes(a)
             for a in (*args, *(v for _, v in sorted(kwargs.items())))
-            if hasattr(a, "shape") and hasattr(a, "dtype")
+            if _is_array_like(a)
         )
         arg_bytes = max(analytic_args, cached["argument"])
         sharded_out = cached.get("sharded_out")
@@ -781,6 +790,16 @@ class FitReport:
     #: sweep as a live per-fit monitor.  ``None`` when the observatory was
     #: off for the fit.
     conditioning: list | None = None
+    #: the block solver's plan of the fit (``solvers.block._plan_bcd``):
+    #: rows, blocks, block width, whether the blocks were ``held`` as one
+    #: matrix or ``made`` inside the solver's programs (``block_source``),
+    #: and the bytes on both sides of that rule.  A rule from bytes, not an
+    #: admission denial: ``denials`` does not see it.
+    bcd_plan: dict | None = None
+
+    @property
+    def block_source(self) -> str | None:
+        return self.bcd_plan["block_source"] if self.bcd_plan else None
 
     def record(self) -> dict:
         """JSON-able form for bench artifacts."""
@@ -799,6 +818,8 @@ class FitReport:
             "oom_retries": list(self.oom_retries),
             "tiers": {k: p.breakdown() for k, p in self.plans.items()},
             "placement": self.placement,
+            "block_source": self.block_source,
+            "bcd_plan": dict(self.bcd_plan) if self.bcd_plan else None,
             # Flight-recorder postmortems this process has dumped
             # (core.telemetry) — a degraded fit links to its evidence.
             "postmortems": telemetry.postmortem_paths(),

@@ -47,7 +47,13 @@ class StandardScaler(Estimator):
     def fit(self, data, nvalid: int | None = None) -> StandardScalerModel:
         n = nvalid if nvalid is not None else data.shape[0]
         _, s, sq = sharded_moments(data)
-        cnt = jnp.asarray(n, data.dtype)  # true row count (excludes pad rows)
+        return self.from_moments(n, s, sq)
+
+    def from_moments(self, n: int, s, sq) -> StandardScalerModel:
+        """The model from the column sums ``s`` and sums of squares ``sq``
+        over ``n`` true rows, however they were taken (of any shape: a
+        stack of blocks' moments gives a stack of scalers)."""
+        cnt = jnp.asarray(n, s.dtype)  # true row count (excludes pad rows)
         mean = s / cnt
         if not self.normalize_std_dev:
             return StandardScalerModel(mean, None)

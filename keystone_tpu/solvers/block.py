@@ -40,6 +40,7 @@ from ..parallel.mesh import (
     current_mesh,
     enumerate_meshes,
     mesh_desc,
+    on_one_device,
     pad_shard_inputs,
     reduced_mesh,
     row_sharding,
@@ -96,13 +97,16 @@ class BlockLinearMapper(Transformer):
             i += w
         return out
 
-    def _blocks_of(self, batch_or_blocks) -> list:
-        """The input as this model's feature blocks: a list of blocks as it
-        is, a concatenated matrix cut at the fitted widths."""
-        if isinstance(batch_or_blocks, (list, tuple)):
-            blocks = list(batch_or_blocks)
-        else:
+    def _blocks_of(self, batch_or_blocks):
+        """The input as this model's feature blocks: a concatenated matrix
+        cut at the fitted widths; anything else (a list of blocks, a
+        :class:`BlockSource` over the rows to score) is an iterable of known
+        length and is handed back as it is, so that a source makes a block
+        when the caller's loop reaches it."""
+        if hasattr(batch_or_blocks, "shape"):
             blocks = self._split_features(batch_or_blocks)
+        else:
+            blocks = batch_or_blocks
         if len(blocks) != len(self.xs):
             raise ValueError(
                 f"{len(blocks)} feature blocks vs {len(self.xs)} model blocks"
@@ -123,18 +127,22 @@ class BlockLinearMapper(Transformer):
     ):
         """Invoke ``evaluator`` on the running prediction after each block —
         streaming evaluation without materializing all block products
-        (reference BlockLinearMapper.scala:104-137)."""
+        (reference BlockLinearMapper.scala:104-137).  Blocks that are made
+        as the loop reaches them (a :class:`BlockSource`) are alive one at a
+        time: each is dropped after its step."""
         running = None
-        for i, (blk, x, scaler) in enumerate(
-            zip(self._blocks_of(batch_or_blocks), self.xs, self.feature_scalers)
-        ):
-            # one section a block: its one step program, then the evaluator's
-            # round trip to the host (its ``wait`` and ``d2h`` nest here and
-            # are charged as themselves)
+        blocks = iter(self._blocks_of(batch_or_blocks))
+        for i, (x, scaler) in enumerate(zip(self.xs, self.feature_scalers)):
+            # one section a block: its one step program (after the program
+            # that makes it, where the blocks come from a source), then the
+            # evaluator's round trip to the host (its ``wait`` and ``d2h``
+            # nest here and are charged as themselves)
             with trace.host("dispatch", "block", block=i):
+                blk = next(blocks)
                 running, with_intercept = _block_step(
                     running, blk, x, scaler, self.b
                 )
+                del blk
                 evaluator(with_intercept)
 
 
@@ -193,6 +201,174 @@ def _block_step(running, blk, x, scaler, b):
     return running, running if b is None else running + b
 
 
+class BlockSource:
+    """A design matrix's feature blocks as *what makes them*: the raw rows
+    ``[N, d]`` and one pure featurizer a block, its parameters arrays (the
+    reference's ``fit(Seq[RDD], ...)`` over lazy chains,
+    BlockLinearMapper.scala:156-203: a block is computed from the rows when
+    the sweep reaches it).
+
+    ``featurizers`` is ONE transformer pytree whose every array leaf carries
+    a leading block axis (:meth:`stacked` makes it from a list); block ``i``
+    is ``featurizers[i](rows)``, ``[N, bs]``.  ``widths``: the true width of
+    each block where some are narrower than ``bs`` (their columns past it
+    are made zero, as ``_blocked_design_matrix`` pads them).  ``means``
+    ``[B, bs]``: the blocks' column means over the valid rows where the
+    caller has them (a scaler at the chain's end makes them zero); ``None``
+    and the solver takes them in one more pass (``_block_moments``).
+
+    ``BlockLeastSquaresEstimator.fit`` holds the blocks as one matrix when
+    that fits the device and makes them inside its programs otherwise;
+    iterating a source makes one block an item (``_make_block``), which is
+    how ``apply_and_evaluate`` streams a test split.
+    """
+
+    def __init__(self, rows, featurizers, widths=None, means=None):
+        self.rows = rows
+        self.featurizers = featurizers
+        self.widths = None if widths is None else tuple(int(w) for w in widths)
+        self.means = means
+
+    @classmethod
+    def stacked(cls, rows, featurizers: Sequence, **kw) -> "BlockSource":
+        """From a list of same-shaped featurizer pytrees, their leaves
+        stacked along a new block axis."""
+        with trace.host("place", "featurizers"):
+            return cls(
+                rows, jax.tree.map(lambda *a: jnp.stack(a), *featurizers), **kw
+            )
+
+    def __len__(self) -> int:
+        return int(jax.tree.leaves(self.featurizers)[0].shape[0])
+
+    def featurizer(self, i):
+        """Block ``i``'s featurizer; ``i`` may be traced."""
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            self.featurizers,
+        )
+
+    @functools.cached_property
+    def _block_aval(self):
+        """Shape and dtype of one made block, worked out abstractly once."""
+        one = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), self.featurizers
+        )
+        rows = jax.ShapeDtypeStruct(self.rows.shape, self.rows.dtype)
+        return jax.eval_shape(lambda f, r: f(r), one, rows)
+
+    @property
+    def block_size(self) -> int:
+        return int(self._block_aval.shape[-1])
+
+    @property
+    def dtype(self):
+        return self._block_aval.dtype
+
+    def block_widths(self) -> tuple:
+        return self.widths or (self.block_size,) * len(self)
+
+    def operand_bytes(self) -> int:
+        """What a program that makes the blocks holds in their place."""
+        return kmem.array_bytes(
+            *jax.tree.leaves((self.rows, self.featurizers, self.means))
+        )
+
+    def make(self, i):
+        """Block ``i`` ``[N, bs]``, columns past its width zero; every row
+        is made, pad rows too (callers mask them).  Traceable."""
+        xi = self.featurizer(i)(self.rows)
+        widths = self.block_widths()
+        if min(widths) < xi.shape[-1]:
+            keep = jnp.arange(xi.shape[-1]) < jnp.asarray(widths)[i]
+            xi = jnp.where(keep, xi, 0)
+        return xi
+
+    def __iter__(self):
+        """The blocks one by one, each made when it is asked for and cut to
+        its own width: what ``apply_and_evaluate`` streams."""
+        for i, w in enumerate(self.block_widths()):
+            yield _make_block(self, i)[:, :w]
+
+
+jax.tree_util.register_pytree_node(
+    BlockSource,
+    lambda s: ((s.rows, s.featurizers, s.means), s.widths),
+    lambda widths, kids: BlockSource(kids[0], kids[1], widths, kids[2]),
+)
+
+
+@jax.jit
+def _make_block(source: BlockSource, i):
+    """One made block as an array of its own (the streamed apply's input);
+    ``i`` is traced, so one program serves every block."""
+    return source.make(i)
+
+
+def _count_blocks_made(rows: int, blocks: int) -> None:
+    """``bcd.block_rows_made``: training rows x blocks made, reckoned from
+    shapes where the programs that make them are called (every pass)."""
+    trace.metrics.inc("bcd.block_rows_made", int(rows) * int(blocks))
+
+
+def _valid_rows(n: int, nvalid):
+    return (jnp.arange(n) < nvalid)[:, None]
+
+
+def block_moments(source: BlockSource, nvalid: int):
+    """(Σx, Σx²) of every made block's columns over the first ``nvalid``
+    rows, ``[B, bs]`` each: one pass over the blocks, none of them kept
+    (program ``_block_moments``).  A workload's scaler takes its mean and
+    deviation from it, and the solver a source's block means where the
+    source states none."""
+    _count_blocks_made(nvalid, len(source))
+    if not on_one_device(source.rows):  # the column sums cross the chips
+        count_psum(2 * len(source) * source.block_size * source.dtype.itemsize)
+    return _block_moments(source, nvalid)
+
+
+@jax.jit
+def _block_moments(source: BlockSource, nvalid):
+    valid = _valid_rows(source.rows.shape[0], nvalid)
+
+    def one(_, i):
+        xi = jnp.where(valid, source.make(i), 0)
+        return None, (jnp.sum(xi, axis=0), jnp.sum(xi * xi, axis=0))
+
+    _, sums = jax.lax.scan(one, None, jnp.arange(len(source)))
+    return sums
+
+
+@jax.jit
+def _hold_blocks(source: BlockSource, nvalid):
+    """The made blocks side by side: the ``[N, B*bs]`` matrix of
+    ``_blocked_design_matrix``'s contract (pad rows and pad columns zero),
+    for a source whose matrix fits the device."""
+    n, bs = source.rows.shape[0], source.block_size
+    valid = _valid_rows(n, nvalid)
+
+    def one(x, i):
+        xi = jnp.where(valid, source.make(i), 0)
+        return jax.lax.dynamic_update_slice_in_dim(x, xi, i * bs, axis=1), None
+
+    x0 = jnp.zeros((n, len(source) * bs), source.dtype)
+    return jax.lax.scan(one, x0, jnp.arange(len(source)))[0]
+
+
+def _block(x, mu, i, bs: int):
+    """(block ``i`` of the design matrix ``[N, bs]``, its column means
+    ``[bs]``): sliced out of a held matrix and of ``mu`` ``[B*bs]``, or made
+    from the rows of a :class:`BlockSource` that carries its means (``mu``
+    is then None).  The one place the solver's programs differ between the
+    two forms."""
+    if isinstance(x, BlockSource):
+        return x.make(i), jax.lax.dynamic_index_in_dim(x.means, i, keepdims=False)
+    return (
+        jax.lax.dynamic_slice_in_dim(x, i * bs, bs, axis=1),
+        jax.lax.dynamic_slice_in_dim(mu, i * bs, bs, axis=0),
+    )
+
+
 def _fused_bcd_impl(x, labels, lam, nvalid, num_iter: int, widths, mesh,
                     specs=None):
     """The ENTIRE block-least-squares fit as one compiled program.
@@ -217,6 +393,13 @@ def _fused_bcd_impl(x, labels, lam, nvalid, num_iter: int, widths, mesh,
     are zero, so their solutions are exactly zero and the factorization
     stays positive-definite even at lam=0).
 
+    ``x`` may instead be a :class:`BlockSource` with its ``means`` (no
+    mesh): each scan step then *makes* its block from the rows and the
+    block's featurizer where the held form slices it, so nothing wider than
+    one block is ever alive and the featurizer runs ``num_iter + 1`` times a
+    block.  Everything else (grams, factors, steps, the epoch scan, the
+    pad-column shift, the update order) is the same code.
+
     With ``mesh``: rows shard over the data axis (grams lower to local
     MXU gram + ICI all-reduce), models/labels' class columns shard over the
     model axis.  ``specs`` (static; a sorted tuple of
@@ -228,7 +411,14 @@ def _fused_bcd_impl(x, labels, lam, nvalid, num_iter: int, widths, mesh,
     executed here, so a searched layout is REAL, not just byte accounting.
     ``specs=None`` is bit-for-bit the PR 9 program.
 
-    Returns (models [B, bs, k], label_mean [k], means [B, bs]).
+    Returns (models [B, bs, k], label_mean [k], means [B, bs]); for a
+    ``BlockSource`` also the factor stack ``[B, bs, bs]``, the fit's one
+    large state (3.36 GB at fifty blocks of 4,096).  As a result it is a
+    buffer the device's allocator owns and counts — what ``memory_stats()``,
+    the live budget of the next admission and a plan's ``out_bytes`` read —
+    where as a temporary it is program scratch that none of them sees; the
+    bytes are the same either way, and the held form, whose matrix is the
+    large thing, keeps its three results.
     """
     bs = max(widths)
     nb = len(widths)
@@ -258,16 +448,18 @@ def _fused_bcd_impl(x, labels, lam, nvalid, num_iter: int, widths, mesh,
     nv = jnp.asarray(nvalid, dtype)
     label_mean = jnp.sum(labels * mask, axis=0) / nv
     residual = (labels - label_mean) * mask
-    # All block means in one gemv (pad rows are zero by contract).
-    mu = (mask[:, 0] @ x) / nv  # [B*bs]
-    means = mu.reshape(nb, bs)
+    if isinstance(x, BlockSource):
+        mu, means = None, x.means
+    else:
+        # All block means in one gemv (pad rows are zero by contract).
+        mu = (mask[:, 0] @ x) / nv  # [B*bs]
+        means = mu.reshape(nb, bs)
 
     def centered_block(i):
         """(x_block_i - mean_i) * row_mask — the per-step [N, bs] transient
         (identical numerics to centering the whole matrix, without ever
         materializing more than one centered block)."""
-        xi = jax.lax.dynamic_slice_in_dim(x, i * bs, bs, axis=1)
-        mu_i = jax.lax.dynamic_slice_in_dim(mu, i * bs, bs, axis=0)
+        xi, mu_i = _block(x, mu, i, bs)
         return (xi - mu_i) * mask, mu_i
 
     pad_diag = jnp.stack(
@@ -311,6 +503,8 @@ def _fused_bcd_impl(x, labels, lam, nvalid, num_iter: int, widths, mesh,
     (models, residual), _ = jax.lax.scan(
         epoch, (models, residual), None, length=num_iter
     )
+    if isinstance(x, BlockSource):
+        return models, label_mean, means, chol
     return models, label_mean, means
 
 
@@ -336,7 +530,7 @@ def _single_device_arrays(*arrays) -> bool:
     """True when no argument is a multi-device (sharded) jax.Array — the
     precondition for executing an AOT program planned on unsharded avals
     (its baked SingleDeviceSharding would reject sharded inputs)."""
-    for a in arrays:
+    for a in jax.tree.leaves(arrays):
         if isinstance(a, jax.Array):
             try:
                 if len(a.sharding.device_set) > 1:
@@ -409,16 +603,25 @@ def _bcd_spec_variants(m) -> list[dict]:
     return out
 
 
-def _blocked_design_matrix(features, block_size: int, num_features=None):
+def _blocked_design_matrix(features, block_size: int, num_features=None,
+                           nvalid=None):
     """(x, widths): the [N, B*bs] zero-padded blocked layout _fused_bcd_fit
     consumes, from either a monolithic [N, d] array or a list of pre-split
-    feature blocks (the reference's fit(Seq[RDD]) form).
+    feature blocks (the reference's fit(Seq[RDD]) form), or from a
+    :class:`BlockSource` whose matrix fits the device: its blocks made once
+    and written side by side by one program (``nvalid`` says which of its
+    rows are true).
 
     Monolithic input with d a block_size multiple is passed through with NO
     copy — the common production shape (d = 2·2·descDim·vocabSize etc.) pays
     zero extra HBM.  Anything needing column padding costs one copy (np.pad
     host-side for host arrays, so nothing transient lands on device).
     """
+    if isinstance(features, BlockSource):
+        if nvalid is None:
+            nvalid = int(features.rows.shape[0])
+        _count_blocks_made(nvalid, len(features))
+        return _hold_blocks(features, nvalid), features.block_widths()
     if isinstance(features, (list, tuple)):
         widths = tuple(int(b.shape[1]) for b in features)
         bs = max(widths)
@@ -471,8 +674,7 @@ def _bcd_block_factor(x, mu, mask, lam, pad_diag_i, i, bs: int):
     """Cholesky factor of block i's regularized gram — computed once per
     block and reused across epochs (the factors are constant, exactly as
     the fused path caches them in its first scan)."""
-    xi = jax.lax.dynamic_slice_in_dim(x, i * bs, bs, axis=1)
-    mu_i = jax.lax.dynamic_slice_in_dim(mu, i * bs, bs, axis=0)
+    xi, mu_i = _block(x, mu, i, bs)
     a_i = (xi - mu_i) * mask
     return jsl.cho_factor(a_i.T @ a_i + jnp.diag(lam + pad_diag_i))[0]
 
@@ -481,8 +683,7 @@ def _bcd_block_factor(x, mu, mask, lam, pad_diag_i, i, bs: int):
 def _bcd_block_solve(x, mu, mask, residual, m_old, c_i, i, bs: int):
     """One BCD block update given the cached factor — identical math to
     one ``block_step`` of ``_fused_bcd_fit``."""
-    xi = jax.lax.dynamic_slice_in_dim(x, i * bs, bs, axis=1)
-    mu_i = jax.lax.dynamic_slice_in_dim(mu, i * bs, bs, axis=0)
+    xi, mu_i = _block(x, mu, i, bs)
     a_i = (xi - mu_i) * mask
     r_i = residual + a_i @ m_old
     m_new = jsl.cho_solve((c_i, False), a_i.T @ r_i)
@@ -568,6 +769,58 @@ def _host_staged_bcd_fit(x_host, labels, lam, nvalid, num_iter: int, widths):
             del xi  # the one big device buffer — released before the next H2D
     means = jnp.stack([mus[i] for i in range(nb)])
     return jnp.stack(models), label_mean, means
+
+
+def _plan_bcd(features, labels, num_iter: int, block_size: int,
+              num_features=None) -> dict:
+    """What a fit is about to hold, and for a :class:`BlockSource` whether
+    its blocks are held or made: **a rule from bytes**, no option.  The
+    blocks are held as one matrix (the program every other caller runs)
+    when the matrix beside the fused program's own footprint fits the
+    budget ``core.memory.hbm_budget()`` reports, or no budget is known; they
+    are made inside the solver's programs otherwise.  The decision is no
+    admission denial: nothing was tried.  Arrays and lists of arrays are
+    held, as they always were.  The record is the ``bcd_plan`` instant's
+    and ``FitReport.bcd_plan``'s."""
+    n, k = (int(d) for d in np.shape(labels))
+    it = np.dtype(
+        jax.dtypes.canonicalize_dtype(getattr(labels, "dtype", np.float32))
+    ).itemsize
+    source = isinstance(features, BlockSource)
+    operands = features.operand_bytes() if source else 0
+    if source:
+        nb, bs = len(features), features.block_size
+    elif isinstance(features, (list, tuple)):
+        nb, bs = len(features), max(int(b.shape[1]) for b in features)
+    else:
+        d = num_features or int(np.shape(features)[1])
+        nb, bs = -(-d // block_size), min(block_size, d)
+    matrix, block = it * n * nb * bs, it * n * bs
+    factors = it * nb * bs * bs
+    # beside the blocks themselves, either form of the fused program keeps
+    # the labels, two residual carries, the factor stack and the models
+    shared = it * (3 * n * k + nb * bs * k) + factors
+    plan = {
+        "rows": n, "blocks": nb, "block_width": bs, "block_source": "held",
+        "matrix_bytes": matrix, "operand_bytes": operands,
+        "factor_bytes": factors, "block_bytes": block, "passes_a_block": 0,
+    }
+    if not source:
+        return plan
+    budget = kmem.hbm_budget()
+    # a live budget is free bytes: the source's operands and device labels
+    # are out of it already
+    resident = operands + (labels.nbytes if isinstance(labels, jax.Array) else 0)
+    credit = resident if kmem.budget_is_live() else 0
+    held = operands + matrix + block + shared
+    made = operands + 2 * block + shared
+    plan.update(held_bytes=held, made_bytes=made, budget_bytes=budget)
+    if budget is None or held - credit <= budget:
+        plan["passes_a_block"] = 1
+    else:
+        plan["block_source"] = "made"
+        plan["passes_a_block"] = num_iter + 1 + (features.means is None)
+    return plan
 
 
 BCD_STATE_VERSION = 1
@@ -668,10 +921,15 @@ def _stepwise_bcd_fit(
     baked, same avals).  When given, the degraded path executes the very
     program that was planned instead of re-compiling ``_bcd_block_solve``
     at first jit dispatch; ``None`` falls back to the jitted entry.
+
+    ``x`` may be a :class:`BlockSource` with its ``means``: the per-block
+    programs then make the block they work on (``_block``).
     """
     bs = max(widths)
     nb = len(widths)
-    x = jnp.asarray(x)
+    made = isinstance(x, BlockSource)
+    if not made:
+        x = jnp.asarray(x)
     labels = jnp.asarray(labels)
     dtype = labels.dtype
     n = labels.shape[0]
@@ -679,8 +937,8 @@ def _stepwise_bcd_fit(
     mask = (jnp.arange(n) < nvalid).astype(dtype)[:, None]
     nv = jnp.asarray(nvalid, dtype)
     label_mean = jnp.sum(labels * mask, axis=0) / nv
-    mu = (mask[:, 0] @ x) / nv
-    means = mu.reshape(nb, bs)
+    mu = None if made else (mask[:, 0] @ x) / nv
+    means = x.means if made else mu.reshape(nb, bs)
     pad_diag = np.stack(
         [(np.arange(bs) >= w).astype(np.float64) for w in widths]
     )
@@ -688,7 +946,7 @@ def _stepwise_bcd_fit(
     # tell "same fit, resumed" from "different data, same shape" (e.g. a
     # re-featurized train set after a seed change) — resuming across that
     # line would silently mix two models.
-    data_sum = (float(jnp.sum(x)), float(jnp.sum(labels)))
+    data_sum = (float(jnp.sum(x.rows if made else x)), float(jnp.sum(labels)))
 
     if resume_state is not None:
         for field, want in (
@@ -744,6 +1002,8 @@ def _stepwise_bcd_fit(
     chol_cache: dict[int, jax.Array] = {}  # factors are constant across epochs
     for e in range(e0, num_iter):
         for i in range(b0 if e == e0 else 0, nb):
+            if made:  # a block for the step, one more for a new factor
+                _count_blocks_made(nvalid, 1 + (i not in chol_cache))
             c_i = chol_cache.get(i)
             if c_i is None:
                 c_i = chol_cache[i] = _bcd_block_factor(
@@ -877,10 +1137,39 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 "does not run under a mesh — fit without a mesh or without "
                 "checkpointing"
             )
-        with trace.host("place", "design_matrix"):  # the eager column pad
-            x, widths = _blocked_design_matrix(
-                features, self.block_size, num_features
-            )
+        source = features if isinstance(features, BlockSource) else None
+        if source is not None:
+            if mesh is not None:
+                raise ValueError(
+                    "a BlockSource (feature blocks made from the rows inside "
+                    "the solver's programs) does not run under a mesh yet: "
+                    "its rows would shard over the data axis with the "
+                    "featurizers replicated — fit it without a mesh, or hand "
+                    "the mesh fit the blocks themselves"
+                )
+            if resumable:
+                raise ValueError(
+                    "a BlockSource fit cannot be checkpointed or resumed yet "
+                    "— hand the checkpointed fit the blocks themselves"
+                )
+            if nvalid is None:
+                nvalid = int(source.rows.shape[0])
+        bcd_plan = _plan_bcd(
+            features, labels, self.num_iter, self.block_size, num_features
+        )
+        if bcd_plan["block_source"] == "made":
+            x, widths = source, source.block_widths()
+            if x.means is None:
+                with trace.host("dispatch", "block_moments"):
+                    sums, _ = block_moments(x, nvalid)
+                    x = BlockSource(
+                        x.rows, x.featurizers, x.widths, sums / nvalid
+                    )
+        else:
+            with trace.host("place", "design_matrix"):  # the eager column pad
+                x, widths = _blocked_design_matrix(
+                    features, self.block_size, num_features, nvalid
+                )
         # Conditioning monitor (ISSUE 15): per-block κ estimates riding
         # the blocked design matrix this fit already formed (row-capped,
         # so the probe never re-uploads a host-staged matrix).  One flag
@@ -889,7 +1178,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             knum.design_conditioning(
                 x, widths, float(self.lam), label="bcd_fit"
             )
-            if knum.active()
+            if knum.active() and not isinstance(x, BlockSource)
             else None
         )
         # Any per-solve κ estimate emitted DURING the fit (the
@@ -898,13 +1187,19 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         cond_ctx = knum.collect_conditioning()
         solve_cond = cond_ctx.__enter__()
         try:
-            return self._fit_dispatch(
+            model = self._fit_dispatch(
                 features, x, labels, num_features, nvalid, widths,
                 checkpoint, resume_from, donate, plan, mesh, resumable,
                 cond_rows, solve_cond,
             )
         finally:
             cond_ctx.__exit__(None, None, None)
+        # What ran, where every fit says it: the report, one instant and one
+        # counter a fit (``bcd_source.held`` / ``bcd_source.made``).
+        self.last_fit_report.bcd_plan = bcd_plan
+        trace.metrics.inc(f"bcd_source.{bcd_plan['block_source']}")
+        trace.instant("bcd_plan", **bcd_plan)
+        return model
 
     def _fit_dispatch(
         self, features, x, labels, num_features, nvalid, widths,
@@ -941,6 +1236,10 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                     checkpoint_cb=cb,
                     resume_state=state,
                 )
+        elif isinstance(x, BlockSource):
+            models, label_mean, means = self._fit_made_ladder(
+                x, labels, nvalid, plan_arg=plan
+            )
         elif mesh is not None:
             # Multi-chip path: the MESH degradation ladder — full
             # (data, model) mesh with per-chip admission, then the
@@ -1252,6 +1551,116 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             report.chosen = f"single_device/{inner_chosen[0]}"
         return out
 
+    def _fit_made_ladder(self, source: BlockSource, labels, nvalid,
+                         plan_arg=None):
+        """The ladder of a fit whose blocks are made: ``fused[made]`` (the
+        one fused program, each scan step making its block) ->
+        ``stepwise[made]`` (the per-block programs, each making its block;
+        the floor).  ``host_staged`` is no rung here: it is the floor for a
+        matrix that lives on the host.  Plans and hints charge what this
+        form holds (the rows, the featurizers' parameters, the factor stack,
+        the block being made and its centred copy) and what it recomputes
+        (every block once a pass)."""
+        nb, bs = len(source), source.block_size
+        widths = source.block_widths()
+        n, k = int(np.shape(labels)[0]), int(np.shape(labels)[1])
+        dtype = jax.dtypes.canonicalize_dtype(labels.dtype)
+        it = np.dtype(dtype).itemsize
+        with trace.host("plan", "budget"):
+            budget = kmem.hbm_budget()
+        with trace.host("place", "lam"):
+            lam_arr = jnp.asarray(self.lam, dtype)
+            nv_arr = jnp.asarray(nvalid, jnp.int32)
+        sds = jax.ShapeDtypeStruct
+        src_s = jax.tree.map(lambda a: sds(a.shape, a.dtype), source)
+        y_s, lam_s, i32_s = sds((n, k), dtype), sds((), dtype), sds((), jnp.int32)
+        mask_s = sds((n, 1), dtype)
+        res_s, m_s, c_s = sds((n, k), dtype), sds((bs, k), dtype), sds((bs, bs), dtype)
+        operands, y_bytes = source.operand_bytes(), it * n * k
+        res_dev = operands + (labels.nbytes if isinstance(labels, jax.Array) else 0)
+        factors = it * nb * bs * bs
+        persist = it * (n * k + nb * bs * k) + factors
+        # temporaries: the block as the featurizer leaves it and its centred
+        # copy, two residual carries, the models carry (the factor stack is
+        # a result of the fused program, not a temporary)
+        made_floor = it * (2 * n * bs + 2 * n * k + nb * bs * k)
+        passes = self.num_iter + 1
+        # a featurizer is charged as one [n, d] x [d, bs] product a block
+        # and pass: the solver cannot see inside it
+        flops = (
+            2.0 * n * bs * bs * nb + self.num_iter * 4.0 * n * bs * k * nb
+            + passes * 2.0 * n * int(source.rows.shape[1]) * bs * nb
+        )
+
+        def plan_fused():
+            return kmem.plan_program(
+                _fused_bcd_fit, src_s, y_s, lam_s, i32_s,
+                self.num_iter, widths, None,
+                label="bcd_fused_made", budget=budget,
+                min_temp_bytes=made_floor, resident_bytes=res_dev,
+            )
+
+        def plan_stepwise():
+            return kmem.plan_program(
+                _bcd_block_solve, src_s, None, mask_s, res_s, m_s, c_s, i32_s,
+                bs, label="bcd_stepwise_made", budget=budget,
+                extra_bytes=persist, resident_bytes=res_dev,
+            )
+
+        def run_fused(plan):
+            with trace.host("place", "operands"):
+                y_dev = jnp.asarray(labels)
+            _count_blocks_made(nvalid, nb * passes)
+            return _execute_fused_bcd(
+                plan, (), source, y_dev, lam_arr, nv_arr, self.num_iter, widths,
+            )[:3]  # the factor stack is dropped here
+
+        def run_stepwise(plan):
+            y_dev = jnp.asarray(labels)
+            reusable = plan is not None and _single_device_arrays(source, y_dev)
+            return _stepwise_bcd_fit(
+                source, y_dev, self.lam, nvalid, self.num_iter, widths,
+                block_solve=plan.compiled if reusable else None,
+            )
+
+        report = kmem.FitReport(label="bcd_fit", budget_bytes=budget)
+        self.last_fit_report = report
+        common = {
+            "arg_bytes": operands + y_bytes, "resident_bytes": res_dev,
+            "flops": flops, "hbm_passes": passes,
+        }
+        cands = [
+            autoshard.Candidate(
+                "fused[made]", "fused", plan_fused, run_fused,
+                hints=dict(
+                    common, temp_bytes=made_floor,
+                    out_bytes=it * (nb * bs * k + k + nb * bs) + factors,
+                    dispatches=1,
+                ),
+                prior_rank=0,
+            ),
+            autoshard.Candidate(
+                "stepwise[made]", "stepwise", plan_stepwise, run_stepwise,
+                hints=dict(
+                    common, temp_bytes=it * (2 * n * bs + n * k),
+                    out_bytes=it * nb * bs * k, extra_bytes=persist,
+                    dispatches=nb * passes + 2,
+                ),
+                prior_rank=1, floor=True,
+            ),
+        ]
+        with kprof.phase("bcd_fit"):
+            return autoshard.run_search(
+                "bcd_fit", cands, report,
+                fingerprint=autoshard.fingerprint(
+                    "bcd_fit", "made", n, k, widths, self.num_iter,
+                    str(source.dtype), str(dtype), None,
+                    autoshard.device_fingerprint(),
+                ),
+                plan=plan_arg,
+                budget=budget,
+            )
+
     def _fit_ladder(
         self, features, x, labels, num_features, nvalid, widths, donate,
         plan_arg=None, report=None,
@@ -1327,7 +1736,9 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             )
 
         def rebuild_x():
-            xx, _ = _blocked_design_matrix(features, self.block_size, num_features)
+            xx, _ = _blocked_design_matrix(
+                features, self.block_size, num_features, nvalid
+            )
             if isinstance(xx, jax.Array) and xx.is_deleted():
                 raise kmem.LadderSourceLost(
                     "design matrix was donated (donate=True) and the source "

@@ -7,11 +7,19 @@ Gaussian or Cauchy W) then StandardScaler — the batches are the solver's
 feature blocks; BlockLeastSquares runs ``numEpochs`` BCD sweeps over them;
 evaluation streams through ``apply_and_evaluate`` exactly as the reference
 does (:105-113): one compiled step a block, then the evaluator's round trip.
+
+The reference's ``batchFeaturizer`` is a ``Seq`` of lazy chains, and so is
+this one: the solver is handed a ``solvers.block.BlockSource`` (the rows and
+the stacked chains), the scalers are fitted from one moments pass that keeps
+no block, and the test split's blocks are made as the evaluation loop
+reaches them.  At the documented 50 blocks the 204,800-column design matrix
+never exists; where it fits the device the solver holds it, by its own rule.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 from dataclasses import dataclass
 
@@ -27,7 +35,7 @@ from ..loaders.timit import TIMIT_DIMENSION, TIMIT_NUM_CLASSES, TimitFeaturesDat
 from ..ops.stats import CosineRandomFeatures, StandardScaler
 from ..ops.util import ClassLabelIndicatorsFromIntLabels, MaxClassifier
 from ..parallel.mesh import mask_pad_rows, padded_shard_rows, parse_mesh
-from ..solvers.block import BlockLeastSquaresEstimator
+from ..solvers.block import BlockLeastSquaresEstimator, BlockSource, block_moments
 from ..utils.platform import init_device
 
 
@@ -71,35 +79,65 @@ jax.tree_util.register_pytree_node(
 )
 
 
-def build_batch_featurizers(conf: TimitConfig, train_data, nvalid=None) -> list:
-    """numCosines [CosineRandomFeatures -> StandardScaler] chains (:65-84).
+@functools.partial(
+    jax.jit, static_argnames=("num_blocks", "dimension", "width", "w_dist")
+)
+def _draw_cosine_blocks(key, gamma, *, num_blocks, dimension, width, w_dist):
+    """``num_blocks`` CosineRandomFeatures drawn by one program, stacked
+    along a leading block axis: ``key, sub = split(key)`` a block, then
+    ``CosineRandomFeatures.create(..., sub)``, the per-block loop's recipe."""
+
+    def one(key, _):
+        key, sub = jax.random.split(key)
+        return key, CosineRandomFeatures.create(
+            dimension, width, gamma, sub, w_dist=w_dist
+        )
+
+    return jax.lax.scan(one, key, None, length=num_blocks)[1]
+
+
+def fit_block_featurizers(conf: TimitConfig, train_data, nvalid=None):
+    """The numCosines [CosineRandomFeatures -> StandardScaler] chains
+    (:65-84) as ONE ``FeaturizerBlock`` whose leaves carry a leading block
+    axis, what ``BlockSource`` takes.  The scalers' means and deviations
+    come from one moments pass over the made cosine blocks
+    (``solvers.block.block_moments``): no block is held.
 
     ``nvalid``: true row count when ``train_data`` carries zero pad rows —
-    cos maps zero rows to nonzero ``cos(b)``, so pad rows are masked back to
-    zero before the scaler's moment sums.
-    """
-    key = jax.random.PRNGKey(conf.seed)
-    featurizers = []
-    for _ in range(conf.num_cosines):
-        with trace.host("dispatch", "fit_featurizer_block"):
-            key, sub = jax.random.split(key)
-            rf = CosineRandomFeatures.create(
-                conf.dimension,
-                conf.num_cosine_features,
-                conf.gamma,
-                sub,
-                w_dist=conf.rf_type,
-            )
-            feats = mask_pad_rows(rf(train_data), nvalid)
-            scaler = StandardScaler().fit(feats, nvalid=nvalid)
-            featurizers.append(FeaturizerBlock([rf, scaler]))
-    return featurizers
+    cos maps zero rows to nonzero ``cos(b)``, so the pass leaves them out."""
+    with trace.host("dispatch", "draw_featurizers"):
+        cosines = _draw_cosine_blocks(
+            jax.random.PRNGKey(conf.seed), conf.gamma,
+            num_blocks=conf.num_cosines, dimension=conf.dimension,
+            width=conf.num_cosine_features, w_dist=conf.rf_type,
+        )
+    with trace.host("dispatch", "block_moments"):
+        n = int(train_data.shape[0]) if nvalid is None else nvalid
+        s, sq = block_moments(BlockSource(train_data, cosines), n)
+        scalers = StandardScaler().from_moments(n, s, sq)
+    return FeaturizerBlock([cosines, scalers])
+
+
+def build_batch_featurizers(conf: TimitConfig, train_data, nvalid=None) -> list:
+    """:func:`fit_block_featurizers`' chains as a list, a block each (what
+    a caller that holds the blocks applies one by one)."""
+    stacked = fit_block_featurizers(conf, train_data, nvalid)
+    with trace.host("dispatch", "unstack_featurizers"):
+        return [
+            jax.tree.map(lambda a: a[i], stacked) for i in range(conf.num_cosines)
+        ]
 
 
 def run(conf: TimitConfig, data: TimitFeaturesData, mesh=None) -> dict:
     """With ``mesh``, features are row-sharded over the data axis and the
     multi-epoch BCD solver runs distributed — the reference runs this over
-    partitioned RDDs end to end (TimitPipeline.scala:58-113)."""
+    partitioned RDDs end to end (TimitPipeline.scala:58-113); the blocks are
+    then held, a block source having no mesh form yet.
+
+    Hands back, beside ``test_error`` and ``seconds``, what a caller needs
+    to check the fit: ``model``, ``featurizers`` (the stacked chains; under
+    a mesh the list of them), ``test_scores`` / ``test_predictions`` as the
+    evaluator last saw them, and ``fit_report``."""
     configure_logging()
     log = _Log()
     t0 = time.perf_counter()
@@ -111,7 +149,11 @@ def run(conf: TimitConfig, data: TimitFeaturesData, mesh=None) -> dict:
         ev = MulticlassClassifierEvaluator(
             predicted, data.test.labels, conf.num_classes
         )
-        results["test_error"] = 100.0 * ev.total_error
+        results.update(
+            test_error=100.0 * ev.total_error,
+            test_scores=pred,
+            test_predictions=predicted,
+        )
         log.log_info("TEST Error is %s%%", results["test_error"])
 
     # one root span a fit; the three stages tile it but for glue
@@ -124,14 +166,24 @@ def run(conf: TimitConfig, data: TimitFeaturesData, mesh=None) -> dict:
             test_data = jnp.asarray(data.test.data)
 
         with stage_timer("featurize"):
-            batch_featurizer = build_batch_featurizers(conf, train_data, nvalid)
-            training_batches = [
-                mask_pad_rows(f(train_data), nvalid) for f in batch_featurizer
-            ]
             labels = ClassLabelIndicatorsFromIntLabels(conf.num_classes)(
                 data.train.labels
             )
-            test_batches = [f(test_data) for f in batch_featurizer]
+            if mesh is None:
+                featurizers = fit_block_featurizers(conf, train_data, nvalid)
+                # the chains end in their scalers: a made block's columns
+                # have mean zero, and the solver need not take them again
+                training_batches = BlockSource(
+                    train_data, featurizers,
+                    means=jnp.zeros(featurizers.nodes[-1].mean.shape),
+                )
+                test_batches = BlockSource(test_data, featurizers)
+            else:
+                featurizers = build_batch_featurizers(conf, train_data, nvalid)
+                training_batches = [
+                    mask_pad_rows(f(train_data), nvalid) for f in featurizers
+                ]
+                test_batches = [f(test_data) for f in featurizers]
 
         with stage_timer("solve"):
             solver = BlockLeastSquaresEstimator(
@@ -142,7 +194,12 @@ def run(conf: TimitConfig, data: TimitFeaturesData, mesh=None) -> dict:
 
         with stage_timer("eval"):
             model.apply_and_evaluate(test_batches, evaluator)
-    results["seconds"] = time.perf_counter() - t0
+    results.update(
+        seconds=time.perf_counter() - t0,
+        model=model,
+        featurizers=featurizers,
+        fit_report=solver.last_fit_report,
+    )
     return results
 
 

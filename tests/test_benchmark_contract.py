@@ -43,6 +43,12 @@ PROGRAMS = {
     r"^jit__fused_bwls": "keystone_tpu.solvers.weighted",
     r"^jit__class_solves": "keystone_tpu.solvers.weighted",
     r"^jit__block_apply": "keystone_tpu.solvers.block",
+    r"^jit__block_moments$": "keystone_tpu.solvers.block",
+    r"^jit__make_block$": "keystone_tpu.solvers.block",
+    r"^jit__hold_blocks$": "keystone_tpu.solvers.block",
+    r"^jit__block_step$": "keystone_tpu.solvers.block",
+    r"^jit__draw_cosine_blocks$": "keystone_tpu.workloads.timit",
+    r"^jit__confusion_counts$": "keystone_tpu.evaluation.multiclass",
 }
 
 #: pipeline of the cell -> the workload module its window drives, and the
@@ -69,12 +75,16 @@ STAGES = {
         "keystone_tpu.workloads.imagenet_sift_lcs_fv",
         ["sample_descriptors", "featurize", "featurize_test", "solve", "eval"],
     ),
+    "timit_rf_full": (  # drives ``timit.run`` itself
+        "keystone_tpu.workloads.timit", ["featurize", "solve", "eval"],
+    ),
 }
 
 COUNTERS = {
     "fv.descriptor_passes": "keystone_tpu.workloads.voc_sift_fisher",
     "gmm.iterations": "keystone_tpu.workloads.voc_sift_fisher",
     "mesh.psum_bytes": "keystone_tpu.parallel.collectives",
+    "bcd.block_rows_made": "keystone_tpu.solvers.block",
 }
 
 HISTOGRAMS = ["stage_ms", "stage_wait_ms", "stage_h2d_mb"]

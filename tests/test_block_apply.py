@@ -183,6 +183,45 @@ def test_streamed_apply_calls_the_evaluator_once_a_block(rng, as_list, b):
     _close(seen[-1], np.asarray(model(batch)))
 
 
+def test_streamed_apply_makes_one_block_at_a_time(rng):
+    """An iterable of known length is not turned into a list: a block is
+    asked for when the loop reaches it, after the evaluator has seen the
+    block before it.  Fifty made blocks (a ``BlockSource`` over the rows to
+    score): fifty running sums, the last the dense apply's."""
+    from keystone_tpu.ops.stats import CosineRandomFeatures
+    from keystone_tpu.solvers.block import BlockSource
+
+    nb, d, bs = 50, 6, 8
+    chains = [CosineRandomFeatures.create(d, bs, 0.5, jax.random.PRNGKey(i)) for i in range(nb)]
+    rows = jnp.asarray(rng.normal(size=(ROWS, d)), jnp.float32)
+    model = BlockLinearMapper(
+        [jnp.asarray(rng.normal(size=(bs, K)), jnp.float32) for _ in range(nb)],
+        bs, jnp.asarray(rng.normal(size=K), jnp.float32),
+        [StandardScalerModel(jnp.asarray(rng.normal(size=bs), jnp.float32)) for _ in range(nb)],
+    )
+    order = []
+
+    class Watched:
+        def __len__(self):
+            return nb
+
+        def __iter__(self):
+            for i, blk in enumerate(BlockSource.stacked(rows, chains)):
+                order.append(("made", i))
+                yield blk
+
+    seen = []
+
+    def evaluator(scores):
+        order.append(("seen", len(seen)))
+        seen.append(np.asarray(scores))
+
+    model.apply_and_evaluate(Watched(), evaluator)
+    assert order == [(what, i) for i in range(nb) for what in ("made", "seen")]
+    dense = model(jnp.concatenate([f(rows) for f in chains], axis=1))
+    _close(seen[-1], np.asarray(dense))
+
+
 @pytest.mark.parametrize(
     "batch, message",
     [

@@ -326,6 +326,58 @@ def test_apply_compiles_at_benchmark_widths(one_chip):
     assert moved < 1.1 * 50000 * 10000 * 4, moved
 
 
+# -- TimitPipeline at its documented 50 blocks (`timit_rf_50`) ---------------------
+
+
+def test_made_block_solve_compiles_at_published_widths(one_chip):
+    """``f32[32768, 440]`` rows x 50 chains of 4,096 cosine features x 147
+    classes, 5 epochs, as one fused program that makes each block where it
+    consumes it (``timit_rf_fit_full``): no array of the 204,800 columns in
+    the compiled text (the design matrix would be 26.8 GB), the cosine inside
+    a loop's body, and arguments, temporaries and results together under
+    6 GB, of it the fifty Cholesky factors 3.36 GB as a result (a buffer the
+    allocator counts) and under 1 GB of temporaries.  And the held program at
+    ``timit_rf_share8``'s shape, six blocks sliced out of a 3.2 GB matrix,
+    keeps the temporaries it had before the solver could make a block."""
+    import re
+
+    from keystone_tpu.ops.stats import CosineRandomFeatures, StandardScalerModel
+    from keystone_tpu.solvers import block
+    from keystone_tpu.workloads.timit import FeaturizerBlock
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, d, nb, bs, k = 32768, 440, 50, 4096, 147
+    chains = FeaturizerBlock([
+        CosineRandomFeatures(sds(nb, bs, d), sds(nb, bs)),
+        StandardScalerModel(sds(nb, bs), sds(nb, bs)),
+    ])
+    source = block.BlockSource(sds(n, d), chains, None, sds(nb, bs))
+    scalars = (sds(), sds(dtype=jnp.int32))
+    compiled = block._fused_bcd_fit.lower(
+        source, sds(n, k), *scalars, 5, (bs,) * nb, None
+    ).compile()
+    text = compiled.as_text()
+    assert not re.search(rf"[\[,]{nb * bs}[\],]", text)  # in no array's shape
+    row_arrays = {int(w) for w in re.findall(r"\[32768,(\d+)\]", text)}
+    assert bs in row_arrays and max(row_arrays) == bs, sorted(row_arrays)
+    cosines = re.findall(r' cosine\(.*?op_name="([^"]*)"', text)
+    assert cosines and all("/while/body/" in name for name in cosines), cosines
+    mem = compiled.memory_analysis()
+    factors = 4 * nb * bs * bs
+    assert factors < mem.output_size_in_bytes < factors + (1 << 28)
+    assert mem.temp_size_in_bytes < 1 << 30
+    assert (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes < 6e9
+    )
+
+    held = block._fused_bcd_fit.lower(
+        sds(n, 6 * bs), sds(n, k), *scalars, 5, (bs,) * 6, None
+    ).compile()
+    assert held.memory_analysis().temp_size_in_bytes == pytest.approx(561_119_232, rel=0.01)
+
+
 # -- ImageNetSiftLcsFV at its own widths (`imagenet_sift_lcs_fv_16`) ------------------
 
 
