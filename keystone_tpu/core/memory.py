@@ -165,6 +165,17 @@ def shard_bytes(aval, mesh=None) -> int:
     return m * np.dtype(aval.dtype).itemsize
 
 
+def _device_stats(device) -> tuple[int | None, int]:
+    """``(limit, in_use)`` from a device's ``memory_stats()``; the limit is
+    ``None`` on backends without stats."""
+    try:
+        stats = device.memory_stats() or {}
+    except Exception:  # noqa: BLE001 — backends without stats
+        return None, 0
+    limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    return (int(limit) if limit else None), int(stats.get("bytes_in_use", 0))
+
+
 def hbm_budget(device=None) -> int | None:
     """Bytes a program may plan against, or ``None`` when unknowable.
 
@@ -179,17 +190,20 @@ def hbm_budget(device=None) -> int | None:
     raw = os.environ.get(HBM_BUDGET_ENV, "").strip()
     if raw:
         return parse_bytes(raw)
-    device = device if device is not None else jax.devices()[0]
-    try:
-        stats = device.memory_stats()
-    except Exception:  # noqa: BLE001 — backends without stats
-        return None
-    if not stats:
-        return None
-    limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-    if not limit:
-        return None
-    return int(limit) - int(stats.get("bytes_in_use", 0))
+    limit, in_use = _device_stats(device if device is not None else jax.devices()[0])
+    return None if limit is None else limit - in_use
+
+
+def hbm_capacity(device=None) -> int | None:
+    """The device's whole byte budget, whatever is in use: the
+    ``KEYSTONE_HBM_BUDGET`` override (capacity semantics) > the device's
+    ``memory_stats()`` limit > ``None``.  Unlike :func:`hbm_budget`'s free
+    bytes it reads the same before every fit of a process, so a rule that
+    shapes a compiled program from it picks the same program each time."""
+    raw = os.environ.get(HBM_BUDGET_ENV, "").strip()
+    if raw:
+        return parse_bytes(raw)
+    return _device_stats(device if device is not None else jax.devices()[0])[0]
 
 
 @dataclasses.dataclass

@@ -378,6 +378,79 @@ def test_made_block_solve_compiles_at_published_widths(one_chip):
     assert held.memory_analysis().temp_size_in_bytes == pytest.approx(561_119_232, rel=0.01)
 
 
+#: sha256 of the lowered text of the fused program before it could keep a
+#: made block (PR 38's, read from its own checkout: PERF.md §6, PR 39): the
+#: held form at ``timit_rf_share8``'s shape and the made form keeping none
+_HELD_TEXT = "f106907f3dd05c18361d1516bcac3a3936f26e6d472123f305e3d7fe77061383"
+_MADE_TEXT = "2ae5d08dfd2453c8dbede0cfe9dce97c16657ca1e38370513fe2f96ba31d254e"
+#: and the compiled text of the made form keeping none, in characters
+_MADE_COMPILED_CHARS = 2_188_943
+
+
+def test_made_solve_keeping_blocks_compiles_at_published_widths(one_chip, monkeypatch):
+    """``timit_rf_fit_full`` with the made blocks kept that the rule keeps
+    under the v5e's 16.91 GB limit: the compiled program's arguments,
+    temporaries and results fit under that limit less its tenth; the kept
+    blocks are one ``bf16[h + 1, 32768, 4096]`` result, allocated and not
+    zeroed, that every gram and step reads in place and each later block is
+    made into (no block-sized buffer in any loop, no copy of the stack), so
+    the program is no larger than the one that keeps nothing; no array of
+    the 204,800 columns exists.  The programs for an array ``x`` and for a
+    source that keeps nothing lower to PR 38's text, byte for byte."""
+    import hashlib
+    import re
+
+    from keystone_tpu.ops.stats import CosineRandomFeatures, StandardScalerModel
+    from keystone_tpu.solvers import block
+    from keystone_tpu.workloads.timit import FeaturizerBlock
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, d, nb, bs, k, limit = 32768, 440, 50, 4096, 147, 16_909_336_064
+    chains = FeaturizerBlock([
+        CosineRandomFeatures(sds(nb, bs, d), sds(nb, bs)),
+        StandardScalerModel(sds(nb, bs), sds(nb, bs)),
+    ])
+    source = block.BlockSource(sds(n, d), chains, None, sds(nb, bs))
+    scalars = (sds(), sds(dtype=jnp.int32))
+    widths = (bs,) * nb
+
+    def digest(*args):
+        text = block._fused_bcd_fit.lower(*args).as_text()
+        return hashlib.sha256(text.encode()).hexdigest()
+
+    assert digest(sds(n, 6 * bs), sds(n, k), *scalars, 5, (bs,) * 6, None) == _HELD_TEXT
+    assert digest(source, sds(n, k), *scalars, 5, widths, None) == _MADE_TEXT
+
+    monkeypatch.setenv("KEYSTONE_HBM_BUDGET", str(limit))
+    monkeypatch.setattr(block, "_kept_dtype", lambda dtype: np.dtype(jnp.bfloat16))  # the TPU's
+    plan = block._plan_bcd(source, sds(n, k), 5, bs)
+    h = plan["held_blocks"]
+    assert plan["block_source"] == "made" and 30 <= h < nb, plan
+    assert plan["held_stack_bytes"] == (h + 1) * n * bs * 2
+    compiled = block._fused_bcd_fit.lower(
+        source, sds(n, k), *scalars, 5, widths, None, hold=h, hold_dtype="bfloat16"
+    ).compile()
+    text = compiled.as_text()
+    stack = f"bf16[{h + 1},32768,4096]"
+    assert not re.search(rf"[\[,]{nb * bs}[\],]", text)
+    assert not re.search(rf"= {re.escape(stack)}\S* (copy|broadcast)\(", text)
+    bodies = dict(re.findall(r"^(%\S+) \(.*?\) -> .*?\{\n(.*?)^\}", text, re.M | re.S))
+    loops = [bodies[b] for b in set(re.findall(r"body=(%[\w.-]+)", text)) if stack in bodies[b]]
+    assert len(loops) >= 4  # the stack's scan, the grams, the steps, a block made again
+    for body in loops:  # every buffer of a block's size is the stack itself
+        sizes = re.findall(r"^\s*%\S+ = (\w+\[(?:\d+,)?32768,4096\])", body, re.M)
+        assert set(sizes) <= {stack}, set(sizes)
+    mem = compiled.memory_analysis()
+    kept, factors = plan["held_stack_bytes"], 4 * nb * bs * bs
+    assert kept + factors < mem.output_size_in_bytes < kept + factors + (1 << 28)
+    assert mem.temp_size_in_bytes < 1 << 29
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes
+    assert total < limit - limit // 10, total
+    assert len(text) < 1.1 * _MADE_COMPILED_CHARS, len(text)  # PR 39's first form: 4.4 M
+
+
 # -- ImageNetSiftLcsFV at its own widths (`imagenet_sift_lcs_fv_16`) ------------------
 
 
