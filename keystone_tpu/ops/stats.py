@@ -8,9 +8,12 @@ directly.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
+from ..core import trace
 from ..core.pipeline import Estimator, Transformer, node
 from ..parallel.collectives import sharded_moments
 
@@ -143,6 +146,32 @@ class LinearRectifier(Transformer):
 
     def __call__(self, batch):
         return jnp.maximum(self.max_val, batch - self.alpha)
+
+
+@node(data_fields=("signs",), meta_fields=())
+class RandomFFTBlock(Transformer):
+    """One solver block of MnistRandomFFT's features (reference
+    MnistRandomFFT.scala:44-48): for each row of ``signs`` ``[f, d]`` the
+    chain RandomSign -> PaddedFFT -> LinearRectifier(0), the ``f`` outputs
+    side by side in ZipVectors' column order, ``[N, d] -> [N, f * n / 2]``
+    with ``n = next_pow2(d)``.  One node whose one leaf may carry a leading
+    block axis: what ``solvers.block.BlockSource`` takes."""
+
+    def __init__(self, signs):
+        self.signs = signs
+
+    def __call__(self, batch):
+        f, d = self.signs.shape
+        n = next_power_of_two(d)
+        # counted where a program that makes these blocks is traced
+        trace.metrics.inc("fft_form.xla")
+        trace.instant(
+            "fft_form", rows=math.prod(batch.shape[:-1]), n=n, ffts=f, width=d,
+            dtype=str(batch.dtype),
+        )
+        signed = RandomSignNode(self.signs)(batch[..., None, :])
+        out = LinearRectifier(0.0)(PaddedFFT()(signed))
+        return out.reshape(*batch.shape[:-1], f * (n // 2))
 
 
 @node(data_fields=(), meta_fields=())

@@ -215,7 +215,11 @@ class BlockSource:
     are made zero, as ``_blocked_design_matrix`` pads them).  ``means``
     ``[B, bs]``: the blocks' column means over the valid rows where the
     caller has them (a scaler at the chain's end makes them zero); ``None``
-    and the solver takes them in one more pass (``_block_moments``).
+    and the solver takes them in one more pass (``_block_moments``), which
+    also tells it ``zero_columns`` ``[B, bs]``: 1.0 where a column is zero
+    on every valid row (a rectified feature never positive), whose system
+    is then singular at lambda 0; the solver gives such a column a unit
+    diagonal, as it gives a pad column, so its coefficient is 0.
 
     ``BlockLeastSquaresEstimator.fit`` holds the blocks as one matrix when
     that fits the device and makes them inside its programs otherwise;
@@ -223,11 +227,12 @@ class BlockSource:
     how ``apply_and_evaluate`` streams a test split.
     """
 
-    def __init__(self, rows, featurizers, widths=None, means=None):
+    def __init__(self, rows, featurizers, widths=None, means=None, zero_columns=None):
         self.rows = rows
         self.featurizers = featurizers
         self.widths = None if widths is None else tuple(int(w) for w in widths)
         self.means = means
+        self.zero_columns = zero_columns
 
     @classmethod
     def stacked(cls, rows, featurizers: Sequence, **kw) -> "BlockSource":
@@ -271,7 +276,7 @@ class BlockSource:
     def operand_bytes(self) -> int:
         """What a program that makes the blocks holds in their place."""
         return kmem.array_bytes(
-            *jax.tree.leaves((self.rows, self.featurizers, self.means))
+            *jax.tree.leaves((self.rows, self.featurizers, self.means, self.zero_columns))
         )
 
     def make(self, i):
@@ -288,13 +293,14 @@ class BlockSource:
         """The blocks one by one, each made when it is asked for and cut to
         its own width: what ``apply_and_evaluate`` streams."""
         for i, w in enumerate(self.block_widths()):
+            trace.metrics.inc("bcd.block_rows_applied", int(self.rows.shape[0]))
             yield _make_block(self, i)[:, :w]
 
 
 jax.tree_util.register_pytree_node(
     BlockSource,
-    lambda s: ((s.rows, s.featurizers, s.means), s.widths),
-    lambda widths, kids: BlockSource(kids[0], kids[1], widths, kids[2]),
+    lambda s: ((s.rows, s.featurizers, s.means, s.zero_columns), s.widths),
+    lambda widths, kids: BlockSource(kids[0], kids[1], widths, kids[2], kids[3]),
 )
 
 
@@ -307,8 +313,36 @@ def _make_block(source: BlockSource, i):
 
 def _count_blocks_made(rows: int, blocks: int) -> None:
     """``bcd.block_rows_made``: training rows x blocks made, reckoned from
-    shapes where the programs that make them are called (every pass)."""
+    shapes where the programs that make them are called (every pass); the
+    streamed apply counts its own makes, ``bcd.block_rows_applied``."""
     trace.metrics.inc("bcd.block_rows_made", int(rows) * int(blocks))
+
+
+def _make_scratch(source: BlockSource) -> int:
+    """Bytes a make of one block holds beyond the block itself: the
+    compiled ``_make_block``'s temporaries less one block, whose room the
+    centred copy that ``_made_need`` charges leaves free while the make
+    runs.  Zero for a featurizer whose product is the block.  Compiled once
+    a process, shape and device."""
+    leaves, treedef = jax.tree.flatten(source)
+    return _make_scratch_of(
+        treedef, tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in leaves),
+        getattr(source.rows, "sharding", None),
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _make_scratch_of(treedef, leaves: tuple, where) -> int:
+    shapes = jax.tree.unflatten(
+        treedef, [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where) for a in leaves]
+    )
+    with trace.host("plan", "make_scratch"):
+        analysis = _make_block.lower(
+            shapes, jax.ShapeDtypeStruct((), jnp.int32, sharding=where)
+        ).compile().memory_analysis()
+    temps = 0 if analysis is None else int(analysis.temp_size_in_bytes)
+    block = shapes.rows.shape[0] * shapes.block_size * np.dtype(shapes.dtype).itemsize
+    return max(0, temps - block)
 
 
 def _valid_rows(n: int, nvalid):
@@ -478,6 +512,8 @@ def _fused_bcd_impl(x, labels, lam, nvalid, num_iter: int, widths, mesh,
             for w in widths
         ]
     )
+    if isinstance(x, BlockSource) and x.zero_columns is not None:
+        pad_diag = pad_diag + x.zero_columns  # zero on every row: as a pad column
 
     # Regularized grams, factored once (they are constant across epochs —
     # the reference caches them the same way via its gram RDD persist).
@@ -883,8 +919,9 @@ def _plan_bcd(features, labels, num_iter: int, block_size: int,
     in a stack of ``h + 1`` slots (the last takes the block being made):
     ``h = min(B - 1, (capacity - made_bytes - capacity / 10) // kept block
     - 1)``, never below 0, where ``made_bytes`` is what the made program is
-    charged with at ``h = 0`` (operands, labels, :func:`_made_need`) and a
-    kept block is ``rows x width`` in :func:`_kept_dtype`.  The capacity is
+    charged with at ``h = 0`` (operands, labels, :func:`_made_need` and what
+    a make holds beyond it, ``make_scratch_bytes``: :func:`_make_scratch`)
+    and a kept block is ``rows x width`` in :func:`_kept_dtype`.  The capacity is
     ``core.memory.hbm_capacity()``, the device's limit, not its free bytes,
     so every fit of a process picks the same ``h`` and the same program;
     the free bytes only cap it where the process holds more than the tenth
@@ -925,6 +962,9 @@ def _plan_bcd(features, labels, num_iter: int, block_size: int,
     if budget is None or held - credit <= budget:
         plan["passes_a_block"] = 1
         return plan
+    scratch = _make_scratch(features)
+    made += scratch
+    plan.update(made_bytes=made, make_scratch_bytes=scratch)
     kept = _kept_dtype(dtype)
     kept_block = n * bs * kept.itemsize
     capacity = kmem.hbm_capacity() or budget
@@ -1061,6 +1101,8 @@ def _stepwise_bcd_fit(
     pad_diag = np.stack(
         [(np.arange(bs) >= w).astype(np.float64) for w in widths]
     )
+    if made and x.zero_columns is not None:
+        pad_diag = pad_diag + np.asarray(x.zero_columns)
     # Cheap content fingerprint of the inputs: shape checks alone cannot
     # tell "same fit, resumed" from "different data, same shape" (e.g. a
     # re-featurized train set after a seed change) — resuming across that
@@ -1280,9 +1322,10 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             x, widths = source, source.block_widths()
             if x.means is None:
                 with trace.host("dispatch", "block_moments"):
-                    sums, _ = block_moments(x, nvalid)
+                    sums, squares = block_moments(x, nvalid)
                     x = BlockSource(
-                        x.rows, x.featurizers, x.widths, sums / nvalid
+                        x.rows, x.featurizers, x.widths, sums / nvalid,
+                        (squares == 0).astype(sums.dtype),
                     )
         else:
             with trace.host("place", "design_matrix"):  # the eager column pad
@@ -1701,9 +1744,11 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         factors = it * nb * bs * bs
         persist = it * (n * k + nb * bs * k) + factors
         # temporaries: the block as the featurizer leaves it and its centred
-        # copy, two residual carries, the models carry; the factor stack and
-        # the kept blocks are results of the fused program, not temporaries
+        # copy, what the make holds beyond them, two residual carries, the
+        # models carry; the factor stack and the kept blocks are results of
+        # the fused program, not temporaries
         made_floor, made_out = _made_need(n, k, nb, bs, it)
+        made_floor += bcd_plan["make_scratch_bytes"]
         keep, stack = bcd_plan["held_blocks"], bcd_plan["held_stack_bytes"]
         kept = dict(hold=keep, hold_dtype=bcd_plan["held_dtype"]) if keep else {}
         passes = self.num_iter + 1

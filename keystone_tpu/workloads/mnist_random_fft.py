@@ -6,12 +6,18 @@ Pipeline: CSV load -> per-FFT-batch [RandomSign -> PaddedFFT -> LinearRectifier]
 MulticlassClassifierEvaluator.  784-pixel inputs give 512 PaddedFFT features
 per FFT, so blockSize/512 FFTs land in each solver block, exactly as the
 reference computes fftsPerBatch/numFFTBatches (:31-33).
+
+A solver block's chains are one node, ``ops.stats.RandomFFTBlock``, and the
+solver is handed what makes the blocks (``solvers.block.BlockSource``), so
+the documented 200 FFTs (102,400 columns, 24.6 GB at MNIST's 60,000 rows)
+need no design matrix: each block is made where a pass consumes it.
 """
 
 from __future__ import annotations
 
 import argparse
 from dataclasses import dataclass
+import functools
 import math
 import time
 
@@ -27,7 +33,7 @@ from ..core.pipeline import Pipeline
 from ..core.resilience import assert_all_finite, numerics_guard_enabled
 from ..evaluation.multiclass import MulticlassClassifierEvaluator
 from ..loaders.csv_loader import LabeledData, csv_data_loader
-from ..ops.stats import LinearRectifier, PaddedFFT, RandomSignNode
+from ..ops.stats import LinearRectifier, PaddedFFT, RandomFFTBlock, RandomSignNode
 from ..ops.util import (
     ClassLabelIndicatorsFromIntLabels,
     GroupConcatFeaturizer,
@@ -35,7 +41,7 @@ from ..ops.util import (
     ZipVectors,
 )
 from ..parallel.mesh import padded_shard_rows, parse_mesh
-from ..solvers.block import BlockLeastSquaresEstimator
+from ..solvers.block import BlockLeastSquaresEstimator, BlockSource
 from ..utils.platform import init_device
 from . import serve_common
 
@@ -93,27 +99,62 @@ class MnistRandomFFTConfig:
     serve_mesh: str | None = None
 
 
-def build_featurizer_batches(conf: MnistRandomFFTConfig):
-    """The per-batch featurizers (:44-48): blockSize/512 FFT chains per batch."""
+def _fft_batches(conf: MnistRandomFFTConfig) -> tuple[int, int]:
+    """(numFFTBatches, fftsPerBatch) as the reference computes them (:31-33)."""
     ffts_per_batch = conf.block_size // 512
-    num_fft_batches = math.ceil(conf.num_ffts / ffts_per_batch)
-    key = jax.random.PRNGKey(conf.seed)
-    batches = []
-    for _ in range(num_fft_batches):
-        chain = []
-        for _ in range(ffts_per_batch):
-            key, sub = jax.random.split(key)
-            chain.append(
-                Pipeline(
-                    [
-                        RandomSignNode.create(conf.mnist_image_size, sub),
-                        PaddedFFT(),
-                        LinearRectifier(0.0),
-                    ]
-                )
-            )
-        batches.append(chain)
-    return batches
+    return math.ceil(conf.num_ffts / ffts_per_batch), ffts_per_batch
+
+
+def build_featurizer_batches(conf: MnistRandomFFTConfig):
+    """The per-batch featurizers (:44-48): blockSize/512 FFT chains per
+    batch, unstacked from :func:`draw_block_featurizers`' signs (the list a
+    caller that holds the blocks applies one by one)."""
+    signs = draw_block_featurizers(conf).signs
+    with trace.host("dispatch", "unstack_featurizers"):
+        return [
+            [
+                Pipeline([RandomSignNode(s), PaddedFFT(), LinearRectifier(0.0)])
+                for s in group
+            ]
+            for group in signs
+        ]
+
+
+@functools.partial(jax.jit, static_argnames=("num_blocks", "ffts_per_block", "size"))
+def _draw_sign_blocks(key, *, num_blocks, ffts_per_block, size):
+    """Every FFT's signs drawn by one program, in block order: ``key, sub =
+    split(key)``, then ``RandomSignNode.create(size, sub)``; stacked
+    ``[num_blocks, ffts_per_block, size]``."""
+
+    def one(key, _):
+        key, sub = jax.random.split(key)
+        return key, RandomSignNode.create(size, sub).signs
+
+    signs = jax.lax.scan(one, key, None, length=num_blocks * ffts_per_block)[1]
+    return RandomFFTBlock(signs.reshape(num_blocks, ffts_per_block, size))
+
+
+def draw_block_featurizers(conf: MnistRandomFFTConfig) -> RandomFFTBlock:
+    """The RandomSign draws of every FFT (:44-48) from ``PRNGKey(seed)``, as
+    ONE ``RandomFFTBlock`` whose leaf carries a leading block axis: what
+    ``BlockSource`` takes."""
+    num_blocks, ffts_per_block = _fft_batches(conf)
+    with trace.host("dispatch", "draw_featurizers"):
+        return _draw_sign_blocks(
+            jax.random.PRNGKey(conf.seed), num_blocks=num_blocks,
+            ffts_per_block=ffts_per_block, size=conf.mnist_image_size,
+        )
+
+
+def _made_form(conf: MnistRandomFFTConfig, mesh) -> bool:
+    """Whether the solver is handed a ``BlockSource``: everywhere but where
+    the caller asks for what a source fit does not do yet (a mesh, a
+    checkpointed or resumed solve, a forced placement, the auto-Cacher's
+    decision over held feature batches)."""
+    return mesh is None and not (
+        conf.solve_checkpoint is not None or conf.solve_resume is not None
+        or conf.solve_plan is not None or conf.auto_shard or conf.auto_cache
+    )
 
 
 def run(
@@ -125,7 +166,22 @@ def run(
     """With ``mesh``, train/test batches are row-sharded over the data axis
     and the block solver runs fully distributed (sharded grams + model-axis
     sharded solves) — the reference runs this pipeline over partitioned RDDs
-    end to end (MnistRandomFFT.scala:36-88)."""
+    end to end (MnistRandomFFT.scala:36-88).
+
+    Without one, the reference's ``batchFeaturizer`` is a ``Seq`` of lazy
+    chains, and so is this one: the solver is handed a ``BlockSource`` (the
+    rows and ONE ``RandomFFTBlock`` of every block's signs), takes the block
+    means itself, and both splits' blocks are made as the evaluation loop
+    reaches them, so at the documented 200 FFTs the 102,400-column design
+    matrix never exists; where it fits the device the solver holds it, by
+    its own rule.  A caller that asks for what a source fit does not do yet
+    gets the blocks as arrays (:func:`_made_form`).
+
+    Hands back, beside the errors and ``seconds``, ``model``,
+    ``featurizers`` (the stacked node, or the list of per-batch chains),
+    ``train_scores`` / ``train_predictions`` and ``test_scores`` /
+    ``test_predictions`` as the evaluators last saw them, and
+    ``fit_report``."""
     configure_logging()
     log = _Log()
     t0 = time.perf_counter()
@@ -136,140 +192,160 @@ def run(
         # touched, and the run scores/serves with the restored pipeline.
         return _run_restored(conf, test, log, t0)
 
-    labels = ClassLabelIndicatorsFromIntLabels(conf.num_classes)(train.labels)
-    batch_featurizer = build_featurizer_batches(conf)
-
+    made = _made_form(conf, mesh)
     n_train, n_test = len(train.labels), len(test.labels)
-    if mesh is not None:
-        # Featurization is elementwise per row: zero pad rows stay zero
-        # through RandomSign/FFT/rectifier, so no masking is needed.
-        train_data, nvalid = padded_shard_rows(train.data, mesh)
-        test_data, _ = padded_shard_rows(test.data, mesh)
-    else:
-        train_data, nvalid = jnp.asarray(train.data), None
-        test_data = jnp.asarray(test.data)
-
-    def featurize_training():
-        batches = [
-            ZipVectors.apply([chain(train_data) for chain in chains])
-            for chains in batch_featurizer
-        ]
-        # Sync inside the stage: jnp dispatch is async, and an unsynced
-        # featurize span would read ~0 while the compute leaked into the
-        # solve span's time.
-        jax.block_until_ready(batches)
-        return batches
-
-    t_feat = time.perf_counter()
-    with stage_timer("featurize"):
-        training_batches = featurize_training()
-    feat_secs = time.perf_counter() - t_feat
-
+    results: dict = {}
     cache_plan = None
     keep_features = True
-    if conf.auto_cache:
-        # Auto-Cacher decision on the featurized training batches: they are
-        # consumed twice (the block solve, then the train-split streaming
-        # eval).  Caching = the status-quo residency; a denial frees them
-        # after the solve and recomputes at eval time — measured featurize
-        # seconds vs materialized bytes, admitted per-chip under a mesh.
-        cache_plan = optimize.plan_caches(
-            [
-                optimize.CacheCandidate(
-                    index=0,
-                    name="fft_features",
-                    seconds=feat_secs,
-                    output_bytes=sum(int(b.nbytes) for b in training_batches),
-                    reuse=2,
-                )
-            ],
-            mesh=mesh,
-            dataset_rows=n_train,
-        )
-        keep_features = cache_plan.decisions[0].cached
-        log.log_info("%s", cache_plan.summary())
+    # one root span a fit; the three stages tile it but for glue
+    with trace.span("fit", cat="fit", rows=n_train):
+        if mesh is not None:
+            # Featurization is elementwise per row: zero pad rows stay zero
+            # through RandomSign/FFT/rectifier, so no masking is needed.
+            train_data, nvalid = padded_shard_rows(train.data, mesh)
+            test_data, _ = padded_shard_rows(test.data, mesh)
+        else:
+            train_data, nvalid = jnp.asarray(train.data), None
+            test_data = jnp.asarray(test.data)
 
-    with stage_timer("solve"):
-        solver = BlockLeastSquaresEstimator(
-            conf.block_size, 1, conf.lam or 0.0, mesh=mesh
-        )
-        model = solver.fit(
-            training_batches,
-            labels,
-            nvalid=nvalid,
-            checkpoint=conf.solve_checkpoint,
-            resume_from=conf.solve_resume,
-            plan=(
-                conf.solve_plan if conf.solve_plan is not None
-                else (True if conf.auto_shard else None)
-            ),
-        )
-        log_fit_report(solver, label="mnist random-fft solve")
-        if numerics_guard_enabled():
-            # Fail typed (FloatingPointError) instead of serving NaN
-            # scores — a poisoned batch or diverged solve must never look
-            # like a model.
-            assert_all_finite(model, "mnist random-fft model")
+        def featurize_training():
+            batches = [
+                ZipVectors.apply([chain(train_data) for chain in chains])
+                for chains in featurizers
+            ]
+            # Sync inside the stage: jnp dispatch is async, and an unsynced
+            # featurize span would read ~0 while the compute leaked into
+            # the solve span's time.
+            jax.block_until_ready(batches)
+            return batches
 
-    if not keep_features:
-        # The plan priced residency above a recompute: release the feature
-        # batches' memory through the solve->eval gap and rebuild them at
-        # eval (bit-identical — the featurizers are deterministic).
-        training_batches = None
+        t_feat = time.perf_counter()
+        with stage_timer("featurize"):
+            labels = ClassLabelIndicatorsFromIntLabels(conf.num_classes)(
+                train.labels
+            )
+            if made:
+                featurizers = draw_block_featurizers(conf)
+                training_batches = BlockSource(train_data, featurizers)
+            else:
+                featurizers = build_featurizer_batches(conf)
+                training_batches = featurize_training()
+        feat_secs = time.perf_counter() - t_feat
 
-    test_batches = [
-        ZipVectors.apply([chain(test_data) for chain in chains])
-        for chains in batch_featurizer
-    ]
+        if conf.auto_cache:
+            # Auto-Cacher decision on the featurized training batches: they
+            # are consumed twice (the block solve, then the train-split
+            # streaming eval).  Caching = the status-quo residency; a denial
+            # frees them after the solve and recomputes at eval time —
+            # measured featurize seconds vs materialized bytes, admitted
+            # per-chip under a mesh.
+            cache_plan = optimize.plan_caches(
+                [
+                    optimize.CacheCandidate(
+                        index=0,
+                        name="fft_features",
+                        seconds=feat_secs,
+                        output_bytes=sum(int(b.nbytes) for b in training_batches),
+                        reuse=2,
+                    )
+                ],
+                mesh=mesh,
+                dataset_rows=n_train,
+            )
+            keep_features = cache_plan.decisions[0].cached
+            log.log_info("%s", cache_plan.summary())
 
-    results: dict = {}
-    if cache_plan is not None:
-        results["cache_plan"] = cache_plan.record()
-    rep = solver.last_fit_report
-    if rep is not None and rep.placement is not None:
-        # The searched placement table — candidates, deny/score rationale,
-        # chosen plan with predicted-vs-actual cost (tools/plan_view.py
-        # pretty-prints it from this record).
-        results["placement"] = rep.placement
+        with stage_timer("solve"):
+            solver = BlockLeastSquaresEstimator(
+                conf.block_size, 1, conf.lam or 0.0, mesh=mesh
+            )
+            model = solver.fit(
+                training_batches,
+                labels,
+                nvalid=nvalid,
+                checkpoint=conf.solve_checkpoint,
+                resume_from=conf.solve_resume,
+                plan=(
+                    conf.solve_plan if conf.solve_plan is not None
+                    else (True if conf.auto_shard else None)
+                ),
+            )
+            log_fit_report(solver, label="mnist random-fft solve")
+            if numerics_guard_enabled():
+                # Fail typed (FloatingPointError) instead of serving NaN
+                # scores — a poisoned batch or diverged solve must never
+                # look like a model.
+                assert_all_finite(model, "mnist random-fft model")
 
-    def train_eval(pred):
-        predicted = MaxClassifier()(pred[:n_train])
-        ev = MulticlassClassifierEvaluator(predicted, train.labels, conf.num_classes)
-        results["train_error"] = 100.0 * ev.total_error
-        log.log_info("Train Error is %s%%", results["train_error"])
+        if not keep_features:
+            # The plan priced residency above a recompute: release the
+            # feature batches' memory through the solve->eval gap and
+            # rebuild them at eval (bit-identical — the featurizers are
+            # deterministic).
+            training_batches = None
 
-    def test_eval(pred):
-        predicted = MaxClassifier()(pred[:n_test])
-        ev = MulticlassClassifierEvaluator(predicted, test.labels, conf.num_classes)
-        results["test_error"] = 100.0 * ev.total_error
-        # Full-model predicted labels (the streaming evaluator's last call
-        # sees the complete model) — the chaos harness diffs these against
-        # the fault-free run to rule out silent wrong models.
-        results["test_predictions"] = np.asarray(predicted)
-        log.log_info("TEST Error is %s%%", results["test_error"])
+        if cache_plan is not None:
+            results["cache_plan"] = cache_plan.record()
+        rep = solver.last_fit_report
+        if rep is not None and rep.placement is not None:
+            # The searched placement table — candidates, deny/score
+            # rationale, chosen plan with predicted-vs-actual cost
+            # (tools/plan_view.py pretty-prints it from this record).
+            results["placement"] = rep.placement
 
-    # Streaming evaluation after each block, as the reference does (:70-86);
-    # the last invocation sees the full-model prediction.
-    with stage_timer("eval"):
-        if training_batches is None:
-            training_batches = featurize_training()
-        model.apply_and_evaluate(training_batches, train_eval)
-        model.apply_and_evaluate(test_batches, test_eval)
+        def evaluator(split: str, truth, n: int):
+            def seen(pred):
+                predicted = MaxClassifier()(pred[:n])
+                ev = MulticlassClassifierEvaluator(predicted, truth, conf.num_classes)
+                results[f"{split}_error"] = 100.0 * ev.total_error
+                results[f"{split}_scores"] = pred[:n]
+                results[f"{split}_predictions"] = predicted
 
-    # The fitted SERVABLE chain: the same featurize groups as one node,
-    # whose concatenated output the model's VectorSplitter cuts back into
-    # exactly the per-group blocks — served scores bit-equal the fit-path
-    # apply.  Checkpointed whole for the serving endpoint to warm-load.
-    servable = Pipeline(
-        [GroupConcatFeaturizer(batch_featurizer), model, MaxClassifier()]
-    )
+            return seen
+
+        # Streaming evaluation after each block, train split first, as the
+        # reference does (:70-86); the last invocation sees the full model.
+        with stage_timer("eval"):
+            if made:
+                test_batches = BlockSource(test_data, featurizers)
+            else:
+                if training_batches is None:
+                    training_batches = featurize_training()
+                test_batches = [
+                    ZipVectors.apply([chain(test_data) for chain in chains])
+                    for chains in featurizers
+                ]
+            model.apply_and_evaluate(
+                training_batches, evaluator("train", train.labels, n_train)
+            )
+            model.apply_and_evaluate(
+                test_batches, evaluator("test", test.labels, n_test)
+            )
+            predicted = results["test_predictions"]
+            with trace.d2h("test_predictions", predicted.nbytes):
+                # the streaming evaluator's last call saw the complete model:
+                # the chaos harness diffs these against the fault-free run
+                results["test_predictions"] = np.asarray(predicted)
+    log.log_info("Train Error is %s%%", results["train_error"])
+    log.log_info("TEST Error is %s%%", results["test_error"])
+
     if conf.pipeline_file is not None:
         from ..core import numerics as knum
 
-        # Fit-time output baseline (ISSUE 15): the predicted-class
-        # distribution is persisted in the checkpoint manifest — the
-        # reference the serving tier's output-drift monitor judges live
-        # answers against once the engine warm-loads this artifact.
+        # The fitted SERVABLE chain: the same featurize groups as one node,
+        # whose concatenated output the model's VectorSplitter cuts back
+        # into exactly the per-group blocks — served scores bit-equal the
+        # fit-path apply.  Checkpointed whole for the serving endpoint to
+        # warm-load, with the fit-time predicted-class distribution the
+        # serving tier's output-drift monitor judges live
+        # answers against.
+        groups = (
+            [[jax.tree.map(lambda a: a[i], featurizers)] for i in range(len(model.xs))]
+            if made else featurizers
+        )
+        servable = Pipeline(
+            [GroupConcatFeaturizer(groups), model, MaxClassifier()]
+        )
         save_pipeline(
             conf.pipeline_file,
             servable,
@@ -280,7 +356,12 @@ def run(
         log.log_info("saved fitted servable pipeline to %s", conf.pipeline_file)
     _maybe_serve(conf, test, results, log)
 
-    results["seconds"] = time.perf_counter() - t0
+    results.update(
+        seconds=time.perf_counter() - t0,
+        model=model,
+        featurizers=featurizers,
+        fit_report=solver.last_fit_report,
+    )
     log.log_info("Pipeline took %.3f s", results["seconds"])
     return results
 

@@ -48,6 +48,7 @@ PROGRAMS = {
     r"^jit__hold_blocks$": "keystone_tpu.solvers.block",
     r"^jit__block_step$": "keystone_tpu.solvers.block",
     r"^jit__draw_cosine_blocks$": "keystone_tpu.workloads.timit",
+    r"^jit__draw_sign_blocks$": "keystone_tpu.workloads.mnist_random_fft",
     r"^jit__confusion_counts$": "keystone_tpu.evaluation.multiclass",
 }
 
@@ -78,6 +79,9 @@ STAGES = {
     "timit_rf_full": (  # drives ``timit.run`` itself
         "keystone_tpu.workloads.timit", ["featurize", "solve", "eval"],
     ),
+    "mnist_fft": (  # drives ``mnist_random_fft.run`` itself
+        "keystone_tpu.workloads.mnist_random_fft", ["featurize", "solve", "eval"],
+    ),
 }
 
 COUNTERS = {
@@ -85,6 +89,7 @@ COUNTERS = {
     "gmm.iterations": "keystone_tpu.workloads.voc_sift_fisher",
     "mesh.psum_bytes": "keystone_tpu.parallel.collectives",
     "bcd.block_rows_made": "keystone_tpu.solvers.block",
+    "bcd.block_rows_applied": "keystone_tpu.solvers.block",
 }
 
 HISTOGRAMS = ["stage_ms", "stage_wait_ms", "stage_h2d_mb"]
@@ -196,7 +201,10 @@ def _check_stage(name, module):
 
 
 def _check_counter(name, module):
-    assert name in {m.get("counter") for m in _metrics()}, (
+    read = {m.get("counter") for m in _metrics()} | {
+        c for m in _metrics() for c in m.get("counters", ())
+    }
+    assert name in read, (
         f"no metric reads the counter {name} any more"
     )
     incs = _literal_first_args(importlib.import_module(module), "metrics.inc")

@@ -451,6 +451,66 @@ def test_made_solve_keeping_blocks_compiles_at_published_widths(one_chip, monkey
     assert len(text) < 1.1 * _MADE_COMPILED_CHARS, len(text)  # PR 39's first form: 4.4 M
 
 
+def test_a_cosine_make_holds_nothing_beside_its_block(one_chip):
+    """``timit_rf_fit_full``'s make is its product's output: the compiled
+    make asks no scratch beyond the block, so the plan charges nothing and
+    keeps the blocks it kept before the charge existed."""
+    from keystone_tpu.ops.stats import CosineRandomFeatures, StandardScalerModel
+    from keystone_tpu.solvers import block
+    from keystone_tpu.workloads.timit import FeaturizerBlock
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, d, nb, bs = 32768, 440, 50, 4096
+    chains = FeaturizerBlock([
+        CosineRandomFeatures(sds(nb, bs, d), sds(nb, bs)),
+        StandardScalerModel(sds(nb, bs), sds(nb, bs)),
+    ])
+    assert block._make_scratch(block.BlockSource(sds(n, d), chains, None, sds(nb, bs))) == 0
+
+
+# -- MnistRandomFFT at its parser's defaults (`mnist_fft_200`) -------------------------
+
+
+def test_made_fft_solve_compiles_at_published_widths(one_chip, monkeypatch):
+    """``mnist_fft_fit``: 200 FFTs in fifty blocks of 2,048 on 60,000 rows.
+    The compiled make of a block holds its padded rows and transform beside
+    it (2.95 GB of temporaries for a 0.49 GB block): the plan charges what
+    lies beyond the block, keeps fewer made blocks for it, and the fused
+    program that keeps them fits under the v5e's 16.91 GB less its tenth;
+    without the charge the rule would keep more, and the program would not
+    fit."""
+    from keystone_tpu.ops.stats import RandomFFTBlock
+    from keystone_tpu.solvers import block
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, d, nb, f, k, limit = 60000, 784, 50, 4, 10, 16_909_336_064
+    bs = 2048
+    monkeypatch.setenv("KEYSTONE_HBM_BUDGET", str(limit))
+    monkeypatch.setattr(block, "_kept_dtype", lambda dtype: np.dtype(jnp.bfloat16))  # the TPU's
+    plan = block._plan_bcd(block.BlockSource(sds(n, d), RandomFFTBlock(sds(nb, f, d))), sds(n, k), 1, bs)
+    scratch = plan["make_scratch_bytes"]
+    assert 2 * n * bs * 4 < scratch < 6 * n * bs * 4, plan
+    h = plan["held_blocks"]
+    assert plan["block_source"] == "made" and 30 <= h < nb - 1, plan
+    source = block.BlockSource(sds(n, d), RandomFFTBlock(sds(nb, f, d)), None, sds(nb, bs))
+    scalars = (sds(), sds(dtype=jnp.int32))
+
+    def total(keep):
+        mem = block._fused_bcd_fit.lower(
+            source, sds(n, k), *scalars, 1, (bs,) * nb, None, hold=keep, hold_dtype="bfloat16"
+        ).compile().memory_analysis()
+        return mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes
+
+    assert total(h) < limit - limit // 10
+    kept_block = n * bs * 2
+    uncharged = min(nb - 1, (limit - (plan["made_bytes"] - scratch) - limit // 10) // kept_block - 1)
+    assert uncharged > h and total(uncharged) > limit - limit // 10
+
+
 # -- ImageNetSiftLcsFV at its own widths (`imagenet_sift_lcs_fv_16`) ------------------
 
 
