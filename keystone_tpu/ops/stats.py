@@ -155,7 +155,16 @@ class RandomFFTBlock(Transformer):
     chain RandomSign -> PaddedFFT -> LinearRectifier(0), the ``f`` outputs
     side by side in ZipVectors' column order, ``[N, d] -> [N, f * n / 2]``
     with ``n = next_pow2(d)``.  One node whose one leaf may carry a leading
-    block axis: what ``solvers.block.BlockSource`` takes."""
+    block axis: what ``solvers.block.BlockSource`` takes.
+
+    Before the rectifier one FFT's chain is linear in the row, so it is
+    ``x @ T`` with ``T[j, k] = s_j cos(2 pi j k / n)``: the chain's first
+    two nodes applied to the identity's rows give the table ``[d, f * n/2]``
+    and the rows meet it in one float32 product at ``HIGHEST``.  XLA's
+    transform writes each of its stages to HBM: on a TPU v5e, 60,000 rows of
+    ``d`` = 784 through four FFTs took 34.1 ms of device time by the
+    transform and 7.0 ms by the product.  The table is built on every call,
+    ``f * d`` row transforms, so a call of a few rows pays for it."""
 
     def __init__(self, signs):
         self.signs = signs
@@ -163,15 +172,20 @@ class RandomFFTBlock(Transformer):
     def __call__(self, batch):
         f, d = self.signs.shape
         n = next_power_of_two(d)
+        identity = jnp.eye(d, dtype=batch.dtype)[:, None, :]
+        # one [d, f * n/2] operand, so the product writes the block's 2-D
+        # layout: a [rows, f, n/2] result tiles f = 4 as 8 on a TPU and turns
+        # the layout of a stack of made blocks, which a program that keeps
+        # them then copies whole
+        table = PaddedFFT()(RandomSignNode(self.signs)(identity)).reshape(d, f * (n // 2))
         # counted where a program that makes these blocks is traced
-        trace.metrics.inc("fft_form.xla")
+        trace.metrics.inc("fft_form.product")
         trace.instant(
             "fft_form", rows=math.prod(batch.shape[:-1]), n=n, ffts=f, width=d,
-            dtype=str(batch.dtype),
+            dtype=str(batch.dtype), table_bytes=table.nbytes,
         )
-        signed = RandomSignNode(self.signs)(batch[..., None, :])
-        out = LinearRectifier(0.0)(PaddedFFT()(signed))
-        return out.reshape(*batch.shape[:-1], f * (n // 2))
+        out = jnp.matmul(batch, table, precision=jax.lax.Precision.HIGHEST)
+        return LinearRectifier(0.0)(out)
 
 
 @node(data_fields=(), meta_fields=())

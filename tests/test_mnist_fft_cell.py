@@ -74,21 +74,46 @@ def _budget_between(monkeypatch, conf, train):
     return (plan["made_bytes"] + plan["held_bytes"]) // 2
 
 
-@pytest.mark.parametrize("d", [64, 100])
+@pytest.mark.parametrize("d", [64, 100, 784])
 def test_block_node_equals_the_per_fft_chains(rng, d):
     """Column for column: block ``b`` of the stacked node is ZipVectors of
-    block ``b``'s RandomSign -> PaddedFFT -> LinearRectifier chains."""
+    block ``b``'s RandomSign -> PaddedFFT -> LinearRectifier chains, to
+    float32's rounding; and float64 ``np.fft.rfft``'s real part, rectified,
+    to 1e-5."""
     conf = _conf(mnist_image_size=d, num_ffts=6, block_size=1536)
-    x = jnp.asarray(rng.uniform(0, 255, (40, d)).astype(np.float32))
+    x = rng.uniform(0, 255, (40, d)).astype(np.float32)
     stacked = mrf.draw_block_featurizers(conf)
     chains = mrf.build_featurizer_batches(conf)
     n = 1 << (d - 1).bit_length()
     assert stacked.signs.shape == (2, 3, d)
     for b, group in enumerate(chains):
-        want = ZipVectors.apply([chain(x) for chain in group])
-        got = RandomFFTBlock(stacked.signs[b])(x)
+        want = np.asarray(ZipVectors.apply([chain(jnp.asarray(x)) for chain in group]))
+        got = np.asarray(RandomFFTBlock(stacked.signs[b])(jnp.asarray(x)))
         assert got.shape == (40, 3 * n // 2)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6 * np.abs(want).max())
+        signs = np.asarray(stacked.signs[b], np.float64)
+        exact = np.concatenate(
+            [np.maximum(0.0, np.fft.rfft(x * s, n=n).real[:, : n // 2]) for s in signs], axis=1
+        )
+        assert np.sqrt(np.mean((got - exact) ** 2) / np.mean(exact**2)) < 1e-5
+
+
+@pytest.mark.parametrize("d", [64, 100, 784])
+def test_the_product_form_counts_its_programs_and_its_table(rng, d):
+    """``fft_form.product`` counts a traced program, not its calls, and the
+    instant gives the shape the table was built for and its bytes."""
+    n = 1 << (d - 1).bit_length()
+    node_ = RandomFFTBlock(jnp.asarray(np.sign(rng.normal(size=(2, d))).astype(np.float32)))
+    fn = jax.jit(node_.__call__)
+    before = trace.metrics.get("fft_form.product")
+    for _ in range(3):
+        fn(jnp.ones((8, d), jnp.float32))
+    assert trace.metrics.get("fft_form.product") == before + 1
+    last = [e for e in trace.flight_events() if e["name"] == "fft_form"][-1]
+    assert last["args"] == {
+        "rows": 8, "n": n, "ffts": 2, "width": d, "dtype": "float32",
+        "table_bytes": 2 * d * (n // 2) * 4,
+    }
 
 
 @pytest.mark.parametrize("num_ffts", [8, 7])
@@ -197,13 +222,14 @@ def test_the_plan_charges_what_a_make_holds(rng, monkeypatch):
     """What a make holds beyond its block (``_make_scratch``: the compiled
     make's temporaries less the block, whose room the centred copy leaves
     free then) joins the made need, and fewer blocks are kept than the same
-    budget keeps without it.  The FFT's make holds its padded rows and its
-    transform; a cosine block is its product's output, charged nothing."""
+    budget keeps without it.  The FFT's make is one product against its
+    table, charged less than its block; a cosine block is its product's
+    output, charged nothing."""
     rows = jnp.asarray(rng.uniform(0, 255, (N, D)).astype(np.float32))
     labels = jnp.zeros((N, K))
     fft = BlockSource(rows, mrf.draw_block_featurizers(_conf()))
     cosine = _cosine_source(rows)
-    assert block._make_scratch(fft) > 0
+    assert 0 <= block._make_scratch(fft) < N * fft.block_size * 4
     assert block._make_scratch(cosine) == 0
     monkeypatch.setenv(HBM_BUDGET_ENV, "1")
     assert block._plan_bcd(cosine, labels, 1, 1024)["make_scratch_bytes"] == 0
@@ -306,7 +332,8 @@ def test_a_made_run_counts_its_fft_blocks(data, monkeypatch):
     """The solver's counters, rows x blocks at every call that makes them:
     ``bcd.block_rows_made`` its moments pass and its fused program (blocks +
     blocks not kept), ``bcd.block_rows_applied`` both splits' streamed
-    apply; ``bcd_plan`` says what a make holds."""
+    apply; ``bcd_plan`` says what a make holds, less than its block; the
+    cell's width takes the product form."""
     train, test = data
     conf = _conf()
     monkeypatch.setenv(HBM_BUDGET_ENV, str(_budget_between(monkeypatch, conf, train)))
@@ -316,10 +343,10 @@ def test_a_made_run_counts_its_fft_blocks(data, monkeypatch):
     counted = {k: after.get(k, 0) - before.get(k, 0) for k in after}
     plan = got["fit_report"].bcd_plan
     nb, h = 16, plan["held_blocks"]
-    assert plan["make_scratch_bytes"] > 0 and plan["passes_a_block"] == 3
+    assert plan["make_scratch_bytes"] < N * 64 * 4 and plan["passes_a_block"] == 3
     assert counted["bcd.block_rows_made"] == N * (nb + nb + (nb - h))
     assert counted["bcd.block_rows_applied"] == (N + NT) * nb
-    assert after.get("fft_form.xla", 0) >= 1
+    assert after.get("fft_form.product", 0) >= 1
 
 
 def test_the_servable_checkpoint_scores_as_the_fit_did(data, tmp_path):
