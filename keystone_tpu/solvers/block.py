@@ -201,6 +201,28 @@ def _block_step(running, blk, x, scaler, b):
     return running, running if b is None else running + b
 
 
+@functools.partial(jax.jit, static_argnames="widths")
+def _model_blocks(models, means, widths):
+    """The fitted ``[B, bs, k]`` model and its ``[B, bs]`` block means (or
+    ``None``) cut at the fitted widths, every block's ``models[i, :w]`` and
+    ``means[i, :w]`` in one program: a fit pays one dispatch for its split,
+    not two a block.  Each output is ``models[i, :w]`` (``means[i, :w]``)
+    exactly: its values, shape, dtype and the sharding the slice
+    propagates, so the programs that apply the model trace nothing new."""
+    trace.metrics.inc("model_blocks.traced")
+    blocks = tuple(models[i, :w] for i, w in enumerate(widths))
+    if means is None:
+        return blocks, None
+    return blocks, tuple(means[i, :w] for i, w in enumerate(widths))
+
+
+def split_model(models, means, widths):
+    """``(model blocks, mean blocks)`` of a finished solve by
+    :func:`_model_blocks`, counted once a fit (``model_blocks.split``)."""
+    trace.metrics.inc("model_blocks.split")
+    return _model_blocks(models, means, tuple(int(w) for w in widths))
+
+
 class BlockSource:
     """A design matrix's feature blocks as *what makes them*: the raw rows
     ``[N, d]`` and one pure featurizer a block, its parameters arrays (the
@@ -1421,11 +1443,9 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         all_cond = (cond_rows or []) + list(solve_cond)
         if all_cond and self.last_fit_report is not None:
             self.last_fit_report.conditioning = all_cond
-        with trace.host("finish", "model_blocks"):  # two eager slices a block
-            model_list = [models[i, :w] for i, w in enumerate(widths)]
-            feature_scalers = [
-                StandardScalerModel(means[i, :w]) for i, w in enumerate(widths)
-            ]
+        with trace.host("finish", "model_blocks"):  # one compiled split
+            model_list, mean_list = split_model(models, means, widths)
+            feature_scalers = [StandardScalerModel(mu) for mu in mean_list]
         return BlockLinearMapper(
             model_list, self.block_size, label_mean, feature_scalers
         )

@@ -63,7 +63,12 @@ from ..parallel.mesh import (
     mesh_desc,
     reduced_mesh,
 )
-from .block import BlockLinearMapper, _blocked_design_matrix, _design_matrix_owned
+from .block import (
+    BlockLinearMapper,
+    _blocked_design_matrix,
+    _design_matrix_owned,
+    split_model,
+)
 
 #: The statistics of the normal equations (the population gram, a class's
 #: covariance, both XᵀR) ask full float32 products: on a TPU a float32 matmul
@@ -979,7 +984,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             factor_bytes=np.dtype(dtype).itemsize * chunk * (bs + 1) * bs,
         )
         with trace.host("finish", "model_blocks"):
-            model_list = [models_st[i, :wd] for i, wd in enumerate(widths)]
+            model_list, _ = split_model(models_st, None, widths)
         return BlockLinearMapper(model_list, self.block_size, b)
 
     def _fit_mesh_ladder(

@@ -1,7 +1,9 @@
 """The fitted block model applied as one compiled program
 (`solvers/block._block_apply`, `_block_step`): equal to the eager per-block
 formula written out here, `Σ scaler_i(blk_i) @ x_i + b` in block order, for
-every kind of mapper and input the repo makes, and traced once a shape."""
+every kind of mapper and input the repo makes, and traced once a shape; and
+the fitted model cut into those blocks by one program
+(`solvers/block._model_blocks`), equal to the eager slices."""
 
 import jax
 import jax.numpy as jnp
@@ -286,3 +288,121 @@ def test_streamed_apply_traces_its_step_once_a_shape(rng):
     assert trace.metrics.get("block_apply.traced") == before + 2
     run()
     assert trace.metrics.get("block_apply.traced") == before + 2
+
+
+#: fitted widths a fit cuts its stacked model at: every block full
+#: (MnistRandomFFT's 2,048-wide blocks) and a short last block (TIMIT's and
+#: RandomPatchCifar's remainder)
+SPLIT_WIDTHS = {"full": (16, 16, 16, 16), "short_last": (16, 16, 16, 9)}
+#: where a solve leaves its stacked model: one device, rows over a 4-way
+#: ``data`` axis with the model replicated (the four-chip cell), and a 2x2
+#: mesh whose ``model`` axis splits the classes (the mesh tier's own
+#: ``P(None, None, "model")``)
+SPLIT_LAYOUTS = {"one_device": None, "data4": (4, 1), "data2_model2": (2, 2)}
+
+
+@pytest.mark.parametrize("layout", list(SPLIT_LAYOUTS), ids=list(SPLIT_LAYOUTS))
+@pytest.mark.parametrize("widths", list(SPLIT_WIDTHS), ids=list(SPLIT_WIDTHS))
+def test_model_split_equals_the_eager_slices(rng, devices, widths, layout):
+    """The one split program's blocks are the eager slices ``models[i, :w]``
+    / ``means[i, :w]`` bit for bit, with their shapes, dtypes and
+    shardings: the apply programs keyed on them trace nothing new."""
+    from keystone_tpu.solvers.block import _model_blocks
+
+    widths = SPLIT_WIDTHS[widths]
+    nb, bs, k = len(widths), max(widths), 4
+    models = jnp.asarray(rng.normal(size=(nb, bs, k)), jnp.float32)
+    means = jnp.asarray(rng.normal(size=(nb, bs)), jnp.float32)
+    if SPLIT_LAYOUTS[layout] is not None:
+        data, model = SPLIT_LAYOUTS[layout]
+        mesh = make_mesh(data=data, model=model, devices=devices[:4])
+        models = jax.device_put(models, NamedSharding(mesh, P(None, None, "model")))
+        means = jax.device_put(means, NamedSharding(mesh, P()))
+    blocks, mean_blocks = _model_blocks(models, means, widths)
+    _, no_means = _model_blocks(models, None, widths)
+    assert no_means is None
+    for i, w in enumerate(widths):
+        for got, want in ((blocks[i], models[i, :w]), (mean_blocks[i], means[i, :w])):
+            assert got.shape == want.shape and got.dtype == want.dtype
+            assert got.sharding == want.sharding
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_second_fit_splits_its_model_with_the_first_program(rng):
+    """`model_blocks.traced` counts the split programs traced and
+    `model_blocks.split` the fits cut by one: a second fit at the same
+    shapes adds a split, traces no split and no apply program."""
+    _fit_and_score(rng, rows=64)
+    traced = trace.metrics.get("model_blocks.traced")
+    split = trace.metrics.get("model_blocks.split")
+    applied = trace.metrics.get("block_apply.traced")
+    _fit_and_score(rng, rows=64)
+    assert trace.metrics.get("model_blocks.traced") == traced
+    assert trace.metrics.get("model_blocks.split") == split + 1
+    assert trace.metrics.get("block_apply.traced") == applied
+
+
+def _spy_split(monkeypatch, module):
+    """Records what ``module``'s fit hands the split: the stacked model,
+    its means and the fitted widths."""
+    seen = []
+    real = module.split_model
+
+    def spy(models, means, widths):
+        seen.append((models, means, tuple(widths)))
+        return real(models, means, widths)
+
+    monkeypatch.setattr(module, "split_model", spy)
+    return seen
+
+
+def _assert_eager_blocks(xs, stacked, widths):
+    """``xs`` are ``stacked[i, :w]`` for the fitted widths, bit for bit, with
+    the eager slices' shapes, dtypes and shardings."""
+    assert len(xs) == len(widths)
+    for i, (x, w) in enumerate(zip(xs, widths)):
+        want = stacked[i, :w]
+        assert x.shape == want.shape and x.dtype == want.dtype
+        assert x.sharding == want.sharding
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(want))
+
+
+@pytest.mark.parametrize("layout", ["data4", "data2_model2"])
+def test_block_fit_on_a_mesh_keeps_the_eager_blocks(rng, devices, monkeypatch, layout):
+    """A fit on four devices (rows over ``data``; on 2x2 the classes over
+    ``model`` too): the mapper's model and mean blocks are the eager slices
+    of what the mesh tier returned, one split counted."""
+    from keystone_tpu.solvers import block
+
+    seen = _spy_split(monkeypatch, block)
+    data, model_axis = SPLIT_LAYOUTS[layout]
+    mesh = make_mesh(data=data, model=model_axis, devices=devices[:4])
+    x = jnp.asarray(rng.normal(size=(64, 40)), jnp.float32)
+    y = jnp.asarray(rng.normal(size=(64, 4)), jnp.float32)
+    split = trace.metrics.get("model_blocks.split")
+    est = BlockLeastSquaresEstimator(16, 1, 0.1, mesh=mesh)
+    model = est.fit(x, y)
+    assert est.last_fit_report.chosen == f"fused[mesh {data}x{model_axis}]"
+    assert trace.metrics.get("model_blocks.split") == split + 1
+    (models, means, widths), = seen
+    assert widths == (16, 16, 8)
+    _assert_eager_blocks(model.xs, models, widths)
+    _assert_eager_blocks([s.mean for s in model.feature_scalers], means, widths)
+
+
+def test_weighted_fit_keeps_the_eager_blocks(rng, monkeypatch):
+    """The class-weighted solver cuts its stacked model by the same program
+    (no means): its mapper's blocks are the eager slices, one split
+    counted."""
+    from keystone_tpu.solvers import weighted
+
+    seen = _spy_split(monkeypatch, weighted)
+    classes = rng.integers(0, K, 60)
+    x = jnp.asarray(rng.normal(size=(60, 10)) + classes[:, None], jnp.float32)
+    y = jnp.asarray(2.0 * np.eye(K)[classes] - 1.0, jnp.float32)
+    split = trace.metrics.get("model_blocks.split")
+    model = weighted.BlockWeightedLeastSquaresEstimator(4, 2, 0.1, 0.5).fit(x, y)
+    assert trace.metrics.get("model_blocks.split") == split + 1
+    (models, means, widths), = seen
+    assert means is None and widths == (4, 4, 2)
+    _assert_eager_blocks(model.xs, models, widths)
