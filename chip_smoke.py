@@ -673,9 +673,6 @@ def main(argv=None) -> int:
     if legs and not set(legs) <= set(LEGS):
         p.error(f"--legs takes a subset of {sorted(LEGS)}")
 
-    # Hermetic: the placement search would otherwise train on, and write
-    # to, ~/.keystone_plans.jsonl — outside the checkout.
-    os.environ["KEYSTONE_PLAN_LOG"] = "off"
     if a.rehearsal:
         # Off-TPU the production chooser takes the jnp IDCT; the rehearsal
         # wants the kernel on the path, in the interpreter.

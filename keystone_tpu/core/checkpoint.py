@@ -99,7 +99,7 @@ def _is_replicated(v) -> bool:
 
 
 def _sharding_spec(v) -> str:
-    """The autoshard spec string (``'replicated'`` / ``'data@dimN'`` /
+    """The sharding spec string (``'replicated'`` / ``'data@dimN'`` /
     ``'model@dimN'``) an array leaf is laid out as — what the manifest
     records per array so a reshard load can re-lower the SAME layout onto
     whatever mesh survived.  A sharding outside that vocabulary (multi-axis
@@ -423,12 +423,12 @@ class _Resharder:
         }
 
     def _target_spec(self, arr: np.ndarray, recorded: str) -> str:
-        from . import autoshard
+        from ..parallel.mesh import spec_chip_bytes, spec_pspec
 
         if recorded not in ("replicated", "opaque"):
             try:
-                autoshard.spec_pspec(recorded, arr.ndim)
-                autoshard.spec_chip_bytes(
+                spec_pspec(recorded, arr.ndim)
+                spec_chip_bytes(
                     arr.shape, arr.dtype, recorded, self.mesh_shape
                 )
                 return recorded
@@ -437,13 +437,13 @@ class _Resharder:
         return "replicated"
 
     def put(self, arr: np.ndarray, key: str, where: str):
-        from . import autoshard
+        from ..parallel.mesh import best_spec, spec_chip_bytes, spec_sharding
         from . import memory as kmem
 
         recorded = self.array_specs.get(key, {}).get("sharding", "replicated")
         spec = self._target_spec(arr, recorded)
         self.stats["arrays"] += 1
-        per_chip = autoshard.spec_chip_bytes(
+        per_chip = spec_chip_bytes(
             arr.shape, arr.dtype, spec, self.mesh_shape
         )
         plan = kmem.plan_bytes(
@@ -455,7 +455,7 @@ class _Resharder:
         if not plan.admitted and spec == "replicated":
             # No chip fits the whole array: shard it instead — the
             # host-staged fallback tier of the reshard ladder.
-            cand = autoshard.best_spec(arr, self.mesh_shape)
+            cand = best_spec(arr, self.mesh_shape)
             if cand["spec"] != "replicated":
                 spec = cand["spec"]
                 per_chip = int(cand["per_chip_bytes"])
@@ -474,7 +474,7 @@ class _Resharder:
                 f"{spec!r} vs budget "
                 f"{kmem.fmt_bytes(self.budget or 0)} ({plan.reason})"
             )
-        sharding = autoshard.spec_sharding(spec, self.mesh, arr.ndim)
+        sharding = spec_sharding(spec, self.mesh, arr.ndim)
         if spec != "replicated" or recorded != "replicated":
             self.stats["resharded"] += 1
         self.stats["bytes"] += int(arr.nbytes)

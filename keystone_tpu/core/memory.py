@@ -549,18 +549,10 @@ def plan_bytes(
     mesh=None,
     budget: int | None | object = _UNSET,
 ) -> MemoryPlan:
-    """ANALYTIC-ONLY admission of a candidate program from caller-supplied
-    per-chip byte figures — no lower, no compile, no cache entry: the
-    zero-cost half of the placement search's candidate-batch preflight
-    (core.autoshard prunes enumerated candidates with this before any of
-    them is worth an AOT compile).
-
-    Deliberately a LOWER BOUND on what :func:`plan_program` would charge
-    (no alias credit is modeled, and callers pass only the transient floors
-    they can prove): a plan denied here is denied a fortiori by the
-    compiled preflight, while an admitted one still faces the full
-    admission when the ladder actually selects it — pruning can skip work,
-    never under-admit.  Same budget/credit semantics as ``plan_program``
+    """ANALYTIC-ONLY admission from caller-supplied per-chip byte figures —
+    no lower, no compile, no cache entry (the checkpoint's reshard loader
+    admits each array it places with this).  Same budget/credit semantics
+    as ``plan_program``
     (min per-chip free HBM under a ``mesh``; resident credit only against a
     live free-bytes budget); denials are counted under
     ``hbm_preflight_denied`` like any other admission decision."""
@@ -616,28 +608,6 @@ def plan_bytes(
     if not admitted:
         counters.record("hbm_preflight_denied", f"{label}: {reason}")
     return _admission_event(plan)
-
-
-def plan_batch(
-    planners: Sequence[tuple[str, Callable[[], MemoryPlan]]],
-) -> dict[str, MemoryPlan]:
-    """Candidate-batch preflight: evaluate every ``(label, planner)`` pair
-    and return ``{label: MemoryPlan}``.  A planner that RAISES becomes a
-    denied plan carrying the error (one broken candidate must not kill the
-    search over the others) — the batch analog of ``plan_program``'s
-    compile-failure-is-an-answer rule."""
-    out: dict[str, MemoryPlan] = {}
-    for label, planner in planners:
-        try:
-            out[label] = planner()
-        except Exception as e:  # noqa: BLE001 — a failed plan IS a deny
-            out[label] = _admission_event(MemoryPlan(
-                label=label,
-                admitted=False,
-                reason=f"planner failed: {type(e).__name__}: {e}"[:200],
-                error=f"{type(e).__name__}: {e}"[:300],
-            ))
-    return out
 
 
 def plan_cache_bytes(
@@ -785,20 +755,10 @@ class FitReport:
     chosen: str | None = None
     denials: list = dataclasses.field(default_factory=list)
     oom_retries: list = dataclasses.field(default_factory=list)
-    #: the placement search's program fingerprint (set by
-    #: autoshard.run_search) — the grouping key the profiler's HBM
-    #: watermark drift rows use, so byte-drift evidence joins the same
-    #: program family as the time outcomes.
-    fingerprint: str | None = None
     #: mesh ladders: the (data, model) axis sizes of the mesh that actually
     #: RAN the solve; ``None`` after a step-down to the single-device floor
     #: (and for plain single-device fits).
     mesh_shape: dict | None = None
-    #: placement search (core.autoshard): the PlacementPlan record of the
-    #: searched ranking this fit ran through — the full candidate table
-    #: with deny/score rationale and the chosen plan's predicted-vs-actual
-    #: cost.  ``None`` when the fit walked the hand ladder.
-    placement: dict | None = None
     #: numerics observatory (core.numerics, KEYSTONE_NUMERICS=1): per-block
     #: κ estimates of this solve's gram blocks — the ACCURACY.md §6 offline
     #: sweep as a live per-fit monitor.  ``None`` when the observatory was
@@ -831,7 +791,6 @@ class FitReport:
             "denials": list(self.denials),
             "oom_retries": list(self.oom_retries),
             "tiers": {k: p.breakdown() for k, p in self.plans.items()},
-            "placement": self.placement,
             "block_source": self.block_source,
             "bcd_plan": dict(self.bcd_plan) if self.bcd_plan else None,
             # Flight-recorder postmortems this process has dumped
@@ -864,6 +823,12 @@ def run_ladder(label: str, tiers: Sequence[Tier], report: FitReport):
     floor: it runs even when its preflight is a deny — with a warning —
     because failing is the only thing below it.  Every CONSIDERED tier's
     plan lands in ``report`` so the decision is auditable afterwards.
+
+    A tier's run is the host section ``dispatch`` (site ``plan:<tier>``),
+    and its result is synced inside the walk (``trace.wait(out, "solve")``):
+    an asynchronous ``RESOURCE_EXHAUSTED`` then steps down here like a
+    synchronous one instead of escaping at the caller's first use of the
+    result.
     """
     report.label = label
     last_oom: BaseException | None = None
@@ -895,7 +860,9 @@ def run_ladder(label: str, tiers: Sequence[Tier], report: FitReport):
                     solve=label, admitted=plan.admitted,
                 ), profiler.phase(f"solve:{label}"):
                     t_run = time.perf_counter()
-                    out = tier.run(plan)
+                    with trace.host("dispatch", f"plan:{tier.name}", label=label):
+                        out = tier.run(plan)
+                        _sync(out)
             except Exception as e:  # noqa: BLE001 — only OOM is retried
                 if not is_oom_error(e) or floor:
                     raise
@@ -918,17 +885,15 @@ def run_ladder(label: str, tiers: Sequence[Tier], report: FitReport):
                 # compiled program lands in the per-program MFU ledger
                 # with its device-synced wall, and the HBM watermark the
                 # sampler saw during the solve is audited against what
-                # this plan CHARGED — drift is counted and logged as
-                # calibration evidence.  One enabled() check when off.
+                # this plan CHARGED — drift is counted and logged.  One
+                # enabled() check when off.
                 wall = profiler.synced_wall(out, t_run)
                 if plan.compiled is not None:
                     profiler.record_program(
                         f"{label}:{tier.name}", plan.compiled, wall
                     )
                 profiler.audit_plan(
-                    f"{label}:{tier.name}", plan,
-                    phase_name=f"solve:{label}",
-                    fingerprint=report.fingerprint,
+                    f"{label}:{tier.name}", plan, phase_name=f"solve:{label}"
                 )
             return out
         # Unreachable in practice (the floor either returns or raises), but
@@ -937,6 +902,16 @@ def run_ladder(label: str, tiers: Sequence[Tier], report: FitReport):
         raise RuntimeError(
             f"{label}: every ladder tier failed"
         ) from last_oom
+
+
+def _sync(out) -> None:
+    """Best-effort sync on a tier run's result pytree: a result that cannot
+    sync (or no live backend) is not an error, an OOM is."""
+    try:
+        trace.wait(out, "solve")
+    except Exception as e:  # noqa: BLE001 — only OOM matters here
+        if is_oom_error(e):
+            raise
 
 
 def log_fit_report(est, logger=None, label: str = "") -> None:

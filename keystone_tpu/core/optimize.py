@@ -412,7 +412,7 @@ def release_caches(pipeline: Pipeline) -> None:
             n.clear_memo()
 
 
-# -- the placement cost model (shared with core.autoshard) --------------------
+# -- the device peaks table ---------------------------------------------------
 
 #: Per-chip peaks by ``device_kind`` — the package's peaks table:
 #: ``core.profiler`` reads this one and keeps none of its own.
@@ -422,13 +422,12 @@ def release_caches(pipeline: Pipeline) -> None:
 #: specifications — "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM; the other
 #: generations from the same pages.  JAX reports the v5e as ``TPU v5
 #: lite`` (chip run, PR 21); for the chips nobody here could ask, both
-#: spellings the two earlier tables used are kept.  ``ici_gbps`` is the
-#: placement prior's per-link figure, not a published peak.
-_V5E = {"peak_flops": 197e12, "hbm_gbps": 819.0, "ici_gbps": 50.0}
-_V5P = {"peak_flops": 459e12, "hbm_gbps": 2765.0, "ici_gbps": 100.0}
-_V6E = {"peak_flops": 918e12, "hbm_gbps": 1640.0, "ici_gbps": 100.0}
+#: spellings the two earlier tables used are kept.
+_V5E = {"peak_flops": 197e12, "hbm_gbps": 819.0}
+_V5P = {"peak_flops": 459e12, "hbm_gbps": 2765.0}
+_V6E = {"peak_flops": 918e12, "hbm_gbps": 1640.0}
 DEVICE_RATES: dict[str, dict] = {
-    "TPU v4": {"peak_flops": 275e12, "hbm_gbps": 1228.0, "ici_gbps": 50.0},
+    "TPU v4": {"peak_flops": 275e12, "hbm_gbps": 1228.0},
     "TPU v5 lite": _V5E,
     "TPU v5e": _V5E,
     "TPU v5": _V5P,
@@ -436,183 +435,6 @@ DEVICE_RATES: dict[str, dict] = {
     "TPU v6 lite": _V6E,
     "TPU v6e": _V6E,
 }
-
-#: Relative rates for ranking candidate placements on a device that is not
-#: in :data:`DEVICE_RATES` (the CPU test platform): only the ORDER of the
-#: predictions matters there.  Never a denominator for a utilization —
-#: ``core.profiler`` reports ``mfu: None`` for such a device.
-_DEFAULT_RATES = {"peak_flops": 50e9, "hbm_gbps": 20.0, "ici_gbps": 5.0}
-
-
-@dataclasses.dataclass
-class CostModel:
-    """Analytic roofline prior for one candidate placement's solve wall.
-
-    The Learned-Cost-Model placement paper's structure (PAPERS.md): an
-    analytic prior over the quantities a plan determines — per-chip bytes
-    moved through HBM, per-chip FLOPs, host<->device dispatch round trips,
-    H2D streaming traffic, cross-chip collective volume — refined by a
-    learned per-(program, candidate) calibration factor fitted to measured
-    outcomes (core.autoshard reads them from the persistent plan-outcome
-    log and multiplies :meth:`predict_seconds` by the measured/predicted
-    ratio).  The prior only has to RANK candidates sanely on a cold start;
-    the calibration makes the absolute numbers honest across runs.
-    """
-
-    peak_flops: float = _DEFAULT_RATES["peak_flops"]
-    hbm_gbps: float = _DEFAULT_RATES["hbm_gbps"]
-    ici_gbps: float = _DEFAULT_RATES["ici_gbps"]
-    h2d_gbps: float = 8.0  #: PCIe-class host->device streaming rate
-    dispatch_seconds: float = 1e-3  #: one host->device dispatch round trip
-
-    @classmethod
-    def for_devices(cls, devices=None) -> "CostModel":
-        """Rates for the live platform (:data:`DEVICE_RATES` by
-        ``device_kind``, default rates for unknown kinds)."""
-        try:
-            if devices is None:
-                import jax
-
-                devices = jax.devices()
-            kind = devices[0].device_kind
-        except Exception:  # noqa: BLE001 — no backend: relative ranking only
-            kind = ""
-        rates = DEVICE_RATES.get(kind, _DEFAULT_RATES)
-        return cls(
-            peak_flops=rates["peak_flops"],
-            hbm_gbps=rates["hbm_gbps"],
-            ici_gbps=rates["ici_gbps"],
-        )
-
-    def predict_seconds(self, hints: dict) -> float:
-        """Prior wall seconds for one candidate from its cost hints.
-
-        ``hints`` keys (all optional, per chip): ``arg_bytes`` /
-        ``out_bytes`` / ``temp_bytes`` (HBM traffic, charged once),
-        ``hbm_passes`` (how many times the solve streams that working set;
-        default 1), ``flops``, ``dispatches``, ``h2d_bytes``,
-        ``coll_bytes``.  The roofline term takes the MAX of the HBM and
-        FLOP times (they overlap on the MXU); dispatches, H2D streaming,
-        and collectives are serial adders."""
-        touched = (
-            hints.get("arg_bytes", 0)
-            + hints.get("out_bytes", 0)
-            + hints.get("temp_bytes", 0)
-        ) * max(1.0, float(hints.get("hbm_passes", 1)))
-        hbm_s = touched / (self.hbm_gbps * 2**30)
-        flop_s = float(hints.get("flops", 0.0)) / self.peak_flops
-        return (
-            max(hbm_s, flop_s)
-            + float(hints.get("dispatches", 1)) * self.dispatch_seconds
-            + float(hints.get("h2d_bytes", 0)) / (self.h2d_gbps * 2**30)
-            + float(hints.get("coll_bytes", 0)) / (self.ici_gbps * 2**30)
-        )
-
-
-# -- the cross-program calibration model (ISSUE 10) ---------------------------
-
-#: featurized outcomes required before a cross-program fit is attempted
-#: (core.autoshard additionally requires >= 2 distinct program
-#: fingerprints — transfer between programs is the model's entire point).
-MIN_MODEL_ROWS = 8
-
-#: one-sided bound on the learned factor: a regression extrapolating onto
-#: a feature vector far outside its training hull must not predict a
-#: thousandfold slowdown/speedup and blow a candidate past every margin.
-_FACTOR_CLIP = 32.0
-
-
-@dataclasses.dataclass
-class CalibrationModel:
-    """Cross-program calibration: ridge regression of
-    ``log(measured / analytic-prior)`` on candidate FEATURES (operand
-    bytes, mesh factorization, strategy kind, arithmetic intensity — see
-    ``core.autoshard.plan_features``), fitted over every program's logged
-    outcomes.
-
-    This replaces PR 9's per-(fingerprint, candidate) memorization as the
-    below-:data:`~keystone_tpu.core.autoshard.MIN_TRAIN` fallback: a
-    median keyed on the program fingerprint cannot say anything about a
-    shape it never ran, while a feature-space fit transfers — train on a
-    16k x 2k solve, predict the ratio for an 8k x 4k one (the Learned
-    Cost Model placement direction, PAPERS.md).  Direct per-pair medians
-    still win once they exist, and only THEY tighten the ranking margin;
-    the model only shifts absolute predictions toward honesty, bounded by
-    :data:`_FACTOR_CLIP`.
-    """
-
-    feature_names: list
-    kinds: list  #: strategy one-hot vocabulary seen at fit time
-    weights: "np.ndarray"  #: [1 + features + kinds] — bias first
-    n_rows: int
-    n_programs: int
-
-    @classmethod
-    def fit_rows(cls, rows, l2: float = 1.0) -> "CalibrationModel | None":
-        """Fit from ``[(fingerprint, features_dict, ratio)]`` rows (the
-        shape ``core.autoshard.model_rows`` yields).  Returns ``None``
-        for degenerate inputs (no rows / no positive ratios)."""
-        import numpy as np
-
-        rows = [
-            (fp, f, r) for fp, f, r in rows
-            if isinstance(f, dict) and r and r > 0
-        ]
-        if not rows:
-            return None
-        names = sorted({
-            k for _fp, f, _r in rows
-            for k, v in f.items()
-            if isinstance(v, (int, float))
-        })
-        kinds = sorted({f.get("kind") for _fp, f, _r in rows} - {None})
-        xs, ys = [], []
-        for _fp, f, r in rows:
-            xs.append(cls._vector(f, names, kinds))
-            ys.append(np.log(r))
-        x = np.asarray(xs, np.float64)
-        y = np.asarray(ys, np.float64)
-        reg = l2 * np.eye(x.shape[1])
-        reg[0, 0] = 0.0  # the bias absorbs the global mean unpenalized
-        w = np.linalg.solve(x.T @ x + reg, x.T @ y)
-        return cls(
-            feature_names=names,
-            kinds=kinds,
-            weights=w,
-            n_rows=len(rows),
-            n_programs=len({fp for fp, _f, _r in rows}),
-        )
-
-    @staticmethod
-    def _vector(features: dict, names, kinds):
-        import numpy as np
-
-        v = [1.0]
-        v.extend(float(features.get(k, 0.0) or 0.0) for k in names)
-        kind = features.get("kind")
-        v.extend(1.0 if kind == k else 0.0 for k in kinds)
-        return np.asarray(v, np.float64)
-
-    def predict_factor(self, features: dict) -> float:
-        """The calibration factor (measured/prior ratio) this model
-        predicts for one candidate's feature vector, clipped to
-        ``[1/32, 32]``."""
-        import numpy as np
-
-        pred = float(
-            self._vector(features, self.feature_names, self.kinds)
-            @ self.weights
-        )
-        lim = float(np.log(_FACTOR_CLIP))
-        return float(np.exp(np.clip(pred, -lim, lim)))
-
-    def record(self) -> dict:
-        return {
-            "n_rows": self.n_rows,
-            "n_programs": self.n_programs,
-            "features": list(self.feature_names),
-            "kinds": list(self.kinds),
-        }
 
 
 # -- the closed-loop ingest autotuner -----------------------------------------

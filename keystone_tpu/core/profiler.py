@@ -3,19 +3,16 @@ sampler, and triggered XLA profiler capture.
 
 The host side of this system is observable (spans, SLO surface, flight
 recorder); the DEVICE was a black box: XLA's ``cost_analysis`` was called
-ad-hoc in two bench-only sites, the solvers' hand-derived ``flops`` hints
-were never audited against the compiler, and ``plan_program``'s charged
-bytes were never compared to what the device actually allocated.  This
-module is the measured substrate that closes those gaps (and the one the
-ROADMAP's learned placement cost model reads — PAPERS.md: Automap; Learned
-Cost Model for Placement on Reconfigurable Dataflow Hardware):
+ad-hoc in two bench-only sites, and ``plan_program``'s charged bytes were
+never compared to what the device actually allocated.  This module is the
+measured substrate that closes those gaps:
 
 * **Program ledger** — every compiled-program execution path
   (``run_ladder`` tiers, ``ServingEngine`` buckets, the fused
   device-decode+featurize dispatch) calls :func:`record_program` with its
   compiled executable and device-synced measured wall; the ledger joins
   ``cost_analysis()`` FLOPs/bytes with the wall into live per-program MFU
-  and roofline position (``optimize.CostModel`` device rate tables),
+  and roofline position (``optimize.DEVICE_RATES``),
   exported as ``profiler_*`` gauges in ``trace.metrics`` (Prometheus rides
   for free) and ``profiler.program`` trace instants, and aggregated by
   :func:`ledger_record`.
@@ -23,12 +20,8 @@ Cost Model for Placement on Reconfigurable Dataflow Hardware):
   ``device.memory_stats()`` every ``KEYSTONE_HBM_SAMPLE_MS`` and keeps
   per-:func:`phase` high-water marks; :func:`audit_plan` compares a
   phase's watermark against the ``plan_program`` charge — drift beyond
-  ``KEYSTONE_PLAN_DRIFT_TOL`` is counted (``plan_drift``) and appended to
-  the plan-outcome log as calibration evidence (``outcome:"hbm_drift"``
-  rows ``core.autoshard.drift_rows`` feeds to the cross-program
-  ``CalibrationModel``), closing the predict -> measure -> learn loop on
-  the MEMORY side the way plan outcomes already close it on time.  A
-  sampler crash is a counted degradation (``profiler_sampler_crash``),
+  ``KEYSTONE_PLAN_DRIFT_TOL`` is counted (``plan_drift``).  A sampler
+  crash is a counted degradation (``profiler_sampler_crash``),
   never a failed run — the chaos family ``profiler_crash`` enforces it.
 * **Triggered XLA capture** — :func:`maybe_capture` opens a bounded
   ``jax.profiler`` trace window under ``KEYSTONE_XPROF_DIR`` (at most
@@ -81,13 +74,6 @@ DEFAULT_DRIFT_TOL = 0.25
 #: Per-kind capture cap per process: the first windows around a breach
 #: carry the information; a fault storm must not fill a disk with xprof.
 MAX_CAPTURES_PER_KIND = 2
-
-#: The hand-derived solver ``flops`` hints are order-of-magnitude cost
-#: hints, not exact op counts (XLA fuses, rematerializes, and counts
-#: transcendentals its own way) — agreement within this FACTOR is a pass;
-#: outside it the hint is misleading the cost model and the mismatch is
-#: counted (``flops_hint_mismatch``), never silent.
-FLOPS_AUDIT_TOL = 8.0
 
 _NAME_RE = re.compile(r"[^a-zA-Z0-9_.-]")
 
@@ -405,11 +391,10 @@ def ledger() -> dict:
 
 def ledger_record() -> dict:
     """The bench ``profiler`` section: the ledger plus the device rates
-    the MFU figures were computed against and the flops-audit table."""
+    the MFU figures were computed against."""
     return {
         "rates": device_rates(),
         "programs": ledger(),
-        "flops_audits": flops_audits(),
         "captures": capture_paths(),
     }
 
@@ -426,61 +411,6 @@ def synced_wall(out, t0: float) -> float:
     except Exception:  # noqa: BLE001 — an unsyncable result is not an error
         pass
     return time.perf_counter() - t0
-
-
-# -- the hand-derived flops-hint audit -----------------------------------------
-
-_audit_lock = threading.Lock()
-_audits: dict[str, dict] = {}
-
-
-def audit_flops(
-    label: str,
-    hint_flops: float | None,
-    compiled,
-    *,
-    chips: int = 1,
-    tol_factor: float = FLOPS_AUDIT_TOL,
-) -> float | None:
-    """Audit a solver's hand-derived per-chip ``flops`` hint against the
-    compiled program's own ``cost_analysis``.  ``chips`` multiplies the
-    per-chip hint back to module scope for mesh candidates.  Returns the
-    hint/compiled ratio (None when either side is unknown); a ratio
-    outside ``[1/tol_factor, tol_factor]`` is counted
-    (``flops_hint_mismatch``) — a hint misleading the placement cost
-    model by an order of magnitude must be visible, not silent."""
-    if not enabled() or not hint_flops or compiled is None:
-        return None
-    measured = program_cost(compiled)["flops"]
-    if not measured:
-        return None
-    ratio = float(hint_flops) * max(1, int(chips)) / measured
-    ok = (1.0 / tol_factor) <= ratio <= tol_factor
-    with _audit_lock:
-        _audits[label] = {
-            "hint_flops": float(hint_flops) * max(1, int(chips)),
-            "compiled_flops": measured,
-            "ratio": round(ratio, 4),
-            "tol_factor": tol_factor,
-            "ok": ok,
-        }
-    if not ok:
-        counters.record(
-            "flops_hint_mismatch",
-            f"{label}: hand flops hint x{ratio:.3g} of compiled "
-            f"cost_analysis (tolerance x{tol_factor}) — the cost model is "
-            "being fed a misleading hint",
-        )
-    trace.instant(
-        "profiler.flops_audit", label=label, ratio=round(ratio, 4), ok=ok
-    )
-    return ratio
-
-
-def flops_audits() -> dict:
-    """label -> the most recent audit row for it."""
-    with _audit_lock:
-        return {k: dict(v) for k, v in _audits.items()}
 
 
 # -- the HBM watermark sampler -------------------------------------------------
@@ -685,17 +615,11 @@ def audit_plan(
     plan,
     *,
     phase_name: str | None = None,
-    fingerprint: str | None = None,
-    features: dict | None = None,
 ) -> dict | None:
     """Compare the watermark the sampler actually saw against what
     ``plan_program`` charged for the program that ran.  Drift beyond
     ``KEYSTONE_PLAN_DRIFT_TOL`` (relative, either direction) is counted
-    (``plan_drift``) and the row is appended to the plan-outcome log as an
-    ``outcome:"hbm_drift"`` record — the byte-side calibration evidence
-    ``core.autoshard.drift_rows`` feeds to the cross-program
-    :class:`~keystone_tpu.core.optimize.CalibrationModel`.  Returns the
-    audit row, or None when either side is unknown (no sampler, backend
+    (``plan_drift``).  Returns the audit row, or None when either side is unknown (no sampler, backend
     without stats, unanalyzed plan) — skipped, never guessed."""
     if not enabled():
         return None
@@ -733,32 +657,6 @@ def audit_plan(
     trace.metrics.gauge(
         f"profiler_{_NAME_RE.sub('_', label)}_plan_drift", round(drift, 4)
     )
-    # The calibration evidence: one row per audited run, read back by
-    # autoshard.drift_rows() / the byte-drift CalibrationModel in the NEXT
-    # process (same once-per-process read discipline as plan outcomes).
-    from . import autoshard
-
-    if features is None:
-        # Byte-composition features straight off the audited plan — the
-        # same vector shape the search's scoring side builds from hints
-        # (autoshard.hbm_features), so train and predict agree.
-        features = autoshard.hbm_features(
-            getattr(plan, "argument_bytes", 0),
-            getattr(plan, "temp_bytes", 0),
-            getattr(plan, "output_bytes", 0),
-            getattr(plan, "mesh_axes", None),
-        )
-    autoshard.append_outcome({
-        "fingerprint": fingerprint or f"hbm:{label}",
-        "label": label,
-        "candidate": label,
-        "outcome": "hbm_drift",
-        "charged_bytes": charged,
-        "watermark_bytes": int(wm),
-        "drift_ratio": drift,
-        "features": features,
-        "ts": time.time(),
-    })
     return audit
 
 
@@ -870,7 +768,7 @@ def maybe_capture(kind: str, reason: str = "") -> str | None:
 
 
 def reset_state() -> None:
-    """Test isolation: empty ledger/audits, forget capture caps, stop and
+    """Test isolation: empty ledger, forget capture caps, stop and
     drop the sampler, cancel any open capture window's timer (a stale
     timer firing later would stop a NEW window early — or call
     ``stop_trace`` with nothing open)."""
@@ -896,8 +794,6 @@ def reset_state() -> None:
             _stop_trace()
     with _ledger_lock:
         _ledger.clear()
-    with _audit_lock:
-        _audits.clear()
     _cost_cache.clear()
     _keyed_cost_cache.clear()
 

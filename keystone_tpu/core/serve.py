@@ -323,9 +323,9 @@ class ServingEngine:
         leaf already resident on exactly this mesh's devices keeps its
         SOLVE placement (a mesh fit serves from where it solved — no host
         pull); every other array leaf is placed replicated
-        (``autoshard.spec_sharding``) so each bucket program sees
+        (``parallel.mesh.spec_sharding``) so each bucket program sees
         committed, mesh-consistent parameters."""
-        from . import autoshard
+        from ..parallel.mesh import spec_sharding
 
         jax = self._jax
         mesh_devs = set(mesh.devices.flat)
@@ -341,7 +341,7 @@ class ServingEngine:
                 return leaf
             arr = np.asarray(jax.device_get(leaf))
             return jax.device_put(
-                arr, autoshard.spec_sharding("replicated", mesh, arr.ndim)
+                arr, spec_sharding("replicated", mesh, arr.ndim)
             )
 
         return jax.tree_util.tree_map(place, pipe)

@@ -74,7 +74,6 @@ DEFAULT_METRICS_INTERVAL_S = 10.0
 POSTMORTEM_KINDS = frozenset(
     {
         "solver_oom_retry",
-        "autoshard_stepdown",
         "deadline_exceeded",
         "serve_burst_oom",
         "serve_batch_failed",

@@ -444,7 +444,7 @@ SECTIONS = frozenset({
     "stack",  # a chunk's images gathered, padded and flattened on the host
     "draw",  # the sampling pass's positions inside a chunk
     "concat",  # the chunks' results joined
-    "search",  # the solvers' candidate enumeration and placement search
+    "search",  # a solver's degradation ladder built, tier by tier
     "plan",  # a tier's admission preflight
     "place",  # operands padded, sorted and placed ahead of a tier's program
     "sort",  # the weighted solver's class sort

@@ -58,10 +58,8 @@ def mesh_desc(mesh: Mesh) -> str:
 
 @functools.lru_cache(maxsize=None)
 def _mesh_shapes(n_devices: int) -> tuple[tuple[int, int], ...]:
-    """Memoized factorization body of :func:`enumerate_mesh_shapes` — the
-    device count never changes within a process, yet the placement search
-    re-enumerates on every ``fit()``; computing the divisor walk once per
-    count keeps that recurring call a dict hit."""
+    """Memoized factorization body of :func:`enumerate_mesh_shapes` (the
+    device count never changes within a process)."""
     if n_devices < 1:
         raise ValueError(f"need >= 1 device, got {n_devices}")
     return tuple(
@@ -73,14 +71,11 @@ def _mesh_shapes(n_devices: int) -> tuple[tuple[int, int], ...]:
 
 def enumerate_mesh_shapes(n_devices: int) -> list[tuple[int, int]]:
     """Every (data, model) factorization of ``n_devices``, data-major
-    descending — the candidate set the placement search (core.autoshard)
-    scores instead of the hand ladder's two fixed rungs.  All devices
-    participate in every candidate (a smaller mesh never beats a larger one
-    on the cost model's axes, and the single-device strategies are their
-    own candidates); ``n_devices=1`` is the one-shape list ``[(1, 1)]``,
-    and a prime count yields exactly its two degenerate factorizations.
-    Memoized per device count (a fresh list is returned per call; the
-    cached tuple is never handed out mutable)."""
+    descending; all devices participate in every shape.  ``n_devices=1``
+    is the one-shape list ``[(1, 1)]``, and a prime count yields exactly
+    its two degenerate factorizations.  Memoized per device count (a fresh
+    list is returned per call; the cached tuple is never handed out
+    mutable)."""
     return list(_mesh_shapes(n_devices))
 
 
@@ -92,11 +87,10 @@ _mesh_cache: dict[tuple, tuple[Mesh, ...]] = {}
 
 def enumerate_meshes(devices) -> list[Mesh]:
     """:func:`enumerate_mesh_shapes` materialized over a fixed device
-    list — the same devices in the same order for every candidate, so two
-    searches over one device set enumerate identical meshes (searched-plan
-    determinism).  Memoized per device tuple: every ``fit()`` under a mesh
-    re-enumerates candidates, and each uncached enumeration costs one jax
-    ``Mesh`` construction per factorization."""
+    list — the same devices in the same order for every shape, so two calls
+    over one device set enumerate identical meshes.  Memoized per device
+    tuple: each uncached enumeration costs one jax ``Mesh`` construction per
+    factorization."""
     key = tuple(devices)
     cached = _mesh_cache.get(key)
     if cached is None:
@@ -105,6 +99,93 @@ def enumerate_meshes(devices) -> list[Mesh]:
             for d, m in _mesh_shapes(len(key))
         )
     return list(cached)
+
+
+# -- per-operand sharding specs -----------------------------------------------
+#
+# A spec string names ONE mesh axis over ONE operand dimension
+# ("data@dim0", "model@dim1") or full replication ("replicated").  The
+# checkpoint's restore planner and the serving engine lower the strings they
+# record or choose into the jax objects below, and byte accounting reads the
+# same strings, so the two cannot drift.
+
+
+def spec_candidates(aval, mesh_shape: dict) -> list[dict]:
+    """Candidate shardings for ONE operand aval under a (data, model) mesh
+    shape, generated from the aval's dimensions: the data axis over any
+    evenly-divisible dim, the model axis over any other evenly-divisible
+    dim, and replicated (always legal).  Each entry carries the spec's
+    per-chip bytes."""
+    shape = tuple(int(d) for d in aval.shape)
+    itemsize = np.dtype(aval.dtype).itemsize
+    total = int(np.prod(shape)) * itemsize if shape else itemsize
+    out = [{"spec": "replicated", "per_chip_bytes": total}]
+    d_sz = int(mesh_shape.get(DATA_AXIS, 1))
+    m_sz = int(mesh_shape.get(MODEL_AXIS, 1))
+    for dim, n in enumerate(shape):
+        if d_sz > 1 and n % d_sz == 0:
+            out.append({
+                "spec": f"data@dim{dim}",
+                "per_chip_bytes": total // d_sz,
+            })
+        if m_sz > 1 and n % m_sz == 0:
+            out.append({
+                "spec": f"model@dim{dim}",
+                "per_chip_bytes": total // m_sz,
+            })
+    return out
+
+
+def best_spec(aval, mesh_shape: dict) -> dict:
+    """The minimum-per-chip-bytes legal sharding for one aval (replicated
+    when nothing divides)."""
+    cands = spec_candidates(aval, mesh_shape)
+    return min(cands, key=lambda c: (c["per_chip_bytes"], c["spec"]))
+
+
+def spec_pspec(spec: str, ndim: int) -> P:
+    """Lower one spec string to the ``PartitionSpec`` it names."""
+    if spec == "replicated":
+        return P(*([None] * ndim))
+    axis, sep, dim = spec.partition("@dim")
+    if not sep or axis not in (DATA_AXIS, MODEL_AXIS) or not dim.isdigit():
+        raise ValueError(
+            f"bad sharding spec {spec!r} (want 'replicated', 'data@dimN' "
+            "or 'model@dimN')"
+        )
+    i = int(dim)
+    if i >= ndim:
+        raise ValueError(f"spec {spec!r} names dim {i} of a {ndim}-d operand")
+    parts: list = [None] * ndim
+    parts[i] = axis
+    return P(*parts)
+
+
+def spec_sharding(spec: str, mesh: Mesh, ndim: int) -> NamedSharding:
+    """Lower one spec string to a ``NamedSharding`` on ``mesh``."""
+    return NamedSharding(mesh, spec_pspec(spec, ndim))
+
+
+def spec_chip_bytes(shape, dtype, spec: str, mesh_shape: dict) -> int:
+    """Analytic per-chip bytes of one operand under one spec.  The named
+    dimension must divide evenly (:func:`spec_candidates` only emits legal
+    specs)."""
+    shape = tuple(int(d) for d in shape)
+    total = int(np.prod(shape)) * np.dtype(dtype).itemsize if shape else (
+        np.dtype(dtype).itemsize
+    )
+    if spec == "replicated":
+        return total
+    axis, _, dim = spec.partition("@dim")
+    size = int(mesh_shape.get(axis, 1))
+    n = shape[int(dim)]
+    if size <= 1:
+        return total
+    if n % size:
+        raise ValueError(
+            f"spec {spec!r} does not divide dim of size {n} by {size}"
+        )
+    return total // size
 
 
 def mesh_spans_processes(mesh: Mesh) -> bool:

@@ -24,7 +24,6 @@ import jax.scipy.linalg as jsl
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core import autoshard
 from ..core import memory as kmem
 from ..core import numerics as knum
 from ..core import profiler as kprof
@@ -38,7 +37,6 @@ from ..parallel.mesh import (
     DATA_AXIS,
     MODEL_AXIS,
     current_mesh,
-    enumerate_meshes,
     mesh_desc,
     on_one_device,
     pad_shard_inputs,
@@ -426,7 +424,7 @@ def _block(x, mu, i, bs: int):
 
 
 def _fused_bcd_impl(x, labels, lam, nvalid, num_iter: int, widths, mesh,
-                    specs=None, hold: int = 0, hold_dtype=None):
+                    hold: int = 0, hold_dtype=None):
     """The ENTIRE block-least-squares fit as one compiled program.
 
     Centering (label + per-block feature means over the ``nvalid`` true
@@ -457,15 +455,8 @@ def _fused_bcd_impl(x, labels, lam, nvalid, num_iter: int, widths, mesh,
     pad-column shift, the update order) is the same code.
 
     With ``mesh``: rows shard over the data axis (grams lower to local
-    MXU gram + ICI all-reduce), models/labels' class columns shard over the
-    model axis.  ``specs`` (static; a sorted tuple of
-    ``(operand, spec-string)`` pairs from a searched spec assignment —
-    core.autoshard ISSUE 10) overrides the per-operand layout: ``"x"``
-    defaults to ``data@dim0``, ``"labels"`` to the caller's placement,
-    ``"models"`` to ``model@dim2``; each chosen spec lowers through
-    ``autoshard.spec_sharding`` into the very ``NamedSharding`` constraint
-    executed here, so a searched layout is REAL, not just byte accounting.
-    ``specs=None`` is bit-for-bit the PR 9 program.
+    MXU gram + ICI all-reduce), the models' class columns shard over the
+    model axis, and the labels keep the caller's placement.
 
     Returns (models [B, bs, k], label_mean [k], means [B, bs]); for a
     ``BlockSource`` also the factor stack ``[B, bs, bs]``, the fit's one
@@ -494,21 +485,11 @@ def _fused_bcd_impl(x, labels, lam, nvalid, num_iter: int, widths, mesh,
     col_spec = None
     mrow_spec = None
     if mesh is not None:
-        sp = dict(specs) if specs else {}
         x = jax.lax.with_sharding_constraint(
-            x, autoshard.spec_sharding(sp.get("x", "data@dim0"), mesh, 2)
+            x, NamedSharding(mesh, P(DATA_AXIS, None))
         )
-        lspec = sp.get("labels")
-        if lspec is not None:
-            labels = jax.lax.with_sharding_constraint(
-                labels, autoshard.spec_sharding(lspec, mesh, 2)
-            )
-        mspec = sp.get("models", "model@dim2")
-        if mspec == "model@dim2":
-            col_spec = NamedSharding(mesh, P(None, None, MODEL_AXIS))
-            mrow_spec = NamedSharding(mesh, P(None, MODEL_AXIS))
-        elif mspec != "replicated":  # replicated: no constraint at all
-            raise ValueError(f"unsupported models spec {mspec!r}")
+        col_spec = NamedSharding(mesh, P(None, None, MODEL_AXIS))
+        mrow_spec = NamedSharding(mesh, P(None, MODEL_AXIS))
 
     mask = (jnp.arange(n) < nvalid).astype(dtype)[:, None]
     nv = jnp.asarray(nvalid, dtype)
@@ -638,7 +619,7 @@ def _fused_bcd_fit_variant(donate_argnums: tuple = ()):
     never a caller-visible passthrough array (VERDICT r5 weak #1)."""
     return jax.jit(
         _fused_bcd_impl,
-        static_argnames=("num_iter", "widths", "mesh", "specs", "hold", "hold_dtype"),
+        static_argnames=("num_iter", "widths", "mesh", "hold", "hold_dtype"),
         donate_argnums=donate_argnums,
     )
 
@@ -682,17 +663,14 @@ def _execute_fused_bcd(plan, donate_argnums, x, labels, lam, nvalid,
 
 
 def _execute_fused_bcd_mesh(plan, x, labels, lam, nvalid, num_iter: int,
-                            widths, mesh, specs=None):
-    """Dispatch the GSPMD fused program for one mesh-ladder tier (``specs``:
-    the tier's searched per-operand layout assignment, hashable, or None
-    for the default layout).  The jitted entry — not ``plan.compiled`` —
-    is used deliberately: an AOT executable bakes committed input
-    shardings and scalar placements that a later call's padded inputs need
-    not match exactly, while the jit cache keys on the same
-    (aval, sharding) signature and reuses its own compilation.  Module
-    level so the chaos harness can inject RESOURCE_EXHAUSTED here to drive
-    the mesh ladder's step-down (the ``spec_mispredict`` family kills the
-    top-ranked spec-sharded plan at this very dispatch)."""
+                            widths, mesh):
+    """Dispatch the GSPMD fused program for one mesh-ladder tier.  The
+    jitted entry — not ``plan.compiled`` — is used deliberately: an AOT
+    executable bakes committed input shardings and scalar placements that a
+    later call's padded inputs need not match exactly, while the jit cache
+    keys on the same (aval, sharding) signature and reuses its own
+    compilation.  Module level so a harness can inject RESOURCE_EXHAUSTED
+    here to drive the mesh ladder's step-down."""
     del plan
     if mesh.size == 1:
         # A mesh of one device is one device: no constraint of the mesh
@@ -701,27 +679,7 @@ def _execute_fused_bcd_mesh(plan, x, labels, lam, nvalid, num_iter: int,
         return _execute_fused_bcd(
             None, (), x, labels, lam, nvalid, num_iter, widths
         )
-    return _fused_bcd_fit(x, labels, lam, nvalid, num_iter, widths, mesh,
-                          specs)
-
-
-def _bcd_spec_variants(m) -> list[dict]:
-    """Per-operand spec assignments the BCD placement search enumerates
-    for one mesh shape, beyond the strategy's default layout (row-sharded
-    inputs, model-axis-sharded model columns): model-axis-sharded label
-    columns (the wide-class layout), fully-replicated model blocks, and
-    fully-replicated small operands.  Every entry is legal by
-    construction — the class axis is padded to a model-axis multiple
-    before execution — and deterministic, so two searches over one device
-    set enumerate identical candidates."""
-    d_sz, m_sz = m.shape[DATA_AXIS], m.shape[MODEL_AXIS]
-    out: list[dict] = []
-    if m_sz > 1:
-        out.append({"labels": "model@dim1"})
-        out.append({"models": "replicated"})
-    if d_sz * m_sz > 1:
-        out.append({"labels": "replicated", "models": "replicated"})
-    return out
+    return _fused_bcd_fit(x, labels, lam, nvalid, num_iter, widths, mesh)
 
 
 def _blocked_design_matrix(features, block_size: int, num_features=None,
@@ -1260,7 +1218,6 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         checkpoint=None,
         resume_from=None,
         donate: bool | None = None,
-        plan=None,
     ) -> BlockLinearMapper:
         """``nvalid``: true global row count when inputs were zero-padded for
         sharding — pad rows are masked back to zero after centering so grams
@@ -1298,19 +1255,6 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         (the caller must not reuse them; an exec-level OOM then cannot
         rebuild them for the step-down), ``False`` never donates.  The
         decision trail is ``self.last_fit_report``.
-
-        Placement search (core.autoshard, on by default): the ladders above
-        are the HAND enumeration — the fit actually runs the cost-model
-        RANKED candidate list (every (data, model) mesh factorization of
-        the live devices x fused/stepwise/host-staged strategy), pruned by
-        the zero-cost batch preflight, with the hand order as the
-        untrained-model tie-break and the host-staged/single-device floor
-        pinned last; runtime RESOURCE_EXHAUSTED steps down the ranked list
-        (counted ``autoshard_stepdown``) exactly as the hand ladder did.
-        ``plan``: ``None`` honors ``KEYSTONE_AUTOSHARD``, ``False`` forces
-        the hand ladder, ``True`` forces the search, a ``PlacementPlan``
-        (or candidate-name list) replays a previous ranking.  The searched
-        table lands in ``last_fit_report.placement``.
         """
         mesh = self.mesh if self.mesh is not None else current_mesh()
         resumable = checkpoint is not None or resume_from is not None
@@ -1373,7 +1317,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         try:
             model = self._fit_dispatch(
                 features, x, labels, num_features, nvalid, widths,
-                checkpoint, resume_from, donate, plan, mesh, resumable,
+                checkpoint, resume_from, donate, mesh, resumable,
                 cond_rows, solve_cond, bcd_plan,
             )
         finally:
@@ -1387,7 +1331,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
 
     def _fit_dispatch(
         self, features, x, labels, num_features, nvalid, widths,
-        checkpoint, resume_from, donate, plan, mesh, resumable,
+        checkpoint, resume_from, donate, mesh, resumable,
         cond_rows, solve_cond, bcd_plan,
     ):
         if resumable:
@@ -1422,23 +1366,20 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 )
         elif isinstance(x, BlockSource):
             models, label_mean, means = self._fit_made_ladder(
-                x, labels, nvalid, bcd_plan, plan_arg=plan
+                x, labels, nvalid, bcd_plan
             )
         elif mesh is not None:
             # Multi-chip path: the MESH degradation ladder — full
             # (data, model) mesh with per-chip admission, then the
-            # model-axis-collapsed mesh, then the single-device ladder —
-            # searched/ranked by core.autoshard unless plan=False.
+            # model-axis-collapsed mesh, then the single-device ladder.
             models, label_mean, means = self._fit_mesh_ladder(
                 features, x, labels, num_features, nvalid, widths, mesh,
-                plan_arg=plan,
             )
         else:
             if nvalid is None:
                 nvalid = int(jnp.shape(labels)[0])
             models, label_mean, means = self._fit_ladder(
                 features, x, labels, num_features, nvalid, widths, donate,
-                plan_arg=plan,
             )
         all_cond = (cond_rows or []) + list(solve_cond)
         if all_cond and self.last_fit_report is not None:
@@ -1452,7 +1393,6 @@ class BlockLeastSquaresEstimator(LabelEstimator):
 
     def _fit_mesh_ladder(
         self, features, x, labels, num_features, nvalid, widths, mesh,
-        plan_arg=None,
     ):
         """Distributed solve through the MESH degradation ladder.
 
@@ -1478,117 +1418,58 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         with trace.host("place", "lam"):
             lam_arr = jnp.asarray(self.lam, dtype)
 
-        report = kmem.FitReport(label="bcd_fit")
-        self.last_fit_report = report
+        with trace.host("search", "ladder"):
+            report = kmem.FitReport(label="bcd_fit")
+            self.last_fit_report = report
 
-        itx = np.dtype(xdt).itemsize
-
-        def mesh_tier(m, prior_rank, hand, specs=None):
-            """One fused-mesh candidate: ``specs=None`` is the strategy's
-            default layout (row-sharded inputs, model-axis-sharded model
-            columns — the PR 9 hand rung, bit-for-bit); a spec assignment
-            makes the candidate EXECUTE that per-operand layout, with the
-            hints charging the chosen specs' actual per-chip bytes instead
-            of the best-spec lower bound."""
-            name = f"fused[mesh {mesh_desc(m)}]"
-            if specs:
-                name = f"fused[mesh {mesh_desc(m)}|{autoshard.spec_tag(specs)}]"
-            d_sz, m_sz = m.shape[DATA_AXIS], m.shape[MODEL_AXIS]
-            # The design matrix may arrive row-padded for the CALLER's data
-            # axis (nvalid < its rows); every candidate pads from there.
-            n_x = int(np.shape(x)[0])
-            n_pad = n_x + (-n_x) % d_sz
-            k_pad = k + (-k) % m_sz
-            mdict = dict(m.shape)
-            lspec = (specs or {}).get("labels", "data@dim0")
-            mspec = (specs or {}).get("models", "model@dim2")
-            # The residual carries inherit the labels layout; the models
-            # carry follows the models spec.  One byte helper feeds the
-            # transient floor, the prune figure, and the cost model alike.
-            res_b = autoshard.spec_chip_bytes(
-                (n_pad, k_pad), dtype, lspec, mdict
-            )
-            models_b = autoshard.spec_chip_bytes(
-                (nb, bs, k_pad), dtype,
-                "model@dim2" if mspec == "model@dim2" else "replicated",
-                mdict,
-            )
-            # Analytic per-chip transient floor (CPU backends report
-            # temp 0): one centered row-sharded block, the replicated
-            # Cholesky stack, two residual carries, the models carry.
-            floor = (
-                it * (n_pad * bs // d_sz + nb * bs * bs)
-                + 2 * res_b + models_b
-            )
-            if specs:
-                # A spec candidate charges the bytes of the layout it
-                # will actually execute — the spec dimension is real.
-                arg_bytes = (
-                    autoshard.spec_chip_bytes(
-                        (n_pad, nb * bs), xdt,
-                        (specs or {}).get("x", "data@dim0"), mdict,
-                    )
-                    + autoshard.spec_chip_bytes(
-                        (n_pad, k_pad), dtype, lspec, mdict
-                    )
+            def mesh_tier(m):
+                """One fused-mesh rung: row-sharded inputs, model-axis-sharded
+                model columns."""
+                name = f"fused[mesh {mesh_desc(m)}]"
+                d_sz, m_sz = m.shape[DATA_AXIS], m.shape[MODEL_AXIS]
+                # The design matrix may arrive row-padded for the CALLER's data
+                # axis (nvalid < its rows); every rung pads from there.
+                n_x = int(np.shape(x)[0])
+                n_pad = n_x + (-n_x) % d_sz
+                k_pad = k + (-k) % m_sz
+                # Analytic per-chip transient floor (CPU backends report
+                # temp 0): one centered row-sharded block, the replicated
+                # Cholesky stack, two row-sharded residual carries, the models
+                # carry sharded over the model axis.
+                floor = it * (
+                    n_pad * bs // d_sz + nb * bs * bs
+                    + 2 * (n_pad // d_sz) * k_pad + nb * bs * (k_pad // m_sz)
                 )
-            else:
-                # Hand accounting: per-operand bytes through the spec
-                # enumeration's minimum (the best sharding this mesh shape
-                # can achieve) — a lower bound of any layout the compiled
-                # admission will charge.
-                arg_bytes = sum(
-                    autoshard.best_spec(a, mdict)["per_chip_bytes"]
-                    for a in (
-                        jax.ShapeDtypeStruct((n_pad, nb * bs), xdt),
-                        jax.ShapeDtypeStruct((n_pad, k_pad), dtype),
-                    )
-                )
-            hints = {
-                "arg_bytes": arg_bytes,
-                "temp_bytes": floor,
-                "out_bytes": it * (k_pad + nb * bs) + models_b,
-                "flops": (
-                    2.0 * n_pad * bs * bs * nb
-                    + self.num_iter * 4.0 * n_pad * bs * k_pad * nb
-                ) / (d_sz * m_sz),
-                "dispatches": 1,
-                "hbm_passes": self.num_iter + 1,
-                "coll_bytes": (
-                    it * nb * (bs * bs + self.num_iter * bs * k_pad)
+                # what the program sums over the data axis, from the shapes: a
+                # gram and num_iter cross terms a block, the block means' gemv
+                # and the label mean
+                psum_bytes = (
+                    it * (nb * (bs * bs + self.num_iter * bs * k_pad) + nb * bs + k_pad)
                     if d_sz > 1 else 0
-                ),
-            }
-            spec_t = tuple(sorted(specs.items())) if specs else None
+                )
 
-            def plan():
-                budget, _worst = kmem.min_chip_budget(m)
                 sds = jax.ShapeDtypeStruct
                 row = row_sharding(m)
                 x_s = sds((n_pad, nb * bs), xdt, sharding=row)
-                y_s = sds(
-                    (n_pad, k_pad), dtype,
-                    sharding=(
-                        row if lspec == "data@dim0"
-                        else autoshard.spec_sharding(lspec, m, 2)
-                    ),
-                )
+                y_s = sds((n_pad, k_pad), dtype, sharding=row)
                 lam_s, i32_s = sds((), dtype), sds((), jnp.int32)
-                return kmem.plan_program(
-                    _fused_bcd_fit, x_s, y_s, lam_s, i32_s,
-                    self.num_iter, widths, m, spec_t,
-                    label=f"bcd_{name}", budget=budget,
-                    min_temp_bytes=floor, mesh=m,
-                )
 
-            def run(plan):
-                report.mesh_shape = dict(m.shape)
-                with trace.host("place", "operands"):
-                    if spec_t is None or lspec == "data@dim0":
+                def plan():
+                    budget, _worst = kmem.min_chip_budget(m)
+                    return kmem.plan_program(
+                        _fused_bcd_fit, x_s, y_s, lam_s, i32_s,
+                        self.num_iter, widths, m,
+                        label=f"bcd_{name}", budget=budget,
+                        min_temp_bytes=floor, mesh=m,
+                    )
+
+                def run(plan):
+                    report.mesh_shape = dict(m.shape)
+                    with trace.host("place", "operands"):
                         (x_p, y_p), nv = pad_shard_inputs(m, nvalid0, x, labels)
                         # Class columns shard over the model axis; zero label
                         # columns stay zero through every BCD update — exact
-                        # pad.  Rows: a candidate whose data axis is not the
+                        # pad.  Rows: a rung whose data axis is not the
                         # caller's pads a caller-padded design matrix and the
                         # unpadded labels to different counts; labels take the
                         # design matrix's (zero rows, masked by nv).
@@ -1597,153 +1478,72 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                         if row_pad or col_pad:
                             y_p = jnp.pad(y_p, ((0, row_pad), (0, col_pad)))
                         nv = nv if nv is not None else int(jnp.shape(y_p)[0])
-                    else:
-                        # Non-default labels layout: pad rows to the sharded
-                        # design matrix's count and columns to a model-axis
-                        # multiple, then PLACE per the chosen spec — the
-                        # program's constraint and this placement read the
-                        # same spec string, so they cannot drift.
-                        (x_p,), nv = pad_shard_inputs(m, nvalid0, x)
-                        nv = nv if nv is not None else n0
-                        row_pad = int(jnp.shape(x_p)[0]) - n0
-                        col_pad = (-k) % m_sz
-                        if isinstance(labels, jax.Array):
-                            y_p = (
-                                jnp.pad(labels, ((0, row_pad), (0, col_pad)))
-                                if row_pad or col_pad else labels
-                            )
-                        else:
-                            y_p = np.pad(
-                                np.asarray(labels),
-                                ((0, row_pad), (0, col_pad)),
-                            )
-                        y_p = jax.device_put(
-                            jnp.asarray(y_p), autoshard.spec_sharding(lspec, m, 2)
-                        )
-                # what the program sums over the data axis, from the
-                # shapes: a gram and num_iter cross terms a block, the
-                # block means' gemv and the label mean
-                count_psum(hints["coll_bytes"] + (it * (nb * bs + k_pad) if d_sz > 1 else 0))
-                models, label_mean, means = _execute_fused_bcd_mesh(
-                    plan, jnp.asarray(x_p), jnp.asarray(y_p), lam_arr,
-                    nv, self.num_iter, widths, m, spec_t,
+                    count_psum(psum_bytes)
+                    models, label_mean, means = _execute_fused_bcd_mesh(
+                        plan, jnp.asarray(x_p), jnp.asarray(y_p), lam_arr,
+                        nv, self.num_iter, widths, m,
+                    )
+                    if k_pad != k:
+                        models = models[:, :, :k]
+                        label_mean = label_mean[:k]
+                    return models, label_mean, means
+
+                return kmem.Tier(name, plan, run)
+
+            def plan_single():
+                return kmem.MemoryPlan(
+                    label="single_device",
+                    admitted=True,
+                    reason=(
+                        "mesh ladder floor: single-device degradation ladder "
+                        "(its own per-tier admission runs inside)"
+                    ),
                 )
-                if k_pad != k:
-                    models = models[:, :, :k]
-                    label_mean = label_mean[:k]
-                return models, label_mean, means
 
-            return autoshard.Candidate(
-                name, "fused_mesh", plan, run, hints=hints,
-                mesh_axes=mdict, prior_rank=prior_rank, hand=hand,
-                specs=dict(specs) if specs else None,
-            )
+            inner_chosen = []
 
-        def plan_single():
-            return kmem.MemoryPlan(
-                label="single_device",
-                admitted=True,
-                reason=(
-                    "mesh ladder floor: single-device degradation ladder "
-                    "(its own per-tier admission runs inside)"
-                ),
-            )
+            def run_single(_plan):
+                report.mesh_shape = None
+                x_h = (
+                    np.asarray(jax.device_get(x)) if isinstance(x, jax.Array) else x
+                )
+                y_h = (
+                    np.asarray(jax.device_get(labels))
+                    if isinstance(labels, jax.Array)
+                    else labels
+                )
+                out = self._fit_ladder(
+                    x_h, x_h, y_h, num_features, nvalid0, widths, None,
+                    report=report,
+                )
+                inner_chosen.append(report.chosen)
+                return out
 
-        inner_chosen = []
-
-        def run_single(_plan):
-            report.mesh_shape = None
-            x_h = (
-                np.asarray(jax.device_get(x)) if isinstance(x, jax.Array) else x
-            )
-            y_h = (
-                np.asarray(jax.device_get(labels))
-                if isinstance(labels, jax.Array)
-                else labels
-            )
-            out = self._fit_ladder(
-                x_h, x_h, y_h, num_features, nvalid0, widths, None,
-                # The mesh-level search already ranked this floor; the
-                # nested single-device ladder walks its hand order (a
-                # nested search would overwrite the report's placement).
-                plan_arg=False,
-                report=report,
-            )
-            inner_chosen.append(report.chosen)
-            return out
-
-        with trace.host("search", "enumerate"):
-            cands = [mesh_tier(mesh, 0, True)]
+            tiers = [mesh_tier(mesh)]
             rm = reduced_mesh(mesh)
             if rm is not None:
-                cands.append(mesh_tier(rm, 1, True))
-            # The searched candidate set: every remaining (data, model)
-            # factorization of the SAME devices, then (KEYSTONE_AUTOSHARD_SPECS)
-            # the per-operand SPEC assignments of every mesh shape — e.g.
-            # model-axis-sharded label columns, or fully-replicated model
-            # blocks — each an executable layout, ranked by the cost model but
-            # never promoted past the hand rungs on an untrained prior.  Only
-            # enumerated when the search will run — a hand-ladder walk would
-            # discard them, and each costs a jax Mesh construction.
-            if autoshard.will_search(plan_arg):
-                hand_shapes = {
-                    mesh_desc(c_mesh) for c_mesh in (mesh, rm) if c_mesh
-                }
-                searched_meshes = [mesh] + ([rm] if rm is not None else [])
-                for extra in enumerate_meshes(list(mesh.devices.flat)):
-                    if mesh_desc(extra) not in hand_shapes:
-                        searched_meshes.append(extra)
-                        cands.append(mesh_tier(extra, len(cands), False))
-                if autoshard.specs_enabled():
-                    for sm in searched_meshes:
-                        for sp in _bcd_spec_variants(sm):
-                            cands.append(
-                                mesh_tier(sm, len(cands), False, specs=sp)
-                            )
-            cands.append(autoshard.Candidate(
-                "single_device", "single_device", plan_single, run_single,
-                hints={
-                    # Host pull + refit on one chip: the whole design matrix
-                    # crosses back over PCIe and nothing divides — the floor's
-                    # predicted cost is honest about why it is the floor.
-                    "arg_bytes": itx * n0 * nb * bs + it * n0 * k,
-                    "h2d_bytes": itx * n0 * nb * bs + it * n0 * k,
-                    "flops": 2.0 * n0 * bs * bs * nb
-                    + self.num_iter * 4.0 * n0 * bs * k * nb,
-                    "dispatches": 3,
-                },
-                prior_rank=len(cands), floor=True,
-            ))
+                tiers.append(mesh_tier(rm))
+            tiers.append(kmem.Tier("single_device", plan_single, run_single))
         # The solver declares its fit as a profiler PHASE (core.profiler):
         # the HBM watermark sampler attributes this solve's high-water
         # mark to "bcd_fit", separable from serving/ingest residency in
         # the same process.  A no-op when the profiler is off.
         with kprof.phase("bcd_fit"):
-            out = autoshard.run_search(
-                "bcd_fit", cands, report,
-                fingerprint=autoshard.fingerprint(
-                    "bcd_fit", n0, k, widths, self.num_iter, str(xdt),
-                    str(dtype), dict(mesh.shape),
-                    autoshard.device_fingerprint(),
-                ),
-                plan=plan_arg,
-            )
+            out = kmem.run_ladder("bcd_fit", tiers, report)
         if inner_chosen and report.chosen == "single_device":
             # Keep the inner rung visible: "single_device/host_staged".
             report.chosen = f"single_device/{inner_chosen[0]}"
         return out
 
-    def _fit_made_ladder(self, source: BlockSource, labels, nvalid, bcd_plan,
-                         plan_arg=None):
+    def _fit_made_ladder(self, source: BlockSource, labels, nvalid, bcd_plan):
         """The ladder of a fit whose blocks are made: ``fused[made]`` (the
         one fused program, each scan step making its block, the first
         ``bcd_plan["held_blocks"]`` of them kept after the gram pass) ->
         ``stepwise[made]`` (the per-block programs, each making its block;
         the floor).  ``host_staged`` is no rung here: it is the floor for a
-        matrix that lives on the host.  Plans and hints charge what this
-        form holds (the rows, the featurizers' parameters, the factor stack,
-        the kept blocks, the block being made and its centred copy) and what
-        it recomputes (every block not kept, once a pass)."""
+        matrix that lives on the host.  Plans charge what this form holds
+        (the rows, the featurizers' parameters, the factor stack, the kept
+        blocks, the block being made and its centred copy)."""
         nb, bs = len(source), source.block_size
         widths = source.block_widths()
         n, k = int(np.shape(labels)[0]), int(np.shape(labels)[1])
@@ -1754,105 +1554,69 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         with trace.host("place", "lam"):
             lam_arr = jnp.asarray(self.lam, dtype)
             nv_arr = jnp.asarray(nvalid, jnp.int32)
-        sds = jax.ShapeDtypeStruct
-        src_s = jax.tree.map(lambda a: sds(a.shape, a.dtype), source)
-        y_s, lam_s, i32_s = sds((n, k), dtype), sds((), dtype), sds((), jnp.int32)
-        mask_s = sds((n, 1), dtype)
-        res_s, m_s, c_s = sds((n, k), dtype), sds((bs, k), dtype), sds((bs, bs), dtype)
-        operands, y_bytes = source.operand_bytes(), it * n * k
-        res_dev = operands + (labels.nbytes if isinstance(labels, jax.Array) else 0)
-        factors = it * nb * bs * bs
-        persist = it * (n * k + nb * bs * k) + factors
-        # temporaries: the block as the featurizer leaves it and its centred
-        # copy, what the make holds beyond them, two residual carries, the
-        # models carry; the factor stack and the kept blocks are results of
-        # the fused program, not temporaries
-        made_floor, made_out = _made_need(n, k, nb, bs, it)
-        made_floor += bcd_plan["make_scratch_bytes"]
-        keep, stack = bcd_plan["held_blocks"], bcd_plan["held_stack_bytes"]
-        kept = dict(hold=keep, hold_dtype=bcd_plan["held_dtype"]) if keep else {}
-        passes = self.num_iter + 1
-        fused_makes = nb + (nb - keep) * self.num_iter
-
-        def flops(makes):
-            # a featurizer is charged as one [n, d] x [d, bs] product a
-            # block it makes: the solver cannot see inside it
-            return (
-                2.0 * n * bs * bs * nb + self.num_iter * 4.0 * n * bs * k * nb
-                + makes * 2.0 * n * int(source.rows.shape[1]) * bs
+        with trace.host("search", "ladder"):
+            sds = jax.ShapeDtypeStruct
+            src_s = jax.tree.map(lambda a: sds(a.shape, a.dtype), source)
+            y_s, lam_s, i32_s = sds((n, k), dtype), sds((), dtype), sds((), jnp.int32)
+            mask_s = sds((n, 1), dtype)
+            res_s, m_s, c_s = sds((n, k), dtype), sds((bs, k), dtype), sds((bs, bs), dtype)
+            res_dev = source.operand_bytes() + (
+                labels.nbytes if isinstance(labels, jax.Array) else 0
             )
+            persist = it * (n * k + nb * bs * k + nb * bs * bs)
+            # temporaries: the block as the featurizer leaves it and its centred
+            # copy, what the make holds beyond them, two residual carries, the
+            # models carry; the factor stack and the kept blocks are results of
+            # the fused program, not temporaries
+            made_floor = _made_need(n, k, nb, bs, it)[0] + bcd_plan["make_scratch_bytes"]
+            keep = bcd_plan["held_blocks"]
+            kept = dict(hold=keep, hold_dtype=bcd_plan["held_dtype"]) if keep else {}
+            fused_makes = nb + (nb - keep) * self.num_iter
 
-        def plan_fused():
-            return kmem.plan_program(
-                _fused_bcd_fit, src_s, y_s, lam_s, i32_s,
-                self.num_iter, widths, None,
-                label="bcd_fused_made", budget=budget,
-                min_temp_bytes=made_floor, resident_bytes=res_dev, **kept,
-            )
+            def plan_fused():
+                return kmem.plan_program(
+                    _fused_bcd_fit, src_s, y_s, lam_s, i32_s,
+                    self.num_iter, widths, None,
+                    label="bcd_fused_made", budget=budget,
+                    min_temp_bytes=made_floor, resident_bytes=res_dev, **kept,
+                )
 
-        def plan_stepwise():
-            return kmem.plan_program(
-                _bcd_block_solve, src_s, None, mask_s, res_s, m_s, c_s, i32_s,
-                bs, label="bcd_stepwise_made", budget=budget,
-                extra_bytes=persist, resident_bytes=res_dev,
-            )
+            def plan_stepwise():
+                return kmem.plan_program(
+                    _bcd_block_solve, src_s, None, mask_s, res_s, m_s, c_s, i32_s,
+                    bs, label="bcd_stepwise_made", budget=budget,
+                    extra_bytes=persist, resident_bytes=res_dev,
+                )
 
-        def run_fused(plan):
-            with trace.host("place", "operands"):
+            def run_fused(plan):
+                with trace.host("place", "operands"):
+                    y_dev = jnp.asarray(labels)
+                _count_blocks_made(nvalid, fused_makes)
+                trace.metrics.inc("bcd.block_rows_held", nvalid * keep * self.num_iter)
+                return _execute_fused_bcd(
+                    plan, (), source, y_dev, lam_arr, nv_arr, self.num_iter, widths,
+                )[:3]  # the factor stack and the kept blocks are dropped here
+
+            def run_stepwise(plan):
                 y_dev = jnp.asarray(labels)
-            _count_blocks_made(nvalid, fused_makes)
-            trace.metrics.inc("bcd.block_rows_held", nvalid * keep * self.num_iter)
-            return _execute_fused_bcd(
-                plan, (), source, y_dev, lam_arr, nv_arr, self.num_iter, widths,
-            )[:3]  # the factor stack and the kept blocks are dropped here
+                reusable = plan is not None and _single_device_arrays(source, y_dev)
+                return _stepwise_bcd_fit(
+                    source, y_dev, self.lam, nvalid, self.num_iter, widths,
+                    block_solve=plan.compiled if reusable else None,
+                )
 
-        def run_stepwise(plan):
-            y_dev = jnp.asarray(labels)
-            reusable = plan is not None and _single_device_arrays(source, y_dev)
-            return _stepwise_bcd_fit(
-                source, y_dev, self.lam, nvalid, self.num_iter, widths,
-                block_solve=plan.compiled if reusable else None,
-            )
-
-        report = kmem.FitReport(label="bcd_fit", budget_bytes=budget)
-        self.last_fit_report = report
-        common = {"arg_bytes": operands + y_bytes, "resident_bytes": res_dev}
-        cands = [
-            autoshard.Candidate(
-                "fused[made]", "fused", plan_fused, run_fused,
-                hints=dict(
-                    common, temp_bytes=made_floor, out_bytes=made_out + stack,
-                    flops=flops(fused_makes), hbm_passes=fused_makes / nb,
-                    dispatches=1,
-                ),
-                prior_rank=0,
-            ),
-            autoshard.Candidate(
-                "stepwise[made]", "stepwise", plan_stepwise, run_stepwise,
-                hints=dict(
-                    common, temp_bytes=it * (2 * n * bs + n * k),
-                    out_bytes=it * nb * bs * k, extra_bytes=persist,
-                    flops=flops(nb * passes), hbm_passes=passes,
-                    dispatches=nb * passes + 2,
-                ),
-                prior_rank=1, floor=True,
-            ),
-        ]
+            report = kmem.FitReport(label="bcd_fit", budget_bytes=budget)
+            self.last_fit_report = report
+            tiers = [
+                kmem.Tier("fused[made]", plan_fused, run_fused),
+                kmem.Tier("stepwise[made]", plan_stepwise, run_stepwise),
+            ]
         with kprof.phase("bcd_fit"):
-            return autoshard.run_search(
-                "bcd_fit", cands, report,
-                fingerprint=autoshard.fingerprint(
-                    "bcd_fit", "made", n, k, widths, self.num_iter,
-                    str(source.dtype), str(dtype), None,
-                    autoshard.device_fingerprint(),
-                ),
-                plan=plan_arg,
-                budget=budget,
-            )
+            return kmem.run_ladder("bcd_fit", tiers, report)
 
     def _fit_ladder(
         self, features, x, labels, num_features, nvalid, widths, donate,
-        plan_arg=None, report=None,
+        report=None,
     ):
         """Single-device solve through the degradation ladder.
 
@@ -1871,188 +1635,127 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         with trace.host("plan", "budget"):  # asks the device for its free bytes
             budget = kmem.hbm_budget()
 
-        donate_x = donate if donate is not None else _design_matrix_owned(x, features)
-        donate_y = donate if donate is not None else not isinstance(labels, jax.Array)
-        dn = tuple(i for i, d in ((0, donate_x), (1, donate_y)) if d)
-
         with trace.host("place", "lam"):
             lam_arr = jnp.asarray(self.lam, dtype)
             nv_arr = jnp.asarray(nvalid, jnp.int32)
-        sds = jax.ShapeDtypeStruct
-        x_s, y_s = sds((n, nb * bs), xdt), sds((n, k), dtype)
-        lam_s, i32_s = sds((), dtype), sds((), jnp.int32)
-        mu_s, mask_s = sds((nb * bs,), xdt), sds((n, 1), dtype)
-        res_s, m_s, c_s = sds((n, k), dtype), sds((bs, k), dtype), sds((bs, bs), dtype)
-        # Caller inputs already on device: charged by every tier's plan
-        # (they stay resident through the fit — run_host cannot free a
-        # caller-owned buffer) and credited back when the budget is live
-        # free bytes, which already excludes them.
-        res_dev = (x.nbytes if isinstance(x, jax.Array) else 0) + (
-            labels.nbytes if isinstance(labels, jax.Array) else 0
-        )
-        # Persistent device buffers the per-block programs' argument lists
-        # do not see: labels + the models stack + the cached Cholesky
-        # factors (and, host-staged, the cached block means).
-        persist = it * (n * k + nb * bs * k + nb * bs * bs)
-        # Analytic transient floor of the fused program — one centered
-        # block, the chol stack, two residual carries, the models carry.
-        # CPU backends report temp_size 0, which would otherwise rank the
-        # fused program cheaper than its own stepwise decomposition.
-        fused_floor = it * (n * bs + nb * bs * bs + 2 * n * k + nb * bs * k)
-
-        def plan_fused():
-            return kmem.plan_program(
-                _fused_bcd_fit_variant(dn), x_s, y_s, lam_s, i32_s,
-                self.num_iter, widths, None,
-                label="bcd_fused", budget=budget, min_temp_bytes=fused_floor,
-                resident_bytes=res_dev,
+        with trace.host("search", "ladder"):
+            donate_x = donate if donate is not None else _design_matrix_owned(x, features)
+            donate_y = donate if donate is not None else not isinstance(labels, jax.Array)
+            dn = tuple(i for i, d in ((0, donate_x), (1, donate_y)) if d)
+            sds = jax.ShapeDtypeStruct
+            x_s, y_s = sds((n, nb * bs), xdt), sds((n, k), dtype)
+            lam_s, i32_s = sds((), dtype), sds((), jnp.int32)
+            mu_s, mask_s = sds((nb * bs,), xdt), sds((n, 1), dtype)
+            res_s, m_s, c_s = sds((n, k), dtype), sds((bs, k), dtype), sds((bs, bs), dtype)
+            # Caller inputs already on device: charged by every tier's plan
+            # (they stay resident through the fit — run_host cannot free a
+            # caller-owned buffer) and credited back when the budget is live
+            # free bytes, which already excludes them.
+            res_dev = (x.nbytes if isinstance(x, jax.Array) else 0) + (
+                labels.nbytes if isinstance(labels, jax.Array) else 0
             )
+            # Persistent device buffers the per-block programs' argument lists
+            # do not see: labels + the models stack + the cached Cholesky
+            # factors (and, host-staged, the cached block means).
+            persist = it * (n * k + nb * bs * k + nb * bs * bs)
+            # Analytic transient floor of the fused program — one centered
+            # block, the chol stack, two residual carries, the models carry.
+            # CPU backends report temp_size 0, which would otherwise rank the
+            # fused program cheaper than its own stepwise decomposition.
+            fused_floor = it * (n * bs + nb * bs * bs + 2 * n * k + nb * bs * k)
 
-        def plan_stepwise():
-            return kmem.plan_program(
-                _bcd_block_solve, x_s, mu_s, mask_s, res_s, m_s, c_s, i32_s,
-                bs, label="bcd_stepwise", budget=budget, extra_bytes=persist,
-                resident_bytes=res_dev,
-            )
-
-        def plan_host():
-            return kmem.plan_program(
-                _hs_block_solve, sds((n, bs), xdt), sds((bs,), xdt), mask_s,
-                res_s, m_s, c_s,
-                label="bcd_host_staged", budget=budget,
-                extra_bytes=persist + it * nb * bs + res_dev,
-                resident_bytes=res_dev,
-            )
-
-        def rebuild_x():
-            xx, _ = _blocked_design_matrix(
-                features, self.block_size, num_features, nvalid
-            )
-            if isinstance(xx, jax.Array) and xx.is_deleted():
-                raise kmem.LadderSourceLost(
-                    "design matrix was donated (donate=True) and the source "
-                    "features are gone — cannot step the ladder down; refit "
-                    "with donate=False to keep OOM recovery possible"
+            def plan_fused():
+                return kmem.plan_program(
+                    _fused_bcd_fit_variant(dn), x_s, y_s, lam_s, i32_s,
+                    self.num_iter, widths, None,
+                    label="bcd_fused", budget=budget, min_temp_bytes=fused_floor,
+                    resident_bytes=res_dev,
                 )
-            return xx
 
-        def get_x():
-            return rebuild_x() if isinstance(x, jax.Array) and x.is_deleted() else x
-
-        def get_y_dev():
-            if isinstance(labels, jax.Array) and labels.is_deleted():
-                raise kmem.LadderSourceLost(
-                    "labels were donated (donate=True) and cannot be rebuilt "
-                    "for the ladder step-down"
+            def plan_stepwise():
+                return kmem.plan_program(
+                    _bcd_block_solve, x_s, mu_s, mask_s, res_s, m_s, c_s, i32_s,
+                    bs, label="bcd_stepwise", budget=budget, extra_bytes=persist,
+                    resident_bytes=res_dev,
                 )
-            return jnp.asarray(labels)
 
-        def run_fused(plan):
-            with trace.host("place", "operands"):
+            def plan_host():
+                return kmem.plan_program(
+                    _hs_block_solve, sds((n, bs), xdt), sds((bs,), xdt), mask_s,
+                    res_s, m_s, c_s,
+                    label="bcd_host_staged", budget=budget,
+                    extra_bytes=persist + it * nb * bs + res_dev,
+                    resident_bytes=res_dev,
+                )
+
+            def rebuild_x():
+                xx, _ = _blocked_design_matrix(
+                    features, self.block_size, num_features, nvalid
+                )
+                if isinstance(xx, jax.Array) and xx.is_deleted():
+                    raise kmem.LadderSourceLost(
+                        "design matrix was donated (donate=True) and the source "
+                        "features are gone — cannot step the ladder down; refit "
+                        "with donate=False to keep OOM recovery possible"
+                    )
+                return xx
+
+            def get_x():
+                return rebuild_x() if isinstance(x, jax.Array) and x.is_deleted() else x
+
+            def get_y_dev():
+                if isinstance(labels, jax.Array) and labels.is_deleted():
+                    raise kmem.LadderSourceLost(
+                        "labels were donated (donate=True) and cannot be rebuilt "
+                        "for the ladder step-down"
+                    )
+                return jnp.asarray(labels)
+
+            def run_fused(plan):
+                with trace.host("place", "operands"):
+                    x_dev, y_dev = jnp.asarray(get_x()), get_y_dev()
+                return _execute_fused_bcd(
+                    plan, dn, x_dev, y_dev, lam_arr, nv_arr, self.num_iter, widths,
+                )
+
+            def run_stepwise(plan):
                 x_dev, y_dev = jnp.asarray(get_x()), get_y_dev()
-            return _execute_fused_bcd(
-                plan, dn, x_dev, y_dev, lam_arr, nv_arr, self.num_iter, widths,
-            )
+                reusable = (
+                    plan is not None and _single_device_arrays(x_dev, y_dev)
+                )
+                return _stepwise_bcd_fit(
+                    x_dev, y_dev, self.lam, nvalid, self.num_iter, widths,
+                    # The preflight already compiled the per-block solve on
+                    # these very avals — execute that executable instead of
+                    # paying a second compile at first jit dispatch.  (Sharded
+                    # caller inputs fall back to the jitted entry: the planned
+                    # program baked single-device placements.)
+                    block_solve=plan.compiled if reusable else None,
+                )
 
-        def run_stepwise(plan):
-            x_dev, y_dev = jnp.asarray(get_x()), get_y_dev()
-            reusable = (
-                plan is not None and _single_device_arrays(x_dev, y_dev)
-            )
-            return _stepwise_bcd_fit(
-                x_dev, y_dev, self.lam, nvalid, self.num_iter, widths,
-                # The preflight already compiled the per-block solve on
-                # these very avals — execute that executable instead of
-                # paying a second compile at first jit dispatch.  (Sharded
-                # caller inputs fall back to the jitted entry: the planned
-                # program baked single-device placements.)
-                block_solve=plan.compiled if reusable else None,
-            )
+            def run_host(plan):
+                xx = get_x()
+                x_h = (
+                    np.asarray(jax.device_get(xx))
+                    if isinstance(xx, jax.Array) else np.asarray(xx)
+                )
+                if isinstance(xx, jax.Array) and _design_matrix_owned(xx, features):
+                    # Fit-owned device copy (initial or rebuilt): the host tier
+                    # must not keep the full matrix resident in HBM while
+                    # streaming blocks — that residency is what it exists to
+                    # avoid.  Caller-owned arrays are left alone.
+                    kmem.free_buffers(xx)
+                return _host_staged_bcd_fit(
+                    x_h, get_y_dev(), self.lam, nvalid, self.num_iter, widths
+                )
 
-        def run_host(plan):
-            xx = get_x()
-            x_h = (
-                np.asarray(jax.device_get(xx))
-                if isinstance(xx, jax.Array) else np.asarray(xx)
-            )
-            if isinstance(xx, jax.Array) and _design_matrix_owned(xx, features):
-                # Fit-owned device copy (initial or rebuilt): the host tier
-                # must not keep the full matrix resident in HBM while
-                # streaming blocks — that residency is what it exists to
-                # avoid.  Caller-owned arrays are left alone.
-                kmem.free_buffers(xx)
-            return _host_staged_bcd_fit(
-                x_h, get_y_dev(), self.lam, nvalid, self.num_iter, widths
-            )
-
-        if report is None:
-            report = kmem.FitReport(label="bcd_fit", budget_bytes=budget)
-            self.last_fit_report = report
-        itx = np.dtype(xdt).itemsize
-        x_bytes, y_bytes = itx * n * nb * bs, it * n * k
-        flops = (
-            2.0 * n * bs * bs * nb + self.num_iter * 4.0 * n * bs * k * nb
-        )
-        per_block_dispatches = nb * (self.num_iter + 1) + 2
-        cands = [
-            autoshard.Candidate(
-                "fused", "fused", plan_fused, run_fused,
-                hints={
-                    "arg_bytes": x_bytes + y_bytes,
-                    # The donating variant aliases its donated args — the
-                    # zero-cost prune must stay a lower bound of the
-                    # compiled admission, which credits them back.
-                    "alias_bytes": (
-                        (x_bytes if 0 in dn else 0)
-                        + (y_bytes if 1 in dn else 0)
-                    ),
-                    "temp_bytes": fused_floor,
-                    "out_bytes": it * (nb * bs * k + k + nb * bs),
-                    "resident_bytes": res_dev,
-                    "flops": flops,
-                    "dispatches": 1,
-                    "hbm_passes": self.num_iter + 1,
-                },
-                prior_rank=0,
-            ),
-            autoshard.Candidate(
-                "stepwise", "stepwise", plan_stepwise, run_stepwise,
-                hints={
-                    "arg_bytes": x_bytes + y_bytes,
-                    "temp_bytes": it * (n * bs + n * k),
-                    "out_bytes": it * nb * bs * k,
-                    "extra_bytes": persist,
-                    "resident_bytes": res_dev,
-                    "flops": flops,
-                    "dispatches": per_block_dispatches,
-                    "hbm_passes": self.num_iter + 1,
-                },
-                prior_rank=1,
-            ),
-            autoshard.Candidate(
-                "host_staged", "host_staged", plan_host, run_host,
-                hints={
-                    "arg_bytes": itx * n * bs + y_bytes,
-                    "temp_bytes": it * n * k,
-                    "extra_bytes": persist + it * nb * bs,
-                    "resident_bytes": res_dev,
-                    "flops": flops,
-                    "dispatches": per_block_dispatches,
-                    # Each epoch re-streams every block over PCIe — the
-                    # term that keeps the floor at the bottom of every
-                    # untrained ranking.
-                    "h2d_bytes": self.num_iter * x_bytes,
-                },
-                prior_rank=2, floor=True,
-            ),
-        ]
+            if report is None:
+                report = kmem.FitReport(label="bcd_fit", budget_bytes=budget)
+                self.last_fit_report = report
+            tiers = [
+                kmem.Tier("fused", plan_fused, run_fused),
+                kmem.Tier("stepwise", plan_stepwise, run_stepwise),
+                kmem.Tier("host_staged", plan_host, run_host),
+            ]
         with kprof.phase("bcd_fit"):
-            return autoshard.run_search(
-                "bcd_fit", cands, report,
-                fingerprint=autoshard.fingerprint(
-                    "bcd_fit", n, k, widths, self.num_iter, str(xdt),
-                    str(dtype), None, autoshard.device_fingerprint(),
-                ),
-                plan=plan_arg,
-                budget=budget,
-            )
+            return kmem.run_ladder("bcd_fit", tiers, report)

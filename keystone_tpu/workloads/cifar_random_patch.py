@@ -106,16 +106,6 @@ class RandomCifarConfig:
     #: the hand-placed always-materialize.  Decision table in
     #: ``results["cache_plan"]``.
     auto_cache: bool = False
-    #: Placement search (core.autoshard): force the cost-model-ranked
-    #: candidate search for the block solve (on by default via
-    #: ``KEYSTONE_AUTOSHARD``); the searched table lands in
-    #: ``results["placement"]`` whenever a search ran.
-    auto_shard: bool = False
-    #: Placement override forwarded verbatim to ``fit(plan=...)`` —
-    #: ``False`` hand ladder, ``True`` force search, a PlacementPlan or
-    #: candidate-name list replays/forces a ranking (the chaos harness
-    #: forces a SPEC-assignment plan to the top through this).
-    solve_plan: object = None
     #: Closed-loop ingest autotuner on the ``--streamTestTar`` path: retune
     #: decode width / ring depth / decode-ahead mid-stream from live stall
     #: metrics (results carry the knob trajectory).
@@ -543,10 +533,6 @@ def _fit_and_score(conf: RandomCifarConfig, train, test, mesh) -> dict:
             labels,
             checkpoint=conf.solve_checkpoint,
             resume_from=conf.solve_resume,
-            plan=(
-                conf.solve_plan if conf.solve_plan is not None
-                else (True if conf.auto_shard else None)
-            ),
         )
         log_fit_report(solver, label="cifar random-patch solve")
         if numerics_guard_enabled():
@@ -664,10 +650,6 @@ def _fit_and_score(conf: RandomCifarConfig, train, test, mesh) -> dict:
             "denials": list(rep.denials),
             "oom_retries": list(rep.oom_retries),
         }
-        if rep.placement is not None:
-            # The searched placement table — candidates, deny/score
-            # rationale, chosen plan with predicted-vs-actual cost.
-            results["placement"] = rep.placement
     if mesh is not None:
         by_device = rows_by_device(train_features)
         results["feature_rows_by_device"] = by_device
@@ -862,14 +844,6 @@ def main(argv=None):
         "equivalent)",
     )
     p.add_argument(
-        "--autoShard",
-        action="store_true",
-        help="placement search (core.autoshard): force the cost-model "
-        "ranked mesh/strategy candidate search for the block solve and "
-        "record the searched plan in results['placement'] (on by "
-        "default; KEYSTONE_AUTOSHARD=0 disables it except here)",
-    )
-    p.add_argument(
         "--autoTune",
         action="store_true",
         help="closed-loop ingest autotuner on --streamTestTar: retune "
@@ -921,7 +895,6 @@ def main(argv=None):
         whitener_size=a.whitenerSize,
         stream_test_tar=a.streamTestTar,
         auto_cache=a.autoCache or optimize.auto_cache_env(),
-        auto_shard=a.autoShard,
         auto_tune=a.autoTune,
         decode_backend=a.decodeBackend,
         snapshot_dir=a.snapshotDir,

@@ -204,11 +204,6 @@ class ImageNetSiftLcsFVConfig:
     # the GMM EM fit or are re-projected per consumer under a tight HBM
     # budget.  Decision tables in results["cache_plan"].
     auto_cache: bool = False
-    # Placement search (core.autoshard): force the cost-model-ranked
-    # candidate search for the weighted block solve (on by default via
-    # KEYSTONE_AUTOSHARD); the searched table lands in
-    # results["placement"] whenever a search ran.
-    auto_shard: bool = False
 
 
 class _Log(Logging):
@@ -554,7 +549,6 @@ def _fit_and_score(conf: ImageNetSiftLcsFVConfig, train, test, mesh) -> dict:
             model = solver.fit(
                 train_features, train_labels,
                 num_features=2 * 2 * conf.desc_dim * conf.vocab_size,
-                plan=True if conf.auto_shard else None,
             )
             log_fit_report(solver, label="ImageNet weighted block solve")
             assert_all_finite(model, "ImageNet weighted block solve")
@@ -601,10 +595,6 @@ def _fit_and_score(conf: ImageNetSiftLcsFVConfig, train, test, mesh) -> dict:
             "denials": list(solver_report.denials),
             "oom_retries": list(solver_report.oom_retries),
         }
-        if solver_report.placement is not None:
-            # The searched placement table for the weighted block solve —
-            # candidates, deny/score rationale, predicted-vs-actual cost.
-            results["placement"] = solver_report.placement
     if conf.pipeline_file is not None and not restored:
         with stage_timer("checkpoint"):
             save_pipeline(conf.pipeline_file, results["pipeline"])
@@ -621,7 +611,7 @@ def _run_resident(conf: ImageNetSiftLcsFVConfig, train, test, mesh) -> dict:
     log = _Log()
     t0 = time.perf_counter()
 
-    sift_plan = lcs_plan = placement_rec = None
+    sift_plan = lcs_plan = None
     if conf.pipeline_file is not None and checkpoint_exists(conf.pipeline_file):
         log.log_info("restoring fitted pipeline from %s", conf.pipeline_file)
         ck = load_pipeline(conf.pipeline_file)
@@ -675,12 +665,9 @@ def _run_resident(conf: ImageNetSiftLcsFVConfig, train, test, mesh) -> dict:
             model = solver.fit(
                 train_features, labels,
                 num_features=2 * 2 * conf.desc_dim * conf.vocab_size,
-                plan=True if conf.auto_shard else None,
             )
             log_fit_report(solver, label="ImageNet weighted block solve")
             assert_all_finite(model, "ImageNet weighted block solve")
-            rep = solver.last_fit_report
-            placement_rec = rep.placement if rep is not None else None
 
         if conf.pipeline_file is not None:
             save_pipeline(
@@ -715,10 +702,6 @@ def _run_resident(conf: ImageNetSiftLcsFVConfig, train, test, mesh) -> dict:
         for name, plan in (("sift", sift_plan), ("lcs", lcs_plan)):
             if plan is not None:
                 log.log_info("%s branch %s", name, plan.summary())
-    if placement_rec is not None:
-        # The searched placement table for the weighted block solve —
-        # candidates, deny/score rationale, predicted-vs-actual cost.
-        results["placement"] = placement_rec
     autotune = collect_autotune(train, test)
     if autotune:
         results["autotune"] = autotune
@@ -766,14 +749,6 @@ def main(argv=None):
         help="cost-based auto-Cacher (core.optimize): per-branch "
         "probe-measured decision on PCA-descriptor residency vs "
         "re-projection (KEYSTONE_AUTOCACHE=1 equivalent)",
-    )
-    p.add_argument(
-        "--autoShard",
-        action="store_true",
-        help="placement search (core.autoshard): force the cost-model "
-        "ranked mesh/strategy candidate search for the weighted block "
-        "solve and record the searched plan in results['placement'] (on "
-        "by default; KEYSTONE_AUTOSHARD=0 disables it except here)",
     )
     p.add_argument(
         "--autoTune",
@@ -854,7 +829,6 @@ def main(argv=None):
         num_classes=a.numClasses,
         pipeline_file=a.pipelineFile,
         auto_cache=a.autoCache or optimize.auto_cache_env(),
-        auto_shard=a.autoShard,
     )
     if conf.pipeline_file is not None and checkpoint_exists(conf.pipeline_file):
         # Restored runs never touch training data — skip decoding the

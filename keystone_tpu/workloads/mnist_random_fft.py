@@ -71,17 +71,6 @@ class MnistRandomFFTConfig:
     #: ``KEYSTONE_HBM_BUDGET`` the optimizer picks recompute instead of
     #: OOMing on residency.  Decision table in ``results["cache_plan"]``.
     auto_cache: bool = False
-    #: Placement search (core.autoshard): force the cost-model-ranked
-    #: candidate search for the block solve even when ``KEYSTONE_AUTOSHARD``
-    #: disabled it process-wide.  The searched candidate table (scores,
-    #: deny rationale, chosen plan's predicted-vs-actual cost) lands in
-    #: ``results["placement"]`` whenever a search ran.
-    auto_shard: bool = False
-    #: Placement override forwarded verbatim to ``fit(plan=...)`` —
-    #: ``False`` hand ladder, ``True`` force search, a PlacementPlan or
-    #: candidate-name list replays/forces a ranking (the chaos harness
-    #: forces a SPEC-assignment plan to the top through this).
-    solve_plan: object = None
     #: Whole-fitted-SERVABLE-pipeline checkpoint stem (core.checkpoint):
     #: load-or-fit of ``GroupConcatFeaturizer >> model >> MaxClassifier``
     #: — the artifact the serving endpoint warm-loads.
@@ -149,11 +138,11 @@ def draw_block_featurizers(conf: MnistRandomFFTConfig) -> RandomFFTBlock:
 def _made_form(conf: MnistRandomFFTConfig, mesh) -> bool:
     """Whether the solver is handed a ``BlockSource``: everywhere but where
     the caller asks for what a source fit does not do yet (a mesh, a
-    checkpointed or resumed solve, a forced placement, the auto-Cacher's
-    decision over held feature batches)."""
+    checkpointed or resumed solve, the auto-Cacher's decision over held
+    feature batches)."""
     return mesh is None and not (
         conf.solve_checkpoint is not None or conf.solve_resume is not None
-        or conf.solve_plan is not None or conf.auto_shard or conf.auto_cache
+        or conf.auto_cache
     )
 
 
@@ -265,10 +254,6 @@ def run(
                 nvalid=nvalid,
                 checkpoint=conf.solve_checkpoint,
                 resume_from=conf.solve_resume,
-                plan=(
-                    conf.solve_plan if conf.solve_plan is not None
-                    else (True if conf.auto_shard else None)
-                ),
             )
             log_fit_report(solver, label="mnist random-fft solve")
             if numerics_guard_enabled():
@@ -286,12 +271,6 @@ def run(
 
         if cache_plan is not None:
             results["cache_plan"] = cache_plan.record()
-        rep = solver.last_fit_report
-        if rep is not None and rep.placement is not None:
-            # The searched placement table — candidates, deny/score
-            # rationale, chosen plan with predicted-vs-actual cost
-            # (tools/plan_view.py pretty-prints it from this record).
-            results["placement"] = rep.placement
 
         def evaluator(split: str, truth, n: int):
             def seen(pred):
@@ -443,14 +422,6 @@ def main(argv=None):
         "(KEYSTONE_AUTOCACHE=1 equivalent)",
     )
     p.add_argument(
-        "--autoShard",
-        action="store_true",
-        help="placement search (core.autoshard): force the cost-model "
-        "ranked mesh/strategy candidate search for the block solve and "
-        "record the searched plan in results['placement'] (the search is "
-        "on by default; KEYSTONE_AUTOSHARD=0 disables it except here)",
-    )
-    p.add_argument(
         "--pipelineFile",
         default=None,
         help="fitted-SERVABLE-pipeline checkpoint stem: load-or-fit of "
@@ -484,7 +455,6 @@ def main(argv=None):
         solve_checkpoint=a.solveCheckpoint,
         solve_resume=a.resumeFrom,
         auto_cache=a.autoCache or optimize.auto_cache_env(),
-        auto_shard=a.autoShard,
         pipeline_file=a.pipelineFile,
         serve=a.serve,
         serve_bench=a.serveBench,

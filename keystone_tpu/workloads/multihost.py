@@ -418,11 +418,9 @@ def _worker_cmd(mode: str, extra) -> list[str]:
     return [sys.executable, "-m", "keystone_tpu.workloads.multihost", mode, *extra]
 
 
-def _hermetic_env(env: dict, tmpdir: str, tag: str, *, trace_path=None) -> dict:
-    """Spawned workers must not write the parent's trace or train the
-    parent's plan log."""
+def _hermetic_env(env: dict, *, trace_path=None) -> dict:
+    """Spawned workers must not write the parent's trace."""
     env = dict(env)
-    env["KEYSTONE_PLAN_LOG"] = os.path.join(tmpdir, f"plan_{tag}.jsonl")
     if trace_path is None:
         env.pop("KEYSTONE_TRACE", None)
     else:
@@ -468,7 +466,6 @@ def run_two_process_fit_serve(
         ),
         env=_hermetic_env(
             kdist.worker_env(0, 1, "", local_devices=local_devices),
-            tmpdir, "ref",
         ),
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
     )
@@ -481,7 +478,6 @@ def run_two_process_fit_serve(
                 kdist.worker_env(
                     r, world, coord, local_devices=local_devices
                 ),
-                tmpdir, f"rank{r}",
             ),
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
         )
@@ -843,7 +839,7 @@ def _run_drill_subprocess(
                 kdist.worker_env(
                     r, hosts, "controller", local_devices=local_devices
                 ),
-                tmpdir, f"host{r}", trace_path=trace_paths[r],
+                trace_path=trace_paths[r],
             )
             env["KEYSTONE_POSTMORTEM_DIR"] = pm_dir
             workers.append(
@@ -1293,7 +1289,6 @@ def _run_obs_drill_subprocess(
                 kdist.worker_env(
                     r, hosts, "controller", local_devices=local_devices
                 ),
-                tmpdir, f"obshost{r}",
             )
             env["KEYSTONE_POSTMORTEM_DIR"] = pm_dir
             workers.append(

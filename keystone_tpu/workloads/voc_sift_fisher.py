@@ -84,11 +84,6 @@ class SIFTFisherConfig:
     # Resumable-solve state path: the BCD fit checkpoints after every block
     # and restarts from the last completed block if the state file exists.
     solve_checkpoint: str | None = None
-    # Placement search (core.autoshard): force the cost-model-ranked
-    # candidate search for the block solve (on by default via
-    # KEYSTONE_AUTOSHARD); the searched table lands in
-    # results["placement"] whenever a search ran.
-    auto_shard: bool = False
     # Serving modes (core.serve via serve_common): warm-load the
     # pipeline_file bundle, assemble the servable chain (grayscale ->
     # SIFT -> PCA -> Fisher features -> model), and answer/SLO-bench
@@ -232,7 +227,6 @@ def _fit_and_score(conf: SIFTFisherConfig, train, test, mesh) -> dict:
             model = solver.fit(
                 train_features, train_labels,
                 num_features=2 * conf.desc_dim * conf.vocab_size,
-                plan=True if conf.auto_shard else None,
                 **solve_kwargs,
             )
             log_fit_report(solver, label="VOC SIFT-Fisher solve")
@@ -276,10 +270,6 @@ def _fit_and_score(conf: SIFTFisherConfig, train, test, mesh) -> dict:
             "denials": list(solver_report.denials),
             "oom_retries": list(solver_report.oom_retries),
         }
-        if solver_report.placement is not None:
-            # The searched placement table of the block solve: candidates,
-            # deny/score rationale, chosen plan's predicted-vs-actual cost.
-            results["placement"] = solver_report.placement
     if conf.pipeline_file is not None and not restored:
         with stage_timer("checkpoint"):
             save_pipeline(conf.pipeline_file, results["pipeline"])
@@ -365,14 +355,6 @@ def main(argv=None):
         default=None,
         help="resumable BCD state path: per-block checkpoint + auto-resume",
     )
-    p.add_argument(
-        "--autoShard",
-        action="store_true",
-        help="placement search (core.autoshard): force the cost-model "
-        "ranked mesh/strategy candidate search for the block solve and "
-        "record the searched plan in results['placement'] (on by "
-        "default; KEYSTONE_AUTOSHARD=0 disables it except here)",
-    )
     serve_common.add_serve_args(p)
     p.add_argument(
         "--mesh",
@@ -409,7 +391,6 @@ def main(argv=None):
         num_gmm_samples=a.numGmmSamples,
         pipeline_file=a.pipelineFile,
         solve_checkpoint=a.solveCheckpoint,
-        auto_shard=a.autoShard,
         serve=a.serve,
         serve_bench=a.serveBench,
         serve_clients=a.serveClients,

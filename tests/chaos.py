@@ -64,19 +64,6 @@ draws its parameters — fully deterministic):
   ``serve_burst_oom``), re-answers the same requests through smaller
   buckets, and every answer stays bit-equal — degradation, never a
   silent wrong answer and never a dead endpoint.
-* ``plan_mispredict`` — a cost-model misprediction made real: the
-  placement search's TOP-RANKED plan dies RESOURCE_EXHAUSTED at runtime
-  (injected at dispatch).  The fit must step down to the NEXT plan in the
-  searched ranking (``results["placement"]`` proves the order), count an
-  ``autoshard_stepdown``, and land predictions bit-equal to the
-  fault-free fit — a wrong cost model degrades loudly, never silently.
-* ``spec_mispredict`` — the SPEC-sharded analog (ISSUE 10): the workload
-  runs under a mesh, so the search's top-ranked plan is a real
-  ``NamedSharding``-layout (spec-executing) mesh plan; injected
-  RESOURCE_EXHAUSTED at its GSPMD dispatch forces a counted
-  ``autoshard_stepdown`` to the next-ranked plan, and predictions must
-  stay bit-equal to the fault-free MESH run — a mispredicted sharded
-  layout degrades loudly, never silently.
 * ``wire_disconnect`` — a wire client vanishes MID-BATCH (socket closed
   with requests in flight, core.wire + core.frontend): the disconnect is
   counted (``wire_client_disconnect``), the micro-batches its requests
@@ -208,8 +195,6 @@ FAMILIES = (
     "slow_client",
     "malformed_request",
     "serve_burst_oom",
-    "plan_mispredict",
-    "spec_mispredict",
     "wire_disconnect",
     "slow_loris",
     "jpeg_corrupt_entropy",
@@ -371,10 +356,6 @@ def make_schedule(seed: int) -> Fault:
             kind,
             {"burst": int(rng.integers(9, 17)), "failures": 1},
         )
-    if kind == "plan_mispredict":
-        return Fault(kind, {"failures": 1})
-    if kind == "spec_mispredict":
-        return Fault(kind, {"failures": 1})
     if kind == "wire_disconnect":
         return Fault(
             kind,
@@ -508,7 +489,7 @@ def _mnist_case():
 _mnist_data_cache: list = []
 
 
-def _run_mnist(train_override=None, mesh=None, **conf_kw):
+def _run_mnist(train_override=None, **conf_kw):
     from keystone_tpu.workloads.mnist_random_fft import (
         MnistRandomFFTConfig,
         run,
@@ -527,7 +508,7 @@ def _run_mnist(train_override=None, mesh=None, **conf_kw):
         num_classes=5,
         **conf_kw,
     )
-    return run(conf, train, test, mesh=mesh)
+    return run(conf, train, test)
 
 
 _cifar_paths_cache: list = []
@@ -551,7 +532,7 @@ def _write_synthetic_cifar(path, n, rng, num_classes=4, base=None):
     recs.tofile(path)
 
 
-def _run_cifar(train_override=None, mesh=None, **conf_kw):
+def _run_cifar(train_override=None, **conf_kw):
     from keystone_tpu.workloads.cifar_random_patch import (
         RandomCifarConfig,
         run,
@@ -579,52 +560,27 @@ def _run_cifar(train_override=None, mesh=None, **conf_kw):
     train, test = cifar_loader(tr), cifar_loader(te)
     if train_override is not None:
         train = train_override(train)
-    return run(conf, train, test, mesh=mesh)
+    return run(conf, train, test)
 
 
-def _run_workload(workload: str, train_override=None, mesh=None, **conf_kw):
+def _run_workload(workload: str, train_override=None, **conf_kw):
     if workload == "mnist":
-        return _run_mnist(train_override=train_override, mesh=mesh, **conf_kw)
+        return _run_mnist(train_override=train_override, **conf_kw)
     if workload == "cifar":
-        return _run_cifar(train_override=train_override, mesh=mesh, **conf_kw)
+        return _run_cifar(train_override=train_override, **conf_kw)
     raise ValueError(f"unknown chaos workload {workload!r}")
 
 
-_spec_mesh_cache: list = []
+_baselines: dict[str, dict] = {}
 
 
-def _spec_mesh():
-    """The mesh the ``spec_mispredict`` family runs under: all live
-    devices, (data, model=2) when the count divides — so the search's
-    top-ranked plan is a real spec-executing GSPMD layout.  Cached: the
-    baseline and every faulted run must fit on the SAME mesh for the
-    bit-equality judgement to mean anything."""
-    if not _spec_mesh_cache:
-        import jax
-
-        from keystone_tpu.parallel.mesh import make_mesh
-
-        n = len(jax.devices())
-        model = 2 if n >= 2 and n % 2 == 0 else 1
-        _spec_mesh_cache.append(make_mesh(data=n // model, model=model))
-    return _spec_mesh_cache[0]
-
-
-_baselines: dict[tuple, dict] = {}
-
-
-def baseline(workload: str, mesh: bool = False) -> dict:
+def baseline(workload: str) -> dict:
     """The fault-free run every schedule is judged against (cached — one
     per workload per process; also pre-warms every jit cache so faulted
-    runs measure fault handling, not compilation).  ``mesh=True``: the
-    fault-free MESH run (the ``spec_mispredict`` oracle — a sharded
-    faulted run must be judged against a sharded baseline)."""
-    key = (workload, bool(mesh))
-    if key not in _baselines:
-        _baselines[key] = _run_workload(
-            workload, mesh=_spec_mesh() if mesh else None
-        )
-    return _baselines[key]
+    runs measure fault handling, not compilation)."""
+    if workload not in _baselines:
+        _baselines[workload] = _run_workload(workload)
+    return _baselines[workload]
 
 
 def _preds_equal(a, b) -> bool:
@@ -2037,56 +1993,6 @@ def _obs_capture_phase(fault: Fault, tmpdir: str, seed: int) -> None:
         )
 
 
-def _stepdown_oracle(
-    res: dict,
-    stepdown_delta: int,
-    *,
-    require_specs: bool = False,
-    require_mesh: bool = False,
-) -> None:
-    """Shared oracle of the plan/spec-mispredict families: the searched
-    placement record must prove the top-ranked plan died and the fit
-    chose the NEXT-ranked one, with the step-down counted.
-    ``require_mesh``/``require_specs`` additionally pin that the killed
-    plan was a mesh plan / a non-default spec-assignment layout."""
-    placement = res.get("placement")
-    if placement is None:
-        raise ChaosOracleError(
-            "no searched placement in results — the mispredict families "
-            "require the placement search to be active"
-        )
-    ranking, chosen = placement["ranking"], placement["chosen"]
-    top_rec = next(
-        (
-            c for c in placement["candidates"]
-            if ranking and c["name"] == ranking[0]
-        ),
-        {},
-    )
-    if require_mesh and not top_rec.get("mesh"):
-        raise ChaosOracleError(
-            f"top-ranked plan {ranking[0] if ranking else None!r} is not "
-            "a mesh plan — the schedule did not exercise a sharded layout"
-        )
-    if require_specs and not top_rec.get("specs"):
-        raise ChaosOracleError(
-            f"top-ranked plan {ranking[0] if ranking else None!r} carries "
-            "no spec assignment — the schedule killed the default layout, "
-            "not a searched spec layout"
-        )
-    if len(ranking) < 2 or chosen != ranking[1]:
-        raise ChaosOracleError(
-            f"top-ranked plan {ranking[0] if ranking else None!r} died "
-            f"but the fit chose {chosen!r}, not the next-ranked "
-            f"{ranking[1] if len(ranking) > 1 else None!r}"
-        )
-    if stepdown_delta < 1:
-        raise ChaosOracleError(
-            "the top-ranked plan died RESOURCE_EXHAUSTED but no "
-            f"autoshard_stepdown was counted (top candidate: {top_rec})"
-        )
-
-
 def _drift_refit_phase(fault: Fault, tmpdir: str, seed: int) -> None:
     """The closed lifecycle loop end-to-end (ISSUE 18) plus its fault
     legs — see the module docstring's ``drift_refit`` bullet.
@@ -2495,72 +2401,6 @@ def _run_faulted(fault: Fault, workload: str, tmpdir: str, seed: int):
         _slow_loris_phase(fault, tmpdir, seed)
         return _run_workload(workload)
 
-    if fault.kind == "plan_mispredict":
-        # The cost model's top-ranked plan (fused, on these shapes) is made
-        # WRONG at runtime: injected RESOURCE_EXHAUSTED at its dispatch.
-        # Oracle: the fit walks to the NEXT plan in the SEARCHED ranking
-        # (the placement record proves the order), the step-down is
-        # counted, and the judge then holds predictions to bit-equality.
-        from keystone_tpu.core.resilience import counters as _counters
-
-        before = _counters.get("autoshard_stepdown")
-        with faults.oom_faults(
-            block_mod, "_execute_fused_bcd", failures=fault.params["failures"]
-        ):
-            res = _run_workload(workload)
-        _stepdown_oracle(res, _counters.get("autoshard_stepdown") - before)
-        return res
-
-    if fault.kind == "spec_mispredict":
-        # The spec-ASSIGNMENT analog (ISSUE 10): the fault-free mesh
-        # baseline's placement table names the enumerated spec candidates;
-        # one on the head mesh shape is FORCED to the top of the faulted
-        # run's ranking (conf.solve_plan -> fit(plan=[name])), so the plan
-        # that dies at the GSPMD dispatch is a real non-default
-        # NamedSharding layout lowered from searched spec strings — not
-        # the same default rung plan_mispredict already kills.  The fit
-        # must step down the ranking (counted autoshard_stepdown) onto
-        # the default plan, and the judge then holds predictions
-        # bit-equal to the fault-free MESH baseline.
-        from keystone_tpu.core.resilience import counters as _counters
-
-        base_pl = baseline(workload, mesh=True).get("placement")
-        forced = None
-        if base_pl and base_pl.get("ranking"):
-            head = next(
-                (
-                    c for c in base_pl["candidates"]
-                    if c["name"] == base_pl["ranking"][0]
-                ),
-                {},
-            )
-            forced = next(
-                (
-                    [c["name"]] for c in base_pl["candidates"]
-                    if c.get("specs") and not c["pruned"]
-                    and c["mesh"] == head.get("mesh")
-                ),
-                None,
-            )
-        before = _counters.get("autoshard_stepdown")
-        with faults.oom_faults(
-            block_mod, "_execute_fused_bcd_mesh",
-            failures=fault.params["failures"],
-        ):
-            res = _run_workload(
-                workload, mesh=_spec_mesh(), solve_plan=forced
-            )
-        _stepdown_oracle(
-            res,
-            _counters.get("autoshard_stepdown") - before,
-            # With >= 2 devices a spec candidate always exists; a 1x1 mesh
-            # has no non-default layouts, so the oracle degrades to the
-            # mesh-plan check there instead of passing vacuously.
-            require_specs=forced is not None,
-            require_mesh=True,
-        )
-        return res
-
     if fault.kind == "nan_input":
         frac = fault.params["frac"]
         rng = np.random.default_rng(seed)
@@ -2651,9 +2491,7 @@ def run_schedule(
     t0 = time.monotonic()
     result = ChaosResult(seed=seed, workload=workload, fault=fault, outcome="")
     with _clean_env():
-        # spec_mispredict runs under a mesh, so it is judged against the
-        # fault-free MESH baseline (same devices, same mesh shape).
-        base = baseline(workload, mesh=fault.kind == "spec_mispredict")
+        base = baseline(workload)
         if trace_path is not None:
             # Per-schedule timeline: clear the buffer so this trace holds
             # exactly this schedule's events (baseline is pre-cached above).

@@ -7,7 +7,6 @@ exercised without TPU hardware.  Must set flags before jax initializes.
 """
 
 import os
-import tempfile
 
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:
@@ -19,15 +18,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # the checkout (utils.platform.init_device); a suite run neither fills it
 # nor runs executables an earlier run left there.
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
-# Hermetic placement search: the plan-outcome log (core.autoshard) defaults
-# to ~/.keystone_plans.jsonl and TRAINS the cost model across processes — a
-# suite run must neither pollute the operator's log nor inherit a trained
-# ranking that deviates from the hand ladder (the bit-identical baselines
-# several suites pin).  This covers what runs while modules are imported;
-# the `_plan_log_per_test` fixture below gives every test a log of its own.
-os.environ["KEYSTONE_PLAN_LOG"] = os.path.join(
-    tempfile.mkdtemp(prefix="keystone_plans_"), "plans.jsonl"
-)
 
 import jax  # noqa: E402
 
@@ -62,23 +52,6 @@ def mesh42(devices):
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)
-
-
-@pytest.fixture(autouse=True)
-def _plan_log_per_test(tmp_path, monkeypatch):
-    """Placement state is per test: a fit appends its outcome to the plan
-    log and the search trains on what it reads there, so with one log a
-    process the ranking a test sees depends on which tests ran before it
-    in its worker (the searched `fused[mesh 1x8]` where the hand order
-    says `4x2`).  A test that sets the variable itself nests inside."""
-    from keystone_tpu.core import autoshard
-
-    monkeypatch.setenv(
-        autoshard.PLAN_LOG_ENV, str(tmp_path / "plans.jsonl")
-    )
-    autoshard.clear_outcome_cache()
-    yield
-    autoshard.clear_outcome_cache()
 
 
 def pytest_configure(config):

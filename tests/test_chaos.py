@@ -31,8 +31,6 @@ EXPECTED_COUNTER = {
     "slow_client": "chaos_slow_client",
     "malformed_request": "serve_malformed_request",
     "serve_burst_oom": "serve_burst_oom",
-    "plan_mispredict": "autoshard_stepdown",
-    "spec_mispredict": "autoshard_stepdown",
     "wire_disconnect": "wire_client_disconnect",
     "slow_loris": "chaos_slow_loris",
     "jpeg_corrupt_entropy": "jpeg_corrupt_entropy",
@@ -60,13 +58,13 @@ def _check(r):
 def test_chaos_schedule_mnist(seed, tmp_path):
     """Every tier-1 schedule runs TRACED and its trace is held to the
     never-silent bar (the ``chaos_run.py --trace`` invariant, extended
-    from the original 10 families to all 27): every counted fault appears
+    from the original 10 families to all 25): every counted fault appears
     as a kind-tagged ``fault`` instant, every typed error as a failed
     span or fault event."""
     trace_path = str(tmp_path / f"chaos_seed{seed}.json")
     r = chaos.run_schedule(
         seed, "mnist", tmpdir=str(tmp_path), trace_path=trace_path
-    )  # 27 families as of ISSUE 20 (obs_capture)
+    )
     _check(r)
     violations = chaos.verify_trace(trace_path, r)
     assert violations == [], {
@@ -99,13 +97,6 @@ def test_tier1_seed_set_meets_the_chaos_bar():
     # Serving coverage (ISSUE 8): the typed-or-equal invariant extends to
     # the online path — slow clients, malformed requests, burst OOM
     assert set(chaos.SERVE_FAMILIES) <= kinds
-    # Placement-search coverage (ISSUE 9): a mispredicted top-ranked plan
-    # must step down the SEARCHED ranking typed + counted
-    assert "plan_mispredict" in kinds
-    # Spec-execution coverage (ISSUE 10): a mispredicted SPEC-SHARDED
-    # (GSPMD-layout) top plan must step down counted and stay bit-equal
-    # to the fault-free mesh run
-    assert "spec_mispredict" in kinds
     # Wire-protocol coverage (ISSUE 12): a client disconnect mid-batch
     # must be counted with the batch still completing, and slow-loris
     # partial frames must never stall the accept loop or starve honest

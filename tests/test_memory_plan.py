@@ -116,6 +116,23 @@ class TestPlanProgram:
         )
 
 
+class TestPlanBytes:
+    def test_plan_bytes_admits_and_denies_analytically(self):
+        ok = kmem.plan_bytes(
+            "t", argument_bytes=100, temp_bytes=50, budget=1000
+        )
+        assert ok.admitted and not ok.analyzed  # no compile happened
+        deny = kmem.plan_bytes("t", argument_bytes=2000, budget=1000)
+        assert not deny.admitted
+        assert "DENIED" in deny.reason
+        assert deny.total_bytes == 2000
+
+    def test_plan_bytes_without_budget_skips_admission(self):
+        plan = kmem.plan_bytes("t", argument_bytes=1 << 50, budget=None)
+        assert plan.admitted
+        assert "skipped" in plan.reason
+
+
 class TestOomDetection:
     def test_injected_oom_is_recognized(self):
         assert kmem.is_oom_error(resource_exhausted_error())
@@ -357,16 +374,6 @@ class TestBwlsLadder:
         np.testing.assert_allclose(p_fused, p_step, rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(p_fused, p_host, rtol=1e-5, atol=1e-5)
 
-    def test_oom_retry_steps_down(self, rng, monkeypatch):
-        monkeypatch.delenv(kmem.HBM_BUDGET_ENV, raising=False)
-        x, y = self._bwls_problem(rng)
-        _, p_clean = self._bwls_fit(x, y)
-        with oom_faults(weighted_mod, "_execute_fused_bwls", failures=1):
-            est, p_retry = self._bwls_fit(x, y)
-        assert est.last_fit_report.chosen == "stepwise"
-        assert est.last_fit_report.oom_retries == ["fused"]
-        np.testing.assert_allclose(p_clean, p_retry, rtol=1e-5, atol=1e-5)
-
 
 class TestReportPlumbing:
     def test_report_record_is_jsonable(self, rng, monkeypatch):
@@ -602,44 +609,3 @@ class TestMeshLadder:
 
         np.testing.assert_allclose(p_full, p_red, rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(p_full, p_single, rtol=1e-5, atol=1e-5)
-
-    def test_runtime_oom_on_mesh_tier_steps_down(self, rng, mesh42, monkeypatch):
-        monkeypatch.delenv(kmem.HBM_BUDGET_ENV, raising=False)
-        a, b = self._problem(rng)
-        _, p_clean = self._fit(a, b, mesh42)
-        before = counters.get("solver_oom_retry")
-        with oom_faults(block_mod, "_execute_fused_bcd_mesh", failures=1):
-            est, p_retry = self._fit(a, b, mesh42)
-        rep = est.last_fit_report
-        assert rep.oom_retries == ["fused[mesh 4x2]"]
-        assert rep.chosen == "fused[mesh 8x1]"  # one tier down, not the floor
-        assert rep.mesh_shape == {"data": 8, "model": 1}
-        assert counters.get("solver_oom_retry") == before + 1
-        np.testing.assert_allclose(p_clean, p_retry, rtol=1e-5, atol=1e-5)
-
-    def test_bwls_mesh_ladder_steps_down(self, rng, mesh42, monkeypatch):
-        n, d, c = 512, 128, 8
-        cls = rng.integers(0, c, n)
-        x = (rng.normal(size=(n, d)) + 0.1 * cls[:, None]).astype(np.float32)
-        y = (2.0 * np.eye(c)[cls] - 1.0).astype(np.float32)
-
-        def fit():
-            est = BlockWeightedLeastSquaresEstimator(
-                32, num_iter=1, lam=0.1, mixture_weight=0.5, mesh=mesh42
-            )
-            model = est.fit(x, y)
-            return est, np.asarray(model(jnp.asarray(x)))
-
-        monkeypatch.setenv(kmem.HBM_BUDGET_ENV, "1G")
-        est, p_full = fit()
-        rep = est.last_fit_report
-        assert rep.chosen == "fused[mesh 4x2]"
-        t_full = rep.plans["fused[mesh 4x2]"].total_bytes
-
-        monkeypatch.setenv(kmem.HBM_BUDGET_ENV, str(t_full - 1))
-        est, p_red = fit()
-        rep = est.last_fit_report
-        assert rep.chosen == "fused[mesh 8x1]"
-        assert rep.denials == ["fused[mesh 4x2]"]
-        assert rep.mesh_shape == {"data": 8, "model": 1}
-        np.testing.assert_allclose(p_full, p_red, rtol=2e-4, atol=2e-4)

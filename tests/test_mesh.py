@@ -1,19 +1,29 @@
 """Degradation-ladder substrate coverage (ISSUE 16): ``reduced_mesh`` and
 the mesh enumerators over the DEGENERATE survivor shapes the elastic
-re-anchor walks — 1xN, Nx1, a single device, odd/prime device counts.
-The happy-path 8-device shapes were already exercised by the solver and
-autoshard suites; device loss hands these helpers whatever is left."""
+re-anchor walks — 1xN, Nx1, a single device, odd/prime device counts —
+and over the happy-path 8-device shapes; plus the per-operand sharding-spec
+vocabulary (``spec_candidates`` / ``best_spec`` / ``spec_pspec`` /
+``spec_sharding`` / ``spec_chip_bytes``) the checkpoint's reshard loader
+and the serving engine lower their layouts through."""
 
+import jax
+import jax.numpy as jnp
 import pytest
 
+from keystone_tpu.core import memory as kmem
 from keystone_tpu.parallel.mesh import (
     DATA_AXIS,
     MODEL_AXIS,
+    best_spec,
     enumerate_mesh_shapes,
     enumerate_meshes,
     make_mesh,
     mesh_desc,
     reduced_mesh,
+    spec_candidates,
+    spec_chip_bytes,
+    spec_pspec,
+    spec_sharding,
 )
 
 
@@ -124,3 +134,159 @@ def test_ladder_walk_over_survivors(devices):
             mesh = reduced_mesh(mesh)
             rungs += 1
             assert rungs <= 2, "ladder failed to reach the floor"
+
+
+# -- enumeration over the full 8-device set ------------------------------------
+
+
+def test_enumerate_mesh_shapes_one_device():
+    assert enumerate_mesh_shapes(1) == [(1, 1)]
+
+
+def test_enumerate_mesh_shapes_prime_count():
+    # A prime count has exactly the two degenerate factorizations.
+    assert enumerate_mesh_shapes(7) == [(7, 1), (1, 7)]
+
+
+def test_enumerate_mesh_shapes_composite_data_major_descending():
+    assert enumerate_mesh_shapes(8) == [(8, 1), (4, 2), (2, 4), (1, 8)]
+    for n in (2, 6, 12):
+        shapes = enumerate_mesh_shapes(n)
+        assert all(d * m == n for d, m in shapes)
+        assert [d for d, _ in shapes] == sorted(
+            (d for d, _ in shapes), reverse=True
+        )
+
+
+def test_enumerate_mesh_shapes_rejects_zero():
+    with pytest.raises(ValueError):
+        enumerate_mesh_shapes(0)
+
+
+def test_reduced_mesh_on_already_collapsed_mesh_is_none():
+    # Pure data-parallel: nothing left to collapse — the ladder's next
+    # rung is the single-device floor, not another mesh.
+    collapsed = make_mesh(data=8, model=1)
+    assert reduced_mesh(collapsed) is None
+    # And collapsing a real (data, model) mesh yields the collapsed form
+    # whose own reduction is again None.
+    full = make_mesh(data=4, model=2)
+    rm = reduced_mesh(full)
+    assert mesh_desc(rm) == "8x1"
+    assert reduced_mesh(rm) is None
+
+
+def test_enumerate_meshes_deterministic_over_fixed_devices():
+    devices = jax.devices()
+    a = enumerate_meshes(devices)
+    b = enumerate_meshes(devices)
+    assert [mesh_desc(m) for m in a] == [mesh_desc(m) for m in b]
+    assert [mesh_desc(m) for m in a] == [
+        f"{d}x{m}" for d, m in enumerate_mesh_shapes(len(devices))
+    ]
+    # Same devices in the same order for every mesh.
+    for m in a:
+        assert list(m.devices.flat) == list(devices)
+
+
+def test_enumerate_meshes_memoized_per_device_tuple():
+    devices = jax.devices()
+    a = enumerate_meshes(devices)
+    b = enumerate_meshes(devices)
+    # same Mesh OBJECTS back (the construction happened once), but a
+    # fresh list each call (callers may mutate their copy)
+    assert a is not b
+    assert all(x is y for x, y in zip(a, b))
+
+
+def test_enumerate_mesh_shapes_memoized_returns_fresh_list():
+    a = enumerate_mesh_shapes(8)
+    b = enumerate_mesh_shapes(8)
+    assert a == b and a is not b
+    a.append(("junk", 0))
+    assert enumerate_mesh_shapes(8) == b  # cache not polluted
+
+
+# -- the sharding-spec vocabulary ----------------------------------------------
+
+
+def test_spec_candidates_generated_from_aval_dims():
+    aval = jnp.zeros((8, 6), jnp.float32)
+    specs = {
+        c["spec"]: c["per_chip_bytes"]
+        for c in spec_candidates(aval, {"data": 2, "model": 3})
+    }
+    total = 8 * 6 * 4
+    # replicated always legal; data over any dim divisible by 2; model
+    # over any dim divisible by 3 — all from the aval, no hand list.
+    assert specs == {
+        "replicated": total,
+        "data@dim0": total // 2,
+        "data@dim1": total // 2,
+        "model@dim1": total // 3,
+    }
+
+
+def test_best_spec_minimizes_per_chip_bytes_and_replicates_when_odd():
+    aval = jnp.zeros((8, 6), jnp.float32)
+    best = best_spec(aval, {"data": 4, "model": 2})
+    assert best["spec"] == "data@dim0"
+    assert best["per_chip_bytes"] == 8 * 6 * 4 // 4
+    # Nothing divides a prime dim: replicated is the only legal spec.
+    odd = jnp.zeros((7,), jnp.float32)
+    assert best_spec(odd, {"data": 4, "model": 2})["spec"] == "replicated"
+
+
+def test_spec_pspec_lowers_every_vocabulary_entry():
+    from jax.sharding import PartitionSpec as P
+
+    assert spec_pspec("replicated", 2) == P(None, None)
+    assert spec_pspec("data@dim0", 2) == P("data", None)
+    assert spec_pspec("model@dim1", 2) == P(None, "model")
+    assert spec_pspec("model@dim2", 3) == P(None, None, "model")
+    with pytest.raises(ValueError):
+        spec_pspec("bogus", 2)
+    with pytest.raises(ValueError):
+        spec_pspec("data@dim5", 2)  # names a missing dim
+
+
+def test_spec_sharding_places_arrays_per_spec(mesh42):
+    a = jnp.zeros((8, 6), jnp.float32)
+    sharded = jax.device_put(a, spec_sharding("data@dim0", mesh42, 2))
+    # data axis 4: each chip holds a [2, 6] shard
+    shard_shape = sharded.sharding.shard_shape((8, 6))
+    assert shard_shape == (2, 6)
+    rep = jax.device_put(a, spec_sharding("replicated", mesh42, 2))
+    assert rep.sharding.shard_shape((8, 6)) == (8, 6)
+
+
+def test_spec_chip_bytes_matches_enumeration():
+    mesh_shape = {"data": 2, "model": 3}
+    aval = jnp.zeros((8, 6), jnp.float32)
+    for c in spec_candidates(aval, mesh_shape):
+        assert spec_chip_bytes(
+            (8, 6), jnp.float32, c["spec"], mesh_shape
+        ) == c["per_chip_bytes"]
+    with pytest.raises(ValueError):
+        spec_chip_bytes((7,), jnp.float32, "data@dim0", mesh_shape)
+
+
+def test_spec_candidates_bytes_lower_bound_of_compiled_layouts(mesh42):
+    """For every enumerated spec, the analytic per-chip bytes are a true
+    LOWER bound of what the compiled admission charges for that layout
+    (max of the analytic shard division and XLA's own memory_analysis,
+    exactly as plan_program's mesh mode charges)."""
+    shapes = [(64, 48), (32, 8, 6)]
+    for shape in shapes:
+        aval = jnp.zeros(shape, jnp.float32)
+        for c in spec_candidates(aval, dict(mesh42.shape)):
+            sharding = spec_sharding(c["spec"], mesh42, len(shape))
+            s = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+            compiled = jax.jit(lambda a: a * 2.0).lower(s).compile()
+            ma = compiled.memory_analysis()
+            charged = max(
+                kmem.shard_bytes(s), int(ma.argument_size_in_bytes)
+            )
+            assert c["per_chip_bytes"] <= charged, (
+                shape, c, charged, int(ma.argument_size_in_bytes),
+            )

@@ -7,7 +7,7 @@ against float64 NumPy), the solver's charge for what a make holds beside
 the block, the callers that keep the list form, what a made run counts and
 saves, and the cell's own reference, limits and counts."""
 
-import dataclasses
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -60,7 +60,8 @@ def data(rng):
 
 def _list_form(conf, train, test):
     """The run as it hands the solver the blocks themselves."""
-    return mrf.run(dataclasses.replace(conf, solve_plan=False), train, test)
+    with mock.patch.object(mrf, "_made_form", return_value=False):
+        return mrf.run(conf, train, test)
 
 
 def _budget_between(monkeypatch, conf, train):
@@ -310,7 +311,7 @@ def test_a_column_zero_on_every_row_gets_no_weight(rng, monkeypatch, tier):
 
 @pytest.mark.parametrize("asks", [
     {"mesh": "mesh"}, {"solve_checkpoint": "path"}, {"solve_resume": "path"},
-    {"solve_plan": False}, {"auto_shard": True}, {"auto_cache": True},
+    {"auto_cache": True},
 ])
 def test_what_a_source_fit_does_not_do_keeps_the_list_form(asks):
     asks = dict(asks)

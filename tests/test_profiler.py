@@ -1,6 +1,5 @@
 """Device cost attribution tests (core.profiler, ISSUE 14): the
-per-program MFU ledger, the flops-hint audit, the HBM watermark sampler +
-plan-drift accounting, triggered XLA capture rate limiting, the
+per-program MFU ledger, the HBM watermark sampler + plan-drift accounting, triggered XLA capture rate limiting, the
 disabled-mode zero-overhead bound, and the cross-process stitched request
 waterfall (a REAL two-process serve over sockets)."""
 
@@ -16,9 +15,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from keystone_tpu.core import autoshard
 from keystone_tpu.core import memory as kmem
-from keystone_tpu.core import optimize as kopt
 from keystone_tpu.core import profiler as kprof
 from keystone_tpu.core import serve as kserve
 from keystone_tpu.core import telemetry as ktelemetry
@@ -36,17 +33,6 @@ def _clean_profiler():
     kprof.reset_state()
     yield
     kprof.reset_state()
-
-
-@pytest.fixture
-def fresh_log(tmp_path, monkeypatch):
-    """A private plan log (the conftest one is process-shared; drift-row
-    tests must not leak evidence into other tests' calibration)."""
-    path = str(tmp_path / "plans.jsonl")
-    monkeypatch.setenv(autoshard.PLAN_LOG_ENV, path)
-    autoshard.clear_outcome_cache()
-    yield path
-    autoshard.clear_outcome_cache()
 
 
 def _matmul_compiled(n=64):
@@ -102,37 +88,23 @@ class TestLedger:
         assert led["wall_seconds"] == pytest.approx(0.04, rel=1e-6)
 
     def test_run_ladder_feeds_ledger_and_solver_hint_audited(self, rng):
-        """A profiled BCD fit lands its chosen tier in the ledger AND its
-        hand-derived flops hint is audited against the compiled
-        cost_analysis within the tolerance factor — the regression pin on
-        hint/compiler agreement (measured ~1.03x on this shape)."""
+        """A profiled BCD fit lands its chosen tier in the ledger with the
+        compiled program's own cost analysis: the FLOPs the ledger reports
+        are the compiler's, not a hand-derived hint."""
         x = jnp.asarray(rng.normal(size=(512, 128)).astype(np.float32))
         y = jnp.asarray(
             2.0 * np.eye(4)[rng.integers(0, 4, 512)] - 1.0, jnp.float32
         )
         with kprof.profiled(True):
-            BlockLeastSquaresEstimator(128, 2, 1e-2).fit(x, y)
+            est = BlockLeastSquaresEstimator(128, 2, 1e-2)
+            est.fit(x, y)
             led = kprof.ledger()
-            audits = kprof.flops_audits()
-        rows = {k: v for k, v in led.items() if k.startswith("bcd_fit")}
-        assert rows, f"no bcd_fit ledger rows in {sorted(led)}"
-        chosen = rows[sorted(rows)[0]]
+        assert est.last_fit_report.chosen == "fused"
+        chosen = led.get("bcd_fit:fused")
+        assert chosen is not None, f"no fused ledger row in {sorted(led)}"
         assert chosen["runs"] >= 1 and chosen["wall_seconds"] > 0
-        assert chosen["flops"]  # cost analysis reached the ledger
-        audit = audits.get("bcd_fit:fused")
-        assert audit is not None, f"no fused audit in {sorted(audits)}"
-        assert audit["ok"], audit
-        ratio = audit["ratio"]
-        assert 1 / kprof.FLOPS_AUDIT_TOL <= ratio <= kprof.FLOPS_AUDIT_TOL
-
-    def test_flops_hint_mismatch_is_counted(self):
-        _f, _x, compiled = _matmul_compiled(64)
-        before = counters.get("flops_hint_mismatch")
-        with kprof.profiled(True):
-            ratio = kprof.audit_flops("bogus", 1e15, compiled)
-        assert ratio is not None and ratio > kprof.FLOPS_AUDIT_TOL
-        assert counters.get("flops_hint_mismatch") == before + 1
-        assert kprof.flops_audits()["bogus"]["ok"] is False
+        plan = est.last_fit_report.plans["fused"]
+        assert chosen["flops"] == kprof.program_cost(plan.compiled)["flops"] > 0
 
 
 # -- disabled-mode zero overhead ----------------------------------------------
@@ -147,7 +119,7 @@ class TestDisabledMode:
         assert not kprof.enabled()
         _f, _x, compiled = _matmul_compiled(32)
         assert kprof.record_program("off", compiled, 0.01) is None
-        assert kprof.audit_flops("off", 1e6, compiled) is None
+        assert kprof.audit_plan("off", _plan(1000)) is None
         x = jnp.asarray(rng.normal(size=(128, 64)).astype(np.float32))
         y = jnp.asarray(
             2.0 * np.eye(4)[rng.integers(0, 4, 128)] - 1.0, jnp.float32
@@ -232,7 +204,7 @@ class TestWatermark:
             assert s.unavailable
             assert kprof.watermark() is None
 
-    def test_drift_within_tolerance_not_counted_but_logged(self, fresh_log):
+    def test_drift_within_tolerance_not_counted_but_logged(self):
         before = counters.get("plan_drift")
         with kprof.profiled(
             True, interval_ms=10_000.0, stats_fn=lambda: 1000
@@ -241,87 +213,44 @@ class TestWatermark:
                 kprof.sampler().sample()
             audit = kprof.audit_plan("fit:tier", _plan(1100))
         assert audit is not None and not audit["drifted"]
+        assert audit["watermark_bytes"] == 1000
+        assert audit["charged_bytes"] == 1100
         assert counters.get("plan_drift") == before
-        autoshard.clear_outcome_cache()
-        recs = [
-            r for r in autoshard.load_outcomes(fresh_log)
-            if r.get("outcome") == "hbm_drift"
-        ]
-        assert len(recs) == 1  # calibration evidence lands either way
-        assert recs[0]["watermark_bytes"] == 1000
-        assert recs[0]["charged_bytes"] == 1100
+        # the ratio lands as a gauge either way
+        gauges = ktrace.metrics.snapshot()["gauges"]
+        assert gauges["profiler_fit_tier_plan_drift"] == pytest.approx(1000 / 1100, rel=1e-3)
 
-    def test_drift_beyond_tolerance_counted_and_logged(self, fresh_log):
+    def test_drift_beyond_tolerance_counted_and_logged(self):
         before = counters.get("plan_drift")
         with kprof.profiled(
             True, interval_ms=10_000.0, stats_fn=lambda: 4000
         ):
             with kprof.phase("fit:tier"):
                 kprof.sampler().sample()
-            audit = kprof.audit_plan(
-                "fit:tier", _plan(1000), fingerprint="fp-A"
-            )
+            audit = kprof.audit_plan("fit:tier", _plan(1000))
         assert audit["drifted"] and audit["drift_ratio"] == pytest.approx(4.0)
         assert counters.get("plan_drift") == before + 1
-        autoshard.clear_outcome_cache()
-        rows = autoshard.drift_rows(fresh_log)
-        assert len(rows) == 1
-        fp, feats, ratio = rows[0]
-        assert fp == "fp-A"
-        assert ratio == pytest.approx(4.0)
-        assert feats["kind"] == "hbm" and feats["log_charged"] > 0
+        gauges = ktrace.metrics.snapshot()["gauges"]
+        assert gauges["profiler_fit_tier_plan_drift"] == pytest.approx(4.0)
 
-    def test_run_ladder_audits_watermark(self, fresh_log, monkeypatch):
+    def test_run_ladder_audits_watermark(self):
         """The generic ladder hook: a profiled fit with a live (injected)
-        stats source appends a drift row for its chosen tier, keyed by
-        the search fingerprint."""
+        stats source audits its chosen tier's plan against the phase
+        watermark and records the ratio under the tier's label."""
         x = np.random.default_rng(0).normal(size=(128, 64)).astype(np.float32)
         y = (2.0 * np.eye(4)[np.random.default_rng(1).integers(0, 4, 128)]
              - 1.0).astype(np.float32)
         with kprof.profiled(
             True, interval_ms=10_000.0, stats_fn=lambda: 10 * 2**20
         ):
-            BlockLeastSquaresEstimator(64, 1, 1e-2).fit(
-                jnp.asarray(x), jnp.asarray(y)
-            )
-        autoshard.clear_outcome_cache()
-        rows = autoshard.drift_rows(fresh_log)
-        assert rows, "no drift row appended by the profiled ladder run"
-        fp, feats, ratio = rows[0]
-        assert fp and fp != "hbm:bcd_fit:fused"  # the REAL fingerprint
-        assert ratio > 0
-
-    def test_drift_rows_train_a_calibration_model(self, fresh_log):
-        """The predict->measure->learn loop closes: logged drift rows are
-        consumed by the cross-program CalibrationModel, and the trained
-        byte-drift factor feeds the search's scoring."""
-        rng = np.random.default_rng(3)
-        for i in range(12):
-            arg = float(2 ** (16 + rng.integers(0, 8)))
-            feats = autoshard.hbm_features(arg, arg / 4, arg / 8, None)
-            autoshard.append_outcome({
-                "fingerprint": f"fp-{i % 3}",
-                "candidate": f"cand-{i}",
-                "outcome": "hbm_drift",
-                "drift_ratio": 2.0,  # device holds 2x the charge, always
-                "features": feats,
-                "ts": time.time(),
-            })
-        autoshard.clear_outcome_cache()
-        rows = autoshard.drift_rows(fresh_log)
-        assert len(rows) == 12
-        model = kopt.CalibrationModel.fit_rows(rows)
-        assert model is not None and model.n_programs == 3
-        feats = autoshard.hbm_features(2**20, 2**18, 2**17, None)
-        assert model.predict_factor(feats) == pytest.approx(2.0, rel=0.05)
-        # ...and the search-side entry point sees the same factor.
-        assert autoshard.drift_factor(feats, fresh_log) == pytest.approx(
-            2.0, rel=0.05
+            est = BlockLeastSquaresEstimator(64, 1, 1e-2)
+            est.fit(jnp.asarray(x), jnp.asarray(y))
+        assert est.last_fit_report.chosen == "fused"
+        charged = est.last_fit_report.plans["fused"].total_bytes
+        gauges = ktrace.metrics.snapshot()["gauges"]
+        assert gauges["profiler_bcd_fit_fused_plan_drift"] == pytest.approx(
+            10 * 2**20 / charged, rel=1e-3
         )
-
-    def test_untrained_drift_factor_is_exactly_one(self, fresh_log):
-        feats = autoshard.hbm_features(2**20, 2**18, 2**17, None)
-        assert autoshard.drift_factor(feats, fresh_log) == 1.0
 
     def test_sampler_crash_is_counted_and_run_survives(self):
         calls = {"n": 0}

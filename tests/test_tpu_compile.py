@@ -238,7 +238,7 @@ def test_mesh_solve_compiles_at_published_widths(data_mesh):
     compiled = block._fused_bcd_fit.lower(
         sds((25000, 81920), jnp.float32, sharding=row),
         sds((25000, 10), jnp.float32, sharding=row),
-        sds((), jnp.float32), sds((), jnp.int32), 1, widths, data_mesh, None,
+        sds((), jnp.float32), sds((), jnp.int32), 1, widths, data_mesh,
     ).compile()
     text = compiled.as_text()
     reduced = [kind for op, kind in _collectives(text) if op == "all-reduce"]
@@ -537,7 +537,7 @@ def test_weighted_solve_compiles_at_published_classes(one_chip):
         sds((p, d)), sds((p, classes)), sds((p, 1)), sds((p,), jnp.int32),
         sds((classes,), jnp.int32), sds((classes,), jnp.int32), sds((classes,)), sds((classes,)),
         sds((), jnp.int32), sds(()), sds(()),
-        1, n_max, chunk, classes, (d,), None, None,
+        1, n_max, chunk, classes, (d,), None,
     ).compile()
     text = compiled.as_text()
     assert "jit__fused_bwls_impl" in text

