@@ -18,8 +18,11 @@ means.  Legs:
      production shape, against its jnp reference.
   D  (>= 4 devices) leg A's fit on a 4-way data mesh at 1,250 filters, a
      width at which the conv featurizer takes its kernel form under
-     shard_map (each chip its rows of a chunk), and the __graft_entry__
-     multi-chip dry run in-process on the real devices.
+     shard_map (each chip its rows of a chunk); SIFT's assembly and the
+     Fisher vector's statistics on the same mesh, whose rules take their
+     kernels under shard_map too, against their one-device kernel forms;
+     and the __graft_entry__ multi-chip dry run in-process on the real
+     devices.
   E  TimitPipeline at its documented 50 blocks of 4,096 cosine features
      through ``timit.run``, 8,192 rows, under a budget the 6.7 GB design
      matrix does not fit: the solver must make the blocks inside its one
@@ -64,13 +67,14 @@ FULL = dict(
     jpeg_train=4_096, jpeg_test=2_048, golden=64,
     idct_images=2_048, fv=(8, 73_866, 80, 256), conv=(2_048, 1_250, 128),
     sift=(64, 375, 500), mesh_filters=1_250,
+    mesh_sift=(128, 375, 500), mesh_fv=(16, 40_584, 64, 16),
     timit=dict(train=8_192, test=2_048, width=4_096, budget="8G"),
 )
 TINY = dict(
     train=600, test=200, whitener=4_000, requests=24,
     jpeg_train=96, jpeg_test=48, golden=8,
     idct_images=8, fv=(3, 700, 24, 8), conv=(5, 24, 4), sift=(32, 30, 46),
-    mesh_filters=24,
+    mesh_filters=24, mesh_sift=(128, 30, 46), mesh_fv=(8, 300, 24, 8),
     timit=dict(train=1_024, test=256, width=32, budget="12M"),
 )
 
@@ -509,6 +513,81 @@ def leg_c(ctx) -> dict:
     return out
 
 
+def _mesh_kernel_forms(ctx) -> dict:
+    """SIFT's assembly and the Fisher vector's statistics on a 4-way data
+    mesh, each chip its own images, against the one-device kernel forms.  On
+    the chip each node's own ``__call__`` picks the form (its rule takes the
+    kernel under ``shard_map`` there, and ``sift_form.kernel`` /
+    ``fv_form.kernel`` count it); in a rehearsal the sharded kernel forms run
+    in the interpreter."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from keystone_tpu.core import trace
+    from keystone_tpu.ops.fisher import FisherVector
+    from keystone_tpu.ops.sift import SIFTExtractor
+    from keystone_tpu.parallel.mesh import make_mesh, row_sharding
+    from keystone_tpu.solvers.gmm import GaussianMixtureModel
+
+    on_chip = ctx["device"]["platform"] == "tpu"
+    mesh = make_mesh(data=4, model=1, devices=jax.devices()[:4])
+    rng = np.random.default_rng(44)
+    before = {f: trace.metrics.get(f"{f}.kernel") for f in ("sift_form", "fv_form")}
+
+    def on_mesh(node, batch):
+        spread = jax.device_put(batch, row_sharding(mesh))
+        if on_chip:
+            return jax.jit(node.__call__)(spread)
+        return jax.jit(lambda b: node._sharded_kernel_form(b, mesh, interpret=True))(spread)
+
+    n, h, w = ctx["size"]["mesh_sift"]
+    img = rng.uniform(size=(n, h, w)).astype(np.float32)
+    img[1] = 0.5
+    sift = SIFTExtractor(scale_step=0, compute_dtype=jnp.bfloat16)
+    one = np.asarray(jax.jit(lambda b: sift._kernel_form(b, interpret=not on_chip))(img))
+    got = on_mesh(sift, img)
+    sift_rows = sorted({s.data.shape[0] for s in got.addressable_shards})
+    got = np.asarray(got)
+    sift_out = {
+        "shape": [n, h, w, sift.num_descriptors(h, w)],
+        "identical_share": float((got == one).mean()),
+        "max_step": float(np.abs(got - one).max()),
+    }
+    check(sift_rows == [n // 4], f"SIFT's descriptors are not split four ways: {sift_rows}")
+    # a bfloat16 plane summed in another order moves a byte by one, rarely
+    check(
+        sift_out["max_step"] <= 1 and sift_out["identical_share"] >= 0.999,
+        f"the sharded assembly differs: {sift_out}",
+    )
+
+    n, cols, d, k = ctx["size"]["mesh_fv"]
+    x = rng.normal(size=(n, d, cols)).astype(np.float32)
+    node = FisherVector(GaussianMixtureModel(
+        rng.normal(size=(d, k)).astype(np.float32),
+        rng.uniform(0.5, 2.0, (d, k)).astype(np.float32),
+        rng.dirichlet(np.ones(k)).astype(np.float32),
+    ))
+    one = np.asarray(jax.jit(lambda b: node._kernel_form(b, interpret=not on_chip))(x))
+    got = on_mesh(node, x)
+    fv_rows = sorted({s.data.shape[0] for s in got.addressable_shards})
+    gap = float(np.abs(np.asarray(got) - one).max() / np.abs(one).max())
+    check(fv_rows == [n // 4], f"the Fisher vectors are not split four ways: {fv_rows}")
+    check(gap < 1e-5, f"the sharded statistics differ from the one-device kernel's by {gap}")
+
+    forms = {f: trace.metrics.get(f"{f}.kernel") - before[f] for f in before}
+    if on_chip:
+        check(
+            min(forms.values()) >= 1,
+            f"a node under the mesh did not take its kernel form: {forms}",
+        )
+    return {
+        "sift": sift_out,
+        "fv": {"shape": [n, cols, d, k], "max_rel_gap": gap},
+        "kernel_forms": forms,
+    }
+
+
 def leg_d(ctx) -> dict:
     import __graft_entry__ as graft
     from keystone_tpu.core import trace
@@ -555,7 +634,9 @@ def leg_d(ctx) -> dict:
             f"the dry run did not admit against the chips' own memory: "
             f"budget {budget_gb} GB, record device {record['device']}",
         )
+    forms = _mesh_kernel_forms(ctx)
     return {
+        "mesh_kernel_forms": forms,
         "mesh_fit_test_error_pct": round(res["test_error"], 3),
         "mesh_fit_solver": res["solver"],
         "mesh_fit_conv_kernel_forms": kernel_forms,

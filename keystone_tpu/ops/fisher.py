@@ -60,10 +60,14 @@ a product, four times (1.2-1.5 GB and 1.6-2.5 ms each at the narrowest vocab)
 where the kernel reads them once, and since ``_log_resp`` asks full float32
 (PR 28) its two log-density products alone cost more than the whole kernel at
 every width.  So the rule has no threshold to set, and the shapes ride the
-``fv_form`` instant only.  What it does turn on: the kernel form is a custom call, which the
-compiler does not partition (under a mesh it would run replicated on every
-chip); the kernel knows prefix counts, not arbitrary masks; Mosaic compiles
-for the TPU only.  There the XLA form runs.  No environment variable or flag
+``fv_form`` instant only.  What it does turn on: the kernel form is a custom
+call, which the compiler does not partition (left to GSPMD under a mesh it
+would run whole on every chip), so where only the ``data`` axis splits the
+input the kernel runs under ``shard_map`` over that axis
+(``_sharded_kernel_form``: each chip its own images against the replicated
+mixture, nothing exchanged) and under a ``model`` split the XLA form runs;
+the kernel knows prefix counts, not arbitrary masks; Mosaic compiles for the
+TPU only.  Elsewhere the XLA form runs.  No environment variable or flag
 reaches the choice.
 """
 
@@ -71,18 +75,28 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..core import trace
 from ..core.pipeline import Transformer, node
 from ..solvers.gmm import GaussianMixtureModel, _log_resp
-from ..parallel.mesh import on_one_device as _on_one_device
+from ..parallel.mesh import DATA_AXIS
+from ..parallel.mesh import input_mesh as _input_mesh
+from ..parallel.mesh import split_axes as _split_axes
 from .fv_pallas import fv_stats_pallas
 
 
-def fv_form(backend: str, one_device: bool, masked: bool) -> str:
-    """``"kernel"`` or ``"xla"``: the rule of the module docstring, in one
-    place."""
-    return "kernel" if backend == "tpu" and one_device and not masked else "xla"
+def fv_form(
+    backend: str, split_axes: tuple, masked: bool, images: int = 1, shards: int = 1
+) -> str:
+    """``"kernel"`` or ``"xla"`` for ``images`` descriptor matrices split
+    over the mesh axes ``split_axes`` (``()`` on one device) into ``shards``
+    equal parts: the rule of the module docstring, in one place."""
+    kernel = (
+        backend == "tpu" and set(split_axes) <= {DATA_AXIS} and not masked
+        and images % shards == 0
+    )
+    return "kernel" if kernel else "xla"
 
 
 def _fv_from_stats(s0, s1, s2, means, variances, weights, n_valid):
@@ -151,13 +165,22 @@ class FisherVector(Transformer):
         """``mask``: optional [N, cols] validity for ragged descriptor counts
         (always the XLA form: the kernel knows prefix counts, not masks)."""
         n, d, cols = batch.shape
-        form = fv_form(jax.default_backend(), _on_one_device(batch), mask is not None)
-        # Counted where the program is traced: once a jitted shape.
+        axes = _split_axes(batch)
+        mesh = _input_mesh(batch) if axes else None
+        shards = 1 if mesh is None else dict(mesh.shape).get(DATA_AXIS, 1)
+        form = fv_form(jax.default_backend(), axes, mask is not None, n, shards)
+        # Counted where the program is traced: once a jitted shape; the
+        # instant's ``shards`` and ``split`` as ``sift_form``'s.
         trace.metrics.inc(f"fv_form.{form}")
-        trace.instant("fv_form", form=form, images=n, cols=cols, d=d, k=self.gmm.k)
-        if form == "kernel":
+        trace.instant(
+            "fv_form", form=form, images=n, cols=cols, d=d, k=self.gmm.k,
+            shards=shards if form == "kernel" else 1, split=",".join(axes),
+        )
+        if form == "xla":
+            return self._xla_form(batch, mask)
+        if mesh is None:
             return self._kernel_form(batch)
-        return self._xla_form(batch, mask)
+        return self._sharded_kernel_form(batch, mesh)
 
     def _xla_form(self, batch, mask=None):
         gmm = self.gmm
@@ -178,3 +201,17 @@ class FisherVector(Transformer):
         return _fv_from_stats(
             s0, s1, s2, gmm.means, gmm.variances, gmm.weights, n_valid
         )
+
+    def _sharded_kernel_form(self, batch, mesh, interpret: bool = False):
+        """The kernel form on descriptor matrices split over ``mesh``'s data
+        axis: every chip runs :meth:`_kernel_form` on its own images against
+        the whole mixture (the node is replicated), and the Fisher vectors
+        stay split as the images were.  No collective."""
+        fn = jax.shard_map(
+            lambda node_, rows: node_._kernel_form(rows, interpret=interpret),
+            mesh=mesh,
+            in_specs=(P(), P(DATA_AXIS)),
+            out_specs=P(DATA_AXIS),
+            check_vma=False,
+        )
+        return fn(self, batch)

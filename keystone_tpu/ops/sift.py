@@ -42,8 +42,16 @@ published sizes", has the measurements):
   ``compute_dtype`` and runs the tail on that.  On a TPU the compiler lays
   the staged array out with the frames along the lanes and pays for it five
   times over (42.8 of a 61.8 ms chunk of 64 images of 375x500 where the
-  kernel form takes 7.9: PR 31); it is what runs on a CPU, under a mesh, in
-  float32 and on batches that are no multiple of 32 images.
+  kernel form takes 7.9; ROOFLINE.md); it is what runs on a CPU, under a
+  ``model`` split, in float32 and on batches whose chip's share is no
+  multiple of 32 images.
+
+The kernel form is a custom call, which the compiler does not partition:
+left to GSPMD under a mesh it would run whole on every chip.  A program's
+input shows which mesh axes split it (``parallel.mesh.split_axes``).  Where
+only the ``data`` axis does, the kernel form runs under ``shard_map`` over
+that axis (``_sharded_kernel_form``: each chip its own images of the chunk,
+nothing exchanged), as ``ops/conv_fused`` runs its kernel.
 
 Descriptor layout note: the reference transposes each descriptor
 (vl_dsift_transpose_descriptor, VLFeat.cxx:256) to undo its x/y-swapped
@@ -61,10 +69,13 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..core import trace
 from ..core.pipeline import Transformer, node
-from ..parallel.mesh import on_one_device as _on_one_device
+from ..parallel.mesh import DATA_AXIS
+from ..parallel.mesh import input_mesh as _input_mesh
+from ..parallel.mesh import split_axes as _split_axes
 
 MAGNIF = 6.0
 CONTRAST_THRESHOLD = 0.005
@@ -177,18 +188,25 @@ def _scale_geometry(h: int, w: int, step: int, bin_size: int, num_scales: int, s
     return ys, xs
 
 
-def sift_form(backend: str, one_device: bool, compute_dtype, images: int, fx: int) -> str:
+def sift_form(
+    backend: str, split_axes: tuple, compute_dtype, images: int, fx: int, shards: int = 1
+) -> str:
     """``"kernel"`` or ``"xla"``: which form of the descriptor assembly runs
-    (module docstring).  The kernel form is a Mosaic custom call: it compiles
-    for the TPU only and is not partitioned under a mesh; it reads bfloat16
-    planes two frame rows a word and writes whole byte tiles of 32 images;
-    its blocks hold a frame row of all planes, so a very wide image would
-    not fit VMEM.  Everything else takes the XLA form."""
+    for ``images`` images of ``fx`` frames a row, split over the mesh axes
+    ``split_axes`` (``()`` on one device) into ``shards`` equal parts
+    (module docstring).  The kernel form is a Mosaic custom call: it
+    compiles for the TPU only, and under a mesh it runs a chip's images
+    under ``shard_map``, which a split over ``data`` alone allows; it reads
+    bfloat16 planes two frame rows a word and writes whole byte tiles of 32
+    images, so a chip's share is a multiple of 32; its blocks hold a frame
+    row of all planes, so a very wide image would not fit VMEM.  Everything
+    else takes the XLA form."""
     from .sift_pallas import fits
 
     kernel = (
-        backend == "tpu" and one_device
-        and jnp.dtype(compute_dtype) == jnp.bfloat16 and fits(images, fx)
+        backend == "tpu" and set(split_axes) <= {DATA_AXIS}
+        and jnp.dtype(compute_dtype) == jnp.bfloat16
+        and images % shards == 0 and fits(images // shards, fx)
     )
     return "kernel" if kernel else "xla"
 
@@ -246,20 +264,29 @@ class SIFTExtractor(Transformer):
             batch = batch[..., 0]
         n, h, w = batch.shape
         grids = list(self._grids(h, w))
+        axes = _split_axes(batch)
+        mesh = _input_mesh(batch) if axes else None
+        shards = 1 if mesh is None else dict(mesh.shape).get(DATA_AXIS, 1)
         form = sift_form(
-            jax.default_backend(), _on_one_device(batch), self.compute_dtype,
-            n, max((len(xs) for _b, _ys, xs in grids), default=0),
+            jax.default_backend(), axes, self.compute_dtype,
+            n, max((len(xs) for _b, _ys, xs in grids), default=0), shards,
         )
-        # Counted where the program is traced: once a jitted shape.
+        # Counted where the program is traced: once a jitted shape.  The
+        # instant's ``shards`` are the chips the kernel form runs on, each its
+        # own images (1 for the XLA form); ``split`` the axes that split the
+        # input.
         trace.metrics.inc(f"sift_form.{form}")
         trace.instant(
             "sift_form", form=form, images=n, scales=len(grids),
             frames=self.num_descriptors(h, w),
+            shards=shards if form == "kernel" else 1, split=",".join(axes),
             smooth="banded", smooth_rows=f"{h}x{h}", smooth_cols=f"{w}x{w}",
         )
-        if form == "kernel":
+        if form == "xla":
+            return self._xla_form(batch)
+        if mesh is None:
             return self._kernel_form(batch)
-        return self._xla_form(batch)
+        return self._sharded_kernel_form(batch, mesh)
 
     def _grids(self, h: int, w: int):
         """``(bin size, frame rows, frame columns)`` of each scale that has
@@ -334,6 +361,19 @@ class SIFTExtractor(Transformer):
             )
             offset += fy * fx
         return jnp.transpose(descs, (1, 2, 0)).astype(jnp.float32)  # [N, 128, D]
+
+    def _sharded_kernel_form(self, batch, mesh, interpret: bool = False):
+        """The kernel form on images split over ``mesh``'s data axis: every
+        chip runs :meth:`_kernel_form` on its own images, and the
+        descriptors stay split as the images were.  No collective."""
+        fn = jax.shard_map(
+            lambda rows: self._kernel_form(rows, interpret=interpret),
+            mesh=mesh,
+            in_specs=P(DATA_AXIS),
+            out_specs=P(DATA_AXIS),
+            check_vma=False,
+        )
+        return fn(batch)
 
     def _xla_form(self, batch):
         n = batch.shape[0]

@@ -25,10 +25,17 @@ TPU-native re-design:
   scratch that is the scan's carry.  Only that scratch and a
   ``[chunk, n_max, d]`` slab are ever materialized: no ``[chunk, d, d]``
   array of systems, never the full [C, n_max, d] tensor;
-* with a mesh, features are row-sharded over the data axis (population
-  grams lower to local gram + ICI all-reduce) and each class chunk is
-  sharded over the model axis — the class-partitioned parallelism of the
-  reference's one-partition-per-class layout;
+* with a mesh, features are row-sharded over the data axis.  Where the
+  mesh's ``model`` axis is 1 the classes split over ``data``, the
+  reference's one-partition-per-class layout (:324-361): one ``all_to_all``
+  regroup (``_RegroupPlan``) leaves every chip whole classes, its rows
+  contiguous and padded to the largest chip's share (``_class_split``); the
+  population mean, gram, class sums and XᵀR are summed across the chips and
+  replicated; each chip factors and solves its own classes from its own rows
+  and the model block's columns are gathered once a block
+  (``_split_bwls_impl``, under ``shard_map``).  Under a ``model`` split the
+  population grams lower to local gram + ICI all-reduce and each class chunk
+  is sharded over the model axis;
 * broadcasts/collects disappear (single-controller, arrays stay in HBM).
 
 Semantics (update order, statistics caching across passes, the λ-shifted
@@ -54,6 +61,7 @@ from ..core import profiler as kprof
 from ..core import trace
 from ..core.pipeline import LabelEstimator
 from ..core.resilience import counters
+from ..parallel.collectives import count_psum
 from ..parallel.mesh import (
     DATA_AXIS,
     MODEL_AXIS,
@@ -118,32 +126,36 @@ class _RegroupPlan:
     each device locally gathers its send buckets (padded to the max bucket
     ``m_pad``), one lax.all_to_all exchanges them, and a local gather (with
     out-of-range fill) places received rows and zeroes the tail.  The only
-    overhead vs optimal is bucket padding (m_pad * D^2 / n rows).
+    overhead vs optimal is bucket padding (m_pad * D^2 / n rows).  ``place``
+    is where each sorted row lands in the ``p_tot`` rows (the first ``n``
+    where it is not given; ``_class_split``'s layout where the classes
+    split over the chips).
     """
 
-    def __init__(self, order: np.ndarray, n_src: int, p_tot: int, d: int):
+    def __init__(self, order: np.ndarray, n_src: int, p_tot: int, d: int, place=None):
         n = order.shape[0]
         rows_in, rows_out = n_src // d, p_tot // d
-        r = np.arange(n)
-        src = order // rows_in
-        dst = r // rows_out
-        # occurrence rank of each row within its (src, dst) bucket,
-        # preserving destination order
-        key = src * d + dst
-        by_key = np.argsort(key, kind="stable")
-        ks = key[by_key]
-        change = np.r_[True, ks[1:] != ks[:-1]]
-        grp_start = np.maximum.accumulate(np.where(change, np.arange(n), 0))
-        j = np.empty(n, np.int64)
-        j[by_key] = np.arange(n) - grp_start
-        m_pad = int(j.max()) + 1 if n else 1
+        with trace.host("place", "regroup", rows=n, shards=d):
+            r = np.arange(n) if place is None else place
+            src = order // rows_in
+            dst = r // rows_out
+            # occurrence rank of each row within its (src, dst) bucket,
+            # preserving destination order
+            key = src * d + dst
+            by_key = np.argsort(key, kind="stable")
+            ks = key[by_key]
+            change = np.r_[True, ks[1:] != ks[:-1]]
+            grp_start = np.maximum.accumulate(np.where(change, np.arange(n), 0))
+            j = np.empty(n, np.int64)
+            j[by_key] = np.arange(n) - grp_start
+            m_pad = int(j.max()) + 1 if n else 1
 
-        send = np.zeros((d, d, m_pad), np.int32)
-        send[src, dst, j] = (order % rows_in).astype(np.int32)
-        # received layout on dst: [src bucket, j] -> flat src*m_pad + j;
-        # out-of-range index for the zero tail (jnp.take mode="fill")
-        recv = np.full((d, rows_out), d * m_pad, np.int32)
-        recv[dst, r % rows_out] = (src * m_pad + j).astype(np.int32)
+            send = np.zeros((d, d, m_pad), np.int32)
+            send[src, dst, j] = (order % rows_in).astype(np.int32)
+            # received layout on dst: [src bucket, j] -> flat src*m_pad + j;
+            # out-of-range index for the zero tail (jnp.take mode="fill")
+            recv = np.full((d, rows_out), d * m_pad, np.int32)
+            recv[dst, r % rows_out] = (src * m_pad + j).astype(np.int32)
 
         self.d = d
         self.m_pad = m_pad
@@ -158,36 +170,44 @@ class _RegroupPlan:
         if self.usable:
             self.send_idx = jnp.asarray(send)
             self.recv_idx = jnp.asarray(recv)
-        self._jitted = {}  # mesh -> compiled regroup program
+
+    def exchanged_bytes(self, x) -> int:
+        """Bytes the all_to_all of ``x`` sends between chips: every chip's
+        padded buckets but its own."""
+        return self.d * (self.d - 1) * self.m_pad * int(np.prod(x.shape[1:])) * x.dtype.itemsize
 
     def apply(self, mesh, x):
         """Sorted + zero-tail-padded copy of row-sharded ``x`` via one
         all_to_all; output row-sharded over the data axis."""
-        d, m_pad = self.d, self.m_pad
+        return _regroup_program(mesh, self.d, self.m_pad)(x, self.send_idx, self.recv_idx)
 
-        if mesh not in self._jitted:
 
-            def f(x_l, s_l, r_l):
-                cols = x_l.shape[1]
-                buf = jnp.take(x_l, s_l[0].reshape(-1), axis=0)
-                buf = buf.reshape(d, m_pad, cols)
-                recv = jax.lax.all_to_all(buf, DATA_AXIS, 0, 0)
-                flat = recv.reshape(d * m_pad, cols)
-                return jnp.take(flat, r_l[0], axis=0, mode="fill", fill_value=0)
+@functools.lru_cache(maxsize=None)
+def _regroup_program(mesh, d: int, m_pad: int):
+    """The regroup's one program for a mesh and bucket size, kept across
+    fits: every fit makes a plan of its own, and a program made anew with it
+    would be traced and compiled again each fit."""
 
-            self._jitted[mesh] = jax.jit(
-                shard_map(
-                    f,
-                    mesh=mesh,
-                    in_specs=(
-                        P(DATA_AXIS, None),
-                        P(DATA_AXIS, None, None),
-                        P(DATA_AXIS, None),
-                    ),
-                    out_specs=P(DATA_AXIS, None),
-                )
-            )
-        return self._jitted[mesh](x, self.send_idx, self.recv_idx)
+    def _regroup_rows(x_l, s_l, r_l):
+        cols = x_l.shape[1]
+        buf = jnp.take(x_l, s_l[0].reshape(-1), axis=0)
+        buf = buf.reshape(d, m_pad, cols)
+        recv = jax.lax.all_to_all(buf, DATA_AXIS, 0, 0)
+        flat = recv.reshape(d * m_pad, cols)
+        return jnp.take(flat, r_l[0], axis=0, mode="fill", fill_value=0)
+
+    return jax.jit(
+        shard_map(
+            _regroup_rows,
+            mesh=mesh,
+            in_specs=(
+                P(DATA_AXIS, None),
+                P(DATA_AXIS, None, None),
+                P(DATA_AXIS, None),
+            ),
+            out_specs=P(DATA_AXIS, None),
+        )
+    )
 
 
 #: Panel width of ``_factor_solve``, whatever the systems' width: at 4,096
@@ -289,9 +309,17 @@ def _class_solves(
     n_max: int,
     chunk: int,
     mesh=None,
+    classes=None,
 ):
     """Per-class solve sweep (reference :228-263): scan over class chunks,
-    ``chunk`` concurrent solves per step — returns ΔW [d, C]."""
+    ``chunk`` concurrent solves per step — returns ΔW [d, C].
+
+    ``classes`` given, the sweep solves one system a *slot*: slot ``s`` is
+    class ``classes[s]`` (its columns of ``res_pad``, ``pop_xtr``,
+    ``joint_means``, ``residual_mean``, ``model_block``) on the rows
+    ``starts[s]:starts[s] + counts[s]`` of ``xb_pad``, and ΔW is ``[d,
+    slots]``: a chip's own classes from its own rows (``_split_bwls_impl``).
+    """
     d = xb_pad.shape[1]
     c_total = starts.shape[0]
     w = mixture_weight
@@ -352,6 +380,7 @@ def _class_solves(
     cls_pad = jnp.concatenate(
         [cls, jnp.zeros(n_chunks * chunk - c_total, cls.dtype)]
     )
+    ids = cls_pad if classes is None else classes[cls_pad]
 
     def chunked(x):
         return x.reshape((n_chunks, chunk) + x.shape[1:])
@@ -359,11 +388,11 @@ def _class_solves(
     xs = (
         chunked(starts[cls_pad]),
         chunked(counts[cls_pad]),
-        chunked(cls_pad),
-        chunked(pop_xtr.T[cls_pad]),
-        chunked(joint_means[cls_pad]),
-        chunked(residual_mean[cls_pad]),
-        chunked(model_block.T[cls_pad]),
+        chunked(ids),
+        chunked(pop_xtr.T[ids]),
+        chunked(joint_means[ids]),
+        chunked(residual_mean[ids]),
+        chunked(model_block.T[ids]),
     )
 
     model_spec = work_spec = None
@@ -387,47 +416,38 @@ def _class_solves(
     return dws.reshape(n_chunks * chunk, d)[:c_total].T  # [d, C]
 
 
-def _fused_bwls_impl(
-    x, labels_sorted, valid, seg_ids, starts, counts, counts_f,
-    joint_label_mean, nvalid, lam, w,
-    num_iter: int, n_max: int, chunk: int, num_classes: int, widths, mesh,
+def _bwls_passes(
+    x, labels_sorted, valid, seg_ids, counts_f, joint_label_mean, n, w,
+    reduce, solve, num_iter: int, num_classes: int, widths,
 ):
-    """The ENTIRE BWLS solve as one compiled program (the
-    BlockLeastSquares treatment, solvers/block._fused_bcd_fit): residual
-    init, per-block population statistics (computed once, cached across
-    passes like the reference's persisted grams), ``num_iter`` passes of a
-    lax.scan over blocks (population XᵀR gram + class-solve sweep + model
-    and residual updates + residual class means), and the joint-means
-    intercept — one program per fit instead of ~5 eager dispatches per
-    block per pass.  (reference :134-311.)
+    """The BWLS solve's body (reference :134-311): residual init, per-block
+    population statistics (computed once, cached across passes like the
+    reference's persisted grams), ``num_iter`` passes of a lax.scan over
+    blocks (population XᵀR + class-solve sweep + model and residual updates
+    + residual class means), and the joint-means intercept.
 
-    x: ONE sorted, zero-tail-padded [P, B*bs] design matrix (bs =
-    max(widths)); block i occupies columns [i*bs, i*bs + widths[i]) with
-    zero pad columns.  Scan steps dynamic-slice their block out of ``x``,
-    so peak HBM is one design matrix plus a single [P, bs] block slice —
-    the round-4 form stacked blocks into a [B, P, bs] tensor, transiently
-    doubling the footprint.  Pad columns get a unit diagonal shift on the
+    ``reduce`` sums a statistic of the rows at hand over every row of the
+    fit (the identity where one program holds them all, ``psum`` over the
+    data axis under ``shard_map``); ``solve(xb, res, pop_cov, pop_mean,
+    pop_xtr, joint_means, residual_mean, model)`` is the class-solve sweep,
+    ΔW ``[bs, C]``.  Pad columns of ``x`` get a unit diagonal shift on the
     population covariance (scaled by (1-w) > 0 in the joint normal
     equations), so their solutions are exactly zero and every batched solve
     stays nonsingular even at lam=0.
 
-    With ``mesh``: rows shard over the data axis; the sorted labels (and
-    through them the residual carries) keep the caller's placement.
-
-    Returns (models [B, bs, C], intercept [C]).
-    """
+    Returns (models [B, bs, C], intercept [C])."""
     bs = max(widths)
     nb = len(widths)
     dtype = labels_sorted.dtype
-    n = nvalid.astype(dtype)
 
-    if mesh is not None:
-        x = jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(DATA_AXIS, None))
-        )
+    def class_means(v):
+        return reduce(_class_sums(v, seg_ids, num_classes)) / counts_f[:, None]
+
+    def residual_means(res):
+        return jnp.mean(class_means(res), axis=0)
 
     res = (labels_sorted - joint_label_mean) * valid
-    rmean = _residual_class_means(res, seg_ids, counts_f, num_classes)
+    rmean = residual_means(res)
 
     pad_diag = jnp.stack(
         [(jnp.arange(bs) >= wd).astype(dtype) for wd in widths]
@@ -439,13 +459,12 @@ def _fused_bwls_impl(
     def stats_one(carry, inp):
         i, pd = inp
         xb = slice_block(i)
-        pop_mean = jnp.sum(xb, axis=0) / n
+        pop_mean = reduce(jnp.sum(xb, axis=0)) / n
         pop_cov = (
-            jnp.matmul(xb.T, xb, precision=_HIGHEST) / n
+            reduce(jnp.matmul(xb.T, xb, precision=_HIGHEST)) / n
             - jnp.outer(pop_mean, pop_mean) + jnp.diag(pd)
         )
-        class_means = _class_sums(xb, seg_ids, num_classes) / counts_f[:, None]
-        joint_means = w * class_means + (1.0 - w) * pop_mean
+        joint_means = w * class_means(xb) + (1.0 - w) * pop_mean
         return carry, (pop_cov, pop_mean, joint_means)
 
     _, (pop_covs, pop_means, joint_means_all) = jax.lax.scan(
@@ -458,15 +477,10 @@ def _fused_bwls_impl(
         res, rmean = carry
         i, pop_cov, pop_mean, jm, model = inp
         xb = slice_block(i)
-        pop_xtr = jnp.matmul(xb.T, res, precision=_HIGHEST) / n
-        dw = _class_solves(
-            xb, res, starts, counts, pop_cov, pop_mean, pop_xtr,
-            jm, rmean, model, lam, w, n_max, chunk, mesh,
-        )
-        model_new = model + dw
+        pop_xtr = reduce(jnp.matmul(xb.T, res, precision=_HIGHEST)) / n
+        dw = solve(xb, res, pop_cov, pop_mean, pop_xtr, jm, rmean, model)
         res_new = res - xb @ dw
-        rmean_new = _residual_class_means(res_new, seg_ids, counts_f, num_classes)
-        return (res_new, rmean_new), model_new
+        return (res_new, residual_means(res_new)), model + dw
 
     def one_pass(carry, _):
         models, res, rmean = carry
@@ -489,9 +503,163 @@ def _fused_bwls_impl(
     return models, intercept
 
 
+def _fused_bwls_impl(
+    x, labels_sorted, valid, seg_ids, starts, counts, counts_f,
+    joint_label_mean, nvalid, lam, w,
+    num_iter: int, n_max: int, chunk: int, num_classes: int, widths, mesh,
+):
+    """The ENTIRE BWLS solve (:func:`_bwls_passes`) as one compiled program
+    (the BlockLeastSquares treatment, solvers/block._fused_bcd_fit): one
+    program per fit instead of ~5 eager dispatches per block per pass.
+
+    x: ONE sorted, zero-tail-padded [P, B*bs] design matrix (bs =
+    max(widths)); block i occupies columns [i*bs, i*bs + widths[i]) with
+    zero pad columns.  Scan steps dynamic-slice their block out of ``x``,
+    so peak HBM is one design matrix plus a single [P, bs] block slice —
+    the round-4 form stacked blocks into a [B, P, bs] tensor, transiently
+    doubling the footprint.
+
+    With ``mesh``: rows shard over the data axis; the sorted labels (and
+    through them the residual carries) keep the caller's placement.
+
+    Returns (models [B, bs, C], intercept [C]).
+    """
+    if mesh is not None:
+        x = jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, P(DATA_AXIS, None))
+        )
+
+    def solve(xb, res, *stats):
+        return _class_solves(
+            xb, res, starts, counts, *stats, lam, w, n_max, chunk, mesh,
+        )
+
+    return _bwls_passes(
+        x, labels_sorted, valid, seg_ids, counts_f, joint_label_mean,
+        nvalid.astype(labels_sorted.dtype), w, lambda v: v, solve,
+        num_iter, num_classes, widths,
+    )
+
+
+@dataclasses.dataclass(frozen=True)
+class _ClassSplit:
+    """The classes split over a data axis of ``d`` chips: chip ``k`` solves
+    classes ``bounds[k]:bounds[k + 1]`` (``C // d`` or one more) from its
+    own rows, which the regroup lays out contiguously at ``k * rows_out``,
+    sorted by class, its share padded with zero rows to ``rows_out`` (the
+    largest share and ``n_max``, so that every class's slice of ``n_max``
+    rows stays inside the chip).  A chip's systems are ``slots`` wide: slot
+    ``j`` is its ``j``-th class, and a chip with fewer classes repeats its
+    first in the slots left over (solved, then dropped).  ``columns[c]`` is
+    class ``c``'s column among the chips' gathered ``[d * slots]``."""
+
+    bounds: np.ndarray  # [d + 1]
+    rows: np.ndarray  # [d] rows of each chip's classes
+    rows_out: int
+    place: np.ndarray  # [n] row of each class-sorted row in the layout
+    slot_starts: np.ndarray  # [d, slots] first row of a slot's class on its chip
+    slot_counts: np.ndarray  # [d, slots]
+    slot_classes: np.ndarray  # [d, slots]
+    columns: np.ndarray  # [C]
+
+
+def _class_split(counts: np.ndarray, d: int, n_max: int) -> _ClassSplit:
+    """Whole classes to each of ``d`` chips, as evenly by count as they go
+    (every chip solves ``C // d`` or one more systems, and a system's cost is
+    its width's, whatever its rows); needs ``C >= d``."""
+    c = len(counts)
+    bounds = np.array([k * c // d for k in range(d + 1)])
+    starts = np.concatenate([[0], np.cumsum(counts)])  # [C + 1]
+    first = starts[bounds[:-1]]
+    rows = starts[bounds[1:]] - first
+    rows_out = int(rows.max()) + n_max
+    chip_of_class = np.repeat(np.arange(d), np.diff(bounds))
+    chip_of_row = np.repeat(chip_of_class, counts)
+    place = chip_of_row * rows_out + np.arange(int(starts[-1])) - first[chip_of_row]
+    per_chip = np.diff(bounds)
+    slots = int(per_chip.max())
+    j = np.arange(slots)[None, :]
+    slot_classes = np.where(j < per_chip[:, None], bounds[:-1, None] + j, bounds[:-1, None])
+    columns = chip_of_class * slots + np.arange(c) - bounds[chip_of_class]
+    return _ClassSplit(
+        bounds=bounds, rows=rows, rows_out=rows_out, place=place,
+        slot_starts=(starts[slot_classes] - first[:, None]).astype(np.int32),
+        slot_counts=counts[slot_classes].astype(np.int32),
+        slot_classes=slot_classes.astype(np.int32),
+        columns=columns.astype(np.int32),
+    )
+
+
+def _split_bwls_impl(
+    x, labels_sorted, valid, seg_ids, slot_starts, slot_counts, slot_classes,
+    columns, counts_f, joint_label_mean, nvalid, lam, w,
+    num_iter: int, n_max: int, chunk: int, num_classes: int, widths, mesh,
+):
+    """The BWLS solve (:func:`_bwls_passes`) with the classes split over
+    ``mesh``'s data axis (``_class_split``'s layout), one program under
+    ``shard_map``.  A chip holds its rows of the design matrix, of the sorted
+    labels and of the residual; the population mean, gram, class sums and
+    XᵀR are its rows' sums summed across the chips (``psum``) and
+    replicated; it factors and solves its own classes' systems from its own
+    rows (``_class_solves`` over its slots), and the chips' ΔW columns are
+    gathered once a block into the replicated model.  Returns (models [B,
+    bs, C], intercept [C]), replicated."""
+
+    def local(x, ys, valid, seg, starts, counts, classes, columns, counts_f, jlm, nvalid, lam, w):
+        starts, counts, classes = starts[0], counts[0], classes[0]
+
+        def solve(xb, res, *stats):
+            own = _class_solves(
+                xb, res, starts, counts, *stats, lam, w, n_max, chunk, None, classes,
+            )  # [bs, slots]: this chip's classes
+            gathered = jax.lax.all_gather(own, DATA_AXIS, axis=1, tiled=True)
+            return jnp.take(gathered, columns, axis=1)  # [bs, C]
+
+        return _bwls_passes(
+            x, ys, valid, seg, counts_f, jlm, nvalid.astype(ys.dtype), w,
+            functools.partial(jax.lax.psum, axis_name=DATA_AXIS), solve,
+            num_iter, num_classes, widths,
+        )
+
+    rows = P(DATA_AXIS, None)
+    return jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(rows, rows, rows, P(DATA_AXIS), rows, rows, rows) + (P(),) * 6,
+        out_specs=(P(), P()),
+        check_vma=False,
+    )(
+        x, labels_sorted, valid, seg_ids, slot_starts, slot_counts, slot_classes,
+        columns, counts_f, joint_label_mean, nvalid, lam, w,
+    )
+
+
 _BWLS_STATICS = (
     "num_iter", "n_max", "chunk", "num_classes", "widths", "mesh",
 )
+
+#: The class-split solve as one program.  It donates the sorted design
+#: matrix and sorted labels, copies the fit itself made (``sort_pad``).
+_split_bwls_fit = jax.jit(
+    _split_bwls_impl, static_argnames=_BWLS_STATICS, donate_argnums=(0, 1)
+)
+
+
+def _split_psum_bytes(itemsize: int, widths, num_classes: int, num_iter: int) -> int:
+    """Bytes of the arrays ``_split_bwls_impl`` sums across the chips: a
+    block's column sums, gram and class sums once, its XᵀR and the
+    residual's class sums once a pass, and the residual's class sums once
+    before the first."""
+    bs, nb, c = max(widths), len(widths), num_classes
+    stats = nb * (bs + bs * bs + c * bs)
+    steps = nb * num_iter * (bs * c + c * c)
+    return itemsize * (stats + steps + c * c)
+
+
+def _execute_split_bwls(args, statics):
+    """Dispatch the class-split solve.  Module level so that a harness can
+    stand in for it."""
+    return _split_bwls_fit(*args, *statics)
 
 
 @functools.lru_cache(maxsize=None)
@@ -707,7 +875,9 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         sit above those — full ``(data, model)`` mesh → model-axis-
         collapsed mesh → the single-device ladder — each admitted PER CHIP
         against the minimum free HBM across the mesh's devices, with
-        ``last_fit_report.mesh_shape`` recording which mesh actually ran.  The fused program
+        ``last_fit_report.mesh_shape`` recording which mesh actually ran; a
+        mesh tier whose ``model`` axis is 1 splits the classes over
+        ``data`` (the module docstring).  The fused program
         always donates the SORTED design-matrix/label copies (they are
         fit-private).  ``donate=True`` additionally frees the CALLER's
         device-resident inputs as soon as their sorted copies exist —
@@ -715,6 +885,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         an exec-level OOM can no longer rebuild them for the step-down.
         The decision trail is ``self.last_fit_report``."""
         mesh = self.mesh if self.mesh is not None else current_mesh()
+        self._split_ran = None  # the _ClassSplit the tier that ran solved by
         n = nvalid if nvalid is not None else int(np.shape(labels)[0])
         n_classes = int(np.shape(labels)[1])
         # Class of each valid row: device argmax for device labels, so only
@@ -756,8 +927,11 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         )
         dtype = jnp.asarray(x[:1, :1]).dtype
         w = self.mixture_weight
+        # What the regroups of the tier that ran did: their kind and the
+        # bytes they sent between chips (the ``bwls_plan`` instant's).
+        regroups: list = []
 
-        def prep(m, labels_src):
+        def prep(m, labels_src, split=None):
             """Mesh-dependent solve context for one ladder tier.
 
             Padded row layout: sorted valid rows, then a zero tail of >=
@@ -771,6 +945,8 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             sizes (p_tot, the gather index, seg ids, the class chunk, the
             sort/regroup closures) lives in the returned context, so each
             rung of the mesh degradation ladder rebuilds its own layout.
+            With ``split`` (a ``_ClassSplit``) the rows lie as it lays them
+            out instead, each chip's classes at the head of its rows.
             """
             pad_total = n_max
             row_shard = None
@@ -779,14 +955,19 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                 pad_total += (-(n + n_max)) % d_size
                 row_shard = NamedSharding(m, P(DATA_AXIS, None))
             p_tot = n + pad_total
+            place = dest = None
+            if split is not None:
+                p_tot = m.shape[DATA_AXIS] * split.rows_out
+                place = dest = split.place
+            if dest is None:
+                dest = slice(None, n)
 
             # gather index: order for valid rows, then an out-of-range
             # index so ``mode="fill"`` writes exact zero rows for the tail
             # — the sort and the padding are a single device gather, no
             # host round-trip.
-            gather_np = np.concatenate(
-                [order, np.full(pad_total, n, dtype=order.dtype)]
-            )
+            gather_np = np.full(p_tot, n, dtype=order.dtype)
+            gather_np[dest] = order
             gather_idx = jnp.asarray(gather_np)
             valid = jnp.asarray((gather_np < n).astype(np.float32))[:, None]
 
@@ -811,7 +992,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                 if not isinstance(x, jax.Array):
                     xh = np.asarray(x)
                     out_h = np.zeros((p_tot,) + xh.shape[1:], xh.dtype)
-                    out_h[:n] = xh[order]
+                    out_h[dest] = xh[order]
                     out = jnp.asarray(out_h)
                     if row_shard is not None:
                         out = jax.device_put(out, row_shard)
@@ -821,10 +1002,13 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                     n_src = x.shape[0]
                     if n_src not in regroup_plans:
                         regroup_plans[n_src] = _RegroupPlan(
-                            order, n_src, p_tot, m.shape[DATA_AXIS]
+                            order, n_src, p_tot, m.shape[DATA_AXIS], place
                         )
                     plan = regroup_plans[n_src]
                     if plan.usable:  # else: skew guard — fallback below
+                        sent = plan.exchanged_bytes(x)
+                        trace.metrics.inc("bwls.regroup_bytes", sent)
+                        regroups.append(("all_to_all", sent))
                         return plan.apply(m, jax.device_put(x, row_shard))
                     # A survivable degradation, counted so operators (and
                     # the multichip dryrun) can see which regroup path ran.
@@ -835,6 +1019,12 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                         "optimal — taking the chunked-gather fallback",
                     )
 
+                if m is not None:
+                    # a replicated-index gather of a row-sharded operand:
+                    # every chip is sent the other chips' rows
+                    sent = (m.shape[DATA_AXIS] - 1) * x.nbytes
+                    trace.metrics.inc("bwls.regroup_bytes", sent)
+                    regroups.append(("gather", sent))
                 chunk_cols = max(1, _GATHER_COL_CHUNK // max(1, x.itemsize))
                 if x.shape[1] <= chunk_cols:
                     g = jnp.take(
@@ -872,7 +1062,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             starts = jnp.asarray(starts_np)
             # Segment ids: class of each sorted row, pad rows -> segment C.
             seg_np = np.full(p_tot, n_classes, np.int32)
-            seg_np[:n] = class_idx[order]
+            seg_np[dest] = class_idx[order]
             seg_ids = jnp.asarray(seg_np)
             counts_f = counts.astype(dtype)
 
@@ -916,7 +1106,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             # model-axis-collapsed mesh, then the single-device ladder.
             models_st, b = self._fit_mesh_ladder(
                 features, x, labels, prep, mesh, order, n, n_max,
-                n_classes, widths, dtype, donate,
+                n_classes, widths, dtype, donate, counts_np,
             )
         else:
             with trace.host("place", "solve_context"):
@@ -936,12 +1126,26 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         trace.metrics.inc("bwls.class_solves", class_solves)
         trace.metrics.inc("bwls.factor_panels", class_solves * factor_panels)
         trace.metrics.inc("bwls.classes", n_classes)
+        split = self._split_ran
         trace.instant(
             "bwls_plan", rows=n, n_max=n_max, classes=n_classes, blocks=len(widths),
             class_chunk=chunk, factor_panel=factor_panel, factor_panels=factor_panels,
             tier=self.last_fit_report.chosen if self.last_fit_report else None,
             slab_bytes=np.dtype(dtype).itemsize * chunk * n_max * bs,
             factor_bytes=np.dtype(dtype).itemsize * chunk * (bs + 1) * bs,
+            # the classes and rows each chip solves from (one device: all;
+            # a model split: every chip a share of each chunk, so no list),
+            # the rows each holds, and how the rows reached them
+            classes_by_chip=(
+                np.diff(split.bounds).tolist() if split is not None
+                else [n_classes] if mesh is None else None
+            ),
+            rows_by_chip=(
+                split.rows.tolist() if split is not None else [n] if mesh is None else None
+            ),
+            rows_padded=split.rows_out if split is not None else None,
+            regroup=regroups[0][0] if regroups else None,
+            regroup_bytes=sum(b for _kind, b in regroups),
         )
         with trace.host("finish", "model_blocks"):
             model_list, _ = split_model(models_st, None, widths)
@@ -949,7 +1153,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
 
     def _fit_mesh_ladder(
         self, features, x, labels, prep, mesh, order, n, n_max, n_classes,
-        widths, dtype, donate,
+        widths, dtype, donate, counts_np,
     ):
         """Distributed BWLS through the MESH degradation ladder: full
         ``(data, model)`` mesh → model-axis-collapsed mesh (row-sharded
@@ -958,7 +1162,9 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         its own sort/pad layout (``prep(m, ...)``), is admitted PER CHIP
         against the minimum free HBM across the mesh's devices, and a
         runtime ``RESOURCE_EXHAUSTED`` from any chip steps down one tier.
-        ``report.mesh_shape`` records which mesh actually ran."""
+        ``report.mesh_shape`` records which mesh actually ran.  A mesh whose
+        ``model`` axis is 1 splits the classes over ``data`` where there are
+        at least as many classes as chips (``split_tier``)."""
         bs, nb = max(widths), len(widths)
         d_tot = nb * bs
         it = np.dtype(dtype).itemsize
@@ -1041,6 +1247,83 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
 
                 return kmem.Tier(name, plan, run)
 
+            def split_tier(m):
+                """One fused-mesh BWLS rung whose chips solve their own
+                classes (``_class_split``, ``_split_bwls_impl``)."""
+                name = f"fused[mesh {mesh_desc(m)}]"
+                d_sz = m.shape[DATA_AXIS]
+                split = _class_split(counts_np, d_sz, n_max)
+                slots = split.slot_classes.shape[1]
+                chunk_a = max(1, min(self.class_chunk, slots))
+                # Analytic per-chip transient floor (CPU backends report
+                # temp 0): two residual carries and a block slice of a
+                # chip's rows, its class-solve slab and factor scratch, the
+                # replicated stats/models stacks and a gathered ΔW.
+                floor = it * (
+                    2 * split.rows_out * n_classes
+                    + split.rows_out * bs
+                    + chunk_a * (n_max + bs + 1) * bs
+                    + nb * (bs * bs + bs + n_classes * bs)
+                    + nb * bs * n_classes
+                    + d_sz * slots * bs
+                )
+                ctx_box: list = []
+
+                def ctx():
+                    if not ctx_box:
+                        ctx_box.append(prep(m, labels, split))
+                    return ctx_box[0]
+
+                def plan():
+                    ctx_ = ctx()
+                    budget, _worst = kmem.min_chip_budget(m)
+                    sds = jax.ShapeDtypeStruct
+                    i32 = jnp.int32
+                    row = NamedSharding(m, P(DATA_AXIS, None))
+                    rows1 = NamedSharding(m, P(DATA_AXIS))
+                    x_s = sds((ctx_.p_tot, d_tot), xdt, sharding=row)
+                    y_s = sds((ctx_.p_tot, n_classes), dtype, sharding=row)
+                    v_s = sds((ctx_.p_tot, 1), dtype, sharding=row)
+                    seg_s = sds((ctx_.p_tot,), i32, sharding=rows1)
+                    slot_s = sds((d_sz, slots), i32, sharding=row)
+                    col_s = sds((n_classes,), i32)
+                    c_f = sds((n_classes,), dtype)
+                    sc_s, nv_s = sds((), dtype), sds((), i32)
+                    return kmem.plan_program(
+                        _split_bwls_fit,
+                        x_s, y_s, v_s, seg_s, slot_s, slot_s, slot_s, col_s,
+                        c_f, c_f, nv_s, sc_s, sc_s,
+                        self.num_iter, n_max, chunk_a, n_classes, widths, m,
+                        label=f"bwls_{name}", budget=budget,
+                        min_temp_bytes=floor, mesh=m,
+                    )
+
+                def run(plan):
+                    ctx_ = ctx()
+                    report.mesh_shape = dict(m.shape)
+                    row = NamedSharding(m, P(DATA_AXIS, None))
+                    rows1 = NamedSharding(m, P(DATA_AXIS))
+                    args = (
+                        ctx_.sort_pad(x), ctx_.sort_labels(),
+                        jax.device_put(ctx_.valid_d, row),
+                        jax.device_put(ctx_.seg_ids, rows1),
+                        *(
+                            jax.device_put(t, row)
+                            for t in (split.slot_starts, split.slot_counts, split.slot_classes)
+                        ),
+                        jnp.asarray(split.columns), ctx_.counts_f,
+                        ctx_.joint_label_mean, jnp.asarray(n),
+                        jnp.asarray(self.lam, dtype),
+                        jnp.asarray(self.mixture_weight, dtype),
+                    )
+                    statics = (self.num_iter, n_max, chunk_a, n_classes, widths, m)
+                    out = _execute_split_bwls(args, statics)
+                    count_psum(_split_psum_bytes(it, widths, n_classes, self.num_iter))
+                    self._split_ran = split
+                    return out
+
+                return kmem.Tier(name, plan, run)
+
             def plan_single():
                 return kmem.MemoryPlan(
                     label="single_device",
@@ -1070,10 +1353,14 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                 inner_chosen.append(report.chosen)
                 return out
 
-            tiers = [mesh_tier(mesh)]
+            def tier_of(m):
+                splits = m.shape[MODEL_AXIS] == 1 and n_classes >= m.shape[DATA_AXIS]
+                return split_tier(m) if splits else mesh_tier(m)
+
+            tiers = [tier_of(mesh)]
             rm = reduced_mesh(mesh)
             if rm is not None:
-                tiers.append(mesh_tier(rm))
+                tiers.append(tier_of(rm))
             tiers.append(kmem.Tier("single_device", plan_single, run_single))
         # Profiler phase (core.profiler): the watermark sampler attributes
         # this solve's HBM high-water mark to "bwls_fit".  No-op when off.

@@ -310,15 +310,19 @@ def plan_chunks(images: list, nodes, desc_dim: int, vocab_size: int, mesh=None) 
     largest power of two that fits (a budget read
     live moves a little between fits; a power of two does not move with
     it), at most ``MAX_CHUNK`` and at most the bucket; with no budget to read
-    (the CPU) it is ``MAX_CHUNK``.  Under a mesh it is rounded up to the data
-    axis.  Records one ``fv_plan`` instant."""
+    (the CPU) it is ``MAX_CHUNK``.  Under a mesh the rule gives each chip of
+    the data axis what it gives one chip, reckoned against the fullest
+    chip's free bytes: the chunk is that many images a chip, at most a
+    chip's share of the bucket, times the axis.  Records one ``fv_plan``
+    instant."""
     from ..parallel.mesh import DATA_AXIS
 
     branches, _ = _branch_list(nodes)
     groups: dict = {}
     for i, img in enumerate(images):
         groups.setdefault(tuple(img.shape[:2]), []).append(i)
-    budget = kmem.hbm_budget()
+    shards = 1 if mesh is None else mesh.shape[DATA_AXIS]
+    budget = kmem.hbm_budget() if mesh is None else kmem.min_chip_budget(mesh)[0]
     index, cols, image_bytes, chunk, branch_bytes = {}, {}, {}, {}, {}
     for shape, idx in groups.items():
         c = tuple(b.cols(*shape) for b in branches)
@@ -328,10 +332,7 @@ def plan_chunks(images: list, nodes, desc_dim: int, vocab_size: int, mesh=None) 
         if budget is not None:
             fit = max(1, int(CHUNK_BUDGET_SHARE * budget) // per_image)
             fit = min(MAX_CHUNK, 1 << (fit.bit_length() - 1))
-        fit = min(fit, len(idx))
-        if mesh is not None:
-            d = mesh.shape[DATA_AXIS]
-            fit = -(-fit // d) * d
+        fit = min(fit, -(-len(idx) // shards)) * shards
         index[shape] = np.asarray(idx)
         cols[shape], image_bytes[shape], chunk[shape] = c, per_image, fit
         branch_bytes[shape] = each
@@ -346,6 +347,7 @@ def plan_chunks(images: list, nodes, desc_dim: int, vocab_size: int, mesh=None) 
             for k, b in enumerate(branches)
         },
         budget=budget,
+        shards=shards,
     )
     return plan
 

@@ -413,11 +413,16 @@ def run(
     test: LabeledImages,
     mesh=None,
 ) -> dict:
-    """With ``mesh``: every chunk is row-sharded over the data axis and the
-    2·2·descDim·vocabSize-feature class-weighted solve runs distributed —
-    row-sharded population grams with ICI all-reduce and model-axis-sharded
-    batched class solves (the reference runs this over partitioned RDDs +
-    treeReduce, ImageNetSiftLcsFV.scala:150-195).
+    """With ``mesh``: every chunk is row-sharded over the data axis, each
+    chip its share of the one-chip chunk (SIFT's assembly and the Fisher
+    vector's statistics as their kernels under ``shard_map`` where only
+    ``data`` splits the mesh), the sampled columns gathered to every chip so
+    that PCA and EM run replicated, and the 2·2·descDim·vocabSize-feature
+    class-weighted solve runs distributed — row-sharded population grams
+    summed across the chips, and with a ``model`` axis of 1 each chip
+    solving its own classes from its own rows (``solvers/weighted.py``; the
+    reference runs this over partitioned RDDs + treeReduce,
+    ImageNetSiftLcsFV.scala:150-195).
 
     The run is one root span ``fit``; its stages (``stage_timer``) tile it
     but for glue, and each occurs once a fit.  Beside the errors the results

@@ -42,6 +42,7 @@ PROGRAMS = {
     r"^jit__prepare_rows": "keystone_tpu.workloads.imagenet_sift_lcs_fv",
     r"^jit__fused_bwls": "keystone_tpu.solvers.weighted",
     r"^jit__class_solves": "keystone_tpu.solvers.weighted",
+    r"^jit__split_bwls_impl$": "keystone_tpu.solvers.weighted",
     r"^jit__block_apply": "keystone_tpu.solvers.block",
     r"^jit__block_moments$": "keystone_tpu.solvers.block",
     r"^jit__make_block$": "keystone_tpu.solvers.block",
@@ -76,6 +77,10 @@ STAGES = {
         "keystone_tpu.workloads.imagenet_sift_lcs_fv",
         ["sample_descriptors", "featurize", "featurize_test", "solve", "eval"],
     ),
+    "imagenet_fv_mesh": (
+        "keystone_tpu.workloads.imagenet_sift_lcs_fv",
+        ["featurize", "featurize_test", "solve", "eval"],
+    ),
     "timit_rf_full": (  # drives ``timit.run`` itself
         "keystone_tpu.workloads.timit", ["featurize", "solve", "eval"],
     ),
@@ -88,6 +93,7 @@ COUNTERS = {
     "fv.descriptor_passes": "keystone_tpu.workloads.voc_sift_fisher",
     "gmm.iterations": "keystone_tpu.workloads.voc_sift_fisher",
     "mesh.psum_bytes": "keystone_tpu.parallel.collectives",
+    "bwls.regroup_bytes": "keystone_tpu.solvers.weighted",
     "bcd.block_rows_made": "keystone_tpu.solvers.block",
     "bcd.block_rows_applied": "keystone_tpu.solvers.block",
 }

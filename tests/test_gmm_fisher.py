@@ -283,21 +283,31 @@ class TestFvPallasKernel:
         )
 
     @pytest.mark.parametrize(
-        "backend,one_device,masked,want",
+        "backend,split,masked,images,shards,want",
         [
-            ("tpu", True, False, "kernel"),  # VOC's cell, a served request
-            ("tpu", False, False, "xla"),  # a mesh: a custom call is not partitioned
-            ("tpu", True, True, "xla"),  # the kernel knows prefix counts only
-            ("cpu", True, False, "xla"),  # every tier-1 test
-            ("gpu", True, False, "xla"),
+            # VOC's cell, a served request
+            pytest.param("tpu", (), False, 64, 1, "kernel", id="tpu-True-False-kernel"),
+            # a chip's images under shard_map
+            pytest.param("tpu", ("data",), False, 256, 4, "kernel", id="tpu-data-False-256-4-kernel"),
+            # no equal shares to map
+            pytest.param("tpu", ("data",), False, 250, 4, "xla", id="tpu-data-False-250-4-xla"),
+            # a model split: the kernel is not partitioned over it
+            pytest.param("tpu", ("data", "model"), False, 256, 4, "xla", id="tpu-False-False-xla"),
+            # spread over no named mesh
+            pytest.param("tpu", ("?",), False, 256, 1, "xla", id="tpu-spread-False-256-1-xla"),
+            # the kernel knows prefix counts only
+            pytest.param("tpu", (), True, 64, 1, "xla", id="tpu-True-True-xla"),
+            # every tier-1 test
+            pytest.param("cpu", (), False, 64, 1, "xla", id="cpu-True-False-xla"),
+            pytest.param("gpu", (), False, 64, 1, "xla", id="gpu-True-False-xla"),
         ],
     )
-    def test_fv_form_rule(self, backend, one_device, masked, want):
-        """Which form for which (backend, placement, mask); no shape enters
+    def test_fv_form_rule(self, backend, split, masked, images, shards, want):
+        """Which form for which (backend, split, mask); no width enters
         (ops/fisher.py's table: the XLA form won at none on the chip)."""
         from keystone_tpu.ops.fisher import fv_form
 
-        assert fv_form(backend, one_device, masked) == want
+        assert fv_form(backend, split, masked, images, shards) == want
 
     def test_fv_form_sees_mesh_and_mask(self, rng, mesh8):
         """What the node hands the rule: it sees a sharded input and a mask,
@@ -313,12 +323,66 @@ class TestFvPallasKernel:
         sharded = jax.device_put(descs, row_sharding(mesh8))
         assert _on_one_device(descs) and not _on_one_device(sharded)
         before = trace.metrics.get("fv_form.xla")
+        kernel_before = trace.metrics.get("fv_form.kernel")
         alone = np.asarray(node_(descs))
         np.testing.assert_allclose(np.asarray(node_(sharded)), alone, atol=1e-5)
         masked = np.asarray(node_(descs, mask=jnp.ones(descs.shape[::2], jnp.float32)))
         np.testing.assert_allclose(masked, alone, atol=1e-5)
         assert trace.metrics.get("fv_form.xla") == before + 3
-        assert trace.metrics.get("fv_form.kernel") == 0
+        assert trace.metrics.get("fv_form.kernel") == kernel_before
+
+    def test_sharded_kernel_form_equals_one_device(self, rng, devices):
+        """The statistics' kernel under ``shard_map`` over a 4-way data axis,
+        the mixture replicated (Pallas in the interpreter), against the
+        one-device kernel form, row for row."""
+        from keystone_tpu.parallel.mesh import make_mesh, row_sharding
+
+        mesh = make_mesh(data=4, model=1, devices=devices[:4])
+        x, _, means, variances, weights = self._case(rng, n=8, cols=300, ragged=False)
+        node_ = FisherVector(GaussianMixtureModel(means, variances, weights))
+        descs = jnp.asarray(np.swapaxes(x, 1, 2))
+        one = np.asarray(node_._kernel_form(descs, interpret=True))
+        sharded = jax.jit(lambda b: node_._sharded_kernel_form(b, mesh, interpret=True))(
+            jax.device_put(descs, row_sharding(mesh))
+        )
+        assert sharded.sharding.spec == row_sharding(mesh).spec
+        assert {s.data.shape for s in sharded.addressable_shards} == {(2, 24, 16)}
+        # a ulp apart where the gradients' assembly fuses otherwise under jit
+        np.testing.assert_allclose(np.asarray(sharded), one, rtol=0, atol=1e-6 * np.abs(one).max())
+
+    @pytest.mark.parametrize(
+        "layout,form,shards", [((4, 1), "kernel", 4), ((2, 2), "xla", 1)], ids=["data", "model"]
+    )
+    def test_form_follows_the_split(self, rng, devices, monkeypatch, layout, form, shards):
+        """What ``__call__`` does under a mesh, the rule asked as on a TPU:
+        a data split takes the kernel under ``shard_map``, a model split the
+        XLA form; the counter and the instant say which."""
+        from keystone_tpu.ops import fisher
+        from keystone_tpu.parallel.mesh import make_mesh, row_sharding
+
+        rule = fisher.fv_form
+        monkeypatch.setattr(fisher, "fv_form", lambda _b, *a: rule("tpu", *a))
+        kernel = FisherVector._kernel_form
+        monkeypatch.setattr(
+            FisherVector, "_kernel_form", lambda self, b, interpret=True: kernel(self, b, True)
+        )
+        mesh = make_mesh(*layout, devices=devices[:4])
+        x, _, means, variances, weights = self._case(rng, n=8, cols=300, ragged=False)
+        node_ = FisherVector(GaussianMixtureModel(means, variances, weights))
+        descs = jnp.asarray(np.swapaxes(x, 1, 2))
+        before = trace_metrics_get(f"fv_form.{form}")
+        got = np.asarray(jax.jit(node_.__call__)(jax.device_put(descs, row_sharding(mesh))))
+        assert trace_metrics_get(f"fv_form.{form}") == before + 1
+        from keystone_tpu.core import trace
+
+        last = [e for e in trace.flight_events() if e["name"] == "fv_form"][-1]["args"]
+        assert (last["form"], last["shards"]) == (form, shards)
+        assert last["split"] == ("data" if layout[1] == 1 else "data,model")
+        want = kernel(node_, descs, True) if form == "kernel" else node_._xla_form(descs)
+        # a ulp apart where the gradients' assembly fuses otherwise under jit;
+        # GSPMD's partitioned sums of the XLA form run in another order
+        atol = (1e-6 if form == "kernel" else 1e-5) * np.abs(np.asarray(want)).max()
+        np.testing.assert_allclose(got, np.asarray(want), rtol=0, atol=atol)
 
     def test_fv_form_counter_moves(self, rng):
         """``fv_form.<form>`` counts a traced program, not its calls, and the
@@ -329,10 +393,19 @@ class TestFvPallasKernel:
         node_ = FisherVector(GaussianMixtureModel(means, variances, weights))
         descs = jnp.asarray(np.swapaxes(x, 1, 2))
         before = trace.metrics.get("fv_form.xla")
+        kernel_before = trace.metrics.get("fv_form.kernel")
         fn = jax.jit(node_.__call__)
         fn(descs)
         fn(descs)
         assert trace.metrics.get("fv_form.xla") == before + 1
-        assert trace.metrics.get("fv_form.kernel") == 0
+        assert trace.metrics.get("fv_form.kernel") == kernel_before
         last = [e for e in trace.flight_events() if e["name"] == "fv_form"][-1]
-        assert last["args"] == {"form": "xla", "images": 3, "cols": 40, "d": 24, "k": 8}
+        assert last["args"] == {
+            "form": "xla", "images": 3, "cols": 40, "d": 24, "k": 8, "shards": 1, "split": "",
+        }
+
+
+def trace_metrics_get(name: str) -> float:
+    from keystone_tpu.core import trace
+
+    return trace.metrics.get(name)

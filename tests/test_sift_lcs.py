@@ -281,21 +281,33 @@ class TestAssemblyForms:
         assert not got[1].any()
 
     @pytest.mark.parametrize(
-        "backend,one_device,dtype,images,fx,want",
+        "backend,split,dtype,images,fx,shards,want",
         [
-            ("tpu", True, jnp.bfloat16, 64, 160, "kernel"),
-            ("tpu", True, jnp.bfloat16, 32, 79, "kernel"),
-            ("cpu", True, jnp.bfloat16, 64, 160, "xla"),  # Mosaic: TPU only
-            ("tpu", False, jnp.bfloat16, 64, 160, "xla"),  # a mesh: not partitioned
-            ("tpu", True, jnp.float32, 64, 160, "xla"),  # two bf16 rows a word
-            ("tpu", True, jnp.bfloat16, 1, 160, "xla"),  # byte tiles of 32 images
-            ("tpu", True, jnp.bfloat16, 48, 160, "xla"),
-            ("tpu", True, jnp.bfloat16, 64, 2000, "xla"),  # a frame row over VMEM
-            ("tpu", True, jnp.bfloat16, 64, 0, "xla"),  # no frames at all
+            pytest.param("tpu", (), jnp.bfloat16, 64, 160, 1, "kernel", id="tpu-True-bfloat16-64-160-kernel"),
+            pytest.param("tpu", (), jnp.bfloat16, 32, 79, 1, "kernel", id="tpu-True-bfloat16-32-79-kernel"),
+            # Mosaic: TPU only
+            pytest.param("cpu", (), jnp.bfloat16, 64, 160, 1, "xla", id="cpu-True-bfloat16-64-160-xla"),
+            # 64 images a chip under shard_map
+            pytest.param("tpu", ("data",), jnp.bfloat16, 256, 160, 4, "kernel", id="tpu-data-bfloat16-256-160-kernel"),
+            # 16 a chip: no byte tile
+            pytest.param("tpu", ("data",), jnp.bfloat16, 64, 160, 4, "xla", id="tpu-data-bfloat16-64-160-xla"),
+            # a model split: the kernel is not partitioned over it
+            pytest.param("tpu", ("data", "model"), jnp.bfloat16, 256, 160, 4, "xla", id="tpu-False-bfloat16-64-160-xla"),
+            # spread over no named mesh
+            pytest.param("tpu", ("?",), jnp.bfloat16, 64, 160, 1, "xla", id="tpu-spread-bfloat16-64-160-xla"),
+            # two bf16 rows a word
+            pytest.param("tpu", (), jnp.float32, 64, 160, 1, "xla", id="tpu-True-float32-64-160-xla"),
+            # byte tiles of 32 images
+            pytest.param("tpu", (), jnp.bfloat16, 1, 160, 1, "xla", id="tpu-True-bfloat16-1-160-xla"),
+            pytest.param("tpu", (), jnp.bfloat16, 48, 160, 1, "xla", id="tpu-True-bfloat16-48-160-xla"),
+            # a frame row over VMEM
+            pytest.param("tpu", (), jnp.bfloat16, 64, 2000, 1, "xla", id="tpu-True-bfloat16-64-2000-xla"),
+            # no frames at all
+            pytest.param("tpu", (), jnp.bfloat16, 64, 0, 1, "xla", id="tpu-True-bfloat16-64-0-xla"),
         ],
     )
-    def test_sift_form_rule(self, backend, one_device, dtype, images, fx, want):
-        assert sift_form(backend, one_device, dtype, images, fx) == want
+    def test_sift_form_rule(self, backend, split, dtype, images, fx, shards, want):
+        assert sift_form(backend, split, dtype, images, fx, shards) == want
 
     def test_sift_form_counter_moves(self, rng):
         """``sift_form.<form>`` counts a traced program, not its calls, and
@@ -304,16 +316,55 @@ class TestAssemblyForms:
         ext = SIFTExtractor(scale_step=0, compute_dtype=jnp.bfloat16)
         fn = jax.jit(ext.__call__)
         before = trace.metrics.get("sift_form.xla")
+        kernel_before = trace.metrics.get("sift_form.kernel")
         for _ in range(3):
             fn(_textured(rng, 32, 30, 46))
         assert trace.metrics.get("sift_form.xla") == before + 1
-        assert trace.metrics.get("sift_form.kernel") == 0
+        assert trace.metrics.get("sift_form.kernel") == kernel_before
         last = [e for e in trace.flight_events() if e["name"] == "sift_form"][-1]
         assert last["args"] == {
             "form": "xla", "images": 32, "scales": 3,
-            "frames": ext.num_descriptors(30, 46),
+            "frames": ext.num_descriptors(30, 46), "shards": 1, "split": "",
             "smooth": "banded", "smooth_rows": "30x30", "smooth_cols": "46x46",
         }
+
+
+class TestShardedAssembly:
+    """The kernel form of the assembly under ``shard_map`` over a 4-way data
+    axis (Pallas in the interpreter): each chip its own 32 images."""
+
+    @pytest.mark.parametrize(
+        "layout,form,shards", [((4, 1), "kernel", 4), ((2, 2), "xla", 1)], ids=["data", "model"]
+    )
+    def test_form_follows_the_split(self, rng, devices, monkeypatch, layout, form, shards):
+        """What ``__call__`` does under a mesh, the rule asked as on a TPU:
+        a data split takes the kernel under ``shard_map``, each chip's
+        descriptors those of the one-device kernel form row for row and left
+        on its chip; a model split takes the XLA form.  The counter and the
+        instant say which."""
+        from keystone_tpu.parallel.mesh import make_mesh, row_sharding
+
+        rule = sift_ops.sift_form
+        monkeypatch.setattr(sift_ops, "sift_form", lambda _b, *a: rule("tpu", *a))
+        kernel = SIFTExtractor._kernel_form
+        monkeypatch.setattr(
+            SIFTExtractor, "_kernel_form", lambda self, b, interpret=True: kernel(self, b, True)
+        )
+        mesh = make_mesh(*layout, devices=devices[:4])
+        ext = SIFTExtractor(scale_step=0, compute_dtype=jnp.bfloat16)
+        batch = _textured(rng, 128, 30, 46)
+        before = trace.metrics.get(f"sift_form.{form}")
+        out = jax.jit(ext.__call__)(jax.device_put(batch, row_sharding(mesh)))
+        if form == "kernel":
+            frames = ext.num_descriptors(30, 46)
+            assert {s.data.shape for s in out.addressable_shards} == {(32, 128, frames)}
+        got = np.asarray(out)
+        assert trace.metrics.get(f"sift_form.{form}") == before + 1
+        last = [e for e in trace.flight_events() if e["name"] == "sift_form"][-1]["args"]
+        assert (last["form"], last["shards"]) == (form, shards)
+        assert last["split"] == ("data" if layout[1] == 1 else "data,model")
+        want = kernel(ext, batch, True) if form == "kernel" else ext._xla_form(batch)
+        np.testing.assert_array_equal(got, np.asarray(want))
 
 
 def naive_lcs(img, stride, stride_start, sub):

@@ -650,3 +650,129 @@ def test_pca_svd_at_the_published_sample(one_chip, samples):
         assert held > 10e9, held
     else:
         assert held < 2.5e9, held
+
+
+def _replicated_like(tree, mesh):
+    """``tree``'s arrays as shapes replicated on every chip of ``mesh``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    everywhere = NamedSharding(mesh, P())
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=everywhere), tree)
+
+
+def test_mesh_sift_chunk_compiles_at_published_widths(data_mesh, monkeypatch):
+    """`fv_common._describe_chunk` at 256 images of 375x500 over four chips
+    (`imagenet_sift_lcs_fv_16_mesh4`), the kernel form chosen as on the chip:
+    the assembly's kernel under ``shard_map`` a scale, nothing exchanged, a
+    chip writing its 64 images' bytes and no array of all 256."""
+    from keystone_tpu.ops import sift
+    from keystone_tpu.parallel.mesh import row_sharding
+    from keystone_tpu.workloads import fv_common
+
+    rule = sift.sift_form
+    monkeypatch.setattr(sift, "sift_form", lambda _b, *a: rule("tpu", *a))
+    node_ = sift.SIFTExtractor(scale_step=1, compute_dtype=jnp.bfloat16)
+    frames = node_.num_descriptors(375, 500)
+    flat = jax.ShapeDtypeStruct((256, 375 * 500 * 3), jnp.uint8, sharding=row_sharding(data_mesh))
+    fv_common._describe_chunk.clear_cache()
+    try:
+        compiled = fv_common._describe_chunk.lower(node_, flat, image_shape=(375, 500, 3)).compile()
+    finally:
+        fv_common._describe_chunk.clear_cache()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert not _collectives(text)
+    assert f"u8[64,128,{frames}]" in text
+    assert "u8[256," not in text and "[256,375,500" not in text
+    assert compiled.output_shardings.spec == row_sharding(data_mesh).spec
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
+    print("mesh sift chunk: temp a chip", mem.temp_size_in_bytes)
+
+
+def test_mesh_two_branch_encode_compiles_at_published_widths(data_mesh, monkeypatch):
+    """Both branches' PCA and Fisher vectors of a 256-image chunk over four
+    chips, the mixtures and projections replicated: the statistics' kernel
+    under ``shard_map``, nothing exchanged, a chip's 64 rows of features."""
+    from keystone_tpu.ops import fisher
+    from keystone_tpu.parallel.mesh import row_sharding
+    from keystone_tpu.solvers.gmm import GaussianMixtureModel
+    from keystone_tpu.solvers.pca import BatchPCATransformer
+    from keystone_tpu.workloads import fv_common
+    from keystone_tpu.workloads.imagenet_sift_lcs_fv import branch_projection
+
+    rule = fisher.fv_form
+    monkeypatch.setattr(fisher, "fv_form", lambda _b, *a: rule("tpu", *a))
+    rng = np.random.default_rng(0)
+    d, k = 64, 16
+    chains, descs = [], []
+    for branch, dtype in zip(_imagenet_branches(), (jnp.uint8, jnp.float32)):
+        pca = BatchPCATransformer(rng.normal(size=(branch.dim, d)).astype(np.float32))
+        gmm = GaussianMixtureModel(
+            rng.normal(size=(d, k)).astype(np.float32),
+            rng.uniform(0.5, 2.0, (d, k)).astype(np.float32),
+            rng.dirichlet(np.ones(k)).astype(np.float32),
+        )
+        chains.append((branch_projection(branch.name, pca, jnp.zeros((branch.dim,), jnp.float32)), gmm))
+        descs.append(jax.ShapeDtypeStruct(
+            (256, branch.dim, branch.cols(375, 500)), dtype, sharding=row_sharding(data_mesh)
+        ))
+    fv_common._encode_chunk.clear_cache()
+    try:
+        compiled = fv_common._encode_chunk.lower(
+            _replicated_like(tuple(chains), data_mesh), tuple(descs)
+        ).compile()
+    finally:
+        fv_common._encode_chunk.clear_cache()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not _collectives(text)
+    assert "f32[64,4096]" in text and "f32[256,4096]" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3 << 30, mem.temp_size_in_bytes
+    print("mesh two-branch encode: temp a chip", mem.temp_size_in_bytes)
+
+
+def test_class_split_solve_compiles_at_published_classes(data_mesh):
+    """The class-split weighted solve of ``imagenet_fv_fit_mesh4``: 16,000
+    rows of 4,096 over four chips, 1,000 classes of 16 rows, 250 a chip.  A
+    chip holds its 4,016 rows and no operand of all of them, the population
+    statistics cross the chips as all-reduces, the model's columns as one
+    all-gather of every chip's 250, and a chip's sweep has the one-chip scratch of
+    sixteen systems; what a chip holds stays well inside its 16 GB."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from keystone_tpu.parallel.mesh import DATA_AXIS
+    from keystone_tpu.solvers import weighted
+
+    counts = np.full(1000, 16)
+    split = weighted._class_split(counts, 4, 16)
+    assert split.rows_out == 4016 and split.slot_classes.shape == (4, 250)
+    p, d, c = 4 * split.rows_out, 4096, 1000
+    row = NamedSharding(data_mesh, P(DATA_AXIS, None))
+    sds = jax.ShapeDtypeStruct
+    compiled = weighted._split_bwls_fit.lower(
+        sds((p, d), jnp.float32, sharding=row), sds((p, c), jnp.float32, sharding=row),
+        sds((p, 1), jnp.float32, sharding=row),
+        sds((p,), jnp.int32, sharding=NamedSharding(data_mesh, P(DATA_AXIS))),
+        *[sds((4, 250), jnp.int32, sharding=row)] * 3, sds((c,), jnp.int32),
+        sds((c,), jnp.float32), sds((c,), jnp.float32), sds((), jnp.int32),
+        sds((), jnp.float32), sds((), jnp.float32),
+        1, 16, 16, c, (d,), data_mesh,
+    ).compile()
+    text = compiled.as_text()
+    assert "jit__split_bwls_impl" in text
+    kinds = _collectives(text)
+    reduced = [kind for op, kind in kinds if op == "all-reduce"]
+    assert any("f32[4096,4096]" in kind for kind in reduced), reduced
+    gathered = [kind for op, kind in kinds if op == "all-gather"]
+    assert gathered and all("[4,250,4096]" in kind for kind in gathered), gathered
+    assert not [op for op, _kind in kinds if op == "all-to-all"]
+    assert f"[{p}," not in text and "f32[4016,4096]" in text
+    assert re.search(r"f32\[16,4097,4096\]", text)
+    mem = compiled.memory_analysis()
+    per_chip = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    print("class-split solve a chip: args", mem.argument_size_in_bytes, "temp", mem.temp_size_in_bytes)
+    assert per_chip < 4e9, per_chip

@@ -61,7 +61,7 @@ def probe_shape(k, d, n, cols, blocks, reps, rng):
     rec = {
         "vocab": k, "d": d, "images": n, "cols": cols,
         "stream_ratio": 7 * k / d,
-        "rule": fisher.fv_form("tpu", d, k, True, False),
+        "rule": fisher.fv_form("tpu", (), False),
     }
     forms = {"xla": jax.jit(node._xla_form)}
     for block in blocks:

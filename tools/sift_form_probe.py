@@ -59,7 +59,7 @@ def probe_shape(n, h, w, scale_step, reps, floor_ms, rng):
         "images": n, "h": h, "w": w, "scale_step": scale_step,
         "descriptors": node.num_descriptors(h, w),
         "rule": sift.sift_form(
-            "tpu", True, jnp.bfloat16, n, max(len(xs) for _b, _ys, xs in node._grids(h, w))
+            "tpu", (), jnp.bfloat16, n, max(len(xs) for _b, _ys, xs in node._grids(h, w))
         ),
     }
     rule = sift.sift_form
